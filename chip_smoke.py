@@ -1,0 +1,658 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that parsec_tpu still starts on the chip.
+
+ONE process drives the runtime's three device paths once, through the entry
+points a user calls, at full width, cheapest phase first:
+
+  store     the persistent executor store round-trips a one-device program
+            (on a many-chip host too) and a mesh program over every chip
+  flash     ring_attention(impl="flash") + FFN step, S=16384/H=4/dh=128/F=2048,
+            against impl="xla"; the Pallas kernel must be compiled by Mosaic
+  block     build_transformer_block's body_tpu through the host runtime
+            (tile_s=1024, dh=128) against reference_block
+  host      parsec.init -> Context: DTD GEMM n=2048/nb=512 (insert_gemm_dtd)
+            against numpy, PTG build_potrf n=4096/nb=512 via add_taskpool/wait;
+            every task on a tpuN module, none on the inline CPU module
+  panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
+  flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
+            NB=1024, potrf.trsm_hook=gemm, input generated on device, three
+            passes, random-probe residual <= 1e-4, peak HBM printed
+
+and, when more than one chip is visible, the multi-chip checks:
+
+  ring      ring_attention(impl="flash", causal=True) over a `seq` mesh of
+            every chip, against the XLA fold
+  ici       measure_ici_latency: payload resident on two different chips,
+            wire bytes << payload
+  sharded   run_sharded on the wavefront executor; the flagship PanelExecutor
+            state sharded P("rows") over the mesh at N=40960: one shard per
+            chip, residual <= 1e-4, per-chip peak HBM printed
+
+serving/decode.py computes every step in host numpy on a d_model=32 toy: it has
+no device path, so there is nothing of it to smoke here (ROADMAP R1 ports it).
+
+The chip is REQUIRED: with no TPU the script exits non-zero, names the device
+it did not find, and prints no result. `--dry-run-cpu[=N]` states a CPU dry run
+instead (tiny sizes, Pallas interpreted, N virtual devices, every line labelled
+platform: cpu) — the way to debug this file without a chip. One process per
+chip: nothing here starts a child.
+
+Compile cache: through the one resolution in parsec_tpu/utils/compile_cache.py —
+JAX_COMPILATION_CACHE_DIR where set, else the fixed <checkout>/.xla_cache. Run
+the script twice against one directory and the second run reports fewer
+backend compiles and executor-store hits instead of misses.
+
+The last line of stdout is one JSON object:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+and the exit code is 0 iff every phase passed.
+"""
+
+import argparse
+import gc
+import importlib.metadata
+import json
+import os
+import sys
+import time
+import traceback
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
+            panels=(8192, 1024), flagship=(40960, 1024),
+            flash=dict(S=16384, H=4, dh=128, F=2048),
+            block=dict(H=2, T=2, TS=1024, DH=128, F=512),
+            wavefront=(2048, 256), mesh_shape=(1024, 1024))
+DRY = dict(dtd=(256, 64), potrf_host=(256, 64),
+           panels=(256, 64), flagship=(256, 64),
+           flash=dict(S=256, H=2, dh=16, F=64),
+           block=dict(H=2, T=2, TS=64, DH=16, F=64),
+           wavefront=(256, 64), mesh_shape=(64, 64))
+
+# f32 matmuls take bf16 MXU passes on the chip unless
+# ops.matmul_precision=highest: comparisons against an f32/f64 reference
+# of a default-precision result get this relative tolerance. The
+# diagonally-dominant factorization residuals are far tighter (1e-4).
+BF16_TOL = 3e-2
+
+
+def say(phase, **kv):
+    print(f"[{phase}] " + " ".join(f"{k}={v}" for k, v in kv.items()),
+          flush=True)
+
+
+def rel(a, b):
+    import numpy as np
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-300))
+
+
+def tile_devices(tiles):
+    """Ids of the devices a collection's tiles sit on."""
+    import jax
+    return sorted({d.id for t in tiles if isinstance(t, jax.Array)
+                   for d in t.devices()})
+
+
+def require(cond, what):
+    if not cond:
+        raise AssertionError(what)
+
+
+# --------------------------------------------------------------------------
+# phases — each takes the run's sizes, raises on a failed check
+# --------------------------------------------------------------------------
+
+def _mesh_double(x):          # module-level: stable code fingerprint
+    return x @ x.T + 1.0
+
+
+def phase_store(sz, on_chip):
+    """Executor store round trip IN this process: compile+save (or load,
+    on a warm directory), drop the in-process callables, resolve again —
+    that second resolve must be a store hit that RUNS. A one-device
+    program (loading it over every device of the host is what broke the
+    store) and a mesh program on its mesh. Not here: a one-device
+    program compiled for a NON-default chip — libtpu 0.0.34 reloads it
+    onto its first chip; no caller persists one, and the store raises
+    at load if it ever sees that."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from parsec_tpu.compiled.spmd import compile_with_plan, make_mesh
+    from parsec_tpu.utils import compile_cache as cc
+
+    require(cc.executor_store() is not None, "executor store not enabled")
+    devs = jax.devices()
+    m, n = sz["mesh_shape"]
+    x_h = np.arange(m * n, dtype=np.float32).reshape(m, n) / (m * n)
+    ref = x_h @ x_h.T + 1.0
+
+    def one_device():
+        return cc.cached_jit(
+            _mesh_double, key=("smoke_store_1dev", (m, n)),
+            example_args=(jax.ShapeDtypeStruct((m, n), jnp.float32),))
+
+    mesh = make_mesh(len(devs), axis="rows")
+    sh = NamedSharding(mesh, P("rows"))
+
+    def on_mesh():
+        return compile_with_plan(
+            _mesh_double, mesh=mesh, in_shardings=(sh,), out_shardings=sh,
+            example_args=(jax.ShapeDtypeStruct((m, n), jnp.float32),),
+            key=("smoke_store_mesh", (m, n)))
+
+    for name, build, put in (
+            ("one_device", one_device, lambda: jnp.asarray(x_h)),
+            (f"mesh{len(devs)}", on_mesh, lambda: jax.device_put(x_h, sh))):
+        first = np.asarray(build()(put()))
+        s0 = cc.cache_stats()
+        cc.reset_in_process_cache()          # "a second process"
+        again = build()(put())
+        s1 = cc.cache_stats()
+        require(s1["store_hits"] == s0["store_hits"] + 1,
+                f"{name}: second resolve was not a store hit ({s0} -> {s1})")
+        require(np.array_equal(first, np.asarray(again)),
+                f"{name}: reloaded program disagrees with the compiled one")
+        err = rel(first, ref)
+        require(err <= BF16_TOL, f"{name}: rel err {err:.2e}")
+        say("store", program=name, reload="hit", rel_err=f"{err:.1e}",
+            out_devices=sorted(d.id for d in again.devices()))
+    require(cc.cache_stats()["store_errors"] == 0, "store load errors")
+
+
+def _attention_step(sz, n_dev, causal, on_chip, phase):
+    """The bench's transformer step (ring attention + FFN) with the
+    Pallas kernel against the same step with the XLA fold, on a `seq`
+    mesh of ``n_dev`` devices."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from parsec_tpu.compiled.ring_attention import ring_attention
+    from parsec_tpu.compiled.spmd import make_mesh
+
+    import numpy as np
+    f = sz["flash"]
+    S, H, dh, F = f["S"], f["H"], f["dh"], f["F"]
+    mesh = make_mesh(n_dev, axis="seq")
+    sh = NamedSharding(mesh, P("seq"))
+    rng = np.random.default_rng(0)
+    q, k, v = (jax.device_put(
+        rng.standard_normal((S, H, dh)).astype(np.float32), sh)
+        for _ in range(3))
+    W1 = jnp.asarray(rng.standard_normal((H * dh, F)) / 32, jnp.float32)
+    W2 = jnp.asarray(rng.standard_normal((F, H * dh)) / 32, jnp.float32)
+
+    def step(q, impl):
+        o = ring_attention(q, k, v, mesh, axis="seq", impl=impl,
+                           causal=causal)
+        x = o.reshape(o.shape[0], -1)
+        h = jnp.maximum(x @ W1, 0.0)
+        return (x + h @ W2).reshape(q.shape)
+
+    flash = jax.jit(lambda q: step(q, "flash"))
+    text = flash.lower(q).as_text()
+    mosaic = "tpu_custom_call" in text
+    require(mosaic == on_chip,
+            "Pallas kernel was " + ("NOT " if on_chip else "") +
+            "compiled by Mosaic (tpu_custom_call " +
+            ("absent from" if on_chip else "present in") +
+            " the lowered step)")
+    t0 = time.perf_counter()
+    y_f = jax.block_until_ready(flash(q))
+    t_f = time.perf_counter() - t0
+    y_x = jax.block_until_ready(jax.jit(lambda q: step(q, "xla"))(q))
+    require(y_f.shape == q.shape, f"shape {y_f.shape}")
+    require(bool(jnp.isfinite(y_f).all()), "non-finite output")
+    err = rel(y_f, y_x)
+    require(err <= BF16_TOL, f"flash vs xla rel err {err:.2e}")
+    say(phase, seq=q.shape[0], heads=q.shape[1], d_head=q.shape[2],
+        devices=n_dev, causal=causal,
+        kernel="mosaic" if mosaic else "interpreted",
+        first_call_s=f"{t_f:.1f}", flash_vs_xla_rel_err=f"{err:.1e}",
+        out_devices=sorted(d.id for d in y_f.devices()))
+
+
+def phase_flash(sz, on_chip):
+    _attention_step(sz, 1, False, on_chip, "flash")
+
+
+def phase_ring(sz, on_chip):
+    import jax
+    _attention_step(sz, len(jax.devices()), True, on_chip, "ring")
+
+
+def phase_block(sz, on_chip):
+    """The transformer block's TPU incarnation (body_tpu: the Pallas
+    kernel per KV tile, merged into the streaming-softmax chain) through
+    the host runtime."""
+    import jax
+    import numpy as np
+    import parsec_tpu as parsec
+    from parsec_tpu.algorithms.transformer import (build_transformer_block,
+                                                   reference_block)
+    from parsec_tpu.data import LocalCollection
+    from parsec_tpu.ops.flash_attention import flash_attention
+
+    b = sz["block"]
+    H, T, TS, DH, F = b["H"], b["T"], b["TS"], b["DH"], b["F"]
+    D = H * DH
+    rng = np.random.default_rng(1)
+    q, k, v = (rng.standard_normal((H, T * TS, DH)).astype(np.float32)
+               for _ in range(3))
+    Wo = (rng.standard_normal((D, D)) / np.sqrt(D)).astype(np.float32)
+    W1 = (rng.standard_normal((D, F)) / np.sqrt(D)).astype(np.float32)
+    W2 = (rng.standard_normal((F, D)) / np.sqrt(F)).astype(np.float32)
+
+    def tiles(x):
+        return {(h, i): x[h, i * TS:(i + 1) * TS]
+                for h in range(H) for i in range(T)}
+
+    Y = LocalCollection("Y", {(i,): None for i in range(T)})
+    tp = build_transformer_block(
+        LocalCollection("Q", tiles(q)), LocalCollection("K", tiles(k)),
+        LocalCollection("V", tiles(v)), Y, H, T, TS, DH, Wo, W1, W2)
+    tile = jax.ShapeDtypeStruct((TS, 1, DH), np.float32)
+    mosaic = "tpu_custom_call" in jax.jit(
+        lambda a, b_, c: flash_attention(a, b_, c, return_lse=True)
+    ).lower(tile, tile, tile).as_text()
+    require(mosaic == on_chip, f"tile kernel mosaic={mosaic}")
+    ctx = parsec.init(nb_cores=4)
+    try:
+        ctx.start()
+        ctx.add_taskpool(tp)
+        require(ctx.wait(timeout=600), "transformer block did not finish")
+        stats = ctx.devices.dump_statistics()
+    finally:
+        parsec.fini(ctx)
+    got = np.concatenate([np.asarray(Y.data_of((i,))) for i in range(T)])
+    err = rel(got, reference_block(q, k, v, Wo, W1, W2))
+    require(np.isfinite(got).all(), "non-finite output")
+    require(err <= BF16_TOL, f"block vs reference rel err {err:.2e}")
+    say("block", tile_s=TS, d_head=DH, heads=H, tiles=T,
+        kernel="mosaic" if mosaic else "interpreted",
+        rel_err=f"{err:.1e}",
+        tasks={s["name"]: s["tasks"] for s in stats if s["tasks"]})
+
+
+def phase_host(sz, on_chip):
+    """The dynamic path every serving/ workload sits on: host scheduler,
+    device bodies."""
+    import jax
+    import numpy as np
+    import parsec_tpu as parsec
+    from parsec_tpu import _native, dtd
+    from parsec_tpu.algorithms import build_potrf, insert_gemm_dtd
+    from parsec_tpu.data.matrix import TiledMatrix
+
+    n_dev = len(jax.devices())
+    rng = np.random.default_rng(0)
+    ctx = parsec.init(nb_cores=4)
+    try:
+        ctx.start()
+        mods = [d for d in ctx.devices.devices if d.name.startswith("tpu")]
+        require(len(mods) == n_dev,
+                f"{len(mods)} device modules for {n_dev} devices")
+        require(all(m.platform == ("tpu" if on_chip else "cpu")
+                    for m in mods),
+                f"module platforms {[m.platform for m in mods]}")
+
+        n, nb = sz["dtd"]
+        A_h = rng.standard_normal((n, n)).astype(np.float32)
+        B_h = rng.standard_normal((n, n)).astype(np.float32)
+        A = TiledMatrix.from_array(A_h, nb, nb, name="A")
+        B = TiledMatrix.from_array(B_h, nb, nb, name="B")
+        C = TiledMatrix.from_array(np.zeros((n, n), np.float32), nb, nb,
+                                   name="C")
+        tp = dtd.Taskpool("smoke_gemm")
+        ctx.add_taskpool(tp)
+        t0 = time.perf_counter()
+        insert_gemm_dtd(tp, A, B, C)
+        tp.wait()
+        c_tiles = [C.data_of(key) for key in C.local_keys()]
+        jax.block_until_ready(c_tiles)
+        t_gemm = time.perf_counter() - t0
+        # engine_for returns None when a real accelerator is registered:
+        # on the chip every DTD pool runs the Python engine (ROADMAP S1)
+        engine = "native" if tp._native is not None else "python"
+        out_devs = tile_devices(c_tiles)
+        err = rel(C.to_array(), A_h.astype(np.float64) @ B_h)
+        require(err <= BF16_TOL, f"DTD GEMM rel err {err:.2e}")
+        say("host", dtd_gemm=f"n={n}/nb={nb}", tasks=(n // nb) ** 3,
+            engine=engine, native_build=(
+                "loaded" if _native.available() else
+                f"unavailable ({_native.build_error()})"),
+            first_run_s=f"{t_gemm:.1f}", rel_err=f"{err:.1e}",
+            output_devices=out_devs)
+
+        n, nb = sz["potrf_host"]
+        R = rng.standard_normal((n, n)).astype(np.float32)
+        S_h = (0.5 * (R + R.T) + 2.0 * n * np.eye(n)).astype(np.float32)
+        P_ = TiledMatrix.from_array(S_h.copy(), nb, nb, name="P")
+        t0 = time.perf_counter()
+        ctx.add_taskpool(build_potrf(P_))
+        require(ctx.wait(timeout=900), "PTG POTRF did not finish")
+        t_potrf = time.perf_counter() - t0
+        L = np.tril(P_.to_array().astype(np.float64))
+        err = rel(L @ L.T, S_h)
+        require(err <= 1e-3, f"PTG POTRF residual {err:.2e}")
+        p_devs = tile_devices(P_.data_of(key) for key in P_.local_keys())
+        say("host", ptg_potrf=f"n={n}/nb={nb}", first_run_s=f"{t_potrf:.1f}",
+            residual=f"{err:.1e}", output_devices=p_devs)
+        stats = ctx.devices.dump_statistics()
+    finally:
+        parsec.fini(ctx)
+
+    by_name = {s["name"]: s["tasks"] for s in stats}
+    say("host", tasks_by_module=by_name)
+    # on the CPU platform the inline module is a load-balancing peer of
+    # the (CPU-backed) device modules by design; next to a real chip it
+    # is weighted out and must have run nothing
+    require(not on_chip or by_name.get("cpu", 0) == 0,
+            f"the inline CPU module ran {by_name.get('cpu')} device bodies")
+    idle = [m.name for m in mods if by_name.get(m.name, 0) == 0]
+    require(not idle, f"device modules that ran no task: {idle}")
+    if n_dev > 1:
+        require(len(set(out_devs) | set(p_devs)) > 1,
+                f"every output tile lives on chip {out_devs or p_devs}")
+
+
+def _panel_run(ex, state):
+    import jax
+    t0 = time.perf_counter()
+    fn = ex.jitted                 # shared jit store / executor store
+    out = fn(state)
+    jax.block_until_ready(out)
+    return out, time.perf_counter() - t0
+
+
+def phase_panels(sz, on_chip):
+    """The other three panel programs compile and pass their residuals
+    (random-probe identities traced at highest precision)."""
+    import jax
+    import jax.numpy as jnp
+    from parsec_tpu.algorithms.gemm import build_gemm_ptg
+    from parsec_tpu.algorithms.geqrf import build_geqrf_hh
+    from parsec_tpu.algorithms.getrf import build_getrf_left
+    from parsec_tpu.compiled.panels import PanelExecutor
+    from parsec_tpu.compiled.wavefront import plan_taskpool
+    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.ops.tile_kernels import lu_split
+    from parsec_tpu.utils import mca_param
+
+    n, nb = sz["panels"]
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(jax.random.fold_in(key, 99), (n, 8), jnp.float32)
+
+    def tm(name):
+        return TiledMatrix(n, n, nb, nb, name=name)
+
+    def rnd(i):
+        return jax.random.normal(jax.random.fold_in(key, i), (n, n),
+                                 jnp.float32)
+
+    def check(name, ex, state, resid, tol):
+        out, t = _panel_run(ex, state)
+        with jax.default_matmul_precision("highest"):
+            err = float(jax.jit(resid)(out))
+        require(err == err and err <= tol, f"{name} residual {err:.2e}")
+        say("panels", program=name, n=n, nb=nb, first_call_s=f"{t:.1f}",
+            residual=f"{err:.1e}")
+
+    # GEMM: the store holds transposes, so Cᵀ = Bᵀ Aᵀ
+    ex = PanelExecutor(plan_taskpool(build_gemm_ptg(tm("A"), tm("B"),
+                                                    tm("C"))))
+    check("gemm", ex,
+          {"A": rnd(0), "B": rnd(1), "C": jnp.zeros((n, n), jnp.float32)},
+          lambda o: (jnp.linalg.norm(o["C"] @ x - rnd(1) @ (rnd(0) @ x)) /
+                     jnp.linalg.norm(rnd(1) @ (rnd(0) @ x))), BF16_TOL)
+
+    # GEQRF: ‖RᵀRx − AᵀAx‖/‖AᵀAx‖ (orthogonal-invariant identity)
+    def resid_qr(o):
+        A0t = rnd(2)
+        AtAx = A0t @ (A0t.T @ x)
+        R = o["A"].T
+        return jnp.linalg.norm(R.T @ (R @ x) - AtAx) / jnp.linalg.norm(AtAx)
+
+    check("geqrf", PanelExecutor(plan_taskpool(build_geqrf_hh(tm("A")))),
+          {"A": rnd(2)}, resid_qr, BF16_TOL)
+
+    # GETRF (no pivoting, diagonally dominant): ‖LUx − Ax‖/‖Ax‖
+    def gen_lu():
+        return rnd(3).at[jnp.arange(n), jnp.arange(n)].add(2.0 * n)
+
+    def resid_lu(o):
+        L, U = lu_split(o["A"].T)
+        Ax = gen_lu().T @ x
+        return jnp.linalg.norm(L @ (U @ x) - Ax) / jnp.linalg.norm(Ax)
+
+    mca_param.set("getrf.trsm_hook", "gemm")
+    try:
+        check("getrf", PanelExecutor(plan_taskpool(build_getrf_left(
+            tm("A")))), {"A": gen_lu()}, resid_lu, 1e-4)
+    finally:
+        mca_param.unset("getrf.trsm_hook")
+
+
+def _flagship_executor(sz):
+    from parsec_tpu.algorithms.potrf import build_potrf_left
+    from parsec_tpu.compiled.panels import PanelExecutor
+    from parsec_tpu.compiled.wavefront import plan_taskpool
+    from parsec_tpu.data.matrix import TiledMatrix
+    n, nb = sz["flagship"]
+    return PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+
+
+def _peaks():
+    import jax
+    out = {}
+    for d in jax.devices():
+        ms = d.memory_stats() or {}
+        if "peak_bytes_in_use" in ms:
+            out[d.id] = f"{ms['peak_bytes_in_use'] / 2 ** 30:.2f}GiB"
+    return out or "not reported by this backend"
+
+
+def phase_flagship(sz, on_chip):
+    """The compiled path at flagship width, as bench.py times it."""
+    import jax
+    from parsec_tpu.algorithms.potrf import (panel_potrf_residual,
+                                             panel_spd_state)
+    from parsec_tpu.utils import mca_param
+
+    n, nb = sz["flagship"]
+    key = jax.random.PRNGKey(0)
+    mca_param.set("potrf.trsm_hook", "gemm")
+    try:
+        ex = _flagship_executor(sz)
+        gen = jax.jit(lambda k: panel_spd_state(k, n, nb))
+        out, t_first = _panel_run(ex, gen(key))
+        passes = [t_first]
+        for _ in range(2):
+            del out
+            out, t = _panel_run(ex, gen(key))
+            passes.append(t)
+        with jax.default_matmul_precision("highest"):
+            err = float(jax.jit(
+                lambda a, k: panel_potrf_residual(a, k, n, nb))(
+                    out["A"], key))
+    finally:
+        mca_param.unset("potrf.trsm_hook")
+    say("flagship", n=n, nb=nb, first_pass_s=f"{passes[0]:.1f}",
+        later_passes_s=[round(t, 3) for t in passes[1:]],
+        residual=f"{err:.2e}", peak_hbm=_peaks())
+    require(err == err and err <= 1e-4, f"residual {err:.2e} > 1e-4")
+
+
+def phase_ici(sz, on_chip):
+    """The device hop ONE process can measure: two loopback ranks mapped
+    to two chips of one mesh, payload moved chip to chip."""
+    from parsec_tpu.comm.pingpong import measure_ici_latency
+    r = measure_ici_latency(payload_bytes=1 << 16, hops=32)
+    say("ici", **r)
+    require(len(r["payload_device_ids"]) == 2,
+            f"payload sat on devices {r['payload_device_ids']}")
+    require(r["host_bypass"],
+            f"{r['wire_bytes_per_hop']} wire bytes per hop for a "
+            f"{r['payload_bytes']}-byte payload")
+
+
+def phase_sharded(sz, on_chip):
+    """State sharded over every chip: run_sharded on the wavefront
+    executor, then the flagship panel program with its Aᵀ-dense state
+    sharded P("rows")."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from parsec_tpu.algorithms.potrf import (build_potrf,
+                                             panel_potrf_residual,
+                                             panel_spd_state)
+    from parsec_tpu.compiled.spmd import (compile_with_plan, make_mesh,
+                                          run_sharded)
+    from parsec_tpu.compiled.wavefront import (WavefrontExecutor,
+                                               plan_taskpool)
+    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.utils import mca_param
+
+    n_dev = len(jax.devices())
+    n, nb = sz["wavefront"]
+    rng = np.random.default_rng(3)
+    R = rng.standard_normal((n, n)).astype(np.float32)
+    S_h = (0.5 * (R + R.T) + 2.0 * n * np.eye(n)).astype(np.float32)
+    A = TiledMatrix.from_array(S_h.copy(), nb, nb, name="A")
+    run_sharded(WavefrontExecutor(plan_taskpool(build_potrf(A))),
+                mesh=make_mesh(n_dev, axis="tiles"))
+    L = np.tril(A.to_array().astype(np.float64))
+    err = rel(L @ L.T, S_h)
+    require(err <= 1e-3, f"run_sharded POTRF residual {err:.2e}")
+    say("sharded", run_sharded=f"potrf n={n}/nb={nb}", devices=n_dev,
+        residual=f"{err:.1e}")
+
+    n, nb = sz["flagship"]
+    key = jax.random.PRNGKey(0)
+    mesh = make_mesh(n_dev, axis="rows")
+    sh = {"A": NamedSharding(mesh, P("rows"))}
+    mca_param.set("potrf.trsm_hook", "gemm")
+    try:
+        ex = _flagship_executor(sz)
+        gen = jax.jit(lambda k: panel_spd_state(k, n, nb), out_shardings=sh)
+        t0 = time.perf_counter()
+        fn = compile_with_plan(
+            ex.run_state, mesh=mesh, in_shardings=(sh,), out_shardings=sh,
+            donate_argnums=0, example_args=(ex.state_shapes(),),
+            fn_key=("smoke_sharded", ex.monolith_cache_key()))
+        out = fn(gen(key))
+        jax.block_until_ready(out)
+        t_first = time.perf_counter() - t0
+        shard_devs = sorted(s.device.id for s in out["A"].addressable_shards)
+        say("sharded", flagship=f"n={n}/nb={nb}", spec='P("rows")',
+            first_pass_s=f"{t_first:.1f}", shard_devices=shard_devs,
+            peak_hbm=_peaks())
+        # the probe's many row-block slices of a row-SHARDED factor make
+        # GSPMD plan tens of GB of resharding temporaries (it would not
+        # compile): check the factor gathered onto one chip, with the
+        # one-chip probe the flagship phase uses
+        factor = jax.device_put(out.pop("A"), jax.devices()[0])
+        with jax.default_matmul_precision("highest"):
+            err = float(jax.jit(
+                lambda a, k: panel_potrf_residual(a, k, n, nb))(factor, key))
+    finally:
+        mca_param.unset("potrf.trsm_hook")
+    say("sharded", flagship_residual=f"{err:.2e}")
+    require(len(set(shard_devs)) == n_dev,
+            f"shards on devices {shard_devs}, want {n_dev} distinct")
+    require(err == err and err <= 1e-4, f"residual {err:.2e} > 1e-4")
+
+
+ONE_CHIP = [("store", phase_store), ("flash", phase_flash),
+            ("block", phase_block), ("host", phase_host),
+            ("panels", phase_panels), ("flagship", phase_flagship)]
+MULTI_CHIP = [("ring", phase_ring), ("ici", phase_ici),
+              ("sharded", phase_sharded)]
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--dry-run-cpu", nargs="?", const=1, type=int,
+                    metavar="N", help="CPU dry run on N virtual devices: "
+                    "tiny sizes, Pallas interpreted, labelled platform: cpu")
+    ap.add_argument("--phases", default="",
+                    help="comma-separated subset of phases to run")
+    args = ap.parse_args(argv)
+
+    t_start = time.perf_counter()
+    import jax
+    want = "tpu"
+    if args.dry_run_cpu:
+        want = "cpu"
+        jax.config.update("jax_platforms", "cpu")
+        jax.config.update("jax_num_cpu_devices", args.dry_run_cpu)
+    try:
+        devs = jax.devices()
+    except RuntimeError as exc:
+        sys.exit(f"chip_smoke: no TPU — JAX could not bring the backend "
+                 f"up: {exc}")
+    found = sorted({d.platform for d in devs})
+    if found != [want]:
+        sys.exit(f"chip_smoke: no TPU — JAX found only {found} devices. "
+                 "Nothing was run. (A CPU dry run must be stated: "
+                 "--dry-run-cpu)")
+    on_chip = want == "tpu"
+    sizes = FULL if on_chip else DRY
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+
+    from parsec_tpu.utils import compile_cache, mca_param
+    mca_param.set("jit.cache_dir", "auto")
+    cache_dir = compile_cache.enable_compile_cache()
+    compile_cache.backend_compile_count()          # install the counter
+    import jaxlib
+    say("device", platform=device["platform"], device_kind=device["kind"],
+        count=device["count"], jax=jax.__version__,
+        jaxlib=jaxlib.__version__,
+        libtpu=importlib.metadata.version("libtpu"), cache_dir=cache_dir,
+        cache_placed_by=("JAX_COMPILATION_CACHE_DIR"
+                         if os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                         else "jit.cache_dir=auto"))
+
+    phases = ONE_CHIP + (MULTI_CHIP if len(devs) > 1 else [])
+    if args.phases:
+        pick = args.phases.split(",")
+        unknown = set(pick) - {n for n, _ in ONE_CHIP + MULTI_CHIP}
+        if unknown:
+            sys.exit(f"chip_smoke: unknown phases {sorted(unknown)}")
+        phases = [(n, f) for n, f in ONE_CHIP + MULTI_CHIP if n in pick]
+    failed = []
+    for name, fn in phases:
+        t0 = time.perf_counter()
+        c0 = compile_cache.backend_compile_count()
+        try:
+            fn(sizes, on_chip)
+            verdict = "PASS"
+        except Exception:  # noqa: BLE001 — report, run the rest, exit 1
+            traceback.print_exc(file=sys.stdout)
+            failed.append(name)
+            verdict = "FAIL"
+        gc.collect()
+        say(name, verdict=verdict, seconds=f"{time.perf_counter() - t0:.1f}",
+            backend_compiles=compile_cache.backend_compile_count() - c0)
+
+    stats = compile_cache.cache_stats()
+    say("cache", dir=cache_dir,
+        backend_compiles=stats["backend_compiles"],
+        store_hits=stats["store_hits"], store_misses=stats["store_misses"],
+        store_errors=stats["store_errors"],
+        total_s=f"{time.perf_counter() - t_start:.1f}")
+    say("phases", ran=[n for n, _ in phases], failed=failed)
+    result = {"ok": not failed, "device": device}
+    if failed:
+        result["failed"] = failed
+    print(json.dumps(result), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -23,10 +23,10 @@ from parsec_tpu.data import TiledMatrix
 from parsec_tpu.utils import mca_param
 
 # Compile-once serving: the jit.cache_dir knob auto-enables the
-# persistent compile caches (XLA cache + serialized executors under
-# .xla_cache/executors) — re-running this example pays zero XLA
-# compiles for the already-served shapes. PARSEC_COMPILE_CACHE=0
-# disables both layers.
+# persistent compile caches (XLA cache + serialized executors, under
+# JAX_COMPILATION_CACHE_DIR where set, else the checkout's .xla_cache)
+# — re-running this example pays zero XLA compiles for the
+# already-served shapes. PARSEC_COMPILE_CACHE=0 disables both layers.
 mca_param.set("jit.cache_dir", "auto")
 
 

@@ -213,13 +213,9 @@ def _build(var: str = "off") -> bool:
         _build_errors[var] = f"g++ failed (rc={exc.returncode}): {tail}"
     except (OSError, subprocess.SubprocessError) as exc:
         _build_errors[var] = f"build failed: {exc}"
-    # rebuild impossible but a (prebuilt / stampless) .so exists: try
-    # it — a deployment shipping the binary without the toolchain must
-    # not lose the native engine; a STALE binary missing newly-added
-    # symbols fails the bind cleanly (load()'s AttributeError guard).
-    # Sanitizer variants never take this fallback: an unverifiable
-    # sanitized binary would undermine the zero-report contract.
-    return var == "off" and os.path.exists(so)
+    # a binary whose stamp does not match the source is never loaded:
+    # what runs is what this checkout's core.cpp builds, or nothing
+    return False
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

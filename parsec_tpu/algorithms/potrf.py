@@ -323,6 +323,78 @@ def potrf_flops(n: int) -> float:
     return n ** 3 / 3.0 + n ** 2 / 2.0 + n / 6.0
 
 
+# -- the panel executor's own check: input generated on device in its
+# Aᵀ-dense layout + a random-probe residual of the factor. Shared by
+# bench.py's flagship and chip_smoke.py, row-parametric so neither ever
+# holds a second N×N array next to the factor.
+
+def panel_spd_row(key, i: int, n: int, nb: int):
+    """Block-row ``i`` of the Aᵀ-dense diagonally-dominant SPD input,
+    generated on device from a per-row key, so that the residual check
+    can regenerate one row at a time."""
+    import jax
+    import jax.numpy as jnp
+    Ri = jax.random.normal(jax.random.fold_in(key, i), (nb, n),
+                           dtype=jnp.float32)
+    return Ri.at[:, i * nb:(i + 1) * nb].add(
+        2.0 * n * jnp.eye(nb, dtype=jnp.float32))
+
+
+def panel_spd_state(key, n: int, nb: int):
+    """The whole input, entirely on device. Only the upper triangle of
+    D (= lower of A) plus the averaged diagonal blocks are read by the
+    DAG — the fuser symmetrizes diag blocks 0.5·(B+Bᵀ) at their point
+    of use, and :func:`panel_potrf_residual` models exactly that
+    matrix."""
+    import jax.numpy as jnp
+    return {"A": jnp.concatenate(
+        [panel_spd_row(key, i, n, nb) for i in range(n // nb)], axis=0)}
+
+
+def panel_potrf_residual(Lt, key, n: int, nb: int):
+    """Random-probe residual ‖(LLᵀ−A₀)x‖/‖A₀x‖ of the factor ``Lt``
+    (Lᵀ in the upper block triangle), where A₀ is EXACTLY the matrix
+    the DAG factors: strict-lower blocks read from the stored triangle
+    (upper of D), diagonal blocks symmetrized as the fuser does.
+    Computed block-row-wise from regenerated rows — no N×N temporaries.
+    Trace it under ``jax.default_matmul_precision("highest")``: the
+    probe measures the factor, so its own dots must not add bf16
+    noise."""
+    import jax
+    import jax.numpy as jnp
+    nt = n // nb
+    s = 8
+    x = jax.random.normal(jax.random.fold_in(key, nt + 1), (n, s),
+                          jnp.float32)
+
+    def blk(i):
+        return slice(i * nb, (i + 1) * nb)
+
+    # y = A0 @ x, accumulated per regenerated block-row j of D0: diag
+    # averaged, strict-lower blocks Dj[:, i>j]ᵀ plus their
+    # mirrored-upper contribution
+    y = jnp.zeros((n, s), jnp.float32)
+    for j in range(nt):
+        Dj = panel_spd_row(key, j, n, nb)
+        d = Dj[:, blk(j)]
+        yj = 0.5 * (d + d.T) @ x[blk(j)]
+        if j < nt - 1:
+            tail = Dj[:, (j + 1) * nb:]
+            yj = yj + tail @ x[(j + 1) * nb:]
+            y = y.at[(j + 1) * nb:].add(tail.T @ x[blk(j)])
+        y = y.at[blk(j)].add(yj)
+
+    # z = Lᵀ x ; y2 = L z — Lt's diag blocks are exactly upper-
+    # triangular (chol zeroes the strict lower), and only the upper
+    # block triangle of Lt is ever read
+    z = jnp.concatenate(
+        [Lt[blk(j), j * nb:] @ x[j * nb:] for j in range(nt)], axis=0)
+    y2 = jnp.concatenate(
+        [Lt[0:(i + 1) * nb, blk(i)].T @ z[0:(i + 1) * nb]
+         for i in range(nt)], axis=0)
+    return jnp.linalg.norm(y2 - y) / jnp.linalg.norm(y)
+
+
 def build_potrf_left(A: TiledMatrix) -> ptg.Taskpool:
     """Left-looking tiled Cholesky (LAPACK-style blocked ``potrf``).
 

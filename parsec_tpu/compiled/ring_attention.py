@@ -34,25 +34,6 @@ _MASKED = -1e30      # finite "minus infinity": keeps exp() NaN-free when
                      # an entire row is masked (fully-future KV blocks)
 
 
-def _shard_map():
-    """Version-portable ``shard_map``: top-level ``jax.shard_map``
-    (JAX ≥ 0.6) with the ``check_vma`` kwarg, or the older
-    ``jax.experimental.shard_map.shard_map`` whose equivalent kwarg is
-    ``check_rep``. Returns a callable with the NEW signature; the
-    ``check_vma`` kwarg is translated for old JAX."""
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    def compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return sm_old(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-    return compat
-
-
 def _online_softmax_step(q_blk, k_cur, v_cur, acc, m, l, scale,
                          qpos=None, kpos=None):
     """One online-softmax fold. ``qpos``/``kpos``: global sequence
@@ -116,7 +97,6 @@ def ring_attention(q, k, v, mesh, axis: str = "seq",
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    shard_map = _shard_map()
     if impl not in ("xla", "flash"):
         raise ValueError(f"ring_attention impl must be xla|flash: {impl!r}")
 
@@ -168,12 +148,12 @@ def ring_attention(q, k, v, mesh, axis: str = "seq",
             return o_c.astype(q_blk.dtype)
 
         # check_vma=False: pallas_call's out_shape carries no varying-
-        # across-mesh annotation, which the shard_map vma checker (JAX
-        # ≥0.8) rejects; the kernel is per-device-local so the check
-        # adds nothing here
-        return shard_map(block_flash, mesh=mesh,
-                         in_specs=(P(axis), P(axis), P(axis)),
-                         out_specs=P(axis), check_vma=False)(q, k, v)
+        # across-mesh annotation, which the shard_map vma checker
+        # rejects; the kernel is per-device-local so the check adds
+        # nothing here
+        return jax.shard_map(block_flash, mesh=mesh,
+                             in_specs=(P(axis), P(axis), P(axis)),
+                             out_specs=P(axis), check_vma=False)(q, k, v)
 
     def block(q_blk, k_blk, v_blk):
         # [Sb, H, dh] → head-major [H, Sb, dh] for batched matmuls
@@ -238,7 +218,7 @@ def ring_attention(q, k, v, mesh, axis: str = "seq",
 
         # fold the resident block, then rotate n-1 times; the init state
         # derives from qh so it carries the same varying manual axes as
-        # the loop outputs (JAX ≥0.8 shard_map typing)
+        # the loop outputs (shard_map typing)
         acc0, m0, l0 = fold_block(
             kh, vh, qh * 0.0, qh[..., 0] * 0.0 - jnp.inf,
             qh[..., 0] * 0.0, my)
@@ -247,9 +227,9 @@ def ring_attention(q, k, v, mesh, axis: str = "seq",
         out = acc / l[..., None]
         return jnp.swapaxes(out, 0, 1).astype(q_blk.dtype)
 
-    fn = shard_map(block, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(block, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(axis))
     return fn(q, k, v)
 
 
@@ -261,7 +241,6 @@ def ulysses_attention(q, k, v, mesh, axis: str = "seq"):
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    shard_map = _shard_map()
 
     n = mesh.shape[axis]
     H = q.shape[1]
@@ -290,9 +269,9 @@ def ulysses_attention(q, k, v, mesh, axis: str = "seq"):
                              tiled=True)
         return out.astype(q_blk.dtype)
 
-    fn = shard_map(block, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(block, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(axis))
     return fn(q, k, v)
 
 

@@ -224,13 +224,12 @@ def compile_with_plan(fn: Callable, *, mesh=None, in_shardings=None,
             fn, key=full_key, example_args=example_args,
             jit_wrapper=wrapper)
     if mesh is not None:
-        from .ring_attention import _shard_map
         from jax.sharding import PartitionSpec as P
-        sm = _shard_map()
         axis = mesh.axis_names[0]
         ispec = in_specs if in_specs is not None else P(axis)
         ospec = out_specs if out_specs is not None else P(axis)
-        mapped = sm(fn, mesh=mesh, in_specs=ispec, out_specs=ospec)
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=ispec,
+                               out_specs=ospec)
         if not shareable:
             return jax.jit(mapped, donate_argnums=donate_argnums)
         full_key = ("shard_map", fn_key, key, _mesh_repr(mesh),

@@ -314,9 +314,8 @@ class Context:
         copies with coherency (device_gpu stage-in attaches the GPU
         copy to the data object); here the collection's stored tile is
         REPLACED by its staged device array on first read, so every
-        later reader reuses the single H2D transfer — re-staging per
-        task measured 100×-class slowdowns on remote-tunnel backends
-        where host transfers are synchronous. Set ``0`` for host-pure
+        later reader reuses the single H2D transfer instead of paying
+        one per task. Set ``0`` for host-pure
         workloads (e.g. wire-latency harnesses: staging would route
         every payload through the accelerator)."""
         # per-read hot path: cache the resolved answer against the MCA
@@ -346,11 +345,8 @@ class Context:
         import numpy as np
         if not self.stage_reads or not isinstance(value, np.ndarray):
             return value
-        try:
-            import jax
-            staged = jax.device_put(value)
-        except Exception:  # noqa: BLE001 — staging is an optimization
-            return value
+        import jax
+        staged = jax.device_put(value)
         dc.write_tile(key, staged)
         return staged
 

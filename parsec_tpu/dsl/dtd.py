@@ -331,9 +331,8 @@ class Taskpool(CoreTaskpool):
                 # pure=True contract (insert_task): fn is a pure
                 # function of its arguments, so the whole woven body is
                 # jitted once per (argspec signature, arg shapes) and
-                # every task of the class dispatches asynchronously —
-                # eager per-op dispatch through a remote backend costs
-                # ~0.3 s/task where the jitted call pipelines at ~1.4 ms
+                # every task of the class dispatches asynchronously as
+                # ONE launch instead of one eager dispatch per op
                 # (the reference's DTD bodies are BLAS/CUDA kernels,
                 # i.e. pure by construction; impure Python bodies keep
                 # the default eager path). The jit cache is process-wide

@@ -11,8 +11,9 @@ runtime analog: user .jdf BODY CUDA kernels — the runtime schedules
 them, the kernel owns the device).
 
 Public entry: :func:`flash_attention` over ``(S, H, dh)`` operands (the
-layout `compiled.ring_attention` uses). Falls back to pallas interpret
-mode off-TPU so the same code path is exercised by CPU tests.
+layout `compiled.ring_attention` uses). Runs in pallas interpret mode
+only where the caller says so or the run was started on the CPU
+platform (tests, CPU dry runs) — never because a chip failed to come up.
 """
 
 from __future__ import annotations
@@ -23,8 +24,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import mca_param
+from ..utils.jax_platform import cpu_requested
 
 mca_param.register("ops.flash_attention_block_q", 1024,
                    help="flash-attention query block size")
@@ -101,27 +105,19 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             (m_ref[:, 0] + jnp.log(l))[:, None], lse_ref.shape[1:])
 
 
-# pallas imports deferred so the module imports on builds without pallas
-try:  # pragma: no cover - exercised implicitly by every call
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # noqa: BLE001
-    _HAVE_PALLAS = False
-
-
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: int = 0, block_k: int = 0,
                     interpret: Optional[bool] = None,
                     return_lse: bool = False):
     """Softmax attention over ``(S, H, dh)`` operands via the pallas
-    flash kernel. ``interpret=None`` auto-selects interpret mode off-TPU
-    (so CPU tests run the identical kernel). ``return_lse=True`` also
-    returns the per-row log-sum-exp ``(S, H)`` — the merge key for
-    combining partial attention states (ring attention)."""
-    if not _HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable in this jax build")
+    flash kernel. ``interpret=None`` interprets iff the run was started
+    on the CPU platform (``JAX_PLATFORMS=cpu``: tests and CPU dry runs
+    execute the identical kernel); anywhere else Mosaic compiles it, and
+    a chip that did not come up is an error, not an interpreted run.
+    ``return_lse=True`` also returns the per-row log-sum-exp ``(S, H)``
+    — the merge key for combining partial attention states (ring
+    attention)."""
     S, H, dh = q.shape
     Sk = k.shape[0]
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
@@ -139,7 +135,7 @@ def flash_attention(q, k, v, causal: bool = False,
         raise ValueError(f"sequence lengths ({S}, {Sk}) must divide the "
                          f"block sizes ({bq}, {bk})")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = cpu_requested()
 
     # (S, H, dh) → (H, S, dh); pad head dim to the f32 lane tile
     qT = jnp.swapaxes(q, 0, 1).astype(jnp.float32)

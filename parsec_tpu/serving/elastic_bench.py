@@ -84,8 +84,6 @@ def _worker_main(rank: int, world: int, base_port: int, ckpt_dir: str,
     to the front end with the decode state for bitwise verification."""
     import traceback
     try:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
         from ..comm.socket_engine import SocketCommEngine
         from ..core import context as ctx_mod
         from ..data.checkpoint import CheckpointManager

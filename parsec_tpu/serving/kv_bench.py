@@ -307,8 +307,8 @@ def _measure_child(q, n_tenants: int, reqs_per_tenant: int) -> None:
     try:
         import sys
         sys.setswitchinterval(0.0002)
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+        from ..utils.jax_platform import pin_cpu_platform
+        pin_cpu_platform()
         q.put(("ok", measure_serving_kv(n_tenants, reqs_per_tenant)))
     except BaseException as exc:  # noqa: BLE001 — report to parent
         import traceback

@@ -27,16 +27,26 @@ economics with three layers:
    (:func:`enable_compile_cache`), kept as the safety net for programs
    that bypass the store.
 
-Env/knob interaction (documented contract):
+Env/knob interaction (documented contract, ONE resolution —
+:func:`_resolve_dir` — shared by chip_smoke.py, bench.py and the
+examples):
 
+- ``JAX_COMPILATION_CACHE_DIR`` env: the cache placed from OUTSIDE
+  (setting it is also the opt-in). Where it is set, the XLA cache stays
+  exactly where JAX itself put it (this module never rewrites
+  ``jax_compilation_cache_dir`` then), the executor store lives under
+  ``<that dir>/executors``, and no knob, ``PARSEC_COMPILE_CACHE`` path
+  or explicit argument moves either.
 - ``jit.cache_dir`` MCA knob (env ``PARSEC_MCA_jit_cache_dir``):
-  ``""`` = disabled (library default), ``auto`` = ``.xla_cache`` next
-  to the repo root, anything else = that directory. bench.py and the
-  compiled-path examples set it to ``auto`` — serving entry points opt
-  in; the library never writes caches unasked.
-- ``PARSEC_COMPILE_CACHE`` env: legacy/kill switch. ``0`` disables BOTH
-  layers even when the knob is set; a path overrides the knob's
-  directory. :func:`enable_compile_cache` remains the explicit call.
+  ``""`` = disabled (library default), ``auto`` = the FIXED
+  ``.xla_cache`` next to the repo root (the path is part of the XLA
+  cache key, so a directory that moves never hits), anything else =
+  that directory. chip_smoke.py, bench.py and the compiled-path
+  examples set it to ``auto`` — entry points opt in; the library never
+  writes caches unasked.
+- ``PARSEC_COMPILE_CACHE`` env: kill switch. ``0`` disables BOTH layers
+  whatever else is set; a path overrides the knob's directory.
+  :func:`enable_compile_cache` remains the explicit call.
 - ``jit.cache_salt`` MCA knob: extra fingerprint salt — flip it to
   force a cold cache without deleting files (tests use this for the
   version-salt invalidation contract).
@@ -61,7 +71,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 from . import mca_param
 from .debug import debug_verbose, warning
 
-_SCHEMA = 1
+_SCHEMA = 2      # 2: records carry the compiled-for device ids
 
 mca_param.register(
     "jit.cache_dir", "",
@@ -331,9 +341,12 @@ def _initialize_ffi_runtime() -> None:
 
 class ExecutorStore:
     """Serialized-executable store: ``<root>/<digest>.pkl`` holding the
-    AOT-compiled program. Writes are atomic (tmp + rename); any load
-    failure (version skew, corruption, foreign device) degrades to a
-    miss and the entry is recompiled + overwritten."""
+    AOT-compiled program and the ids of the devices it was compiled
+    for. Writes are atomic (tmp + rename). A missing entry is a miss;
+    an entry that exists but cannot be loaded RAISES — version skew and
+    foreign devices change the digest and so can only miss, which
+    leaves a failing load meaning a broken store, and a store that
+    quietly recompiles hides exactly that."""
 
     def __init__(self, root: str) -> None:
         self.root = root
@@ -349,33 +362,51 @@ class ExecutorStore:
             with _CNT_LOCK:
                 _counters["store_misses"] += 1
             return None
+        import jax
+        from jax.experimental import serialize_executable as se
         try:
             with open(path, "rb") as fh:
                 rec = pickle.load(fh)
-            if rec.get("schema") != _SCHEMA:
-                raise ValueError(f"schema {rec.get('schema')}")
-            from jax.experimental import serialize_executable as se
+            # load onto the devices the program was COMPILED for:
+            # deserialize_and_load defaults to every device of the
+            # backend, which turns a one-device program on a
+            # many-device host into one that rejects its own arguments
+            by_id = {d.id: d for d in jax.devices()}
             fn = se.deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
-            with _CNT_LOCK:
-                _counters["store_hits"] += 1
-            debug_verbose(3, "jitcache", "store hit %s (%s)",
-                          digest[:12], rec.get("key", "?")[:80])
-            return fn
-        except Exception as exc:  # noqa: BLE001 — degrade to a miss
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=[by_id[i] for i in rec["device_ids"]])
+            # ...and check the backend honoured that: libtpu reloads a
+            # one-device program compiled for a non-default chip onto
+            # its FIRST chip, which would fail at the first call with
+            # an error about buffers, far from here
+            got = [d.id for d in fn.runtime_executable().local_devices()]
+            if got != rec["device_ids"]:
+                raise ValueError(
+                    f"compiled for devices {rec['device_ids']} but the "
+                    f"backend reloaded it onto {got}; compile it for the "
+                    "default device, or with persist=False")
+        except Exception as exc:
             with _CNT_LOCK:
                 _counters["store_errors"] += 1
-            debug_verbose(1, "jitcache", "store load %s failed: %s",
-                          digest[:12], exc)
-            return None
+            raise RuntimeError(
+                f"executor store entry {path} exists but cannot be "
+                f"loaded ({type(exc).__name__}: {exc}); delete it or "
+                "change jit.cache_salt") from exc
+        with _CNT_LOCK:
+            _counters["store_hits"] += 1
+        debug_verbose(3, "jitcache", "store hit %s (%s)",
+                      digest[:12], rec.get("key", "?")[:80])
+        return fn
 
     def save(self, digest: str, compiled: Any, key_repr: str) -> None:
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
-            rec = {"schema": _SCHEMA, "key": key_repr,
-                   "payload": payload, "in_tree": in_tree,
-                   "out_tree": out_tree}
+            rec = {"key": key_repr, "payload": payload,
+                   "in_tree": in_tree, "out_tree": out_tree,
+                   "device_ids": [
+                       d.id for d in
+                       compiled.runtime_executable().local_devices()]}
             tmp = self._path(digest) + f".tmp{os.getpid()}"
             with open(tmp, "wb") as fh:
                 pickle.dump(rec, fh)
@@ -397,12 +428,18 @@ def _default_dir() -> str:
 
 
 def _resolve_dir(path: Optional[str] = None) -> Optional[str]:
-    """Directory resolution shared by the explicit call and the knob
-    auto-enable: PARSEC_COMPILE_CACHE=0 kills everything; explicit path
-    > env path > jit.cache_dir knob ('auto' -> repo .xla_cache)."""
+    """The ONE directory resolution (explicit call, knob auto-enable,
+    every entry point): PARSEC_COMPILE_CACHE=0 kills everything;
+    JAX_COMPILATION_CACHE_DIR, where set, is where the cache was placed
+    from outside and nothing below moves it; then explicit path >
+    PARSEC_COMPILE_CACHE path > jit.cache_dir knob ('auto' -> the fixed
+    repo .xla_cache)."""
     env = os.environ.get("PARSEC_COMPILE_CACHE", "")
     if env == "0":
         return None
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if placed:
+        return placed
     if path is not None:
         return path
     if env:
@@ -414,26 +451,23 @@ def _resolve_dir(path: Optional[str] = None) -> Optional[str]:
 
 
 def enable_compile_cache(path: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache AND the serialized-
-    executor store at ``path`` (default: ``$PARSEC_COMPILE_CACHE``, the
-    ``jit.cache_dir`` MCA knob, or ``.xla_cache`` next to the repo
-    root). Set ``PARSEC_COMPILE_CACHE=0`` to disable. Safe to call
-    repeatedly; returns the cache dir in use (None when disabled)."""
+    """Turn on JAX's persistent compilation cache AND the serialized-
+    executor store at the directory :func:`_resolve_dir` names
+    (``path`` absent and nothing else set: ``.xla_cache`` next to the
+    repo root). Safe to call repeatedly; returns the cache dir in use
+    (None when disabled by ``PARSEC_COMPILE_CACHE=0``)."""
     global _store, _store_checked
-    env = os.environ.get("PARSEC_COMPILE_CACHE", "")
-    if env == "0":
+    if os.environ.get("PARSEC_COMPILE_CACHE", "") == "0":
         with _STORE_LOCK:
             _store, _store_checked = None, True
         return None
-    if path is None:
-        path = env or _resolve_dir() or _default_dir()
+    path = _resolve_dir(path) or _default_dir()
     import jax
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # placed from outside, JAX already reads it from the env
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except AttributeError:   # knob name varies across jax versions
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     with _STORE_LOCK:
         if _store is None or _store.root != os.path.join(path, "executors"):
             _store = ExecutorStore(os.path.join(path, "executors"))
@@ -536,16 +570,12 @@ def cached_jit(fn: Callable, *, key: Tuple, example_args: Tuple = None,
         if loaded is not None:
             result = loaded
         else:
-            try:
-                compiled = jitted.lower(*example_args).compile()
-                if store is not None:
-                    out: list = []
-                    _canon(key, out)
-                    store.save(digest, compiled, "|".join(out))
-                result = compiled
-            except Exception as exc:  # noqa: BLE001 — fall back to jit
-                warning("jitcache", "AOT compile for %s failed (%s); "
-                        "falling back to plain jit", digest[:12], exc)
-                result = jitted
+            # a failing AOT compile propagates: a plain-jit fallback
+            # would only fail again at first call, minus the context
+            result = jitted.lower(*example_args).compile()
+            if store is not None:
+                out: list = []
+                _canon(key, out)
+                store.save(digest, result, "|".join(out))
     with _JIT_LOCK:
         return _JIT_STORE.setdefault(digest, result)

@@ -636,3 +636,114 @@ def test_protocheck_guard_fires_on_rate_drop():
     assert "protocheck_states_per_sec" in out["throughput_regression"]
     assert bench._compare_captures(
         {"protocheck_states_per_sec": 38000.0}, prior) == {}
+
+
+# ---- process model: one process per chip (the launcher holds none) ----
+
+def _canned_section(name):
+    """What a healthy child of each section prints, reduced to the
+    keys main() reads."""
+    rows = {
+        "device": {"device": {"platform": "tpu", "kind": "TPU v5 lite",
+                              "count": 1}},
+        "flagship": {"flagship": {"gflops": 100000.0, "n": 40960,
+                                  "tile": 1024,
+                                  "peak_proxy_gemm_gflops": 150000.0,
+                                  "precision_variant": {}}},
+        "latency": {"latency": {"eager_1k_p50_us": 500.0}},
+    }
+    return rows.get(name, {name: {}})
+
+
+def test_error_rows_finds_nested_rows():
+    bench = _load_bench()
+    assert bench._error_rows({"a": {"gflops": 1.0}}) == []
+    assert bench._error_rows(
+        {"x": {"error": "boom"}, "t": {"flash_error": "vmem"},
+         "ok": {"error": ""}, "l": {"precision_variant": {"error": "e"}}}
+    ) == ["x.error", "t.flash_error", "l.precision_variant.error"]
+
+
+def test_main_prints_then_exits_nonzero_on_error_row(tmp_path, monkeypatch,
+                                                     capsys):
+    bench = _load_bench()
+    monkeypatch.setattr(bench, "_HERE", str(tmp_path))
+
+    def run_section(name):
+        if name == "getrf":
+            return {"getrf_fused": {"error": "section child rc=1: boom"}}
+        return _canned_section(name)
+
+    monkeypatch.setattr(bench, "_run_section", run_section)
+    import pytest
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    out = capsys.readouterr()
+    last = json.loads(out.out.strip().splitlines()[-1])
+    assert last["metric"] == "tiled_potrf_gflops_per_chip"
+    assert last["value"] == 100000.0           # the rows still print
+    assert "extra_configs.getrf_fused.error" in out.err
+    # a clean run exits normally
+    monkeypatch.setattr(bench, "_run_section", _canned_section)
+    bench.main()
+    assert json.loads(capsys.readouterr().out.strip().splitlines()[-1])[
+        "detail"]["device"]["kind"] == "TPU v5 lite"
+
+
+def test_main_stops_when_the_device_probe_fails(monkeypatch, capsys):
+    bench = _load_bench()
+    ran = []
+
+    def run_section(name):
+        ran.append(name)
+        return {"device": {"error": "needs the tpu platform and JAX "
+                                    "found ['cpu']"}}
+
+    monkeypatch.setattr(bench, "_run_section", run_section)
+    import pytest
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1 and ran == ["device"]
+    assert "no usable tpu device" in capsys.readouterr().err
+
+
+def test_launchers_never_import_jax(tmp_path):
+    """main() and the compile_amortization section start children that
+    need the chip: a parent that touched JAX would hold it. Checked in
+    a fresh interpreter (this one has JAX loaded)."""
+    import subprocess
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('b', {_BENCH!r})\n"
+        "b = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(b)\n"
+        f"b._HERE = {str(tmp_path)!r}\n"
+        "b._run_section = lambda name: {\n"
+        "    'device': {'device': {'platform': 'tpu', 'kind': 'k',\n"
+        "                          'count': 1}},\n"
+        "    'flagship': {'flagship': {'gflops': 1.0,\n"
+        "                 'peak_proxy_gemm_gflops': 2.0}}}.get(\n"
+        "        name, {name: {}})\n"
+        "b._amort_child = lambda *a: {'xla_compiles': 0}\n"
+        "b.main()\n"
+        "b._section_compile_amortization()\n"
+        "bad = [m for m in ('jax', 'jaxlib', 'parsec_tpu')\n"
+        "       if m in sys.modules]\n"
+        "sys.exit(f'launcher imported {bad}' if bad else 0)\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_bench_without_a_chip_exits_nonzero():
+    """No TPU and no stated PARSEC_BENCH_PLATFORM=cpu: an error, not a
+    CPU-sized run under the chip metric's name."""
+    import subprocess
+    env = {k: v for k, v in os.environ.items()
+           if k != "PARSEC_BENCH_PLATFORM"}
+    proc = subprocess.run([sys.executable, _BENCH], capture_output=True,
+                          text=True, timeout=300, env=env)
+    assert proc.returncode != 0
+    assert "no usable tpu device" in proc.stderr
+    assert "tiled_potrf_gflops" not in proc.stdout

@@ -5,6 +5,7 @@ executors). All compile assertions use compilation COUNTERS
 compile_cache.backend_compile_count) — never wall clock."""
 
 import contextlib
+import os
 
 import numpy as np
 import pytest
@@ -212,6 +213,138 @@ def test_jit_cache_dir_knob_auto_enables(tmp_path, monkeypatch):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
+def test_cache_placed_from_outside_is_never_moved(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the XLA cache stays where JAX put
+    it (no code path rewrites jax_compilation_cache_dir) and the store
+    is <dir>/executors — whatever an explicit path, the
+    PARSEC_COMPILE_CACHE path or the jit.cache_dir knob say."""
+    import jax
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    prev = jax.config.jax_compilation_cache_dir
+    want = os.path.join(placed, "executors")
+    try:
+        cc.disable_compile_cache()
+        assert cc.enable_compile_cache(str(tmp_path / "explicit")) == placed
+        assert cc.executor_store().root == want
+        cc.disable_compile_cache()
+        monkeypatch.setenv("PARSEC_COMPILE_CACHE", str(tmp_path / "env"))
+        assert cc.executor_store().root == want
+        monkeypatch.delenv("PARSEC_COMPILE_CACHE")
+        cc.disable_compile_cache()
+        mca_param.set("jit.cache_dir", str(tmp_path / "knob"))
+        assert cc.executor_store().root == want
+        cc.disable_compile_cache()
+        mca_param.set("jit.cache_dir", "")       # the env var is the opt-in
+        assert cc.executor_store().root == want
+        assert jax.config.jax_compilation_cache_dir == prev
+        assert sorted(os.listdir(tmp_path)) == ["placed"]
+        # the kill switch still wins
+        cc.disable_compile_cache()
+        monkeypatch.setenv("PARSEC_COMPILE_CACHE", "0")
+        assert cc.executor_store() is None
+    finally:
+        mca_param.unset("jit.cache_dir")
+        cc.disable_compile_cache()
+
+
+def test_auto_is_the_fixed_checkout_cache(monkeypatch):
+    """Nothing placed from outside: ``auto`` is <checkout>/.xla_cache —
+    a fixed path, because the path is part of the XLA cache key."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("PARSEC_COMPILE_CACHE", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mca_param.set("jit.cache_dir", "auto")
+    try:
+        assert cc._resolve_dir() == os.path.join(root, ".xla_cache")
+    finally:
+        mca_param.unset("jit.cache_dir")
+
+
+def test_store_roundtrip_on_many_device_host(tmp_path):
+    """Regression for the store on the 8-virtual-device platform: a
+    program compiled for ONE device (not the default one), and a mesh
+    program on 4 of the 8, reload as callables that RUN — the store
+    used to load every program over all devices of the backend, which
+    then rejected its own arguments."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+    from parsec_tpu.compiled.spmd import compile_with_plan, make_mesh
+    dev = jax.devices()[5]
+    mesh = make_mesh(4, axis="rows")
+    sh = NamedSharding(mesh, P("rows"))
+    x = np.arange(64, dtype=np.float32).reshape(8, 8)
+
+    def one():
+        return cc.cached_jit(
+            _times_two_plus_one, key=("manydev-1", dev.id),
+            example_args=(jax.ShapeDtypeStruct(
+                (8, 8), jnp.float32, sharding=SingleDeviceSharding(dev)),))
+
+    def meshed():
+        return compile_with_plan(
+            _times_two_plus_one, mesh=mesh, in_shardings=(sh,),
+            out_shardings=sh, key=("manydev-mesh",),
+            example_args=(jax.ShapeDtypeStruct((8, 8), jnp.float32),))
+
+    with _tmp_store(tmp_path / "cache"):
+        for build, where in ((one, dev), (meshed, sh)):
+            build()
+            cc.reset_in_process_cache()
+            s0 = cc.cache_stats()
+            out = build()(jax.device_put(x, where))
+            assert cc.cache_stats()["store_hits"] == s0["store_hits"] + 1
+            np.testing.assert_array_equal(np.asarray(out), x * 2 + 1)
+        assert out.sharding.is_equivalent_to(sh, 2)
+    assert cc.cache_stats()["store_errors"] == 0
+
+
+def _times_two_plus_one(x):
+    return x * 2 + 1
+
+
+def test_unloadable_store_entry_raises(tmp_path):
+    """An entry that exists but cannot be loaded is an error, not a
+    quiet recompile: that quiet miss is what hid the broken store."""
+    import jax
+    with _tmp_store(tmp_path / "cache"):
+        key = ("corrupt-entry-test",)
+        sds = jax.ShapeDtypeStruct((4,), np.float32)
+        cc.cached_jit(_times_two_plus_one, key=key, example_args=(sds,))
+        store = cc.executor_store()
+        (entry,) = os.listdir(store.root)
+        with open(os.path.join(store.root, entry), "wb") as fh:
+            fh.write(b"not a pickle")
+        cc.reset_in_process_cache()
+        with pytest.raises(RuntimeError, match="cannot be loaded"):
+            cc.cached_jit(_times_two_plus_one, key=key, example_args=(sds,))
+
+
+def test_store_rejects_a_program_reloaded_onto_other_devices(tmp_path,
+                                                             monkeypatch):
+    """libtpu reloads a one-device program compiled for a non-default
+    chip onto its first chip. The CPU backend does not, so play that
+    backend: the store must notice at load, not at the first call."""
+    import jax
+    from jax.experimental import serialize_executable as se
+    sds = jax.ShapeDtypeStruct((4,), np.float32)
+    on_dev0 = jax.jit(_times_two_plus_one).lower(sds).compile()
+    with _tmp_store(tmp_path / "cache"):
+        key = ("reloaded-elsewhere", 3)
+        cc.cached_jit(_times_two_plus_one, key=key, example_args=(
+            jax.ShapeDtypeStruct((4,), np.float32,
+                                 sharding=jax.sharding.SingleDeviceSharding(
+                                     jax.devices()[3])),))
+        cc.reset_in_process_cache()
+        monkeypatch.setattr(se, "deserialize_and_load",
+                            lambda *a, **k: on_dev0)
+        with pytest.raises(RuntimeError, match=r"compiled for devices "
+                           r"\[3\] but the backend reloaded it onto \[0\]"):
+            cc.cached_jit(_times_two_plus_one, key=key, example_args=(sds,))
+
+
 # ---------------------------------------------------------------------------
 # compile-once across executors / problem sizes (the acceptance row:
 # second run of any NEW N at a served (NB, dtype) pays zero compiles)
@@ -316,12 +449,13 @@ def test_tpu_device_body_jit_unified():
     taskpools) dispatching the same stable body share one jitted
     wrapper process-wide."""
     from types import SimpleNamespace
+    import jax
     from parsec_tpu.core.task import Chore, DeviceType
     from parsec_tpu.device.tpu import TPUDevice
 
     task = SimpleNamespace(task_class=SimpleNamespace(tc_id=1),
                            taskpool=SimpleNamespace(taskpool_id=1))
-    d1, d2 = TPUDevice(), TPUDevice()
+    d1, d2 = (TPUDevice(jax.devices()[0]) for _ in range(2))
     c1 = Chore(device_type=DeviceType.TPU, hook=_module_level_body)
     c2 = Chore(device_type=DeviceType.TPU, hook=_module_level_body)
     # distinct chore objects, distinct devices — one shared wrapper
