@@ -175,47 +175,61 @@ def sanitizer_runtime(var: str) -> Optional[str]:
     return None
 
 
+def _stamped(so: str, want: str) -> bool:
+    """``so`` is there and its stamp says it was built from ``want``."""
+    if not os.path.exists(so):
+        return False
+    try:
+        with open(so + ".srchash") as f:
+            return f.read().strip() == want
+    except OSError:
+        return False                # pre-hash .so (or stamp lost): rebuild
+
+
 def _build(var: str = "off") -> bool:
     so = so_path(var)
-    stamp = so + ".srchash"
     try:
         want = _stamp_want(var)
     except OSError as exc:
         _build_errors[var] = f"cannot read {_SRC}: {exc}"
         return False
-    if os.path.exists(so):
-        try:
-            with open(stamp) as f:
-                have = f.read().strip()
-        except OSError:
-            have = ""               # pre-hash .so (or stamp lost): rebuild
-        if have == want:
-            return True
+    if _stamped(so, want):
+        return True
+    # a name of this process's own: processes that start together (pytest
+    # -n 6) each compile, and each renames a whole file into place
+    tmp = so + f".{os.getpid()}.tmp"
     cmd = ["g++", *build_flags(var), "-shared", "-fPIC", "-pthread",
-           "-o", so + ".tmp", _SRC]
+           "-o", tmp, _SRC]
     # never compile UNDER a sanitizer runtime: a sanitized Python lane
     # (LD_PRELOAD=libtsan) would otherwise run g++/cc1plus themselves
     # through TSan's shadow — observed as a multi-minute hang
     env = dict(os.environ)
     env.pop("LD_PRELOAD", None)
     try:
-        proc = subprocess.run(cmd, check=True, capture_output=True,
-                              timeout=240, env=env)
-        del proc
-        os.replace(so + ".tmp", so)
-        with open(stamp, "w") as f:
-            f.write(want)
+        try:
+            subprocess.run(cmd, check=True, capture_output=True,
+                           timeout=240, env=env)
+        except FileNotFoundError:
+            _build_errors[var] = "g++ not found on PATH"
+            return False
+        if not _stamped(so, want):      # else another process was first
+            os.replace(tmp, so)
+            with open(tmp, "w") as f:
+                f.write(want)
+            os.replace(tmp, so + ".srchash")
         return True
-    except FileNotFoundError:
-        _build_errors[var] = "g++ not found on PATH"
     except subprocess.CalledProcessError as exc:
         tail = (exc.stderr or b"").decode(errors="replace")[-500:]
         _build_errors[var] = f"g++ failed (rc={exc.returncode}): {tail}"
     except (OSError, subprocess.SubprocessError) as exc:
         _build_errors[var] = f"build failed: {exc}"
-    # a binary whose stamp does not match the source is never loaded:
-    # what runs is what this checkout's core.cpp builds, or nothing
-    return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    # this build failed; another process may have got there first. A
+    # binary whose stamp does not match the source is never loaded: what
+    # runs is what this checkout's core.cpp builds, or nothing
+    return _stamped(so, want)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

@@ -28,6 +28,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import weakref
 
+from jax.profiler import TraceAnnotation
+
 from .future import DataCopyFuture
 from .reshape import resolve_reshape
 from .task import HookReturn, Task, TaskStatus
@@ -77,6 +79,39 @@ mca_param.register("runtime.ckpt_interval_s", 0.0,
                         "points; 0 = only the taskpool-count trigger")
 mca_param.register("runtime.ckpt_dir", "",
                    help="default directory for Context.enable_checkpoints")
+
+
+# the runtime's stages as they appear in a profiler trace: one span per
+# stage-timer site, constant names (benchmark/program_spans.py and an
+# operator's TensorBoard read them beside the device's operations)
+SPAN_INSERT = "parsec:insert"
+SPAN_SELECT = "parsec:select"
+SPAN_PARK = "parsec:park"
+SPAN_DISPATCH = "parsec:dispatch"
+SPAN_EXEC = "parsec:exec"
+SPAN_RELEASE = "parsec:release"
+
+
+class StageSpan:
+    """One pass through a stage-timer site, opened only where
+    ``context.stage_timers`` is on: a ``TraceAnnotation`` (a span on the
+    profiler's own clock, beside the ``/device:TPU:n`` planes, when a
+    session is live; next to nothing when none is) and the seconds it
+    took, which the site adds to its ``es.stats`` / ``insert_s`` sum."""
+
+    __slots__ = ("_ann", "_t0", "seconds")
+
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "StageSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
 
 
 class ExecutionStream:
@@ -151,12 +186,17 @@ class Context:
         # comm.collectives); resolved once like the release knobs
         self._comm_bcast = str(mca_param.get(
             "comm.bcast", 1)).lower() not in ("0", "off", "false")
-        # per-stage overhead timers (select/dispatch/release into
-        # es.stats, insert on DTD taskpools); enabled by the MCA param
-        # or the profiling `overhead` PINS module
-        self.stage_timers = str(mca_param.get(
+        # per-stage overhead timers and spans (select/park/dispatch/
+        # release on the streams, exec in the device module, insert on
+        # DTD taskpools). ``stage_timers`` is the one per-site test: on
+        # when asked for (the MCA param, or the profiling `overhead`
+        # PINS module through set_stage_timers) or while a profiler
+        # session is live (add_taskpool looks, once per pool)
+        self.stage_timers_asked = str(mca_param.get(
             "runtime.stage_timers", 0)).lower() not in ("0", "off",
                                                         "false", "")
+        self._profiler_live = False
+        self.stage_timers = self.stage_timers_asked
         # lineage record for fault recovery (runtime.lineage)
         self._track_completed = str(mca_param.get(
             "runtime.lineage", 1)).lower() not in ("0", "off", "false")
@@ -254,8 +294,23 @@ class Context:
         return self.comm.nb_ranks if self.comm is not None else 1
 
     # ------------------------------------------------------------------ API
+    def set_stage_timers(self, on: bool) -> bool:
+        """Ask for the stage timers (or stop asking); returns what was
+        asked before. A live profiler session keeps them on regardless."""
+        prev, self.stage_timers_asked = self.stage_timers_asked, bool(on)
+        self.stage_timers = self.stage_timers_asked or self._profiler_live
+        return prev
+
     def add_taskpool(self, tp: Taskpool) -> None:
         """parsec_context_add_taskpool analog (scheduling.c:678-727)."""
+        # a profiler session (jax.profiler.start_trace, TensorBoard's
+        # capture) started or stopped since the last pool: its traces get
+        # the runtime's stage spans with nothing to configure. Only the
+        # profiler's part of the flag moves here.
+        live = TraceAnnotation.is_enabled()
+        if live != self._profiler_live:
+            self._profiler_live = live
+            self.stage_timers = self.stage_timers_asked or live
         # registration-time static lint (analysis.lint = off|warn|error):
         # with `error`, a taskpool whose flow declarations carry hazards
         # (undeclared producers, WAW, cycles, ...) is refused BEFORE any
@@ -756,18 +811,12 @@ class Context:
                                       (self._active_taskpools or
                                        self._ndtd_live)):
                     continue
-                self._work_evt.wait(timeout=0.1)
+                self._park(0.1)
                 continue
             task = es.next_task
             es.next_task = None
             if task is None:
-                if self.stage_timers:
-                    t0 = time.perf_counter()
-                    task = self.scheduler.select(es)
-                    es.stats["select_s"] += time.perf_counter() - t0
-                    es.stats["select_calls"] += 1
-                else:
-                    task = self.scheduler.select(es)
+                task = self._select(es)
             if task is None and self._ndtd_live:
                 # native DTD pump (the insert→release loop behind the C
                 # ABI): native-bodied tasks drain entirely inside the
@@ -786,7 +835,7 @@ class Context:
                 # reselect avoids the lost-wakeup race; the timeout only
                 # bounds termdet/shutdown polling.
                 self._work_evt.clear()
-                task = self.scheduler.select(es)
+                task = self._select(es)
                 if task is None and self._ndtd_live and \
                         self._ndtd_pump(es):
                     # a native batch armed between the pump above and
@@ -794,7 +843,7 @@ class Context:
                     backoff = backoff_min
                     continue
                 if task is None:
-                    self._work_evt.wait(timeout=backoff)
+                    self._park(backoff)
                     backoff = min(backoff * 2, backoff_max)
                     continue
             backoff = backoff_min
@@ -819,28 +868,34 @@ class Context:
                 # released with the error instead of hanging (parsec_abort)
                 task.taskpool.abort(exc)
 
+    def _select(self, es: ExecutionStream) -> Optional[Task]:
+        if not self.stage_timers:
+            return self.scheduler.select(es)
+        with StageSpan(SPAN_SELECT) as span:
+            task = self.scheduler.select(es)
+        es.stats["select_s"] += span.seconds
+        es.stats["select_calls"] += 1
+        return task
+
+    def _park(self, timeout: float) -> None:
+        """A worker with nothing to run waits for schedule(),
+        add_taskpool() or start() to set the event."""
+        if not self.stage_timers:
+            self._work_evt.wait(timeout)
+            return
+        with StageSpan(SPAN_PARK):
+            self._work_evt.wait(timeout)
+
     def _task_progress(self, es: ExecutionStream, task: Task) -> None:
         """__parsec_task_progress analog (scheduling.c:472-535)."""
-        tp = task.taskpool
-        tc = task.task_class
-        # prepare_input (generated data_lookup analog): resolve inputs not
-        # attached by the release path (collection reads of startup tasks)
-        task.status = TaskStatus.PREPARE_INPUT
-        t0 = time.perf_counter() if (self.stage_timers and es is not None) \
-            else None
-        lookup = getattr(tc, "data_lookup", None)
-        if lookup is not None:
-            self.pins.prepare_input_begin(es, task)
-            lookup(task)
-            self.pins.prepare_input_end(es, task)
-        # execute: walk incarnations honoring the chore mask
-        task.status = TaskStatus.HOOK
-        self.pins.exec_begin(es, task)
-        rc = self._execute(es, task)
-        if t0 is not None:
+        if self.stage_timers:
             # dispatch = prepare_input + incarnation walk + hook call
             # (for a null body this IS the per-task dispatch overhead)
-            es.stats["dispatch_s"] += time.perf_counter() - t0
+            with StageSpan(SPAN_DISPATCH) as span:
+                rc = self._dispatch(es, task)
+            es.stats["dispatch_s"] += span.seconds
+        else:
+            rc = self._dispatch(es, task)
         if rc == HookReturn.ASYNC:
             return                      # device layer completes it later
         if rc == HookReturn.AGAIN:
@@ -850,6 +905,21 @@ class Context:
         if rc == HookReturn.ERROR:
             raise RuntimeError(f"all incarnations of {task!r} failed")
         self.complete_task(es, task)
+
+    def _dispatch(self, es: ExecutionStream, task: Task) -> HookReturn:
+        tc = task.task_class
+        # prepare_input (generated data_lookup analog): resolve inputs not
+        # attached by the release path (collection reads of startup tasks)
+        task.status = TaskStatus.PREPARE_INPUT
+        lookup = getattr(tc, "data_lookup", None)
+        if lookup is not None:
+            self.pins.prepare_input_begin(es, task)
+            lookup(task)
+            self.pins.prepare_input_end(es, task)
+        # execute: walk incarnations honoring the chore mask
+        task.status = TaskStatus.HOOK
+        self.pins.exec_begin(es, task)
+        return self._execute(es, task)
 
     def _execute(self, es: ExecutionStream, task: Task) -> HookReturn:
         """__parsec_execute analog (scheduling.c:124-203): try incarnations
@@ -900,7 +970,6 @@ class Context:
         (scheduling.c:441-470, parsec.c:1694-1921)."""
         task.status = TaskStatus.COMPLETE
         tp = task.taskpool
-        tc = task.task_class
         if es is not None:
             es.stats["executed"] += 1
         else:
@@ -917,8 +986,37 @@ class Context:
             self.grapher.task_executed(task)
 
         self.pins.release_deps_begin(es, task)
-        t_rel = time.perf_counter() if (self.stage_timers and
-                                        es is not None) else None
+        if self.stage_timers:
+            # a completion by a device manager (es is None) has its span
+            # too; only the es.stats sum needs a stream
+            with StageSpan(SPAN_RELEASE) as span:
+                self._release_deps(es, task)
+            if es is not None:
+                es.stats["release_s"] += span.seconds
+        else:
+            self._release_deps(es, task)
+        self.pins.release_deps_end(es, task)
+        self.pins.complete_exec_end(es, task)
+        # the always-on metrics plane adds NO hot-path work here: the
+        # per-stream es.stats["executed"] counters above already exist,
+        # and the registry exports their sum as
+        # parsec_tasks_completed_total at SCRAPE time (collector)
+        tp.addto_nb_tasks(-1)
+        # no task mempool here BY MEASUREMENT (round 5, PARITY
+        # "Mempools" row): completed tasks die young via refcounting
+        # (~0.7 µs/task); a prototyped per-thread freelist measured
+        # BREAK-EVEN warm (0.94 µs pop+reset) and cannot reduce the
+        # live-object count that drives GC pressure in startup bursts.
+        # The reference's mempool.c amortizes C malloc, which CPython's
+        # refcounting already covers. Native-path tasks use pmempool_*.
+
+    def _release_deps(self, es: Optional[ExecutionStream],
+                      task: Task) -> None:
+        """parsec_release_dep_fct analog (parsec.c:1783-1921): walk the
+        successors, count their dependencies down, schedule the ready
+        ones."""
+        tp = task.taskpool
+        tc = task.task_class
         ready: List[Task] = []
         # local refs accumulate and release in ONE striped-lock batch
         # (runtime.release_batch; parsec_release_dep_fct walks its
@@ -1034,22 +1132,6 @@ class Context:
                 es.next_task = best
             if ready:
                 self.schedule(es, ready)
-        if t_rel is not None:
-            es.stats["release_s"] += time.perf_counter() - t_rel
-        self.pins.release_deps_end(es, task)
-        self.pins.complete_exec_end(es, task)
-        # the always-on metrics plane adds NO hot-path work here: the
-        # per-stream es.stats["executed"] counters above already exist,
-        # and the registry exports their sum as
-        # parsec_tasks_completed_total at SCRAPE time (collector)
-        tp.addto_nb_tasks(-1)
-        # no task mempool here BY MEASUREMENT (round 5, PARITY
-        # "Mempools" row): completed tasks die young via refcounting
-        # (~0.7 µs/task); a prototyped per-thread freelist measured
-        # BREAK-EVEN warm (0.94 µs pop+reset) and cannot reduce the
-        # live-object count that drives GC pressure in startup bursts.
-        # The reference's mempool.c amortizes C malloc, which CPython's
-        # refcounting already covers. Native-path tasks use pmempool_*.
 
 
 class _SnapshotCollection:

@@ -29,6 +29,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Tuple
 
 from .base import Device
+from ..core.context import SPAN_EXEC, StageSpan
 from ..core.task import Chore, DeviceType, HookReturn, Task
 from ..utils import mca_param
 from ..utils.debug import debug_verbose, warning
@@ -151,6 +152,15 @@ class TPUDevice(Device):
                     enqueued = True
             if enqueued:
                 return HookReturn.ASYNC
+        if task.taskpool.context.stage_timers:
+            # the enqueue as the host pays it (staging device_puts,
+            # default_device, the jitted call until it returns), not the
+            # device's work
+            with StageSpan(SPAN_EXEC):
+                return self._launch(task, chore)
+        return self._launch(task, chore)
+
+    def _launch(self, task: Task, chore: Chore) -> HookReturn:
         if not chore.batchable:
             return self._run_hook(task, self._pinned(chore))
         return self._run_sync(task, chore)
@@ -417,73 +427,14 @@ class TPUDevice(Device):
         time."""
         ctx = self._context()
         group = [(t, c) for (t, c, _v, _s, _b) in entries]
-        (t0_, chore) = group[0]
-        tc = t0_.task_class
-        per_task = [v for (_t, _c, v, _s, _b) in entries]
+        tc = group[0][0].task_class
         try:
-            if len(group) == 1:
-                # batch_body chores self-jit in their hook — _run_sync's
-                # jit wrapper would double-jit them
-                hr = self._run_sync(t0_, chore) if chore.batchable \
-                    else self._run_hook(t0_, chore)
-                # the manager cannot fall through to a later chore the
-                # way Context._execute_task does (batch_dispatch assumes
-                # single-incarnation task classes — see the knob help):
-                # surface a non-DONE return instead of silently
-                # completing with stale/no outputs
-                if hr != HookReturn.DONE:
-                    raise RuntimeError(
-                        f"{tc.name}: singleton dispatch returned "
-                        f"{hr!r}; batch_dispatch supports only "
-                        "single-incarnation (DONE) task classes")
+            if ctx.stage_timers:
+                # one span per launch: a batch is one enqueue
+                with StageSpan(SPAN_EXEC):
+                    self._launch_group(entries, group)
             else:
-                tu = self.jax.tree_util
-                sig = entries[0][3]
-                # power-of-two bucketing (the wavefront executor's
-                # padding trick): arbitrary batch sizes would each
-                # compile a fresh program; padding by repeating the
-                # last task bounds the shape set to {2, 4, 8, ...} per
-                # class
-                B = len(group)
-                Bp = 1 << (B - 1).bit_length()
-                padded = per_task + [per_task[-1]] * (Bp - B)
-                treedefs = []
-                flat: List[Any] = []
-                for pos, s in enumerate(sig):
-                    if s is None:
-                        continue
-                    treedefs.append(
-                        tu.tree_flatten(per_task[0][pos])[1])
-                    for vals in padded:
-                        for leaf in tu.tree_leaves(vals[pos]):
-                            # re-commit only cross-device leaves: jit
-                            # raises on mixed committed placements
-                            if isinstance(leaf, self.jax.Array) and \
-                                    getattr(leaf, "device", None) not in \
-                                    (None, self.jax_device):
-                                leaf = self.jax.device_put(
-                                    leaf, self.jax_device)
-                            flat.append(leaf)
-                use_hook = self._hook_ok(tc, chore, group)
-                bsig = entries[0][4]
-                body_override = chore.batch_body(t0_) \
-                    if (chore.batch_body is not None and not use_hook) \
-                    else None
-                with self.jax.default_device(self.jax_device):
-                    res = self._vmapped(
-                        t0_.taskpool.taskpool_id, tc, chore, sig, Bp,
-                        treedefs, use_hook, bsig=bsig,
-                        body_override=body_override)(*flat)
-                outs_by_task = [
-                    self._normalize(tc, self.jax.tree_util.tree_map(
-                        lambda x, b=b: x[b], res))
-                    for b in range(len(group))]
-                for (t, _c), outs in zip(group, outs_by_task):
-                    t.output.update(outs)
-                with self._lock:
-                    self.stats["tasks"] += len(group)
-                self.stats["batches"] += 1
-                self.stats["batched_tasks"] += len(group)
+                self._launch_group(entries, group)
         except Exception as exc:  # noqa: BLE001 — abort, don't hang
             warning("device", "%s batch of %s failed: %s", self.name,
                     tc.name, exc)
@@ -505,6 +456,77 @@ class TPUDevice(Device):
                 from ..utils import debug_history
                 debug_history.dump_on_fatal(f"{self.name} completion")
                 t.taskpool.abort(exc)
+
+    def _launch_group(self, entries, group) -> None:
+        """The launch half of :meth:`_complete_batch`: one vmapped call
+        for the group (a singleton runs as it would unbatched), outputs
+        attached to every task. Raises where the launch fails."""
+        (t0_, chore) = group[0]
+        tc = t0_.task_class
+        per_task = [v for (_t, _c, v, _s, _b) in entries]
+        if len(group) == 1:
+            # batch_body chores self-jit in their hook — _run_sync's
+            # jit wrapper would double-jit them
+            hr = self._run_sync(t0_, chore) if chore.batchable \
+                else self._run_hook(t0_, chore)
+            # the manager cannot fall through to a later chore the
+            # way Context._execute_task does (batch_dispatch assumes
+            # single-incarnation task classes — see the knob help):
+            # surface a non-DONE return instead of silently
+            # completing with stale/no outputs
+            if hr != HookReturn.DONE:
+                raise RuntimeError(
+                    f"{tc.name}: singleton dispatch returned "
+                    f"{hr!r}; batch_dispatch supports only "
+                    "single-incarnation (DONE) task classes")
+        else:
+            tu = self.jax.tree_util
+            sig = entries[0][3]
+            # power-of-two bucketing (the wavefront executor's
+            # padding trick): arbitrary batch sizes would each
+            # compile a fresh program; padding by repeating the
+            # last task bounds the shape set to {2, 4, 8, ...} per
+            # class
+            B = len(group)
+            Bp = 1 << (B - 1).bit_length()
+            padded = per_task + [per_task[-1]] * (Bp - B)
+            treedefs = []
+            flat: List[Any] = []
+            for pos, s in enumerate(sig):
+                if s is None:
+                    continue
+                treedefs.append(
+                    tu.tree_flatten(per_task[0][pos])[1])
+                for vals in padded:
+                    for leaf in tu.tree_leaves(vals[pos]):
+                        # re-commit only cross-device leaves: jit
+                        # raises on mixed committed placements
+                        if isinstance(leaf, self.jax.Array) and \
+                                getattr(leaf, "device", None) not in \
+                                (None, self.jax_device):
+                            leaf = self.jax.device_put(
+                                leaf, self.jax_device)
+                        flat.append(leaf)
+            use_hook = self._hook_ok(tc, chore, group)
+            bsig = entries[0][4]
+            body_override = chore.batch_body(t0_) \
+                if (chore.batch_body is not None and not use_hook) \
+                else None
+            with self.jax.default_device(self.jax_device):
+                res = self._vmapped(
+                    t0_.taskpool.taskpool_id, tc, chore, sig, Bp,
+                    treedefs, use_hook, bsig=bsig,
+                    body_override=body_override)(*flat)
+            outs_by_task = [
+                self._normalize(tc, self.jax.tree_util.tree_map(
+                    lambda x, b=b: x[b], res))
+                for b in range(len(group))]
+            for (t, _c), outs in zip(group, outs_by_task):
+                t.output.update(outs)
+            with self._lock:
+                self.stats["tasks"] += len(group)
+            self.stats["batches"] += 1
+            self.stats["batched_tasks"] += len(group)
 
     def _normalize(self, tc, result) -> Dict[str, Any]:
         """Body result → dict keyed by output-flow name, with the same

@@ -58,6 +58,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..core.context import SPAN_INSERT, StageSpan
 from ..core.task import Chore, DeviceType, Flow, FlowAccess, Task
 from ..core.taskpool import DEPS_COUNTER, SuccessorRef, TaskClass
 from ..core.taskpool import Taskpool as CoreTaskpool
@@ -483,29 +484,30 @@ class Taskpool(CoreTaskpool):
         time and cached by object identity, so they must be treated as
         IMMUTABLE once inserted — mutating an array payload in place
         between inserts would silently serve the stale compile."""
-        timed = self.context is not None and self.context.stage_timers
-        t0 = time.perf_counter() if timed else None
+        ctx = self.context
+        if ctx is None or not ctx.stage_timers:
+            return self._insert_task(fn, args, priority, device, pure)
+        # the insert stage covers both engines: stage timers no longer
+        # force the Python one (ISSUE 13)
+        with StageSpan(SPAN_INSERT) as span:
+            out = self._insert_task(fn, args, priority, device, pure)
+        self.insert_s += span.seconds
+        self.insert_calls += 1
+        return out
+
+    def _insert_task(self, fn, args, priority, device, pure):
         self._check_insertable()
         if self.admission is not None:
             self.admission.admit(self, 1)
         eng = self._engine()
         if eng is not None:
             # native hot loop: returns the task's sequence number (the
-            # opaque handle — native tasks have no Python Task object).
-            # Stage timers no longer force the Python engine (ISSUE
-            # 13), so the insert-stage row is accounted here too.
-            out = eng.insert_rows(fn, [args], priority, device, pure)[0]
-            if timed:
-                self.insert_s += time.perf_counter() - t0
-                self.insert_calls += 1
-            return out
+            # opaque handle — native tasks have no Python Task object)
+            return eng.insert_rows(fn, [args], priority, device, pure)[0]
         tc = self._task_class_for(fn, self._shape_of(args), device,
                                   pure=pure)
         task = self._insert_one(tc, args, priority, None, None)
         self._throttle()
-        if timed:
-            self.insert_s += time.perf_counter() - t0
-            self.insert_calls += 1
         return task
 
     def insert_tasks(self, fn: Callable, rows, *, priority: int = 0,
@@ -538,8 +540,19 @@ class Taskpool(CoreTaskpool):
         priorities are a scheduling-lane hint consumed by the Python
         engine's schedulers; the native engine receives the scalar
         ``priority`` (lane-aware pools — wfq — never run native)."""
-        timed = self.context is not None and self.context.stage_timers
-        t0 = time.perf_counter() if timed else None
+        ctx = self.context
+        if ctx is None or not ctx.stage_timers:
+            return self._insert_tasks(fn, rows, priority, priorities,
+                                      device, pure)
+        # one span per CALL, however many rows it inserts
+        with StageSpan(SPAN_INSERT) as span:
+            out = self._insert_tasks(fn, rows, priority, priorities,
+                                     device, pure)
+        self.insert_s += span.seconds
+        self.insert_calls += len(out)
+        return out
+
+    def _insert_tasks(self, fn, rows, priority, priorities, device, pure):
         self._check_insertable()
         rows = list(rows)
         out: List[Optional[Task]] = []
@@ -555,11 +568,7 @@ class Taskpool(CoreTaskpool):
             self.admission.admit(self, len(rows))
         eng = self._engine()
         if eng is not None:
-            handles = eng.insert_rows(fn, rows, priority, device, pure)
-            if timed:
-                self.insert_s += time.perf_counter() - t0
-                self.insert_calls += len(rows)
-            return handles
+            return eng.insert_rows(fn, rows, priority, device, pure)
         shape0 = self._shape_of(rows[0])
         tc0 = self._task_class_for(fn, shape0, device, pure=pure)
         ready: List[Task] = []
@@ -592,9 +601,6 @@ class Taskpool(CoreTaskpool):
                 self._throttle()
         if ready:
             self.context.schedule(None, ready)
-        if timed:
-            self.insert_s += time.perf_counter() - t0
-            self.insert_calls += len(rows)
         return out
 
     # -- insertion internals ----------------------------------------------

@@ -4,7 +4,7 @@ Reference systems (SURVEY §2.7/§2.13):
 - PINS callback chains on runtime events (parsec/mca/pins/pins.h:26-53).
 - Binary trace with a dictionary of paired begin/end keys (profiling.c),
   converted offline to pandas tables — here :mod:`trace` records events
-  in-memory and exports to pandas/JSON directly.
+  in-memory and exports to records/JSON directly.
 - DOT grapher of the executed DAG (parsec_prof_grapher.c).
 """
 

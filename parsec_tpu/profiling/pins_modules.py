@@ -312,9 +312,11 @@ class OverheadProfiler(PinsModule):
     (release-deps: successor iteration, dependency countdown,
     scheduling). The timers themselves live in the runtime hot loops
     behind ``context.stage_timers`` (one attribute test when off —
-    ``runtime.stage_timers`` MCA param); this module flips the flag on
-    install and aggregates the collected stream/taskpool counters into
-    the per-task overhead budget the taskrate bench reports.
+    ``runtime.stage_timers`` MCA param); this module asks for the flag
+    on install and aggregates the collected stream/taskpool counters
+    into the per-task overhead budget the taskrate bench reports. The
+    same sites open the ``parsec:`` spans of a profiler trace
+    (``core.context.StageSpan``).
 
     Reported seconds are THREAD seconds (summed across workers): with W
     busy workers, per-task wall overhead is roughly the per-task thread
@@ -324,13 +326,12 @@ class OverheadProfiler(PinsModule):
 
     def install(self, context) -> "OverheadProfiler":
         super().install(context)
-        self._prev_flag = context.stage_timers
-        context.stage_timers = True
+        self._prev_flag = context.set_stage_timers(True)
         return self
 
     def uninstall(self) -> None:
         super().uninstall()
-        self.context.stage_timers = self._prev_flag
+        self.context.set_stage_timers(self._prev_flag)
 
     def report(self) -> Dict[str, Any]:
         agg = {"select_s": 0.0, "select_calls": 0, "dispatch_s": 0.0,

@@ -44,12 +44,9 @@ executed dependency edges (the parent links ARE dep edges).
 from __future__ import annotations
 
 import itertools
-import threading
 from typing import Any, Dict, List, Optional, Sequence
 
 _counter = itertools.count(1)
-_rid_counter = itertools.count(1)
-_lock = threading.Lock()
 
 #: rank field width of an integer span id (ids are ints, not strings:
 #: the mint runs once per task on the null-task hot path, where the
@@ -84,12 +81,6 @@ def mint_rid(name: str) -> str:
     of a distributed submission mints the SAME rid without any wire
     exchange — one span tree spans the mesh."""
     return f"req:{name}"
-
-
-def local_rid(rank: int = 0) -> str:
-    """A rank-local rid for untenanted/ad-hoc tracing."""
-    with _lock:
-        return f"req:r{rank}-{next(_rid_counter)}"
 
 
 # ---------------------------------------------------------------------------

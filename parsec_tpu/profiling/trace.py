@@ -15,7 +15,7 @@ is dropped and the per-ring ``dropped`` counter advances — bounded
 memory is the contract, and ``Trace.dropped()`` is the honesty counter
 (a wrapped serving trace says HOW MANY events it lost, never silently).
 
-Export goes directly to pandas (``to_pandas``) or JSON — the offline
+Export goes directly to records (``to_records``) or JSON — the offline
 converter collapses into the runtime since the host side is already
 Python. Dumped traces carry a ``meta`` block ({rank, t0,
 clock_offset_s, dropped}) so the multi-rank merge in
@@ -302,6 +302,11 @@ class Trace:
     #   is materialized at read time, byte-identical to the classic
     #   shape. Tradeoff: a rid'd task that crashes mid-body leaves no
     #   event (the rid-less profiler pair still covers crash forensics).
+    # What a ``task`` span times: from exec_begin to complete_task, on
+    # the HOST clock (perf_counter). With a device body (a jitted call
+    # that returns at enqueue) it ends at release, after the enqueue, not
+    # when the device finishes; device time is in a jax.profiler trace,
+    # beside the runtime's parsec: stage spans (core.context.StageSpan).
     def task_begin(self, es, task) -> None:
         tp = task.taskpool
         if tp.trace_rid is not None:
@@ -409,10 +414,6 @@ class Trace:
             events.extend(src.to_records(t0))
         events.sort(key=lambda ev: ev["t"])
         return events
-
-    def to_pandas(self):
-        import pandas as pd
-        return pd.DataFrame(self.to_records())
 
     def meta(self) -> Dict[str, Any]:
         """Per-rank trace metadata: rank, the local perf_counter origin

@@ -421,7 +421,9 @@ class ServingRuntime:
                 return (f"ready-queue depth {depth} > watermark {wm} "
                         "(serving.shed_watermark)")
         ov = float(mca_param.get("serving.shed_overhead_us", 0.0))
-        if ov > 0 and self.ctx.stage_timers:
+        # what was asked for, not ctx.stage_timers: a live profiler
+        # session turns the timers on too, and must not start shedding
+        if ov > 0 and self.ctx.stage_timers_asked:
             total_s = executed = 0
             for es in self.ctx.streams:
                 total_s += (es.stats.get("select_s", 0.0) +

@@ -12,6 +12,12 @@ def _with_size(size):
     mca_param.set("debug.history_size", size)
 
 
+def setup_function(_fn):
+    # another file's tasks, run with the ring on in this worker process
+    # before this file, leave their marks behind
+    debug_history.purge()
+
+
 def teardown_function(_fn):
     mca_param.unset("debug.history_size")
     debug_history.purge()

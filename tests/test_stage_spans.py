@@ -1,0 +1,243 @@
+"""The runtime's stage spans (``parsec:insert/select/park/dispatch/exec/
+release``): opened at the stage-timer sites behind ``context.stage_timers``,
+which is on while a profiler session is live; written into the profiler's
+own trace and, as before, summed into ``es.stats`` / ``Taskpool.insert_s``.
+A small DTD GEMM with accelerator-typed bodies on the CPU platform, read
+back with ``jax.profiler.ProfileData``."""
+
+import glob
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import parsec_tpu as parsec
+import parsec_tpu.device.tpu     # registers the knobs set below
+from parsec_tpu import dtd, serving
+from parsec_tpu.core import context as context_mod
+from parsec_tpu.core.task import DeviceType
+from parsec_tpu.data.matrix import TiledMatrix
+from parsec_tpu.profiling.pins_modules import new_module
+from parsec_tpu.utils import mca_param
+
+NB, MT, NT, KT = 16, 3, 2, 2
+TASKS, INSERT_CALLS = MT * NT * KT, MT
+STAGES = ("insert", "select", "park", "dispatch", "exec", "release")
+
+
+def _gemm_body(a, b, c):
+    return c + a @ b
+
+
+def _matrix(name, mt, nt):
+    m = TiledMatrix(mt * NB, nt * NB, NB, NB, name=name)
+    for key in m.keys():
+        m.write_tile(key, jnp.ones((NB, NB), jnp.float32))
+    return m
+
+
+def _run_pool(ctx, name, timeout=60.0):
+    """One GEMM through a new pool, every task on an accelerator module:
+    one insert_tasks call per row of C tiles, as insert_gemm_dtd makes."""
+    a, b, c = _matrix("A", MT, KT), _matrix("B", KT, NT), _matrix("C", MT, NT)
+    tp = dtd.Taskpool(name)
+    ctx.add_taskpool(tp)
+    for m in range(MT):
+        tp.insert_tasks(
+            _gemm_body,
+            [(dtd.TileArg(a, (m, k), dtd.INPUT),
+              dtd.TileArg(b, (k, n), dtd.INPUT),
+              dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True))
+             for n in range(NT) for k in range(KT)],
+            device=DeviceType.TPU, pure=True)
+    waiter = threading.Thread(target=tp.wait, daemon=True)
+    waiter.start()
+    waiter.join(timeout)
+    assert not waiter.is_alive(), f"pool {name} did not drain"
+    assert tp._native is None
+    assert float(c.data_of((0, 0))[0, 0]) == 1.0 + KT * NB
+    return tp
+
+
+@pytest.fixture
+def make_ctx():
+    """Contexts on the Python engine (the one a chip gets: engine_for
+    declines a real accelerator), with the knobs a case sets undone."""
+    made, knobs = [], []
+
+    def make(scheduler="lfq", **params):
+        # one accelerator module (the suite runs on 8 virtual devices)
+        params = {"runtime.native_dtd": 0, "device.tpu.max_devices": 1,
+                  **params}
+        for knob, value in params.items():
+            mca_param.set(knob, value)
+            knobs.append(knob)
+        ctx = parsec.init(nb_cores=3, scheduler=scheduler)
+        ctx.start()
+        made.append(ctx)
+        return ctx
+
+    yield make
+    for ctx in made:
+        parsec.fini(ctx)
+    for knob in knobs:
+        mca_param.unset(knob)
+
+
+class _Session:
+    """``jax.profiler`` session around the body; ``spans`` afterwards:
+    ``{thread: {stage: [(start_ns, end_ns), ...]}}`` of the ``parsec:``
+    events."""
+
+    def __init__(self, tmp_path):
+        self.dir, self.spans = str(tmp_path / "trace"), {}
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(self.dir + "/plugins/profile/*/*.xplane.pb")
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("parsec:"):
+                        self.spans.setdefault((plane.name, i), {}) \
+                            .setdefault(e.name[len("parsec:"):], []).append(
+                                (e.start_ns, e.start_ns + e.duration_ns))
+
+    def count(self, stage):
+        return sum(len(t.get(stage, ())) for t in self.spans.values())
+
+    def seconds(self, stage):
+        return 1e-9 * sum(hi - lo for t in self.spans.values()
+                          for lo, hi in t.get(stage, ()))
+
+
+@pytest.mark.parametrize("scheduler,batch", [
+    ("lfq", 0), ("gd", 0), ("wfq", 0), ("lfq", 1), ("gd", 1), ("wfq", 1)])
+def test_a_traced_pool_has_its_stages_in_the_profile(
+        make_ctx, tmp_path, scheduler, batch):
+    ctx = make_ctx(scheduler, **{"device.tpu.batch_dispatch": batch})
+    _run_pool(ctx, "warm")                  # compiles; no session, no span
+    assert not ctx.stage_timers
+    with _Session(tmp_path) as prof:
+        tp = _run_pool(ctx, "traced")
+        assert ctx.stage_timers
+    assert {s for t in prof.spans.values() for s in t} <= set(STAGES)
+    assert prof.count("insert") == INSERT_CALLS
+    assert prof.count("dispatch") == TASKS
+    assert prof.count("release") == TASKS
+    assert prof.count("select") >= 1
+    workers = [t for t in prof.spans.values() if "select" in t]
+    assert 1 <= len(workers) <= ctx.nb_cores
+    if batch:
+        # the manager launches (one span a launch, however many tasks) and
+        # completes: its thread selects nothing
+        assert 1 <= prof.count("exec") <= TASKS
+        (manager,) = [t for t in prof.spans.values() if "exec" in t]
+        assert "select" not in manager and "dispatch" not in manager
+        assert len(manager["release"]) == TASKS
+    else:
+        assert prof.count("exec") == TASKS
+        for thread in prof.spans.values():      # nested, same thread
+            for lo, hi in thread.get("exec", ()):
+                assert any(d0 <= lo and hi <= d1
+                           for d0, d1 in thread["dispatch"])
+    # the sums the `overhead` module reports are of the same passes
+    stats = {k: sum(es.stats[k] for es in ctx.streams)
+             for k in ("select_s", "select_calls", "dispatch_s", "release_s")}
+    assert stats["select_calls"] == prof.count("select")
+    assert tp.insert_calls == TASKS
+    pairs = [(tp.insert_s, prof.seconds("insert")),
+             (stats["dispatch_s"], prof.seconds("dispatch"))]
+    if not batch:       # a manager's completion has no stream to sum into
+        pairs.append((stats["release_s"], prof.seconds("release")))
+    for summed, spanned in pairs:
+        assert summed > 0 and abs(spanned - summed) <= 0.1 * summed
+    # a select is a microsecond: the span's own cost shows, so a bound
+    assert 0 < stats["select_s"] <= prof.seconds("select") \
+        <= stats["select_s"] + 50e-6 * stats["select_calls"]
+
+
+def test_no_session_no_span_and_the_flag_follows_the_session(
+        make_ctx, tmp_path, monkeypatch):
+    made = []
+
+    class Counting(context_mod.StageSpan):
+        def __init__(self, name):
+            made.append(name)
+            super().__init__(name)
+
+    for site in (context_mod, dtd, parsec_tpu.device.tpu):
+        monkeypatch.setattr(site, "StageSpan", Counting)
+    ctx = make_ctx()
+    _run_pool(ctx, "untraced")
+    assert not ctx.stage_timers and made == []
+    assert all(es.stats["dispatch_s"] == 0.0 for es in ctx.streams)
+    with _Session(tmp_path) as prof:
+        _run_pool(ctx, "traced")
+        assert ctx.stage_timers and not ctx.stage_timers_asked
+    assert prof.count("dispatch") == TASKS == made.count("parsec:dispatch")
+    # still on until a pool is added: the profiler is read once per pool,
+    # and without a session a span is next to nothing
+    assert ctx.stage_timers
+    _run_pool(ctx, "after")
+    assert not ctx.stage_timers
+    del made[:]
+    _run_pool(ctx, "after2")
+    assert made == [] or set(made) == {"parsec:park"}   # a worker mid-wait
+
+
+@pytest.mark.parametrize("how", ["parameter", "overhead_module"])
+def test_what_was_asked_for_is_not_switched_off_by_the_profiler(
+        make_ctx, tmp_path, how):
+    if how == "parameter":
+        ctx = make_ctx(**{"runtime.stage_timers": 1})
+    else:
+        ctx = make_ctx()
+        mod = new_module("overhead").install(ctx)
+    assert ctx.stage_timers and ctx.stage_timers_asked
+    _run_pool(ctx, "asked")
+    with _Session(tmp_path) as prof:
+        _run_pool(ctx, "traced")
+    _run_pool(ctx, "after")
+    assert ctx.stage_timers and prof.count("dispatch") == TASKS
+    assert sum(es.stats["dispatch_s"] for es in ctx.streams) > 0
+    if how == "overhead_module":
+        rep = mod.report()
+        assert rep["executed"] == 3 * TASKS == rep["insert_calls"]
+        assert rep["per_task_us"]["dispatch"] > 0
+        mod.uninstall()
+        assert not ctx.stage_timers and not ctx.stage_timers_asked
+
+
+def test_overhead_module_installed_under_a_session_outlives_it(
+        make_ctx, tmp_path):
+    ctx = make_ctx()
+    with _Session(tmp_path):
+        _run_pool(ctx, "traced")
+        mod = new_module("overhead").install(ctx)
+    _run_pool(ctx, "after")
+    assert ctx.stage_timers
+    mod.uninstall()
+    assert not ctx.stage_timers
+
+
+def test_a_live_profiler_does_not_start_the_overhead_shedder(
+        make_ctx, tmp_path):
+    """serving's second shedding trigger reads the per-stage sums: only
+    where they were asked for. A profiler changes records, no behaviour."""
+    ctx = make_ctx("wfq", **{"serving.shed_overhead_us": 0.001})
+    rt = serving.enable(ctx)
+    with _Session(tmp_path):
+        _run_pool(ctx, "traced")
+        assert ctx.stage_timers
+        assert sum(es.stats["dispatch_s"] for es in ctx.streams) > 0
+        assert rt._overload_reason() is None
+    ctx.set_stage_timers(True)
+    assert "serving.shed_overhead_us" in rt._overload_reason()
