@@ -1,12 +1,13 @@
 """Ex07: observability + runtime knobs — PINS counters, trace export,
-the batching manager, and the THREAD_MULTIPLE comm option.
+group launches, and the THREAD_MULTIPLE comm option.
 
 Shows the round-4 surfaces working together on a DTD GEMM:
 - ``pins=counters`` (the pins/papi analog): per-task-class rusage/wall
   deltas sampled at EXEC begin/end;
 - ``Trace`` with Chrome-trace export (open the JSON in Perfetto);
-- ``device.tpu.batch_dispatch=1``: the per-device manager thread
-  batches same-signature pure DTD bodies into one vmapped dispatch;
+- group launches: a worker that selects several ready pure DTD bodies
+  of one signature has the device module issue them as one program
+  (``batches`` / ``batched_tasks`` in the module's statistics);
 - ``comm.thread_multiple`` is a knob of the multi-process socket engine
   (see tests/test_socket_comm.py for 2-rank runs) — single-process runs
   here, so it is only printed, not exercised.
@@ -36,74 +37,68 @@ def main():
     A_h = rng.standard_normal((n, n)).astype(np.float32)
     B_h = rng.standard_normal((n, n)).astype(np.float32)
 
-    mca_param.set("device.tpu.batch_dispatch", 1)   # manager batching
-    try:
-        ctx = parsec.init(nb_cores=2)
-        counters = Counters().install(ctx)
-        trace = Trace().install(ctx)
-        ctx.start()
+    ctx = parsec.init(nb_cores=2)
+    counters = Counters().install(ctx)
+    trace = Trace().install(ctx)
+    ctx.start()
 
-        A = TiledMatrix.from_array(A_h, nb, nb, name="A")
-        B = TiledMatrix.from_array(B_h, nb, nb, name="B")
-        C = TiledMatrix.from_array(np.zeros((n, n), np.float32), nb, nb,
-                                   name="C")
-        tp = dtd.Taskpool("gemm")
-        ctx.add_taskpool(tp)
-        insert_gemm_dtd(tp, A, B, C)
-        tp.wait()
+    A = TiledMatrix.from_array(A_h, nb, nb, name="A")
+    B = TiledMatrix.from_array(B_h, nb, nb, name="B")
+    C = TiledMatrix.from_array(np.zeros((n, n), np.float32), nb, nb,
+                               name="C")
+    tp = dtd.Taskpool("gemm")
+    ctx.add_taskpool(tp)
+    insert_gemm_dtd(tp, A, B, C)
+    tp.wait()
 
-        ref = A_h @ B_h
-        err = np.abs(C.to_array() - ref).max() / np.abs(ref).max()
-        assert err < 1e-2, f"GEMM wrong through batch_dispatch: {err:.2e}"
-        print(f"GEMM ok, rel err {err:.2e}")
+    ref = A_h @ B_h
+    err = np.abs(C.to_array() - ref).max() / np.abs(ref).max()
+    assert err < 1e-2, f"GEMM wrong: {err:.2e}"
+    print(f"GEMM ok, rel err {err:.2e}")
 
-        # NOTE the interaction: under batch_dispatch tasks complete
-        # ASYNC on the manager thread, so the per-thread rusage deltas
-        # are skipped (cross-thread guard) and counted as async_tasks —
-        # only wall time is cross-thread meaningful. Run with the knob
-        # off to see utime/minflt populate.
-        print("\npins/counters (papi analog) per task class:")
-        for cls, tot in counters.report().items():
-            print(f"  {cls}: tasks={int(tot['tasks'])} "
-                  f"wall={tot['wall_s']*1e3:.1f}ms "
-                  f"async={int(tot.get('async_tasks', 0))} "
-                  f"utime={tot.get('utime_s', 0)*1e3:.1f}ms "
-                  f"minflt={int(tot.get('minflt', 0))}")
+    # NOTE: the members of a group launch begin and end together on
+    # the worker that formed the group, so each member's deltas span
+    # the whole group. A task a device completes on another thread
+    # (HookReturn.ASYNC) is counted as async_tasks, wall time only.
+    print("\npins/counters (papi analog) per task class:")
+    for cls, tot in counters.report().items():
+        print(f"  {cls}: tasks={int(tot['tasks'])} "
+              f"wall={tot['wall_s']*1e3:.1f}ms "
+              f"async={int(tot.get('async_tasks', 0))} "
+              f"utime={tot.get('utime_s', 0)*1e3:.1f}ms "
+              f"minflt={int(tot.get('minflt', 0))}")
 
-        stats = [d.dump_statistics() for d in ctx.devices.devices
-                 if d.name.startswith("tpu")]
-        batched = sum(s.get("batched_tasks", 0) for s in stats)
-        batches = sum(s.get("batches", 0) for s in stats)
-        print(f"\nbatching manager: {batched} tasks in {batches} "
-              f"vmapped batches")
+    stats = [d.dump_statistics() for d in ctx.devices.devices
+             if d.name.startswith("tpu")]
+    batched = sum(s.get("batched_tasks", 0) for s in stats)
+    batches = sum(s.get("batches", 0) for s in stats)
+    print(f"\ngroup launches: {batched} tasks in {batches} launches")
 
-        out = os.path.join(tempfile.gettempdir(), "ex07_trace.json")
-        trace.dump_chrome_trace(out)
-        print(f"Chrome trace written to {out} (open in Perfetto)")
+    out = os.path.join(tempfile.gettempdir(), "ex07_trace.json")
+    trace.dump_chrome_trace(out)
+    print(f"Chrome trace written to {out} (open in Perfetto)")
 
-        # ISSUE 9: the always-on metrics plane — Prometheus text +
-        # JSON statusz, no listener needed (set --mca
-        # serving.metrics_port 9100 for the HTTP /metrics + /statusz)
-        print("\n/metrics excerpt:")
-        for line in ctx.metrics_text().splitlines():
-            if line.startswith(("parsec_tasks_completed_total",
-                                "parsec_sched_ready_tasks")):
-                print(" ", line)
-        sz = ctx.statusz()
-        print(f"statusz: scheduler={sz['scheduler']} "
-              f"streams={len(sz['streams'])} "
-              f"metric_families={len(sz['metrics'])}")
-        # request tracing: submissions through Context.submit mint a
-        # rid; `python -m parsec_tpu.profiling.tools critpath <rid>
-        # rank*.json` prints the admission/queue/exec/wire breakdown
-        print(f"\ncomm.thread_multiple = "
-              f"{mca_param.get('comm.thread_multiple', 0)} "
-              "(socket-engine knob; see tests/test_socket_comm.py)")
+    # ISSUE 9: the always-on metrics plane — Prometheus text +
+    # JSON statusz, no listener needed (set --mca
+    # serving.metrics_port 9100 for the HTTP /metrics + /statusz)
+    print("\n/metrics excerpt:")
+    for line in ctx.metrics_text().splitlines():
+        if line.startswith(("parsec_tasks_completed_total",
+                            "parsec_sched_ready_tasks")):
+            print(" ", line)
+    sz = ctx.statusz()
+    print(f"statusz: scheduler={sz['scheduler']} "
+          f"streams={len(sz['streams'])} "
+          f"metric_families={len(sz['metrics'])}")
+    # request tracing: submissions through Context.submit mint a
+    # rid; `python -m parsec_tpu.profiling.tools critpath <rid>
+    # rank*.json` prints the admission/queue/exec/wire breakdown
+    print(f"\ncomm.thread_multiple = "
+          f"{mca_param.get('comm.thread_multiple', 0)} "
+          "(socket-engine knob; see tests/test_socket_comm.py)")
 
-        counters.uninstall()
-        parsec.fini(ctx)
-    finally:
-        mca_param.unset("device.tpu.batch_dispatch")
+    counters.uninstall()
+    parsec.fini(ctx)
 
 
 if __name__ == "__main__":

@@ -32,7 +32,7 @@ from jax.profiler import TraceAnnotation
 
 from .future import DataCopyFuture
 from .reshape import resolve_reshape
-from .task import HookReturn, Task, TaskStatus
+from .task import GROUP_SIZES, Chore, HookReturn, Task, TaskStatus
 from .taskpool import DataRef, SuccessorRef, Taskpool
 from ..utils import debug_history, mca_param
 from ..utils.debug import debug_verbose, warning
@@ -857,7 +857,11 @@ class Context:
                 continue
             es.stats["selected"] += 1
             try:
-                self._task_progress(es, task)
+                chore = self._group_chore(task)
+                if chore is None:
+                    self._task_progress(es, task)
+                else:
+                    self._group_progress(es, task, chore)
             except Exception as exc:  # noqa: BLE001 - worker must survive
                 warning("scheduling", "task %r raised: %s", task, exc)
                 import traceback
@@ -888,14 +892,11 @@ class Context:
 
     def _task_progress(self, es: ExecutionStream, task: Task) -> None:
         """__parsec_task_progress analog (scheduling.c:472-535)."""
-        if self.stage_timers:
-            # dispatch = prepare_input + incarnation walk + hook call
-            # (for a null body this IS the per-task dispatch overhead)
-            with StageSpan(SPAN_DISPATCH) as span:
-                rc = self._dispatch(es, task)
-            es.stats["dispatch_s"] += span.seconds
-        else:
-            rc = self._dispatch(es, task)
+        self._executed(
+            es, task, self._timed_dispatch(es, self._dispatch, es, task))
+
+    def _executed(self, es: ExecutionStream, task: Task,
+                  rc: HookReturn) -> None:
         if rc == HookReturn.ASYNC:
             return                      # device layer completes it later
         if rc == HookReturn.AGAIN:
@@ -906,29 +907,151 @@ class Context:
             raise RuntimeError(f"all incarnations of {task!r} failed")
         self.complete_task(es, task)
 
+    def _timed_dispatch(self, es: ExecutionStream, dispatch, *args):
+        """One task's pass through ``dispatch``, under its span where
+        the stage timers are on."""
+        if not self.stage_timers:
+            return dispatch(*args)
+        # dispatch = prepare_input + incarnation walk + hook call
+        # (for a null body this IS the per-task dispatch overhead)
+        with StageSpan(SPAN_DISPATCH) as span:
+            rc = dispatch(*args)
+        es.stats["dispatch_s"] += span.seconds
+        return rc
+
+    # ------------------------------------------------------ group launch
+    # A worker that holds a ready accelerator task whose chore has a
+    # group program takes the ready tasks of the same body it can select
+    # and has the device module issue them as one launch: one trip
+    # through jit dispatch (and one hand-off of the GIL) for the group
+    # instead of one per task. Formed on the thread that already holds
+    # the tasks; G = 1 is _task_progress.
+
+    @staticmethod
+    def _group_chore(task: Task) -> Optional[Chore]:
+        """The first incarnation the task's mask leaves, if it offers a
+        group program (``batch_body`` + ``batch_sig``: DTD pure woven
+        bodies; ``batch_hook`` on a batchable body) and does not veto the
+        task; else None, and ``_execute`` walks the incarnations."""
+        for i, chore in enumerate(task.task_class.incarnations):
+            if task.chore_mask & (1 << i):
+                break
+        else:
+            return None
+        if chore.batch_body is not None:
+            if chore.batch_sig is None:
+                return None
+        elif not chore.batchable or chore.batch_hook is None:
+            return None
+        if chore.evaluate is not None and not chore.evaluate(task):
+            return None
+        return chore
+
+    def _take_group(self, es: ExecutionStream, task: Task, chore: Chore,
+                    limit: int) -> List[Task]:
+        """``task`` and the tasks the scheduler hands this worker next,
+        while they are of the same taskpool, class, first incarnation and
+        ``batch_sig``, up to ``limit``. The first that differs ends the
+        group and waits in the bypass slot: nothing is pushed back, so
+        the scheduler's order is what it was."""
+        tp, tc = task.taskpool, task.task_class
+        bsig = chore.batch_sig(task) if chore.batch_sig is not None \
+            else None
+        tasks = [task]
+        while len(tasks) < limit:
+            nxt = self._select(es)
+            if nxt is None:
+                break
+            if nxt.taskpool.cancelled:
+                nxt.taskpool.addto_nb_tasks(-1)      # as _worker_main
+                continue
+            if nxt.taskpool is not tp or nxt.task_class is not tc or \
+                    self._group_chore(nxt) is not chore or \
+                    (bsig is not None and chore.batch_sig(nxt) != bsig):
+                es.next_task = nxt
+                break
+            es.stats["selected"] += 1
+            tasks.append(nxt)
+        return tasks
+
+    def _group_progress(self, es: ExecutionStream, task: Task,
+                        chore: Chore) -> None:
+        """``_task_progress`` of ``task`` and the ready tasks of its body
+        this worker can select: every one is prepared, announced and
+        completed exactly once, as alone. The device module says how many
+        one launch may carry and has one group in flight at a time: what
+        a launch made waits on the device for its members' release, so
+        the workers take turns, each holding one task until its turn. A
+        launch that raises leaves the worker's handler to abort the
+        pool, every load released."""
+        dev = self.devices.device_for(chore.device_type, task)
+        limit = dev.group_limit(task) if dev is not None else 0
+        tasks, held = [task], 1
+        try:
+            if limit:
+                with dev.group_turn:
+                    tasks = self._take_group(es, task, chore, limit)
+                    if len(tasks) >= GROUP_SIZES[-1]:
+                        held = len(tasks)
+                        dev.add_load(held - 1)
+                        self._group_launch(es, tasks, chore, dev)
+                        return
+        finally:
+            if dev is not None:
+                dev.release_load(held)
+        for task in tasks:      # too few, or a module without groups
+            self._task_progress(es, task)
+
+    def _group_launch(self, es: ExecutionStream, tasks: List[Task],
+                      chore: Chore, dev) -> None:
+        for task in tasks:      # a dispatch span per task, as alone
+            self._timed_dispatch(es, self._prepare_input, es, task)
+        done = 0
+        while done < len(tasks):
+            # the largest group the module can make of them, never
+            # padded; released before the next launch
+            n = dev.execute_group(es, tasks[done:], chore)
+            if n:
+                for task in tasks[done:done + n]:
+                    self._mark_exe(es, task)
+                    self.complete_task(es, task)
+            else:               # the module sends this one alone
+                n = 1
+                self._executed(es, tasks[done],
+                               self._execute(es, tasks[done]))
+            # a member goes with its release, and its inputs with it
+            tasks[done:done + n] = [None] * n
+            done += n
+
     def _dispatch(self, es: ExecutionStream, task: Task) -> HookReturn:
-        tc = task.task_class
+        self._prepare_input(es, task)
+        # execute: walk incarnations honoring the chore mask
+        return self._execute(es, task)
+
+    def _prepare_input(self, es: ExecutionStream, task: Task) -> None:
         # prepare_input (generated data_lookup analog): resolve inputs not
         # attached by the release path (collection reads of startup tasks)
         task.status = TaskStatus.PREPARE_INPUT
-        lookup = getattr(tc, "data_lookup", None)
+        lookup = getattr(task.task_class, "data_lookup", None)
         if lookup is not None:
             self.pins.prepare_input_begin(es, task)
             lookup(task)
             self.pins.prepare_input_end(es, task)
-        # execute: walk incarnations honoring the chore mask
         task.status = TaskStatus.HOOK
         self.pins.exec_begin(es, task)
-        return self._execute(es, task)
+
+    @staticmethod
+    def _mark_exe(es, task: Task) -> None:
+        if debug_history.enabled():     # DEBUG_MARK_EXE analog
+            debug_history.mark("EXE %s%r es=%s", task.task_class.name,
+                               tuple(task.locals),
+                               getattr(es, "th_id", -1))
 
     def _execute(self, es: ExecutionStream, task: Task) -> HookReturn:
         """__parsec_execute analog (scheduling.c:124-203): try incarnations
         in declaration order, skipping masked/vetoed ones."""
         tc = task.task_class
-        if debug_history.enabled():     # DEBUG_MARK_EXE analog
-            debug_history.mark("EXE %s%r es=%s", tc.name,
-                               tuple(task.locals),
-                               getattr(es, "th_id", -1))
+        self._mark_exe(es, task)
         for i, chore in enumerate(tc.incarnations):
             if not (task.chore_mask & (1 << i)):
                 continue

@@ -99,16 +99,25 @@ class Chore:
     batch_hook: Optional[Callable[..., Any]] = None
     batch_hook_shared: Optional[Sequence[str]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
-    # e.g. DTD's woven argspec) can still opt into manager batching by
+    # e.g. DTD's woven argspec) can still opt into group launches by
     # providing BOTH of: ``batch_sig(task) -> hashable`` — an extra
     # grouping key such that tasks with equal keys share one pure body —
     # and ``batch_body(task) -> fn(*flow_values)`` — that pure body
-    # (UNJITTED; the device jits the vmapped wrapper). Used by
-    # dtd.insert_task(pure=True) so same-shape DTD tiles batch like
-    # PTG tasks do.
+    # (UNJITTED; the device jits one program that calls it once per
+    # member). Used by dtd.insert_task(pure=True).
     batch_sig: Optional[Callable[["Task"], Any]] = None
     batch_body: Optional[Callable[["Task"], Callable[..., Any]]] = None
 
+
+# Tasks a device module issues as one launch (``Chore.batch_body`` /
+# ``batch_hook``), largest first: a worker launches the largest size the
+# ready same-body tasks it selected fill, the next size from what is
+# left, and single tasks below the smallest. A fixed set, so every size
+# is compiled the first time a signature is seen and none later.
+# Settled on the v5e (PERF.md section 6, PR 25): what a launch makes
+# waits in HBM for its members' release, and eight 1024-tiles are what
+# the benchmark's 1% on peak_hbm_gib leaves room for.
+GROUP_SIZES = (8, 4)
 
 _task_counter = itertools.count()
 

@@ -43,17 +43,29 @@ class Device:
     def execute(self, es, task: Task, chore: Chore) -> HookReturn:
         raise NotImplementedError
 
+    def group_limit(self, task: Task) -> int:
+        """The most tasks like ``task`` one launch of this module may
+        carry; 0 for a module that launches every task alone (then it
+        needs no ``execute_group`` and no ``group_turn``)."""
+        return 0
+
     def shutdown(self) -> None:
         """Stop any device-owned threads (called from Context.fini);
         base devices have none."""
 
-    def release_load(self) -> None:
-        """Release the in-flight work unit ``Registry.device_for`` added.
-        The context releases it automatically when ``execute`` returns
-        anything but ASYNC; async devices own the unit until their
-        manager completes the task and MUST call this then."""
+    def add_load(self, units: float) -> None:
+        """In-flight units beyond the one ``Registry.device_for`` added:
+        a group launch accounts every member."""
         with self._lock:
-            self.load = max(0.0, self.load - 1.0)
+            self.load += units
+
+    def release_load(self, units: float = 1.0) -> None:
+        """Release in-flight work units (``Registry.device_for`` adds
+        one). The context releases them when ``execute`` returns anything
+        but ASYNC; an async device owns its unit until it completes the
+        task and MUST call this then."""
+        with self._lock:
+            self.load = max(0.0, self.load - units)
 
     def _run_hook(self, task: Task, chore: Chore) -> HookReturn:
         """Run the functional body and normalize outputs into

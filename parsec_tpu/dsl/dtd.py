@@ -404,14 +404,14 @@ class Taskpool(CoreTaskpool):
                             jit_cache[skey] = jf
                     return jf(*flow_vals)
 
-                # manager batching (device.tpu.batch_dispatch): tasks
-                # whose woven bodies are identical — same argspec
-                # signature at the same precision — may be vmapped into
-                # one dispatch even though the hook itself reads
+                # group launch (Context._take_group, TPUDevice.
+                # execute_group): tasks whose woven bodies are identical
+                # — same argspec signature at the same precision — may
+                # share one launch even though the hook itself reads
                 # per-task metadata
                 def _batch_sig(task: Task):
-                    # fn identity is already in the manager's group key
-                    # via id(chore)
+                    # fn identity is already in the group's key: one
+                    # chore
                     from ..ops.tile_kernels import matmul_precision
                     return (_spec_key(task.dsl["argspec"]),
                             matmul_precision())
@@ -434,9 +434,8 @@ class Taskpool(CoreTaskpool):
 
             if pure:
                 # batchable=False: the hook self-jits (the device's
-                # _run_sync wrapper would double-jit); batch_sig/
-                # batch_body let the batching manager vmap same-woven
-                # groups anyway
+                # jit wrapper would double-jit); batch_sig/batch_body
+                # let the device launch same-woven groups as one program
                 tc.add_chore(Chore(device, _hook, batchable=False,
                                    batch_sig=_batch_sig,
                                    batch_body=_batch_body))

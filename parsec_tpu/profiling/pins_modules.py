@@ -288,7 +288,7 @@ class Counters(PinsModule):
             tot["tasks"] += 1
             tot["wall_s"] += t1 - t0
             if tid0 != tid1:
-                # ASYNC completion (e.g. the batching manager): END
+                # ASYNC completion (a device that completes later): END
                 # fires on a different thread, so a RUSAGE_THREAD delta
                 # would subtract one thread's counters from another's.
                 # Only wall time is cross-thread meaningful.

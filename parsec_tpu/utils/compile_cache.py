@@ -219,6 +219,11 @@ def function_fingerprint(fn: Callable) -> Tuple[bool, str]:
             walk(v, depth + 1)
         elif isinstance(v, types.CodeType):
             code(v)
+        elif isinstance(v, types.ModuleType):
+            # a module in a closure cell (``import jax.numpy as jnp`` in
+            # the enclosing function): name-identified, as the globals a
+            # body calls by name are
+            parts.append(f"module:{v.__name__}")
         else:
             stable[0] = False
             parts.append(f"id:{id(v)}")

@@ -72,60 +72,47 @@ def _dtd_gemm_load_split_body():
     assert len(busy) >= 2, f"no load split: {per_dev}"
 
 
-def test_batch_dispatch_manager(rng):
-    """The per-device manager batches same-class ready tasks into one
-    vmapped dispatch (progress_stream analog): a wide independent wave
-    must complete correctly AND register multi-task batches."""
-    import parsec_tpu as parsec
-    from parsec_tpu.data import LocalCollection
-    from parsec_tpu.dsl import ptg
+def test_group_launch_wide_wave(rng):
+    """A worker that selects ready tasks of one pure body has the device
+    module issue them as one program: a wide independent wave must
+    complete correctly AND register multi-task launches."""
     from parsec_tpu.utils import mca_param
 
     NT = 32
-    store = LocalCollection(
-        "S", {("x", i): rng.standard_normal((16, 16)).astype(np.float32)
-              for i in range(NT)} | {("y", i): None for i in range(NT)})
-    mca_param.set("device.tpu.max_devices", 1)   # one manager: big batches
-    mca_param.set("device.tpu.batch_dispatch", 1)
+    X_h = rng.standard_normal((NT * 16, 16)).astype(np.float32)
+    X = TiledMatrix.from_array(X_h.copy(), 16, 16, name="X")
+    mca_param.set("device.tpu.max_devices", 1)   # one module: big groups
+    mca_param.set("runtime.native_dtd", 0)       # the engine a chip gets
     try:
         ctx = parsec.init(nb_cores=2)
-        ctx.start()
-        tp = ptg.Taskpool("wide", N=NT, S=store)
-        tp.task_class(
-            "W", params=("i",),
-            space=lambda g: ((i,) for i in range(g.N)),
-            flows=[ptg.FlowSpec(
-                "X", ptg.RW,
-                ins=[ptg.In(data=lambda g, i: (g.S, ("x", i)))],
-                outs=[ptg.Out(data=lambda g, i: (g.S, ("y", i)))])])
-
-        @tp.task_class_by_name("W").body
-        def w_body(task, X):
-            import jax.numpy as jnp
-            return jnp.asarray(X) * 2.0 + 1.0
-
+        tp = dtd.Taskpool("wide")
         ctx.add_taskpool(tp)
-        assert ctx.wait(timeout=300)
+        # one flush of 32 ready tasks: two workers cannot split them into
+        # shares that are both under the smallest group
+        tp.insert_tasks(lambda x: x * 2.0 + 1.0,
+                        [(dtd.TileArg(X, (i, 0), dtd.INOUT),)
+                         for i in range(NT)],
+                        device=DeviceType.TPU, pure=True)
+        tp.wait()
         tpu_stats = [d.dump_statistics() for d in ctx.devices.devices
                      if d.name.startswith("tpu")]
         parsec.fini(ctx)
-        for i in range(NT):
-            np.testing.assert_allclose(
-                np.asarray(store.data_of(("y", i))),
-                np.asarray(store.data_of(("x", i))) * 2.0 + 1.0,
-                rtol=1e-6)
+        np.testing.assert_allclose(X.to_array(), X_h * 2.0 + 1.0,
+                                   rtol=1e-6)
         batched = sum(s.get("batched_tasks", 0) for s in tpu_stats)
         batches = sum(s.get("batches", 0) for s in tpu_stats)
         assert batched > batches >= 1, (batched, batches)
+        assert sum(s["tasks"] for s in tpu_stats) == NT
     finally:
         mca_param.unset("device.tpu.max_devices")
-        mca_param.unset("device.tpu.batch_dispatch")
+        mca_param.unset("runtime.native_dtd")
 
 
-def test_batch_dispatch_uses_batch_hook(rng):
+def test_group_launch_uses_batch_hook(rng):
     """A class with a hand-batched hook (shared-flow TRSM shape) must
-    dispatch through it when the shared flow holds ONE value across the
-    group — and produce the same results."""
+    launch through it when a worker holds a group of its ready tasks and
+    the shared flow holds ONE value across the group — and produce the
+    same results."""
     import parsec_tpu as parsec
     from parsec_tpu.data import LocalCollection
     from parsec_tpu.dsl import ptg
@@ -147,10 +134,9 @@ def test_batch_dispatch_uses_batch_hook(rng):
         return jnp.matmul(Cs, Ls[0].T, precision="highest")
 
     mca_param.set("device.tpu.max_devices", 1)
-    mca_param.set("device.tpu.batch_dispatch", 1)
     try:
+        # started by wait(): all eight are ready before a worker selects
         ctx = parsec.init(nb_cores=2)
-        ctx.start()
         tp = ptg.Taskpool("trsmish", N=NT, S=store)
         TC = tp.task_class(
             "T", params=("i",),
@@ -171,16 +157,17 @@ def test_batch_dispatch_uses_batch_hook(rng):
 
         ctx.add_taskpool(tp)
         assert ctx.wait(timeout=300)
+        grouped = sum(d.stats["batched_tasks"]
+                      for d in ctx.devices.by_type(DeviceType.TPU))
         parsec.fini(ctx)
     finally:
         mca_param.unset("device.tpu.max_devices")
-        mca_param.unset("device.tpu.batch_dispatch")
     for i in range(NT):
         np.testing.assert_allclose(
             np.asarray(store.data_of(("y", i))),
             np.asarray(store.data_of(("c", i))) @ L.T, rtol=1e-5,
             atol=1e-5)
-    assert calls["hook"] >= 1, "batch_hook never engaged"
+    assert calls["hook"] >= 1 and grouped >= 4, "batch_hook never engaged"
 
 
 # ---- panel-fused flagship under GSPMD (ISSUE r6 satellite) -------------
@@ -230,11 +217,10 @@ def test_getrf_left_panel_sharded_8dev(hook):
     assert resid <= 1e-5, (hook, resid)
 
 
-# ---- batching manager under 2-rank distribution (VERDICT r3 #8) --------
+# ---- group launches under 2-rank distribution (VERDICT r3 #8) ----------
 # Reference bar: the CUDA manager thread under MPI
-# (device_cuda_module.c:2573-2589 + distributed DTD tests) — both ranks
-# must batch-dispatch their local DTD GEMM tiles while values cross the
-# socket wire.
+# (device_cuda_module.c:2573-2589 + distributed DTD tests) — ranks launch
+# their local DTD GEMM tiles in groups while values cross the socket wire.
 
 def _mgr_dist_child(rank, nb_ranks, base_port, q):
     try:
@@ -249,10 +235,11 @@ def _mgr_dist_child(rank, nb_ranks, base_port, q):
             TwoDimBlockCyclic
         from parsec_tpu.utils import mca_param
 
-        mca_param.set("device.tpu.max_devices", 1)  # one manager/rank
-        mca_param.set("device.tpu.batch_dispatch", 1)
+        mca_param.set("device.tpu.max_devices", 1)  # one module/rank
         engine = SocketCommEngine(rank, nb_ranks, base_port=base_port)
-        ctx = ctx_mod.init(nb_cores=2, comm=engine)
+        # one worker: the four ready tasks of a local row of C tiles
+        # reach it in one flush and leave as one group
+        ctx = ctx_mod.init(nb_cores=1, comm=engine)
         ctx.start()
         rng = _np.random.default_rng(0)            # same data all ranks
         m, kdim, nb = 256, 64, 64
@@ -289,10 +276,12 @@ def _mgr_dist_child(rank, nb_ranks, base_port, q):
 
 
 @pytest.mark.parametrize("nranks", [2, 4])
-def test_batch_dispatch_manager_socket(nranks):
-    """Every rank runs the per-device batching manager while DTD GEMM
-    values cross the socket wire: results correct on every rank's local
-    tiles AND each rank registered at least one multi-task batch.
+def test_group_launch_socket(nranks):
+    """Every rank launches its DTD GEMM tasks through the accelerator
+    module while values cross the socket wire: results correct on every
+    rank's local tiles AND a rank whose inputs are all local (rank 0 owns
+    B) registered a multi-task launch; where B tiles arrive one by one,
+    tasks become ready one by one and may go alone.
     4 ranks = the reference's mid-scale MPI test size (SURVEY §4)."""
     import multiprocessing as mp
     from parsec_tpu.comm.pingpong import _free_port_base
@@ -317,6 +306,5 @@ def test_batch_dispatch_manager_socket(nranks):
             p.join(timeout=10.0)
             if p.is_alive():
                 p.terminate()
-    for rank, r in results.items():
-        assert r["batches"] >= 1, (rank, r)
-        assert r["batched"] >= 2, (rank, r)
+    assert results[0]["batches"] >= 1, results
+    assert results[0]["batched"] >= 4, results

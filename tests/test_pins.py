@@ -211,26 +211,45 @@ def test_ptg_to_dtd_replay_potrf(ctx, rng):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_counters_async_completion_skips_rusage_deltas():
-    """Tasks completed from another thread (batching manager, ASYNC)
-    must not mix per-thread rusage across threads: counted as
-    async_tasks, wall time only."""
+@pytest.mark.parametrize("how", ["another_thread", "group_launch"])
+def test_counters_rusage_deltas_stay_on_one_thread(how):
+    """A task completed from another thread (a device that returns
+    ASYNC) must not mix per-thread rusage across threads: counted as
+    async_tasks, wall time only. The members of a group launch begin
+    and end on the worker that formed the group: none is async."""
+    import threading
+
+    from parsec_tpu.core.task import DeviceType, HookReturn
+    from parsec_tpu.device.base import Device
     from parsec_tpu.profiling import Counters
 
+    class AsyncDevice(Device):
+        device_type = DeviceType.TPU
+        name = "async-test"
+
+        def execute(self, es, task, chore):
+            def finish():
+                task.output["X"] = chore.hook(task, *task.input_values())
+                self.release_load()
+                ctx.complete_task(None, task)
+
+            threading.Thread(target=finish, daemon=True).start()
+            return HookReturn.ASYNC
+
     mca_param.set("device.tpu.max_devices", 1)
-    mca_param.set("device.tpu.batch_dispatch", 1)
     ctx = mod = None
     try:
-        ctx = parsec.init(nb_cores=2)
+        ctx = parsec.init(nb_cores=1)
+        if how == "another_thread":
+            ctx.devices.add(AsyncDevice()).weight = 1000.0   # wins selection
         mod = Counters().install(ctx)
-        ctx.start()
         NT = 8
         store = LocalCollection(
             "S", {("x", i): np.full((8, 8), float(i), np.float32)
                   for i in range(NT)} | {("y", i): None
                                          for i in range(NT)})
         tp = ptg.Taskpool("wide", N=NT, S=store)
-        tp.task_class(
+        W = tp.task_class(
             "W", params=("i",),
             space=lambda g: ((i,) for i in range(g.N)),
             flows=[ptg.FlowSpec(
@@ -238,24 +257,32 @@ def test_counters_async_completion_skips_rusage_deltas():
                 ins=[ptg.In(data=lambda g, i: (g.S, ("x", i)))],
                 outs=[ptg.Out(data=lambda g, i: (g.S, ("y", i)))])])
 
-        @tp.task_class_by_name("W").body
+        # a hand-batched form: the worker that selects four of these
+        # ready tasks has the module issue them as one launch
+        @W.body(batch_hook=lambda xs: xs * 3.0)
         def w_body(task, X):
             import jax.numpy as jnp
             return jnp.asarray(X) * 3.0
 
-        ctx.add_taskpool(tp)
+        ctx.add_taskpool(tp)        # all ready before the worker starts
         assert ctx.wait(timeout=120)
+        for i in range(NT):
+            assert float(store.data_of(("y", i))[0, 0]) == 3.0 * i
         rep = mod.report()["W"]
         assert rep["tasks"] == NT
-        # every manager-completed task is flagged async (END fires on
-        # the manager thread) and contributes wall time but no
-        # cross-thread rusage delta
-        assert rep["async_tasks"] >= 1, rep
         assert rep["wall_s"] > 0.0
+        if how == "another_thread":
+            # END fires on the completing thread: wall time, no
+            # cross-thread rusage delta
+            assert rep["async_tasks"] == NT, rep
+        else:
+            grouped = sum(d.stats["batched_tasks"] for d in
+                          ctx.devices.by_type(DeviceType.TPU)
+                          if "batched_tasks" in d.stats)
+            assert grouped == NT and not rep.get("async_tasks"), rep
     finally:
         if mod is not None:
             mod.uninstall()
         if ctx is not None:
             parsec.fini(ctx)
         mca_param.unset("device.tpu.max_devices")
-        mca_param.unset("device.tpu.batch_dispatch")

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 import parsec_tpu as parsec
-import parsec_tpu.device.tpu     # registers the knobs set below
+import parsec_tpu.device.tpu     # a StageSpan site patched below
 from parsec_tpu import dtd, serving
 from parsec_tpu.core import context as context_mod
 from parsec_tpu.core.task import DeviceType
@@ -37,9 +37,11 @@ def _matrix(name, mt, nt):
     return m
 
 
-def _run_pool(ctx, name, timeout=60.0):
+def _run_pool(ctx, name, timeout=60.0, pure=True):
     """One GEMM through a new pool, every task on an accelerator module:
-    one insert_tasks call per row of C tiles, as insert_gemm_dtd makes."""
+    one insert_tasks call per row of C tiles, as insert_gemm_dtd makes.
+    ``pure`` bodies may share a launch (a worker that selects four of
+    them issues one group program); impure ones run one by one."""
     a, b, c = _matrix("A", MT, KT), _matrix("B", KT, NT), _matrix("C", MT, NT)
     tp = dtd.Taskpool(name)
     ctx.add_taskpool(tp)
@@ -50,7 +52,7 @@ def _run_pool(ctx, name, timeout=60.0):
               dtd.TileArg(b, (k, n), dtd.INPUT),
               dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True))
              for n in range(NT) for k in range(KT)],
-            device=DeviceType.TPU, pure=True)
+            device=DeviceType.TPU, pure=pure)
     waiter = threading.Thread(target=tp.wait, daemon=True)
     waiter.start()
     waiter.join(timeout)
@@ -58,6 +60,22 @@ def _run_pool(ctx, name, timeout=60.0):
     assert tp._native is None
     assert float(c.data_of((0, 0))[0, 0]) == 1.0 + KT * NB
     return tp
+
+
+def _groups(ctx):
+    """(group launches, tasks in them) on the accelerator modules so far."""
+    stats = [d.stats for d in ctx.devices.by_type(DeviceType.TPU)]
+    return (sum(s["batches"] for s in stats),
+            sum(s["batched_tasks"] for s in stats))
+
+
+def _launches(ctx, before):
+    """Launches a pool's TASKS tasks took since ``before = _groups(ctx)``:
+    the tasks that shared none, and the group launches. An exec span
+    each."""
+    groups, grouped = (now - then
+                       for now, then in zip(_groups(ctx), before))
+    return TASKS - grouped + groups
 
 
 @pytest.fixture
@@ -122,42 +140,39 @@ class _Session:
     ("lfq", 0), ("gd", 0), ("wfq", 0), ("lfq", 1), ("gd", 1), ("wfq", 1)])
 def test_a_traced_pool_has_its_stages_in_the_profile(
         make_ctx, tmp_path, scheduler, batch):
-    ctx = make_ctx(scheduler, **{"device.tpu.batch_dispatch": batch})
-    _run_pool(ctx, "warm")                  # compiles; no session, no span
+    ctx = make_ctx(scheduler)
+    pure = bool(batch)      # impure bodies never share a launch
+    _run_pool(ctx, "warm", pure=pure)       # compiles; no session, no span
     assert not ctx.stage_timers
+    before = _groups(ctx)
     with _Session(tmp_path) as prof:
-        tp = _run_pool(ctx, "traced")
+        tp = _run_pool(ctx, "traced", pure=pure)
         assert ctx.stage_timers
+    launches = _launches(ctx, before)
     assert {s for t in prof.spans.values() for s in t} <= set(STAGES)
     assert prof.count("insert") == INSERT_CALLS
-    assert prof.count("dispatch") == TASKS
     assert prof.count("release") == TASKS
     assert prof.count("select") >= 1
     workers = [t for t in prof.spans.values() if "select" in t]
     assert 1 <= len(workers) <= ctx.nb_cores
-    if batch:
-        # the manager launches (one span a launch, however many tasks) and
-        # completes: its thread selects nothing
-        assert 1 <= prof.count("exec") <= TASKS
-        (manager,) = [t for t in prof.spans.values() if "exec" in t]
-        assert "select" not in manager and "dispatch" not in manager
-        assert len(manager["release"]) == TASKS
-    else:
-        assert prof.count("exec") == TASKS
-        for thread in prof.spans.values():      # nested, same thread
-            for lo, hi in thread.get("exec", ()):
-                assert any(d0 <= lo and hi <= d1
-                           for d0, d1 in thread["dispatch"])
+    # a dispatch span a task; an exec span a launch, however many tasks
+    assert prof.count("dispatch") == TASKS
+    assert prof.count("exec") == launches
+    assert launches == TASKS if not batch else 1 <= launches <= TASKS
+    for thread in prof.spans.values():
+        for lo, hi in thread.get("exec", ()):
+            # a lone task's launch is nested in its dispatch span; a group
+            # is launched after its members' spans, on the same thread
+            assert any(d0 <= lo and (hi <= d1 or (batch and d1 <= lo))
+                       for d0, d1 in thread["dispatch"])
     # the sums the `overhead` module reports are of the same passes
     stats = {k: sum(es.stats[k] for es in ctx.streams)
              for k in ("select_s", "select_calls", "dispatch_s", "release_s")}
     assert stats["select_calls"] == prof.count("select")
     assert tp.insert_calls == TASKS
-    pairs = [(tp.insert_s, prof.seconds("insert")),
-             (stats["dispatch_s"], prof.seconds("dispatch"))]
-    if not batch:       # a manager's completion has no stream to sum into
-        pairs.append((stats["release_s"], prof.seconds("release")))
-    for summed, spanned in pairs:
+    for summed, spanned in [(tp.insert_s, prof.seconds("insert")),
+                            (stats["dispatch_s"], prof.seconds("dispatch")),
+                            (stats["release_s"], prof.seconds("release"))]:
         assert summed > 0 and abs(spanned - summed) <= 0.1 * summed
     # a select is a microsecond: the span's own cost shows, so a bound
     assert 0 < stats["select_s"] <= prof.seconds("select") \
