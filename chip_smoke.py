@@ -285,7 +285,7 @@ def phase_host(sz, on_chip):
     import parsec_tpu as parsec
     from parsec_tpu import _native, dtd
     from parsec_tpu.algorithms import build_potrf, insert_gemm_dtd
-    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.data.matrix import SymTwoDimBlockCyclic, TiledMatrix
 
     n_dev = len(jax.devices())
     rng = np.random.default_rng(0)
@@ -327,21 +327,45 @@ def phase_host(sz, on_chip):
             first_run_s=f"{t_gemm:.1f}", rel_err=f"{err:.1e}",
             output_devices=out_devs)
 
+        # the path the benchmark's PTG cell runs (dpotrf_ptg_host): the
+        # lower triangle alone stored, as testing_dpotrf allocates it,
+        # every tile on a chip before the pool starts; the stage timers
+        # on, so that the modules count tasks by class
         n, nb = sz["potrf_host"]
+        nt = n // nb
         R = rng.standard_normal((n, n)).astype(np.float32)
         S_h = (0.5 * (R + R.T) + 2.0 * n * np.eye(n)).astype(np.float32)
-        P_ = TiledMatrix.from_array(S_h.copy(), nb, nb, name="P")
+        P_ = TiledMatrix(n, n, nb, nb, name="P",
+                         dist=SymTwoDimBlockCyclic(1, 1, uplo="lower"))
+        lower = [(i, j) for j in range(nt) for i in range(j, nt)]
+        for i, j in lower:
+            P_.write_tile((i, j), jax.device_put(
+                S_h[i * nb:(i + 1) * nb, j * nb:(j + 1) * nb]))
+        timers = ctx.set_stage_timers(True)
         t0 = time.perf_counter()
         ctx.add_taskpool(build_potrf(P_))
         require(ctx.wait(timeout=900), "PTG POTRF did not finish")
         t_potrf = time.perf_counter() - t0
+        ctx.set_stage_timers(timers)
+        require(sorted(P_._tiles) == sorted(lower),
+                "PTG POTRF touched a tile of the upper triangle")
         L = np.tril(P_.to_array().astype(np.float64))
         err = rel(L @ L.T, S_h)
         require(err <= 1e-3, f"PTG POTRF residual {err:.2e}")
-        p_devs = tile_devices(P_.data_of(key) for key in P_.local_keys())
-        say("host", ptg_potrf=f"n={n}/nb={nb}", first_run_s=f"{t_potrf:.1f}",
-            residual=f"{err:.1e}", output_devices=p_devs)
+        p_devs = tile_devices(P_.data_of(key) for key in lower)
         stats = ctx.devices.dump_statistics()
+        by_class = {}
+        for s in stats:
+            for cls, count in s["tasks_by_class"].items():
+                by_class[cls] = by_class.get(cls, 0) + count
+        want = {"POTRF": nt, "TRSM": nt * (nt - 1) // 2,
+                "SYRK": nt * (nt - 1) // 2,
+                "GEMM": nt * (nt - 1) * (nt - 2) // 6}
+        require(by_class == want, f"PTG POTRF tasks by class {by_class}, "
+                                  f"the graph has {want}")
+        say("host", ptg_potrf=f"n={n}/nb={nb}", first_run_s=f"{t_potrf:.1f}",
+            residual=f"{err:.1e}", output_devices=p_devs,
+            tasks_by_class=by_class)
     finally:
         parsec.fini(ctx)
 

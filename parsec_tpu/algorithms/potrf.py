@@ -11,6 +11,15 @@ and dataflow mirror the classic dpotrf JDF:
 
 Every flow carries its logical tile (FlowSpec.tile), so the taskpool runs
 on the host runtime AND on the compiled wavefront/SPMD executors.
+
+The factorization runs in the matrix's own storage, as upstream's does
+(a task updates its tile's data copy in place): every class writes its
+result to its tile of ``A``, the updates of SYRK and GEMM too. A jax.Array
+cannot be overwritten, so on the host runtime an update that only
+travelled on to the next task would leave the collection holding the
+version before it, and every trailing tile would be on the chip twice
+until its TRSM or POTRF wrote the factor back (1.7 times the matrix at
+N=49152 in 2048-tiles, and not the same twice: PERF.md section 6, PR 27).
 """
 
 from __future__ import annotations
@@ -131,7 +140,9 @@ def build_potrf(A: TiledMatrix) -> ptg.Taskpool:
                 outs=[ptg.Out(dst=("SYRK", lambda g, m, k: (m, k + 1), "C"),
                               guard=lambda g, m, k: k < m - 1),
                       ptg.Out(dst=("POTRF", lambda g, m, k: (m,), "T"),
-                              guard=lambda g, m, k: k == m - 1)])])
+                              guard=lambda g, m, k: k == m - 1),
+                      # in place: the tile's last version is freed
+                      ptg.Out(data=lambda g, m, k: (g.A, (m, m)))])])
 
     GEMM = tp.task_class(
         "GEMM", params=("m", "n", "k"),
@@ -160,7 +171,9 @@ def build_potrf(A: TiledMatrix) -> ptg.Taskpool:
                                    lambda g, m, n, k: (m, n, k + 1), "C"),
                               guard=lambda g, m, n, k: k < n - 1),
                       ptg.Out(dst=("TRSM", lambda g, m, n, k: (m, n), "C"),
-                              guard=lambda g, m, n, k: k == n - 1)])])
+                              guard=lambda g, m, n, k: k == n - 1),
+                      # in place: the tile's last version is freed
+                      ptg.Out(data=lambda g, m, n, k: (g.A, (m, n)))])])
 
     def _potrf_hook(Ts):
         import jax

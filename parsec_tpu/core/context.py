@@ -90,6 +90,10 @@ SPAN_PARK = "parsec:park"
 SPAN_DISPATCH = "parsec:dispatch"
 SPAN_EXEC = "parsec:exec"
 SPAN_RELEASE = "parsec:release"
+# the PTG front end's own stages (dsl/ptg.py names them on its taskpool
+# and task classes; a front end that names none has none)
+SPAN_PTG_STARTUP = "parsec:ptg_startup"
+SPAN_PTG_UNFOLD = "parsec:ptg_unfold"
 
 
 class StageSpan:
@@ -132,7 +136,13 @@ class ExecutionStream:
                       "stolen": 0,
                       # per-stage overhead timers (runtime.stage_timers)
                       "select_s": 0.0, "select_calls": 0,
-                      "dispatch_s": 0.0, "release_s": 0.0}
+                      "dispatch_s": 0.0, "release_s": 0.0,
+                      # of release_s: a closed-form front end evaluating
+                      # the completed task's successor list
+                      "unfold_s": 0.0,
+                      # why _take_group stopped taking (stage timers on)
+                      "group_end_limit": 0, "group_end_empty": 0,
+                      "group_end_class": 0, "group_end_sig": 0}
         self._vp_peers = None        # cached steal orders (sched/base.py)
         self._steal_order = None
         # extensible per-stream info slots (parsec_internal.h:688-702)
@@ -346,12 +356,19 @@ class Context:
         if tp.on_enqueue is not None:
             tp.on_enqueue(tp)
         self.pins.taskpool_init(tp)
-        startup = tp.startup_hook(tp) or []
-        if startup:
-            self.schedule(None, list(startup))
+        if self.stage_timers and tp.startup_span is not None:
+            with StageSpan(tp.startup_span):
+                self._startup(tp)
+        else:
+            self._startup(tp)
         tp.monitor.ready()
         if self._started:
             self._work_evt.set()
+
+    def _startup(self, tp: Taskpool) -> None:
+        startup = tp.startup_hook(tp) or []
+        if startup:
+            self.schedule(None, list(startup))
 
     def start(self) -> None:
         """parsec_context_start analog: release the workers."""
@@ -958,20 +975,28 @@ class Context:
         bsig = chore.batch_sig(task) if chore.batch_sig is not None \
             else None
         tasks = [task]
+        end = "limit"
         while len(tasks) < limit:
             nxt = self._select(es)
             if nxt is None:
+                end = "empty"
                 break
             if nxt.taskpool.cancelled:
                 nxt.taskpool.addto_nb_tasks(-1)      # as _worker_main
                 continue
             if nxt.taskpool is not tp or nxt.task_class is not tc or \
-                    self._group_chore(nxt) is not chore or \
-                    (bsig is not None and chore.batch_sig(nxt) != bsig):
-                es.next_task = nxt
-                break
-            es.stats["selected"] += 1
-            tasks.append(nxt)
+                    self._group_chore(nxt) is not chore:
+                end = "class"
+            elif bsig is not None and chore.batch_sig(nxt) != bsig:
+                end = "sig"
+            else:
+                es.stats["selected"] += 1
+                tasks.append(nxt)
+                continue
+            es.next_task = nxt
+            break
+        if self.stage_timers:
+            es.stats["group_end_" + end] += 1
         return tasks
 
     def _group_progress(self, es: ExecutionStream, task: Task,
@@ -1155,7 +1180,16 @@ class Context:
             {} if self.nb_ranks > 1 else None
         san = self.dfsan
         grapher = self.grapher
-        for ref in tc.iterate_successors(task):
+        successors = tc.iterate_successors(task)
+        if self.stage_timers and tc.unfold_span is not None:
+            # the front end's share of release: guards, target lambdas,
+            # priorities of the whole successor list, before any of it is
+            # counted down or scheduled
+            with StageSpan(tc.unfold_span) as span:
+                successors = list(successors)
+            if es is not None:
+                es.stats["unfold_s"] += span.seconds
+        for ref in successors:
             if isinstance(ref, DataRef):
                 # track (pinned) first, write second, unpin last — see
                 # _hbm_track

@@ -81,6 +81,11 @@ class TaskClass:
     - ``make_key(locals)``, ``priority(locals)``
     """
 
+    # stage span around the evaluation of a completed task's successor
+    # list, inside parsec:release (Context._release_deps, stage timers
+    # on); None where the list costs nothing worth a span of its own
+    unfold_span: Optional[str] = None
+
     def __init__(self, name: str, tc_id: int, params: Sequence[str],
                  flows: Sequence[Flow], deps_mode: str = DEPS_COUNTER):
         self.name = name
@@ -329,6 +334,10 @@ class Taskpool:
     the scheduler → termdet fires ``_on_terminated`` when
     ``nb_tasks == nb_pending_actions == 0``.
     """
+
+    # stage span around ``startup_hook`` and the scheduling of what it
+    # returns (Context.add_taskpool, stage timers on); None for none
+    startup_span: Optional[str] = None
 
     def __init__(self, name: str = "taskpool"):
         self.name = name

@@ -26,7 +26,12 @@ class Device:
         self.registry: Optional["Registry"] = None
         # statistics (reference device.h:132-141 per-device counters)
         self.stats = {"tasks": 0, "exec_s": 0.0,
-                      "bytes_in": 0, "bytes_out": 0}
+                      "bytes_in": 0, "bytes_out": 0,
+                      # by task class name, counted while the context's
+                      # stage timers are on: a launch is a lone task or
+                      # a group, so one class's tasks per launch can be
+                      # read apart from another's
+                      "tasks_by_class": {}, "launches_by_class": {}}
         # relative throughput weight for load balancing
         # (reference: GFLOPS weights, device_cuda_module.c:53-117)
         self.weight = 1.0
@@ -83,10 +88,24 @@ class Device:
         with self._lock:
             self.stats["tasks"] += 1
             self.stats["exec_s"] += time.perf_counter() - t0
+            if task.taskpool.context.stage_timers:
+                self._count_launch(task, 1)
         return HookReturn.DONE
 
+    def _count_launch(self, task: Task, tasks: int) -> None:
+        """One launch of ``tasks`` tasks of ``task``'s class (under
+        ``self._lock``)."""
+        name = task.task_class.name
+        for key, n in (("tasks_by_class", tasks), ("launches_by_class", 1)):
+            counts = self.stats[key]
+            counts[name] = counts.get(name, 0) + n
+
     def dump_statistics(self) -> Dict:
-        return dict(self.stats, name=self.name, index=self.index)
+        with self._lock:
+            return dict(self.stats, name=self.name, index=self.index,
+                        tasks_by_class=dict(self.stats["tasks_by_class"]),
+                        launches_by_class=dict(
+                            self.stats["launches_by_class"]))
 
 
 class Registry:

@@ -280,6 +280,8 @@ class TPUDevice(Device):
             self.stats["exec_s"] += time.perf_counter() - t0
             self.stats["batches"] += 1
             self.stats["batched_tasks"] += len(tasks)
+            if tasks[0].taskpool.context.stage_timers:
+                self._count_launch(tasks[0], len(tasks))
 
     def _flat(self, values) -> List[Any]:
         """The members' input leaves in order, None-valued flows left

@@ -54,6 +54,7 @@ import types
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
+from ..core.context import SPAN_PTG_STARTUP, SPAN_PTG_UNFOLD
 from ..core.future import DataCopyFuture
 from ..core.reshape import compose_specs
 from ..core.task import Chore, DeviceType, Flow, FlowAccess, Task
@@ -141,6 +142,8 @@ class FlowSpec:
 
 class PTGTaskClass(TaskClass):
     """Task class built from closed-form flow specs."""
+
+    unfold_span = SPAN_PTG_UNFOLD       # _iterate_successors, as a list
 
     def __init__(self, tp: "Taskpool", name: str, tc_id: int,
                  params: Sequence[str], specs: List[FlowSpec],
@@ -362,6 +365,8 @@ class PTGTaskClass(TaskClass):
 class Taskpool(CoreTaskpool):
     """PTG taskpool: globals namespace + task classes
     (the ``__parsec_<name>_internal_taskpool_t`` analog)."""
+
+    startup_span = SPAN_PTG_STARTUP     # _startup walks the whole space
 
     def __init__(self, name: str = "ptg", **globals_kw):
         super().__init__(name=name)
