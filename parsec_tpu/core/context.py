@@ -32,7 +32,8 @@ from jax.profiler import TraceAnnotation
 
 from .future import DataCopyFuture
 from .reshape import resolve_reshape
-from .task import GROUP_SIZES, Chore, HookReturn, Task, TaskStatus
+from .task import (GROUP_SIZES, GROUP_TAKE, Chore, DeviceType, HookReturn,
+                   Task, TaskStatus)
 from .taskpool import DataRef, SuccessorRef, Taskpool
 from ..utils import debug_history, mca_param
 from ..utils.debug import debug_verbose, warning
@@ -140,9 +141,11 @@ class ExecutionStream:
                       # of release_s: a closed-form front end evaluating
                       # the completed task's successor list
                       "unfold_s": 0.0,
-                      # why _take_group stopped taking (stage timers on)
+                      # why _take_group stopped taking (_class: on a
+                      # task that cannot be grouped), and the bins its
+                      # takes launched (stage timers on)
                       "group_end_limit": 0, "group_end_empty": 0,
-                      "group_end_class": 0, "group_end_sig": 0}
+                      "group_end_class": 0, "group_bins": 0}
         self._vp_peers = None        # cached steal orders (sched/base.py)
         self._steal_order = None
         # extensible per-stream info slots (parsec_internal.h:688-702)
@@ -938,45 +941,59 @@ class Context:
 
     # ------------------------------------------------------ group launch
     # A worker that holds a ready accelerator task whose chore has a
-    # group program takes the ready tasks of the same body it can select
-    # and has the device module issue them as one launch: one trip
-    # through jit dispatch (and one hand-off of the GIL) for the group
-    # instead of one per task. Formed on the thread that already holds
-    # the tasks; G = 1 is _task_progress.
+    # group program takes the ready tasks of its taskpool it can select,
+    # whatever their class, sorts them by body and has the device module
+    # issue each body's tasks as one launch: one trip through jit
+    # dispatch (and one hand-off of the GIL) for the group instead of one
+    # per task. Formed on the thread that already holds the tasks; G = 1
+    # is _task_progress.
 
     @staticmethod
     def _group_chore(task: Task) -> Optional[Chore]:
-        """The first incarnation the task's mask leaves, if it offers a
-        group program (``batch_body`` + ``batch_sig``: DTD pure woven
-        bodies; ``batch_hook`` on a batchable body) and does not veto the
-        task; else None, and ``_execute`` walks the incarnations."""
+        """The first incarnation the task's mask leaves, if it is an
+        accelerator's, has a group program (``batch_body`` +
+        ``batch_sig``: DTD pure woven bodies; any batchable body: its
+        ``batch_hook``, else its plain ``hook`` once per member) and does
+        not veto the task; else None, and ``_execute`` walks the
+        incarnations."""
         for i, chore in enumerate(task.task_class.incarnations):
             if task.chore_mask & (1 << i):
                 break
         else:
             return None
+        if not chore.device_type & DeviceType.TPU:
+            return None     # a CPU body is one call as it is
         if chore.batch_body is not None:
             if chore.batch_sig is None:
                 return None
-        elif not chore.batchable or chore.batch_hook is None:
+        elif not chore.batchable:
             return None
         if chore.evaluate is not None and not chore.evaluate(task):
             return None
         return chore
 
+    @staticmethod
+    def _bin_key(task: Task, chore: Chore):
+        """Tasks of one key may share a launch."""
+        return (task.task_class, id(chore),
+                chore.batch_sig(task) if chore.batch_sig is not None
+                else None)
+
     def _take_group(self, es: ExecutionStream, task: Task, chore: Chore,
-                    limit: int) -> List[Task]:
+                    dev, limit: int) -> List[Tuple[Chore, List[Task]]]:
         """``task`` and the tasks the scheduler hands this worker next,
-        while they are of the same taskpool, class, first incarnation and
-        ``batch_sig``, up to ``limit``. The first that differs ends the
-        group and waits in the bypass slot: nothing is pushed back, so
-        the scheduler's order is what it was."""
-        tp, tc = task.taskpool, task.task_class
-        bsig = chore.batch_sig(task) if chore.batch_sig is not None \
-            else None
-        tasks = [task]
-        end = "limit"
-        while len(tasks) < limit:
+        in one bin per (class, first incarnation, ``batch_sig``), the
+        bins in the order their first task was selected. The take ends
+        when a bin holds what ``dev`` says one launch of its first task
+        may carry (``limit`` for ``task``'s), at ``GROUP_TAKE`` tasks in
+        all, on an empty queue, or on a task that cannot be grouped
+        (another taskpool, no group chore, none ``dev`` may launch):
+        that one waits in the bypass slot. Nothing is pushed back, so the
+        scheduler's order is what it was."""
+        tp, key = task.taskpool, self._bin_key
+        bins = {key(task, chore): (chore, [task], limit)}
+        taken, end = 1, "limit"
+        while taken < GROUP_TAKE:
             nxt = self._select(es)
             if nxt is None:
                 end = "empty"
@@ -984,47 +1001,66 @@ class Context:
             if nxt.taskpool.cancelled:
                 nxt.taskpool.addto_nb_tasks(-1)      # as _worker_main
                 continue
-            if nxt.taskpool is not tp or nxt.task_class is not tc or \
-                    self._group_chore(nxt) is not chore:
+            entry = None
+            c = self._group_chore(nxt) if nxt.taskpool is tp else None
+            if c is not None:
+                k = key(nxt, c)
+                entry = bins.get(k)
+                if entry is None:
+                    room = dev.group_limit(nxt)
+                    if room:
+                        entry = bins[k] = (c, [], room)
+            if entry is None:
+                es.next_task = nxt
                 end = "class"
-            elif bsig is not None and chore.batch_sig(nxt) != bsig:
-                end = "sig"
-            else:
-                es.stats["selected"] += 1
-                tasks.append(nxt)
-                continue
-            es.next_task = nxt
-            break
+                break
+            es.stats["selected"] += 1
+            entry[1].append(nxt)
+            taken += 1
+            if len(entry[1]) >= entry[2]:
+                break
         if self.stage_timers:
             es.stats["group_end_" + end] += 1
-        return tasks
+            es.stats["group_bins"] += len(bins)
+        return [(c, tasks) for c, tasks, _ in bins.values()]
 
     def _group_progress(self, es: ExecutionStream, task: Task,
                         chore: Chore) -> None:
-        """``_task_progress`` of ``task`` and the ready tasks of its body
-        this worker can select: every one is prepared, announced and
-        completed exactly once, as alone. The device module says how many
-        one launch may carry and has one group in flight at a time: what
-        a launch made waits on the device for its members' release, so
-        the workers take turns, each holding one task until its turn. A
-        launch that raises leaves the worker's handler to abort the
-        pool, every load released."""
+        """``_task_progress`` of ``task`` and the ready tasks of its
+        taskpool this worker can select: every one is prepared, announced
+        and completed exactly once, as alone, and all of them in this
+        pass. The device module says how many one launch may carry and
+        has one group in flight at a time: what a launch made waits on
+        the device for its members' release, so the workers take turns,
+        each holding one task until its turn. The bins that fill a size
+        are launched in that turn, in the order their first task was
+        selected; the tasks of the others go alone once the turn is
+        given up, as a task that was never taken would. A launch that
+        raises leaves the worker's handler to abort the pool, every load
+        released."""
         dev = self.devices.device_for(chore.device_type, task)
         limit = dev.group_limit(task) if dev is not None else 0
-        tasks, held = [task], 1
+        alone, held = [task], 1
         try:
             if limit:
                 with dev.group_turn:
-                    tasks = self._take_group(es, task, chore, limit)
-                    if len(tasks) >= GROUP_SIZES[-1]:
-                        held = len(tasks)
-                        dev.add_load(held - 1)
-                        self._group_launch(es, tasks, chore, dev)
-                        return
+                    bins = self._take_group(es, task, chore, dev, limit)
+                    held = sum(len(tasks) for _, tasks in bins)
+                    dev.add_load(held - 1)
+                    alone = []
+                    for c, tasks in bins:
+                        # the module builds a body's programs on the
+                        # first tasks it is handed, few as they may be:
+                        # in a pool's first step, not when four first meet
+                        if len(tasks) >= GROUP_SIZES[-1] or \
+                                dev.group_due(c):
+                            self._group_launch(es, tasks, c, dev)
+                        else:
+                            alone += tasks
         finally:
             if dev is not None:
                 dev.release_load(held)
-        for task in tasks:      # too few, or a module without groups
+        for task in alone:      # too few, or a module without groups
             self._task_progress(es, task)
 
     def _group_launch(self, es: ExecutionStream, tasks: List[Task],

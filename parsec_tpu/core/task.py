@@ -109,15 +109,23 @@ class Chore:
     batch_body: Optional[Callable[["Task"], Callable[..., Any]]] = None
 
 
-# Tasks a device module issues as one launch (``Chore.batch_body`` /
-# ``batch_hook``), largest first: a worker launches the largest size the
-# ready same-body tasks it selected fill, the next size from what is
-# left, and single tasks below the smallest. A fixed set, so every size
-# is compiled the first time a signature is seen and none later.
+# Tasks a device module issues as one launch, largest first: a worker
+# launches the largest size that ready tasks of one body fill, the next
+# size from what is left, and single tasks below the smallest. Every
+# batchable accelerator body has such a program (``Chore.batch_body``,
+# ``batch_hook``, or the plain ``hook`` unrolled). A fixed set, so every
+# size is compiled the first time a signature is seen and none later.
 # Settled on the v5e (PERF.md section 6, PR 25): what a launch makes
 # waits in HBM for its members' release, and eight 1024-tiles are what
-# the benchmark's 1% on peak_hbm_gib leaves room for.
+# the benchmark's 1% on peak_hbm_gib leaves room for. Which of these
+# sizes a task's bytes admit is the module's rule
+# (``device.tpu.GROUP_BYTES``).
 GROUP_SIZES = (8, 4)
+# The most tasks one take of a worker holds, all classes together
+# (``Context._take_group``): every one is launched before the worker
+# selects again, so this bounds how long a ready task of high priority
+# waits in a worker's hands behind what was selected before it.
+GROUP_TAKE = 2 * GROUP_SIZES[0]
 
 _task_counter = itertools.count()
 

@@ -17,15 +17,19 @@ are played by XLA/PJRT itself:
   cached (XLA compile cache handles shape variants).
 
 *Group launch* (``execute_group``): the worker that selected several
-ready tasks of one body (``Context._take_group``) has them issued here as
-ONE jitted program that calls the body once per member, flat and
-unrolled — the device does the same work per task in the same buffers,
-the host pays one trip through jit dispatch for the group. A module has
-one group in flight (``group_turn``; the next launch waits for the last
-one's output) and a group is held to ``GROUP_BYTES`` of inputs, because
-what a launch makes waits in HBM for its members' release. Whole
-taskpools lowered to one program are ``parsec_tpu.compiled``'s business,
-not this module's.
+ready tasks of one taskpool (``Context._take_group``: one bin per body,
+whatever classes were ready together) has each bin issued here as ONE
+jitted program. Every batchable body has one: it calls the body once per
+member, flat and unrolled — the device does the same work per task in
+the same buffers, the host pays one trip through jit dispatch for the
+group — or, for a body with a ``batch_hook``, that hook over the stacked
+members. A module has one group in flight (``group_turn``; the next
+launch waits for the last one's output) and a launch carries the largest
+size of ``GROUP_SIZES`` whose members' inputs together stay within
+``GROUP_BYTES``, because what a launch makes waits in HBM for its
+members' release: small tasks go many to a launch, large ones alone, by
+the bytes this module sees and nothing else. Whole taskpools lowered to
+one program are ``parsec_tpu.compiled``'s business, not this module's.
 """
 
 from __future__ import annotations
@@ -47,10 +51,16 @@ from ..utils.debug import debug_verbose
 # several large tiles is slower than the tiles' own programs (64 GEMMs of
 # 4096^3 as four programs of sixteen: half again the device time, PERF.md
 # section 6, PR 25), while a task that size keeps the chip busy longer
-# than its launch costs the host. So a group is held to this many bytes
-# of inputs, all members together: 1024 f32 tiles of a GEMM (12 MiB a
-# task) go eight at a time, 2048 and 4096 tiles alone.
-GROUP_BYTES = 128 << 20
+# than its launch costs the host. So a launch carries the largest size of
+# ``GROUP_SIZES`` whose members' inputs, all together, stay within this
+# many bytes: f32 GEMMs of 1024-tiles (12 MiB a task) go eight at a time,
+# of 2048-tiles (48 MiB) four at a time, of 4096-tiles (192 MiB) alone.
+# Settled on the v5e (PERF.md section 6, PR 28): the program of four
+# 2048-tile GEMMs costs the device no more than the four alone (0.43 s a
+# factorization against 0.45) and the host a third of their launches;
+# two at a time under 128 MiB left the host 40% slower, and a size 2
+# beside the four moved no step time. 2 x 192 MiB stays refused.
+GROUP_BYTES = 192 << 20
 
 
 class TPUDevice(Device):
@@ -87,7 +97,8 @@ class TPUDevice(Device):
         # group programs: {(id(chore), batch_sig, input signature):
         # {size: program}}, an entry dropped when its chore dies; and the
         # process-shared programs this module has already run once
-        self._group_cache: Dict[Any, Dict[int, Callable]] = {}
+        # {id(chore): {(batch_sig, signature): {size: program}}}
+        self._group_cache: Dict[int, Dict[Any, Dict[int, Callable]]] = {}
         self._group_lock = threading.Lock()
         # one group in flight: held from taking the tasks to the last
         # member's release (Context._group_progress); and an output of
@@ -233,11 +244,8 @@ class TPUDevice(Device):
         and that ``GROUP_BYTES`` admits. 0 where the first task has to go
         alone (the caller takes the single path). Members that differ in
         signature never share a program. Raises where the launch does."""
-        hooked = chore.batch_body is None
         values = [tasks[0].input_values()]
         sig = self._sig(values[0])
-        if sig is None or (None in sig and not hooked):
-            return 0
         programs = self._group_programs(tasks[0], chore, values[0], sig)
         for size, program in programs.items():      # largest first
             if size > len(tasks):
@@ -248,7 +256,7 @@ class TPUDevice(Device):
                     break
                 values.append(more)
             if len(values) < size or \
-                    (hooked and not self._hook_ok(chore, tasks[:size])):
+                    not self._hook_ok(chore, tasks[:size]):
                 continue
             if tasks[0].taskpool.context.stage_timers:
                 with StageSpan(SPAN_EXEC):      # one span per launch
@@ -341,28 +349,62 @@ class TPUDevice(Device):
         result per member``. Every size of ``GROUP_SIZES`` that
         ``GROUP_BYTES`` admits is built, and run once, the first time a
         signature is seen, and so is the single path's program: whatever
-        sizes a later step forms, nothing compiles then."""
+        sizes a later step forms, nothing compiles then. Empty for inputs
+        that share no program (``_sig``; a woven body with a flow that
+        holds no value)."""
+        mine = self._group_cache.get(id(chore))
+        if mine is None:
+            with self._group_lock:
+                mine = self._group_cache.get(id(chore))
+                if mine is None:
+                    mine = self._group_cache[id(chore)] = {}
+                    # id(chore) is reused once the pool's chore is gone
+                    weakref.finalize(chore, self._group_cache.pop,
+                                     id(chore), None)
+        if sig is None or (None in sig and chore.batch_body is not None):
+            return {}
         bsig = chore.batch_sig(task) if chore.batch_sig is not None \
             else None
-        key = (id(chore), bsig, sig)
-        programs = self._group_cache.get(key)   # one dict hit per launch
+        programs = mine.get((bsig, sig))    # two dict hits per launch
         if programs is None:
             with self._group_lock:      # serializes compile-on-miss only
-                programs = self._group_cache.get(key)
+                programs = mine.get((bsig, sig))
                 if programs is None:
-                    programs = self._build_group_programs(
-                        task, chore, values, bsig, sig)
-                    self._group_cache[key] = programs
-                    # id(chore) is reused once the pool's chore is gone
-                    weakref.finalize(chore, self._group_cache.pop, key,
-                                     None)
+                    programs = mine[bsig, sig] = \
+                        self._build_group_programs(
+                            task, chore, values, bsig, sig)
         return programs
+
+    def group_due(self, chore: Chore) -> bool:
+        """Should this module be handed tasks of ``chore`` too few for a
+        group, to build its programs on them? Once, for a body whose
+        program is the one task's own, repeated: its class then has them
+        from the pool's first step on, however rarely its tasks meet. A
+        ``batch_hook`` is another program than the lone task's, stacked
+        and vmapped, and is built when its first group forms: a class
+        whose tasks never meet (a Cholesky's POTRF) does not pay for it
+        beside a resident matrix."""
+        return chore.batch_hook is None and \
+            id(chore) not in self._group_cache
 
     def _build_group_programs(self, task, chore, values, bsig, sig):
         from ..utils import compile_cache
         jax, tu = self.jax, self.jax.tree_util
-        hooked = chore.batch_body is None
-        body = chore.batch_hook if hooked else chore.batch_body(task)
+        if chore.batch_body is not None:
+            kind, body = "woven", chore.batch_body(task)
+        elif chore.batch_hook is not None:
+            kind, body = "hooked", chore.batch_hook
+        else:
+            # the plain hook, as the single path jits it: the task is
+            # host-side metadata a batchable body does not read, and a
+            # flow without a value is None in its place (``sig`` says
+            # which, so the hook and ``sig`` identify the program)
+            kind, hook = "plain", chore.hook
+            held = [v is not None for v in values]
+
+            def body(*vals):
+                it = iter(vals)
+                return hook(None, *(next(it) if h else None for h in held))
         # (treedef, leaves) of a member's flows that hold a value, and of
         # those the ones a batch_hook takes (READ flows, stacked: the
         # wavefront executor's convention)
@@ -396,8 +438,9 @@ class TPUDevice(Device):
         # equal bodies across taskpools, contexts and device modules
         # trace once: a stable fingerprint shares the program process-
         # wide; an unstable one stays with this chore
-        stable, fp = compile_cache.function_fingerprint(body)
-        shared = ("tpu_group", fp, hooked, reads, bsig, sig) \
+        stable, fp = compile_cache.function_fingerprint(
+            chore.hook if kind == "plain" else body)
+        shared = ("tpu_group", fp, kind, reads, bsig, sig) \
             if stable else None
 
         def first_run(which) -> bool:
@@ -414,7 +457,7 @@ class TPUDevice(Device):
         programs = {}
         with jax.default_device(self.jax_device):
             for size in self._sizes(nbytes):
-                fn = (stacked if hooked else unrolled)(size)
+                fn = (stacked if kind == "hooked" else unrolled)(size)
                 if shared is None:
                     fn = jax.jit(fn)
                 else:

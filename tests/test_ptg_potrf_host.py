@@ -10,6 +10,7 @@ the stage timers are off. One accelerator module, the inline CPU module
 kept a last resort as beside a real chip (a test steers that; the program
 has no knob for it)."""
 
+import gc
 import glob
 import os
 import sys
@@ -23,7 +24,7 @@ import parsec_tpu as parsec
 import parsec_tpu.device.tpu
 from parsec_tpu.algorithms import build_potrf
 from parsec_tpu.core import context as context_mod
-from parsec_tpu.core.task import DeviceType
+from parsec_tpu.core.task import GROUP_SIZES, DeviceType
 from parsec_tpu.data.matrix import SymTwoDimBlockCyclic, TiledMatrix
 from parsec_tpu.utils import mca_param
 
@@ -45,7 +46,7 @@ CLASSES = {"POTRF": NT, "TRSM": NT * (NT - 1) // 2,
            "GEMM": NT * (NT - 1) * (NT - 2) // 6}
 TASKS = sum(CLASSES.values())
 assert TASKS == 120
-GROUP_ENDS = ("limit", "empty", "class", "sig")
+GROUP_ENDS = ("limit", "empty", "class")
 
 
 @pytest.fixture
@@ -161,6 +162,40 @@ def test_a_sound_factor_rounded_to_bfloat16_fails_the_references_limit(
     assert _residual(key, tiles) <= LIMIT < _residual(key, rounded)
 
 
+# -- the cell's graph in groups: what `correct` holds the chip to ------
+
+@pytest.mark.parametrize("nb_cores", [1, 4])
+def test_in_groups_the_factor_every_task_and_the_storage_hold(
+        make_ctx, nb_cores):
+    """The benchmark's three conditions at test size, with the classes
+    leaving in groups: the residual, every task on the module, and each
+    tile held once when the pool has ended (the storage guarantee's
+    shadow on the CPU: no launch keeps an output, no bin a task)."""
+    ctx = make_ctx(nb_cores=nb_cores)
+    A, key, _a0 = _matrix()
+    _factor(ctx, A)                     # compiles; constants are made
+    dev = _module(ctx)
+    groups, tasks = dev.stats["batches"], dev.stats["tasks"]
+    gc.collect()                        # what earlier tests left behind
+    before = _tile_sized()              # the first matrix's 36 tiles
+    A2, key, _a0 = _matrix(step=2)
+    tiles = _factor(ctx, A2)
+    assert _residual(key, tiles) <= LIMIT
+    assert dev.stats["tasks"] - tasks == TASKS
+    assert dev.stats["batches"] > groups
+    assert sum(es.stats["executed"] for es in ctx.streams) == 2 * TASKS
+    assert all(d.load == 0.0 for d in ctx.devices.devices)
+    # what the second pool left is its matrix and nothing else (the
+    # module's last launch's output is one of its tiles): no launch keeps
+    # an output, no bin a task. A parked worker still holds the last task
+    # it completed, and with it the version of a tile that task read:
+    # after either pool, so the two readings differ by the workers that
+    # did not run the last POTRF
+    del tiles
+    gc.collect()
+    assert abs(_tile_sized() - before - len(LOWER)) <= nb_cores - 1
+
+
 # -- in place: every update is the tile's one copy ------
 
 def _tile_sized():
@@ -192,9 +227,12 @@ def test_every_update_is_written_to_its_tile_and_frees_the_last_version(
     ctx.add_taskpool(tp)
     assert tp.wait_completed(300)
     assert len(held) == CLASSES["SYRK"] + CLASSES["GEMM"] and not stale
-    # beside the matrix: what the workers have in flight (0 with one, 6
-    # to 10 with four), never a second copy of each of 28 trailing tiles
-    assert max(held) - before <= 4 * nb_cores, (before, max(held))
+    # beside the matrix: what one launch made and has not yet released
+    # (a module has one group in flight: seven of eight when the first
+    # member completes) and what the other workers have in flight (6 to
+    # 10 with four), never a second copy of each of 28 trailing tiles
+    assert max(held) - before <= GROUP_SIZES[0] + 4 * (nb_cores - 1), \
+        (before, max(held))
     assert _residual(key, {k: A.data_of(k) for k in LOWER}) <= LIMIT
 
 
@@ -270,14 +308,29 @@ def test_with_the_timers_on_tasks_and_launches_are_counted_by_class(
     takes = []
     take = ctx._take_group
 
-    def counted(es, task, chore, limit):
-        tasks = take(es, task, chore, limit)
-        takes.append(len(tasks))
-        return tasks
+    def counted(es, task, chore, module, limit):
+        bins = take(es, task, chore, module, limit)
+        takes.append(len(bins))
+        return bins
 
     monkeypatch.setattr(ctx, "_take_group", counted)
+    handed = {}                         # the smallest bin a class handed
+    launch = ctx._group_launch
+
+    def watched(es, tasks, chore, module):
+        name = tasks[0].task_class.name
+        handed[name] = min(handed.get(name, len(tasks)), len(tasks))
+        launch(es, tasks, chore, module)
+
+    monkeypatch.setattr(ctx, "_group_launch", watched)
     A, _key, _a0 = _matrix()
     _factor(ctx, A)                     # the timers are off: no count
+    # in a pool's first step the module is handed every class whose group
+    # program is the lone task's own, repeated, few as its first tasks
+    # are (it builds on them); a batch_hook's stacked program waits for
+    # its first group, which a POTRF, one ready at a time, never forms
+    assert {"SYRK", "GEMM"} <= set(handed) and "POTRF" not in handed
+    assert handed.get("TRSM", GROUP_SIZES[-1]) >= GROUP_SIZES[-1]
     assert takes and dev.dump_statistics()["tasks_by_class"] == {}
     assert not any(es.stats["group_end_" + why] for es in ctx.streams
                    for why in GROUP_ENDS)
@@ -291,19 +344,21 @@ def test_with_the_timers_on_tasks_and_launches_are_counted_by_class(
     stats = dev.dump_statistics()
     assert stats["tasks_by_class"] == CLASSES
     launches = stats["launches_by_class"]
-    # a launch is a lone task or a group; only bodies with a batch_hook
-    # (POTRF, TRSM) can leave in a group, and one POTRF is ready at a time
-    assert {c: launches[c] for c in ("POTRF", "SYRK", "GEMM")} == \
-        {c: CLASSES[c] for c in ("POTRF", "SYRK", "GEMM")}
-    assert 1 <= launches["TRSM"] <= CLASSES["TRSM"]
+    # a launch is a lone task or a group; every body can leave in a
+    # group, but one POTRF is ready at a time
+    assert launches["POTRF"] == CLASSES["POTRF"]
+    assert all(1 <= launches[c] <= CLASSES[c] for c in CLASSES)
+    assert launches["GEMM"] < CLASSES["GEMM"]
     batches, batched = (stats[k] - before[k]
                         for k in ("batches", "batched_tasks"))
     assert sum(launches.values()) == TASKS - batched + batches
-    # one reason a take, whatever became of the tasks taken
+    # one reason a take, whatever became of the tasks taken; within one
+    # pool every task can be grouped, so none ends on another class
     ends = {why: sum(es.stats["group_end_" + why] for es in ctx.streams)
             for why in GROUP_ENDS}
     assert sum(ends.values()) == len(takes) > 0
-    assert ends["class"] > 0 and ends["sig"] == 0
+    assert ends["class"] == 0
+    assert sum(es.stats["group_bins"] for es in ctx.streams) == sum(takes)
     # the statistics are a copy: a reader's delta is of two readings
     stats["tasks_by_class"]["GEMM"] = 0
     assert dev.dump_statistics()["tasks_by_class"]["GEMM"] == CLASSES["GEMM"]
