@@ -39,8 +39,8 @@ Extras, per the PR-3 fast-path guard brief:
   ordered labels of its committed writers).  ``digest()`` hashes the
   per-tile sequences — schedule-independent iff the DAG fully orders
   each tile's writers, so two runs under different schedulers /
-  ``runtime.release_batch`` / ``runtime.bypass_chain`` settings must
-  produce bitwise-identical digests.
+  ``runtime.bypass_chain`` settings must produce bitwise-identical
+  digests.
 - **access-mode check**: at release, a body that returned a value for a
   READ/CTL flow (possible via dict returns) is flagged — the dynamic
   half of the lint's access-violation rule.

@@ -32,6 +32,9 @@ from jax.profiler import TraceAnnotation
 
 from .future import DataCopyFuture
 from .reshape import resolve_reshape
+from .spans import (SPAN_DISPATCH, SPAN_EXEC, SPAN_INSERT,  # noqa: F401
+                    SPAN_PARK, SPAN_PTG_STARTUP, SPAN_PTG_UNFOLD,
+                    SPAN_RELEASE, SPAN_SELECT, StageSpan)
 from .task import (GROUP_SIZES, GROUP_TAKE, Chore, DeviceType, HookReturn,
                    Task, TaskStatus)
 from .taskpool import DataRef, SuccessorRef, Taskpool
@@ -46,9 +49,6 @@ mca_param.register("runtime.stage_reads", "auto",
                         "registered) | 1 | 0")
 mca_param.register("runtime.backoff_min_us", 50, help="starvation backoff floor")
 mca_param.register("runtime.backoff_max_us", 2000, help="starvation backoff ceiling")
-mca_param.register("runtime.release_batch", 1,
-                   help="batch a completed task's dependency releases "
-                        "into one striped-lock pass (0 = per-dep locks)")
 mca_param.register("runtime.bypass_chain", 1,
                    help="keep a completing task's best ready successor "
                         "in the stream's bypass slot (never queued); "
@@ -80,43 +80,6 @@ mca_param.register("runtime.ckpt_interval_s", 0.0,
                         "points; 0 = only the taskpool-count trigger")
 mca_param.register("runtime.ckpt_dir", "",
                    help="default directory for Context.enable_checkpoints")
-
-
-# the runtime's stages as they appear in a profiler trace: one span per
-# stage-timer site, constant names (benchmark/program_spans.py and an
-# operator's TensorBoard read them beside the device's operations)
-SPAN_INSERT = "parsec:insert"
-SPAN_SELECT = "parsec:select"
-SPAN_PARK = "parsec:park"
-SPAN_DISPATCH = "parsec:dispatch"
-SPAN_EXEC = "parsec:exec"
-SPAN_RELEASE = "parsec:release"
-# the PTG front end's own stages (dsl/ptg.py names them on its taskpool
-# and task classes; a front end that names none has none)
-SPAN_PTG_STARTUP = "parsec:ptg_startup"
-SPAN_PTG_UNFOLD = "parsec:ptg_unfold"
-
-
-class StageSpan:
-    """One pass through a stage-timer site, opened only where
-    ``context.stage_timers`` is on: a ``TraceAnnotation`` (a span on the
-    profiler's own clock, beside the ``/device:TPU:n`` planes, when a
-    session is live; next to nothing when none is) and the seconds it
-    took, which the site adds to its ``es.stats`` / ``insert_s`` sum."""
-
-    __slots__ = ("_ann", "_t0", "seconds")
-
-    def __init__(self, name: str):
-        self._ann = TraceAnnotation(name)
-
-    def __enter__(self) -> "StageSpan":
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._t0
-        self._ann.__exit__(*exc)
 
 
 class ExecutionStream:
@@ -188,15 +151,13 @@ class Context:
         for es in self.streams:
             self.scheduler.flow_init(es)
 
-        # release-path knobs, resolved once per context (the hot loops
+        # release-path knob, resolved once per context (the hot loops
         # read attributes, not the MCA registry); lowercase so
         # set(..., False) / "OFF" disable like "0" does
-        self._release_batch = str(mca_param.get(
-            "runtime.release_batch", 1)).lower() not in ("0", "off", "false")
         self._bypass_chain = str(mca_param.get(
             "runtime.bypass_chain", 1)).lower() not in ("0", "off", "false")
         # data-plane broadcast enable (comm.bcast, registered by
-        # comm.collectives); resolved once like the release knobs
+        # comm.collectives); resolved once like the release knob
         self._comm_bcast = str(mca_param.get(
             "comm.bcast", 1)).lower() not in ("0", "off", "false")
         # per-stage overhead timers and spans (select/park/dispatch/
@@ -877,11 +838,11 @@ class Context:
                 continue
             es.stats["selected"] += 1
             try:
-                chore = self._group_chore(task)
-                if chore is None:
+                found = self._group_chore(task)
+                if found is None:
                     self._task_progress(es, task)
                 else:
-                    self._group_progress(es, task, chore)
+                    self._group_progress(es, task, found)
             except Exception as exc:  # noqa: BLE001 - worker must survive
                 warning("scheduling", "task %r raised: %s", task, exc)
                 import traceback
@@ -941,7 +902,7 @@ class Context:
 
     # ------------------------------------------------------ group launch
     # A worker that holds a ready accelerator task whose chore has a
-    # group program takes the ready tasks of its taskpool it can select,
+    # pure body takes the ready tasks of its taskpool it can select,
     # whatever their class, sorts them by body and has the device module
     # issue each body's tasks as one launch: one trip through jit
     # dispatch (and one hand-off of the GIL) for the group instead of one
@@ -949,13 +910,13 @@ class Context:
     # is _task_progress.
 
     @staticmethod
-    def _group_chore(task: Task) -> Optional[Chore]:
+    def _group_chore(task: Task) -> Optional[Tuple[Chore, Tuple]]:
         """The first incarnation the task's mask leaves, if it is an
-        accelerator's, has a group program (``batch_body`` +
-        ``batch_sig``: DTD pure woven bodies; any batchable body: its
-        ``batch_hook``, else its plain ``hook`` once per member) and does
-        not veto the task; else None, and ``_execute`` walks the
-        incarnations."""
+        accelerator's, has a pure body the module may launch with others
+        (``Chore.pure_body``, asked once a task) and does not veto the
+        task, and the key of the bin its tasks share (one class, one
+        chore, one key of the pure body: they may share a launch); else
+        None, and ``_execute`` walks the incarnations."""
         for i, chore in enumerate(task.task_class.incarnations):
             if task.chore_mask & (1 << i):
                 break
@@ -963,35 +924,27 @@ class Context:
             return None
         if not chore.device_type & DeviceType.TPU:
             return None     # a CPU body is one call as it is
-        if chore.batch_body is not None:
-            if chore.batch_sig is None:
-                return None
-        elif not chore.batchable:
+        pure = chore.pure_body(task)
+        if pure is None:
             return None
         if chore.evaluate is not None and not chore.evaluate(task):
             return None
-        return chore
+        return chore, (task.task_class, id(chore), pure[0])
 
-    @staticmethod
-    def _bin_key(task: Task, chore: Chore):
-        """Tasks of one key may share a launch."""
-        return (task.task_class, id(chore),
-                chore.batch_sig(task) if chore.batch_sig is not None
-                else None)
-
-    def _take_group(self, es: ExecutionStream, task: Task, chore: Chore,
-                    dev, limit: int) -> List[Tuple[Chore, List[Task]]]:
-        """``task`` and the tasks the scheduler hands this worker next,
-        in one bin per (class, first incarnation, ``batch_sig``), the
-        bins in the order their first task was selected. The take ends
-        when a bin holds what ``dev`` says one launch of its first task
-        may carry (``limit`` for ``task``'s), at ``GROUP_TAKE`` tasks in
-        all, on an empty queue, or on a task that cannot be grouped
-        (another taskpool, no group chore, none ``dev`` may launch):
-        that one waits in the bypass slot. Nothing is pushed back, so the
+    def _take_group(self, es: ExecutionStream, task: Task,
+                    found: Tuple[Chore, Tuple], dev,
+                    limit: int) -> List[Tuple[Chore, List[Task]]]:
+        """``task`` (``found`` is its ``_group_chore``) and the tasks the
+        scheduler hands this worker next, one bin per key, the bins in
+        the order their first task was selected. The take ends when a
+        bin holds what ``dev`` says one launch of its first task may
+        carry (``limit`` for ``task``'s), at ``GROUP_TAKE`` tasks in all,
+        on an empty queue, or on a task that cannot be grouped (another
+        taskpool, no group chore, none ``dev`` may launch): that one
+        waits in the bypass slot. Nothing is pushed back, so the
         scheduler's order is what it was."""
-        tp, key = task.taskpool, self._bin_key
-        bins = {key(task, chore): (chore, [task], limit)}
+        tp = task.taskpool
+        bins = {found[1]: (found[0], [task], limit)}
         taken, end = 1, "limit"
         while taken < GROUP_TAKE:
             nxt = self._select(es)
@@ -1002,14 +955,13 @@ class Context:
                 nxt.taskpool.addto_nb_tasks(-1)      # as _worker_main
                 continue
             entry = None
-            c = self._group_chore(nxt) if nxt.taskpool is tp else None
-            if c is not None:
-                k = key(nxt, c)
-                entry = bins.get(k)
+            its = self._group_chore(nxt) if nxt.taskpool is tp else None
+            if its is not None:
+                entry = bins.get(its[1])
                 if entry is None:
                     room = dev.group_limit(nxt)
                     if room:
-                        entry = bins[k] = (c, [], room)
+                        entry = bins[its[1]] = (its[0], [], room)
             if entry is None:
                 es.next_task = nxt
                 end = "class"
@@ -1025,11 +977,12 @@ class Context:
         return [(c, tasks) for c, tasks, _ in bins.values()]
 
     def _group_progress(self, es: ExecutionStream, task: Task,
-                        chore: Chore) -> None:
-        """``_task_progress`` of ``task`` and the ready tasks of its
-        taskpool this worker can select: every one is prepared, announced
-        and completed exactly once, as alone, and all of them in this
-        pass. The device module says how many one launch may carry and
+                        found: Tuple[Chore, Tuple]) -> None:
+        """``_task_progress`` of ``task`` (``found`` is its
+        ``_group_chore``) and the ready tasks of its taskpool this worker
+        can select: every one is prepared, announced and completed
+        exactly once, as alone, and all of them in this pass. The device
+        module says how many one launch may carry and
         has one group in flight at a time: what a launch made waits on
         the device for its members' release, so the workers take turns,
         each holding one task until its turn. The bins that fill a size
@@ -1038,22 +991,18 @@ class Context:
         given up, as a task that was never taken would. A launch that
         raises leaves the worker's handler to abort the pool, every load
         released."""
-        dev = self.devices.device_for(chore.device_type, task)
+        dev = self.devices.device_for(found[0].device_type, task)
         limit = dev.group_limit(task) if dev is not None else 0
         alone, held = [task], 1
         try:
             if limit:
                 with dev.group_turn:
-                    bins = self._take_group(es, task, chore, dev, limit)
+                    bins = self._take_group(es, task, found, dev, limit)
                     held = sum(len(tasks) for _, tasks in bins)
                     dev.add_load(held - 1)
                     alone = []
                     for c, tasks in bins:
-                        # the module builds a body's programs on the
-                        # first tasks it is handed, few as they may be:
-                        # in a pool's first step, not when four first meet
-                        if len(tasks) >= GROUP_SIZES[-1] or \
-                                dev.group_due(c):
+                        if len(tasks) >= GROUP_SIZES[-1]:
                             self._group_launch(es, tasks, c, dev)
                         else:
                             alone += tasks
@@ -1203,8 +1152,8 @@ class Context:
         tc = task.task_class
         ready: List[Task] = []
         # local refs accumulate and release in ONE striped-lock batch
-        # (runtime.release_batch; parsec_release_dep_fct walks its
-        # ready-ring the same way) instead of a lock pair per dep
+        # (parsec_release_dep_fct walks its ready-ring the same way)
+        # instead of a lock pair per dep
         local_refs: List[SuccessorRef] = []
         # remote deps sharing one produced value ship the payload ONCE
         # per rank (the reference's one-data-per-(dep, rank) aggregation,
@@ -1263,12 +1212,7 @@ class Context:
                         id(ref.value), {}).setdefault(
                             target_rank, []).append(ref)
                     continue
-            if self._release_batch:
-                local_refs.append(ref)
-            else:
-                new_task = tp.activate_dep(ref)
-                if new_task is not None:
-                    ready.append(new_task)
+            local_refs.append(ref)
         if local_refs:
             ready.extend(tp.activate_deps(local_refs))
         if remote_groups:

@@ -1,0 +1,45 @@
+"""Stage spans: the runtime's stage-timer sites as a profiler trace shows
+them. A module below ``core/context.py``, the DSLs and the device modules,
+which all open them (``core.context`` re-exports every name)."""
+
+from __future__ import annotations
+
+import time
+
+from jax.profiler import TraceAnnotation
+
+# the runtime's stages as they appear in a profiler trace: one span per
+# stage-timer site, constant names (benchmark/program_spans.py and an
+# operator's TensorBoard read them beside the device's operations)
+SPAN_INSERT = "parsec:insert"
+SPAN_SELECT = "parsec:select"
+SPAN_PARK = "parsec:park"
+SPAN_DISPATCH = "parsec:dispatch"
+SPAN_EXEC = "parsec:exec"
+SPAN_RELEASE = "parsec:release"
+# the PTG front end's own stages (dsl/ptg.py names them on its taskpool
+# and task classes; a front end that names none has none)
+SPAN_PTG_STARTUP = "parsec:ptg_startup"
+SPAN_PTG_UNFOLD = "parsec:ptg_unfold"
+
+
+class StageSpan:
+    """One pass through a stage-timer site, opened only where
+    ``context.stage_timers`` is on: a ``TraceAnnotation`` (a span on the
+    profiler's own clock, beside the ``/device:TPU:n`` planes, when a
+    session is live; next to nothing when none is) and the seconds it
+    took, which the site adds to its ``es.stats`` / ``insert_s`` sum."""
+
+    __slots__ = ("_ann", "_t0", "seconds")
+
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "StageSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)

@@ -19,8 +19,9 @@ trace, vmap-batch and fuse; the runtime owns the store-back.
 from __future__ import annotations
 
 import enum
+import functools
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 
@@ -99,22 +100,47 @@ class Chore:
     batch_hook: Optional[Callable[..., Any]] = None
     batch_hook_shared: Optional[Sequence[str]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
-    # e.g. DTD's woven argspec) can still opt into group launches by
-    # providing BOTH of: ``batch_sig(task) -> hashable`` — an extra
-    # grouping key such that tasks with equal keys share one pure body —
-    # and ``batch_body(task) -> fn(*flow_values)`` — that pure body
-    # (UNJITTED; the device jits one program that calls it once per
-    # member). Used by dtd.insert_task(pure=True).
+    # e.g. DTD's woven argspec) can still hand a device module their pure
+    # body by providing BOTH of: ``batch_sig(task) -> hashable`` — a key
+    # such that tasks with equal keys share one pure body — and
+    # ``batch_body(task) -> fn(*flow_values)`` — that pure body
+    # (UNJITTED; the device jits programs that call it once per member).
+    # Used by dtd.insert_task(pure=True). On the dynamic path these two
+    # and ``batchable`` are read by ``pure_body`` alone.
     batch_sig: Optional[Callable[["Task"], Any]] = None
     batch_body: Optional[Callable[["Task"], Callable[..., Any]]] = None
+    _plain: Optional[functools.partial] = field(
+        default=None, init=False, repr=False, compare=False)
+
+    def pure_body(self, task: "Task"
+                  ) -> Optional[Tuple[Any, Callable[..., Any]]]:
+        """``(key, fn)`` where this incarnation is a pure function of the
+        task's flow values a device module may jit, ``fn(*flow_values)
+        -> outputs``: tasks of this chore with equal keys share ``fn``,
+        so one XLA program runs any of them, alone or several to a
+        launch. ``(batch_sig, batch_body)`` of the task for a body that
+        declares them, ``(None, hook without its task)`` for a
+        ``batchable`` one (the task is host-side metadata such a body
+        does not read); ``None`` for a body that dispatches itself."""
+        if self.batch_body is not None:
+            if self.batch_sig is None:
+                return None
+            return self.batch_sig(task), self.batch_body(task)
+        if not self.batchable:
+            return None
+        plain = self._plain     # made once a chore, not once a task
+        if plain is None or plain.func is not self.hook:
+            plain = self._plain = functools.partial(self.hook, None)
+        return None, plain
 
 
 # Tasks a device module issues as one launch, largest first: a worker
 # launches the largest size that ready tasks of one body fill, the next
 # size from what is left, and single tasks below the smallest. Every
-# batchable accelerator body has such a program (``Chore.batch_body``,
-# ``batch_hook``, or the plain ``hook`` unrolled). A fixed set, so every
-# size is compiled the first time a signature is seen and none later.
+# accelerator body with a pure form has such programs (``Chore.pure_body``
+# unrolled, or its ``batch_hook`` over the stacked members). A fixed set,
+# so every size is compiled the first time a signature is seen (a
+# ``batch_hook``'s when its first group forms) and none later.
 # Settled on the v5e (PERF.md section 6, PR 25): what a launch makes
 # waits in HBM for its members' release, and eight 1024-tiles are what
 # the benchmark's 1% on peak_hbm_gib leaves room for. Which of these

@@ -507,10 +507,9 @@ class Taskpool:
 
     def activate_deps(self, refs: Sequence[SuccessorRef]) -> List[Task]:
         """Batched :meth:`activate_dep`: count all of a completed task's
-        satisfied deps in one striped-lock pass (``runtime.release_batch``)
-        and return every successor whose goal was reached. Semantics are
-        identical to calling ``activate_dep`` per ref; only the lock
-        traffic changes."""
+        satisfied deps in one striped-lock pass and return every
+        successor whose goal was reached. Semantics are identical to
+        calling ``activate_dep`` per ref; only the lock traffic changes."""
         if len(refs) == 1:
             task = self.activate_dep(refs[0])
             return [task] if task is not None else []

@@ -51,7 +51,7 @@ class Device:
     def group_limit(self, task: Task) -> int:
         """The most tasks like ``task`` one launch of this module may
         carry; 0 for a module that launches every task alone (then it
-        needs no ``execute_group``, ``group_due`` or ``group_turn``)."""
+        needs no ``execute_group`` or ``group_turn``)."""
         return 0
 
     def shutdown(self) -> None:

@@ -58,7 +58,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..core.context import SPAN_INSERT, StageSpan
+from ..core.spans import SPAN_INSERT, StageSpan
 from ..core.task import Chore, DeviceType, Flow, FlowAccess, Task
 from ..core.taskpool import DEPS_COUNTER, SuccessorRef, TaskClass
 from ..core.taskpool import Taskpool as CoreTaskpool
@@ -75,7 +75,12 @@ _GOAL_UNSET = 1 << 40       # sentinel while an insert is still linking
 # process-wide jit cache for pure=True bodies: (fn, argspec sig) →
 # jitted woven callable. Keyed by the fn OBJECT (kept alive by the
 # cache — no id-reuse aliasing), so module-level bodies compile once
-# per process even across taskpools
+# per process even across taskpools. It serves the modules that call a
+# chore's hook as it is: the CPU device, and a TPU module where its own
+# program table has no entry (inputs that share no signature); a TPU
+# module otherwise runs ``batch_body`` from its table, alone or in a
+# group, and never calls the woven ``_hook``. Not to be extended: it
+# goes when the CPU device gets its programs from that table (D12)
 _PURE_JIT_CACHE: Dict[Any, Callable] = {}
 _PURE_JIT_LOCK = threading.Lock()
 
@@ -404,11 +409,12 @@ class Taskpool(CoreTaskpool):
                             jit_cache[skey] = jf
                     return jf(*flow_vals)
 
-                # group launch (Context._take_group, TPUDevice.
-                # execute_group): tasks whose woven bodies are identical
-                # — same argspec signature at the same precision — may
-                # share one launch even though the hook itself reads
-                # per-task metadata
+                # the pure body for a device module's program table
+                # (Chore.pure_body): tasks whose woven bodies are
+                # identical — same argspec signature at the same
+                # precision — share its programs, alone or several to a
+                # launch, even though the hook itself reads per-task
+                # metadata
                 def _batch_sig(task: Task):
                     # fn identity is already in the group's key: one
                     # chore
@@ -433,9 +439,9 @@ class Taskpool(CoreTaskpool):
                     return _fn(*args)
 
             if pure:
-                # batchable=False: the hook self-jits (the device's
-                # jit wrapper would double-jit); batch_sig/batch_body
-                # let the device launch same-woven groups as one program
+                # batchable=False: the hook reads its task and
+                # self-jits; batch_sig/batch_body hand a device module
+                # the woven body for its own programs
                 tc.add_chore(Chore(device, _hook, batchable=False,
                                    batch_sig=_batch_sig,
                                    batch_body=_batch_body))
@@ -995,7 +1001,7 @@ class Taskpool(CoreTaskpool):
         return task
 
     def activate_deps(self, refs) -> List[Task]:
-        """Batched :meth:`activate_dep` (runtime.release_batch): group a
+        """Batched :meth:`activate_dep`: group a
         completed task's successor refs by seq-lock stripe so each stripe
         is locked once per completion instead of once per dep. The
         per-seq critical section is `_activate_one_locked`, shared with

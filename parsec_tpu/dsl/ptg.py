@@ -54,7 +54,7 @@ import types
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
-from ..core.context import SPAN_PTG_STARTUP, SPAN_PTG_UNFOLD
+from ..core.spans import SPAN_PTG_STARTUP, SPAN_PTG_UNFOLD
 from ..core.future import DataCopyFuture
 from ..core.reshape import compose_specs
 from ..core.task import Chore, DeviceType, Flow, FlowAccess, Task

@@ -445,21 +445,30 @@ def test_wavefront_segments_shared_across_problem_sizes():
 
 
 def test_tpu_device_body_jit_unified():
-    """device/tpu.py jit-cache unification: two device modules (or two
-    taskpools) dispatching the same stable body share one jitted
-    wrapper process-wide."""
+    """device/tpu.py's one program table: two device modules and two
+    chores (two taskpools) of the same stable body share the program of
+    one AND the group programs process-wide."""
     from types import SimpleNamespace
     import jax
-    from parsec_tpu.core.task import Chore, DeviceType
+    from parsec_tpu.core.task import (GROUP_SIZES, Chore, DeviceType, Flow,
+                                      FlowAccess)
     from parsec_tpu.device.tpu import TPUDevice
 
-    task = SimpleNamespace(task_class=SimpleNamespace(tc_id=1),
-                           taskpool=SimpleNamespace(taskpool_id=1))
+    task = SimpleNamespace(task_class=SimpleNamespace(
+        flows=[Flow("x", FlowAccess.RW)]))
+    values = [np.ones((4, 4), np.float32)]
     d1, d2 = (TPUDevice(jax.devices()[0]) for _ in range(2))
     c1 = Chore(device_type=DeviceType.TPU, hook=_module_level_body)
     c2 = Chore(device_type=DeviceType.TPU, hook=_module_level_body)
-    # distinct chore objects, distinct devices — one shared wrapper
-    assert d1._jitted(task, c1) is d2._jitted(task, c2)
+    # distinct chore objects, distinct devices — one table's worth
+    p1 = d1._programs(task, c1, values, d1._sig(values))
+    c0 = cc.backend_compile_count()
+    p2 = d2._programs(task, c2, values, d2._sig(values))
+    assert cc.backend_compile_count() == c0
+    assert sorted(p1) == sorted((*GROUP_SIZES, 1))
+    assert all(p1[size] is p2[size] for size in p1)
+    (two,) = p2[1](*values)
+    assert (np.asarray(two) == 2.0).all()
 
 
 def _module_level_body(task, x):

@@ -29,15 +29,13 @@ def san_ctx():
         mca_param.unset("pins")
 
 
-def _run_dtd_gemm(scheduler, release_batch, bypass_chain, nb_cores=4,
-                  native_dtd=0):
+def _run_dtd_gemm(scheduler, bypass_chain, nb_cores=4, native_dtd=0):
     """One DTD GEMM run under the sanitizer; returns (races, digest).
     ``native_dtd=1`` is the ISSUE 14 acceptance arm: dfsan no longer
     forces the Python engine — the pool runs NATIVELY and the ring-fed
     fold-time replay must produce a per-tile version digest
     bitwise-identical to every Python-engine configuration."""
     mca_param.set("pins", "dfsan")
-    mca_param.set("runtime.release_batch", release_batch)
     mca_param.set("runtime.bypass_chain", bypass_chain)
     mca_param.set("runtime.native_dtd", native_dtd)
     try:
@@ -71,7 +69,6 @@ def _run_dtd_gemm(scheduler, release_batch, bypass_chain, nb_cores=4,
         return races, digest
     finally:
         mca_param.unset("pins")
-        mca_param.unset("runtime.release_batch")
         mca_param.unset("runtime.bypass_chain")
         mca_param.unset("runtime.native_dtd")
 
@@ -79,22 +76,21 @@ def _run_dtd_gemm(scheduler, release_batch, bypass_chain, nb_cores=4,
 def test_determinism_digest_across_schedulers_and_release_knobs():
     """Satellite/acceptance: the per-tile version-sequence digest is
     bitwise-identical across both scheduler families (lfq =
-    local_queues, gd = global_queues), both `runtime.release_batch`
-    settings, `runtime.bypass_chain` off, AND `runtime.native_dtd`
+    local_queues, gd = global_queues), `runtime.bypass_chain` off, AND
+    `runtime.native_dtd`
     on/off (ISSUE 10: the engine knob must never change the observed
     dataflow) — the regression harness for the scheduler/release fast
     paths."""
     digests = set()
     for scheduler in ("lfq", "gd"):
-        for release_batch in (1, 0):
-            races, digest = _run_dtd_gemm(scheduler, release_batch, 1)
-            assert not races, races
-            digests.add(digest)
-    races, digest = _run_dtd_gemm("lfq", 1, 0)     # bypass_chain off
+        races, digest = _run_dtd_gemm(scheduler, 1)
+        assert not races, races
+        digests.add(digest)
+    races, digest = _run_dtd_gemm("lfq", 0)        # bypass_chain off
     assert not races, races
     digests.add(digest)
     for native in (0, 1):                          # ISSUE 10 engine knob
-        races, digest = _run_dtd_gemm("lfq", 1, 1, native_dtd=native)
+        races, digest = _run_dtd_gemm("lfq", 1, native_dtd=native)
         assert not races, races
         digests.add(digest)
     assert len(digests) == 1, f"schedule-dependent digests: {digests}"

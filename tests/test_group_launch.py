@@ -371,9 +371,8 @@ def test_a_cancelled_pools_tasks_inside_a_take_are_dropped(
 
 
 def test_too_few_for_a_group_go_alone_once_the_turn_is_given_up(make_ctx):
-    """A bin under the smallest size: the module is handed a body's first
-    tasks, builds its programs and sends them alone in the worker's turn;
-    later ones run as tasks that were never taken, the turn given up."""
+    """A bin under the smallest size runs as tasks that were never taken,
+    the turn given up; the body's first lone launch builds its programs."""
     ctx = make_ctx()
     turn = _module(ctx).group_turn
     x = TiledMatrix.from_array(np.ones((3 * 8, 8), np.float32), 8, 8,
@@ -397,7 +396,7 @@ def test_too_few_for_a_group_go_alone_once_the_turn_is_given_up(make_ctx):
         tp.flush(x)             # the three ran; the pool stays open
     ctx.pins.unregister(PinsEvent.EXEC_BEGIN, begin)
     assert _wait(tp) is None
-    assert held == [True] * 3 + [False] * 3
+    assert held == [False] * 6
     assert _groups(ctx) == (0, 0) and (x.to_array() == 4.0).all()
     assert all(d.load == 0.0 for d in ctx.devices.devices)
 
@@ -581,3 +580,179 @@ def test_nothing_compiles_after_the_first_step(make_ctx, first, body):
     steps = np.array([first, 7, 1, 16])
     assert list(x.to_array()[::8, 0]) == \
         [1.0 + (body(0.0) * (steps > i)).sum() for i in range(16)]
+
+
+# -- one route: one table, one staging rule -----------------------------------
+
+def _third(x):          # no other test's: the cases below count its compiles
+    return x / 3.0
+
+
+class _CountingJax:
+    """``jax`` as a module sees it, its ``device_put`` calls counted."""
+
+    def __init__(self, jax):
+        self._jax, self.puts = jax, 0
+
+    def __getattr__(self, name):
+        return getattr(self._jax, name)
+
+    def device_put(self, *args, **kwargs):
+        self.puts += 1
+        return self._jax.device_put(*args, **kwargs)
+
+
+def _placed_tile(where, dev):
+    import jax
+    import jax.numpy as jnp
+    tile = np.full((8, 8), 3.0, np.float32)
+    other = next(d for d in jax.devices() if d != dev)
+    return {"host numpy": lambda: tile,
+            "array here, uncommitted": lambda: jnp.asarray(tile),
+            "committed here": lambda: jax.device_put(tile, dev),
+            "committed to another device":
+                lambda: jax.device_put(tile, other)}[where]()
+
+
+@pytest.mark.parametrize("route,tasks", [("alone", 3), ("in a group", 8)])
+@pytest.mark.parametrize("where,puts", [
+    ("host numpy", 1), ("array here, uncommitted", 0),
+    ("committed here", 0), ("committed to another device", 1)])
+def test_one_staging_rule(make_ctx, where, puts, route, tasks):
+    """Every route places a leaf by one rule: a ``jax.Array`` that is not
+    committed to another chip passes as it is, no ``device_put`` called;
+    a host value and a leaf committed elsewhere are put on the module's
+    chip. So the result sits where the module is on both routes, and what
+    the rule commits here is one argument pattern: one compile a body."""
+    import jax
+    ctx = make_ctx()
+    dev = _module(ctx)
+    assert jax.default_backend() == "cpu"
+    x = TiledMatrix(tasks * 8, 8, 8, 8, name="X")
+
+    def step(place):
+        for i in range(tasks):
+            x.write_tile((i, 0), _placed_tile(place, dev.jax_device))
+        go, gate = _held_worker(ctx)
+        tp = dtd.Taskpool("staged")
+        ctx.add_taskpool(tp)
+        tp.insert_tasks(_third, [(dtd.TileArg(x, (i, 0), dtd.INOUT),)
+                                 for i in range(tasks)],
+                        device=DeviceType.TPU, pure=True)
+        go.set()
+        assert _wait(gate) is None and _wait(tp) is None
+        return [x.data_of((i, 0)) for i in range(tasks)]
+
+    step("committed here")                  # the body's table is built
+    groups = _groups(ctx)
+    compiled = compile_cache.backend_compile_count()
+    dev.jax = counting = _CountingJax(dev.jax)
+    out = step(where)
+    assert counting.puts == puts * tasks
+    assert _groups(ctx) == (groups[0] + (tasks == 8),
+                            groups[1] + tasks * (tasks == 8))
+    one_pattern = where != "array here, uncommitted"
+    if one_pattern:
+        assert compile_cache.backend_compile_count() == compiled
+    for tile in out:
+        assert isinstance(tile, jax.Array)
+        assert tile.devices() == {dev.jax_device}
+        assert tile.committed == one_pattern
+        assert (np.asarray(tile) == 1.0).all()
+
+
+def _hooked(task, x):
+    return x * 2.0
+
+
+def _hooked_stacked(xs):
+    _hooked_stacked.traced.append(xs.shape[0])
+    return xs * 2.0
+
+
+def test_a_batch_hook_body_goes_alone_from_the_table_and_stacks_at_its_first_group(
+        make_ctx):
+    """A body with a ``batch_hook``: its lone program is its plain hook,
+    in the table from the first lone launch; the stacked programs, which
+    no lone task runs, are built when its first group forms."""
+    from parsec_tpu.core.task import Chore, Flow, FlowAccess, Task
+    from parsec_tpu.core.taskpool import Taskpool
+    ctx = make_ctx()
+    dev = _module(ctx)
+    tp = Taskpool("hooked")
+    tc = tp.new_task_class("H", params=("i",),
+                           flows=[Flow("x", FlowAccess.RW)])
+    chore = Chore(DeviceType.TPU, _hooked, batch_hook=_hooked_stacked)
+    tc.add_chore(chore)
+    tp.context = ctx
+    _hooked_stacked.traced = []
+
+    def tasks(n):
+        made = [Task(tp, tc, (i,)) for i in range(n)]
+        for i, t in enumerate(made):
+            t.data["x"] = np.full((8, 8), float(i), np.float32)
+        return made
+
+    def doubled(made):
+        return all((np.asarray(t.output["x"]) == 2.0 * i).all()
+                   for i, t in enumerate(made))
+
+    compiled = compile_cache.backend_compile_count()
+    (lone,) = tasks(1)
+    dev.execute(None, lone, chore)
+    assert doubled([lone]) and _hooked_stacked.traced == []
+    assert compile_cache.backend_compile_count() == compiled + 1
+    eight = tasks(BIG)
+    assert dev.execute_group(None, eight, chore) == BIG and doubled(eight)
+    assert sorted(_hooked_stacked.traced) == [SMALL, BIG]
+    assert compile_cache.backend_compile_count() == compiled + 3
+    # every later size finds its program
+    for made in (tasks(1), tasks(SMALL), tasks(BIG)):
+        if len(made) == 1:
+            dev.execute(None, made[0], chore)
+        else:
+            assert dev.execute_group(None, made, chore) == len(made)
+        assert doubled(made)
+    assert dev.execute_group(None, tasks(SMALL - 1), chore) == 0
+    assert compile_cache.backend_compile_count() == compiled + 3
+    assert _groups(ctx) == (3, 2 * BIG + SMALL)
+    assert dev.stats["tasks"] == 2 + 2 * BIG + SMALL
+
+
+def _impure(x):
+    # a self-dispatching body gets its host values as they are
+    assert isinstance(x, np.ndarray)
+    return x + 1.0
+
+
+@pytest.mark.parametrize("pure,body", [(True, _shift), (False, _impure)])
+def test_a_warmed_pool_of_lone_tasks_constructs_no_chore(
+        make_ctx, monkeypatch, pure, body):
+    """Neither the table's route (a pure body) nor the pinned hook's (a
+    body that dispatches itself) makes a ``Chore`` per task."""
+    from parsec_tpu.core.task import Chore
+    ctx = make_ctx()
+    x = TiledMatrix.from_array(np.ones((3 * 8, 8), np.float32), 8, 8,
+                               name="X")
+    tp = dtd.Taskpool("lone")
+    ctx.add_taskpool(tp)
+
+    def step():
+        go, gate = _held_worker(ctx)
+        tp.insert_tasks(body, [(dtd.TileArg(x, (i, 0), dtd.INOUT),)
+                               for i in range(3)],
+                        device=DeviceType.TPU, pure=pure)
+        go.set()
+        assert _wait(gate) is None
+        tp.flush(x)             # the three ran; the pool stays open
+
+    step()
+    made, init = [], Chore.__init__
+    monkeypatch.setattr(Chore, "__init__", lambda self, *a, **kw: (
+        made.append(a), init(self, *a, **kw))[1])
+    step()
+    assert _wait(tp) is None
+    # the gate's class is new with its pool, and it alone
+    assert [a[0] for a in made] == [DeviceType.CPU]
+    assert _groups(ctx) == (0, 0) and _module(ctx).stats["tasks"] == 6
+    assert (x.to_array() == 3.0).all()

@@ -325,10 +325,9 @@ def test_with_the_timers_on_tasks_and_launches_are_counted_by_class(
     monkeypatch.setattr(ctx, "_group_launch", watched)
     A, _key, _a0 = _matrix()
     _factor(ctx, A)                     # the timers are off: no count
-    # in a pool's first step the module is handed every class whose group
-    # program is the lone task's own, repeated, few as its first tasks
-    # are (it builds on them); a batch_hook's stacked program waits for
-    # its first group, which a POTRF, one ready at a time, never forms
+    # only bins of a group's size are handed to the module as groups; a
+    # POTRF, one ready at a time, never forms one (its stacked programs
+    # are never built: TPUDevice._programs)
     assert {"SYRK", "GEMM"} <= set(handed) and "POTRF" not in handed
     assert handed.get("TRSM", GROUP_SIZES[-1]) >= GROUP_SIZES[-1]
     assert takes and dev.dump_statistics()["tasks_by_class"] == {}

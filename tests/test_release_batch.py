@@ -1,5 +1,5 @@
-"""Batched dependency release (runtime.release_batch) + bypass-slot
-chaining (runtime.bypass_chain): the host-runtime critical-path rework.
+"""Batched dependency release + bypass-slot chaining
+(runtime.bypass_chain): the host-runtime critical-path rework.
 
 Covers the PR-3 tentpole contracts:
 - `_PendingDeps.update_batch` is semantically identical to per-dep
@@ -11,7 +11,7 @@ Covers the PR-3 tentpole contracts:
   priority successor takes the stream's bypass slot, everything else
   reaches the scheduler (and nothing is lost with the knob off);
 - no lost wakeups: a concurrent DTD stress (chains + wide fan-out,
-  batch on AND off) always drains.
+  two schedulers and both engines) always drains.
 """
 
 import threading
@@ -122,7 +122,7 @@ def _bypass_fixture(nb_cores=2):
 def test_bypass_chain_takes_first_maximal_successor():
     ctx, tp, prod = _bypass_fixture()
     try:
-        assert ctx._bypass_chain and ctx._release_batch
+        assert ctx._bypass_chain
         es = ctx.streams[0]
         ctx.complete_task(es, prod)
         assert es.next_task is not None
@@ -149,18 +149,26 @@ def test_bypass_chain_off_queues_everything():
         parsec.fini(ctx)
 
 
-def test_release_batch_off_matches_batched_result():
-    mca_param.set("runtime.release_batch", 0)
+def test_batched_release_matches_a_plain_reference():
+    """What one batched pass over the producer's successors releases,
+    against a plain numpy reading of the same fan-out: every successor
+    once, with its value and priority, the first maximal one in the
+    bypass slot and the rest in the scheduler."""
+    import numpy as np
+    prios = np.array([3, 9, 9, 1])      # _bypass_fixture's fan-out
+    ctx, tp, prod = _bypass_fixture()
     try:
-        ctx, tp, prod = _bypass_fixture()
-    finally:
-        mca_param.unset("runtime.release_batch")
-    try:
-        assert not ctx._release_batch
         es = ctx.streams[0]
         ctx.complete_task(es, prod)
-        assert es.next_task is not None and es.next_task.priority == 9
-        assert ctx.scheduler.pending_tasks() == 3
+        first = int(np.argmax(prios))   # numpy's argmax is the first
+        assert es.next_task.locals == (first,)
+        queued = []
+        while ctx.scheduler.pending_tasks():
+            queued.append(ctx.scheduler.select(es))
+        released = {t.locals[0]: (t.data["x"], t.priority)
+                    for t in [es.next_task, *queued]}
+        assert len(queued) == len(prios) - 1
+        assert released == {i: (i, int(p)) for i, p in enumerate(prios)}
     finally:
         parsec.fini(ctx)
 
@@ -185,8 +193,9 @@ def _null_body():
     return None
 
 
-@pytest.mark.parametrize("release_batch,native", [(1, 0), (0, 0), (1, 1)])
-def test_no_lost_wakeups_concurrent_complete(release_batch, native):
+@pytest.mark.parametrize("scheduler,native", [
+    ("lfq", 0), ("gd", 0), ("lfq", 1)])
+def test_no_lost_wakeups_concurrent_complete(scheduler, native):
     """Chains (serial last-writer links) + wide fan-out draining through
     4 workers: every completion releases successors concurrently with
     further insertion. A lost wakeup or a dropped activation hangs
@@ -198,9 +207,8 @@ def test_no_lost_wakeups_concurrent_complete(release_batch, native):
     if native and not _native.available():
         pytest.skip("native core unavailable")
     mca_param.set("runtime.native_dtd", native)
-    mca_param.set("runtime.release_batch", release_batch)
     try:
-        ctx = parsec.init(nb_cores=4)
+        ctx = parsec.init(nb_cores=4, scheduler=scheduler)
         ctx.start()
         n_chain, n_fan = 60, 400
         S = LocalCollection("S", {("c", j): 0 for j in range(4)})
@@ -221,5 +229,4 @@ def test_no_lost_wakeups_concurrent_complete(release_batch, native):
         assert (tp._native is not None) == bool(native)
         parsec.fini(ctx)
     finally:
-        mca_param.unset("runtime.release_batch")
         mca_param.unset("runtime.native_dtd")
