@@ -572,9 +572,18 @@ def phase_sharded(sz, on_chip):
         jax.block_until_ready(out)
         t_first = time.perf_counter() - t0
         shard_devs = sorted(s.device.id for s in out["A"].addressable_shards)
+        # who partitioned it: the taskpool's mesh lowering under
+        # shard_map (runtime), or the one-chip program handed to GSPMD
+        part = ex.partition_report()
         say("sharded", flagship=f"n={n}/nb={nb}", spec='P("rows")',
+            branch=part["branch"],
+            collectives_per_step=part.get("collectives_per_step"),
+            busiest_chip_ops_share=part.get("busiest_chip_ops_share"),
             first_pass_s=f"{t_first:.1f}", shard_devices=shard_devs,
             peak_hbm=_peaks())
+        require(part["branch"] == "runtime",
+                f"the flagship over a mesh was partitioned by "
+                f"{part['branch']}: {part.get('reason')}")
         # the probe's many row-block slices of a row-SHARDED factor make
         # GSPMD plan tens of GB of resharding temporaries (it would not
         # compile): check the factor gathered onto one chip, with the

@@ -569,6 +569,7 @@ def build_potrf_left(A: TiledMatrix) -> ptg.Taskpool:
         return {"C": trsm_tile(C, L)}
 
     tp.wave_fuser = _potrf_left_wave_fuser
+    tp.mesh_wave_fuser = _potrf_left_mesh_wave_fuser
     tp.panel_segment_fuser = _potrf_left_segment_fuser
     tp.requires_fuser = True     # compiled per-tile executors can't feed
     #                              the UPDATE body's collection reads
@@ -700,6 +701,185 @@ def _potrf_left_wave_fuser(wave, geoms):
         return do_trsm
 
     return None
+
+
+def _potrf_left_mesh_wave_fuser(wave, geoms, part):
+    """Lower one left-looking POTRF wave for ONE shard of Aᵀ split by
+    rows over a mesh axis (compiled.panels mesh contract): owner-computes
+    over the collection's distribution, written by the runtime.
+
+    The update of step k contracts over the factored rows ``< k·nb``,
+    the SPLIT axis. Each chip multiplies the factored rows it already
+    holds — all of its panels (chips before row panel k's owner), the
+    first ``k mod panels-a-chip`` of them (the owner), none (chips after
+    it): static shapes under a ``lax.switch`` on the chip's index,
+    because k and so the owner are static — and the partial products
+    are summed over the chips that can hold any (``part.reducers``; the
+    first chip's own panels are summed with nobody's). The owner alone
+    subtracts, factors the diagonal tile, inverts or solves and writes
+    row panel k, in f32 with the one-chip fuser's precisions; every
+    other chip skips that as a loop of no trips, which unlike a
+    conditional leaves its shard where it is. Row panel k is never read
+    by another chip, so nothing else travels.
+
+    A step runs in column chunks (``part.chunks``), each multiplied,
+    summed, solved and written before the next is multiplied: the UPDATE
+    wave only hands the POTRF and TRSM waves the reduction of a chunk,
+    to be formed where it is consumed, so that one chunk of partial sums
+    is live and never a whole row panel of them.
+
+    The diagonal tile is factored by ``chol_inv_tile``, the loop form of
+    the one-chip fuser's ``potrf_tile_blocked`` + ``tri_inv_tile``: a
+    third of their program text, which a chip holds in HBM 64 times."""
+    import jax
+    from jax import lax
+    from ..ops.tile_kernels import chol_inv_tile
+    (geom,) = geoms.values()      # single-collection DAG
+    jnp, mm, _ = _fuser_helpers(geom)
+    if len(wave) != 1:
+        return None
+    (grp,) = wave
+    kind = grp.tc.name
+    mb, nb, name = geom.mb, geom.nb, geom.name
+    axis = part.axis
+    f32 = jnp.float32
+    tile_bytes = nb * mb * 4          # of an f32 partial sum
+    solve_mode = mca_param.get("potrf.trsm_hook", "solve") == "solve"
+
+    ks = {t[-1] for t in grp.tasks}
+    if kind not in ("UPDATE", "POTRF", "TRSM") or len(ks) != 1:
+        return None
+    k = ks.pop()
+    ms = sorted(t[0] for t in grp.tasks)
+    lo, hi = ms[0], ms[-1] + 1
+    if ms != list(range(lo, hi)) or lo != (k + 1 if kind == "TRSM" else k):
+        return None
+    owner, kl = part.owner(geom, k)
+    held = geom.nt // part.shards * nb        # rows of Aᵀ a chip holds
+    rows = slice(kl * nb, (kl + 1) * nb)      # row panel k in its shard
+    diag = geom.rows(k)
+
+    def mine():
+        return lax.axis_index(axis) == owner
+
+    def owner_alone(write, D):
+        """``write(D)`` on row panel k's owner, ``D`` on every other chip,
+        in place on both: a loop of one trip or none."""
+        return lax.fori_loop(0, mine().astype(jnp.int32),
+                             lambda _, D: write(D), D)
+
+    if kind == "UPDATE":
+        chunks = part.chunks(k, hi, tile_bytes)
+        groups = part.reducers(owner)
+        for t0, t1 in chunks:
+            w = (t1 - t0) * mb
+            if groups is not None:
+                part.count_reduce(nb * w * 4)
+            for shard in range(owner):
+                part.ops[shard] += 2 * held * nb * w
+            part.ops[owner] += 2 * kl * nb * nb * w
+
+        def reduced(D, i):
+            """Σ over chips of (Lᵀ[:k, k])ᵀ · Lᵀ[:k, chunk i], each chip
+            contracting the factored rows it holds (the owner's copy is
+            the one that counts)."""
+            c0, c1 = chunks[i][0] * mb, chunks[i][1] * mb
+
+            def whole(D):
+                return mm(D[:, diag].T, D[:, c0:c1])
+
+            def head(D):
+                return mm(D[:kl * nb, diag].T, D[:kl * nb, c0:c1])
+
+            def nothing(D):
+                return jnp.zeros((nb, c1 - c0), f32)
+
+            own = head if kl else nothing
+            if groups is None:
+                return lax.cond(mine(), own, nothing, D)
+            me = lax.axis_index(axis)
+            # 0: a chip before the owner, 1: the owner, 2: one after it
+            role = (me >= owner).astype(jnp.int32) + \
+                (me > owner).astype(jnp.int32)
+            partial = lax.switch(role, (whole, own, nothing), D)
+            with jax.named_scope("parsec:panel_reduce"):
+                return lax.psum(partial, axis, axis_index_groups=groups)
+
+        def do_update(st):
+            st["_reduced"] = reduced
+            return st
+
+        return do_update
+
+    if kind == "POTRF":
+        if len(grp.tasks) != 1:
+            return None
+        part.ops[owner] += 2 * nb ** 3 // 3
+
+        def do_potrf(st):
+            D = st[name]
+            # chunk 0 of the update's sums: the diagonal tile at its head
+            tot = st["_reduced"](D, 0) if "_reduced" in st else None
+
+            def factor(cur, tot):
+                d = cur.astype(f32) if tot is None else cur - tot[:, :nb]
+                # symmetrized as the one-chip fuser does
+                L, inv = chol_inv_tile(0.5 * (d + d.T))
+                return L.T.astype(D.dtype), L if solve_mode else inv
+
+            def keep(cur, tot):
+                return cur, jnp.zeros((nb, nb), f32)
+
+            Lt, inv = lax.cond(mine(), factor, keep, D[rows, diag], tot)
+            # the one tile every chip writes, its own back where it is
+            # not the owner's: a tile of Lᵀ is L in the other layout, and
+            # written inside owner_alone's loop it may talk XLA into
+            # keeping the whole shard that way round (two copies of it)
+            st[name] = D.at[rows, diag].set(Lt)
+            if k == geom.nt - 1:       # no TRSM wave follows
+                st.pop("_reduced", None)
+            else:
+                st["_rowsum"] = tot
+                st["_potrf_inv"] = inv   # L itself under trsm_hook=solve
+            return st
+
+        return do_potrf
+
+    part.ops[owner] += 2 * nb * nb * (hi - lo) * mb
+
+    def do_trsm(st):
+        D = st[name]
+        reduced = st.pop("_reduced", None)       # None at k = 0
+        tot = st.pop("_rowsum")
+        inv = st.pop("_potrf_inv")
+        # the update's chunks, less the diagonal tile at the first's head
+        for i, (t0, t1) in enumerate(part.chunks(k, hi, tile_bytes)):
+            c0, c1 = t0 * mb, t1 * mb
+            if i and reduced is not None:
+                tot = reduced(D, i)
+            if not i:
+                c0 += mb
+                tot = None if tot is None else tot[:, mb:]
+            if c0 == c1:
+                continue
+
+            def write(D, c0=c0, c1=c1, tot=tot):
+                cur = D[rows, c0:c1]
+                rest = cur.astype(f32) if tot is None else cur - tot
+                if solve_mode:
+                    # exact wide triangular solve: no inversion
+                    rest = jax.scipy.linalg.solve_triangular(
+                        inv, rest, lower=True)
+                else:
+                    rest = mm(inv, rest)
+                # one contiguous write a chunk
+                return D.at[rows, c0:c1].set(rest.astype(D.dtype))
+
+            D = owner_alone(write, D)
+        st[name] = D
+        return st
+
+    return do_trsm
 
 
 # ---------------------------------------------------------------------------

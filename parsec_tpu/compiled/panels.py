@@ -71,6 +71,27 @@ re-uses every already-compiled bucket, and a second run (or second
 process) pays zero XLA compiles. Only the thin extract/write programs
 are keyed per state shape (they are slice+mask copies, cheap to
 compile, and they persist too).
+
+Over a mesh (the runtime's own partition)
+-----------------------------------------
+
+A taskpool may also register a ``mesh_wave_fuser``::
+
+    mesh_fuser(wave, geoms, part: PanelPartition)
+        -> Callable[[dict], dict] | None
+
+which lowers a wave for ONE shard of the state, every collection's axis
+0 split in ``part.shards`` equal contiguous parts over the mesh axis
+``part.axis``. The returned function runs under ``jax.shard_map``: it
+sees its shard alone, tells the chips apart by ``lax.axis_index`` and
+writes its own collectives. That is owner-computes over the data
+collection's distribution, decided by the runtime, where the
+``wave_fuser``'s one-chip program handed to GSPMD with a
+``PartitionSpec`` leaves the partition to be derived (and re-derived at
+every slice that is not aligned to a shard).
+:func:`~.spmd.compile_with_plan` asks :meth:`PanelExecutor.partitioned`
+first; a taskpool without a mesh lowering goes through GSPMD as before
+and :meth:`PanelExecutor.partition_report` says which it was.
 """
 
 from __future__ import annotations
@@ -105,6 +126,59 @@ class PanelGeometry:
     def cols(self, j: int) -> slice:
         """Row range of D covering block-column j of A."""
         return slice(j * self.nb, (j + 1) * self.nb)
+
+
+# A mesh lowering multiplies, sums across chips, solves and writes a row
+# panel in column chunks of at most this many bytes of partial sums, so
+# that none of its temporaries is a whole row panel (XLA:TPU's all-reduce
+# is synchronous: a chunk buys memory, not overlap).
+REDUCE_CHUNK_BYTES = 64 << 20
+_NO_MESH_LOWERING = "taskpool registers no mesh_wave_fuser"
+
+
+class PanelPartition:
+    """The split a ``mesh_wave_fuser`` lowers for — every collection's
+    axis 0 (the row panels of the transposed store) in ``shards`` equal
+    contiguous parts over mesh axis ``axis`` — and the lowering's own
+    account of what it emitted: static counts, no run needed."""
+
+    def __init__(self, axis: str, shards: int):
+        self.axis = axis
+        self.shards = shards
+        self.chunk_bytes = REDUCE_CHUNK_BYTES
+        self.collectives = 0
+        self.reduced_bytes = 0          # payload each chip hands in
+        self.ops = [0] * shards         # operations lowered per chip
+
+    def owner(self, geom: PanelGeometry, j: int) -> Tuple[int, int]:
+        """``(shard, local row panel)`` holding row panel ``j``."""
+        return divmod(j, geom.nt // self.shards)
+
+    def chunks(self, lo: int, hi: int, tile_bytes: int
+               ) -> List[Tuple[int, int]]:
+        """Tile range ``[lo, hi)`` in the fewest equal runs of whole
+        tiles whose ``tile_bytes`` stay within ``chunk_bytes``."""
+        most = max(1, self.chunk_bytes // tile_bytes)
+        n = -(-(hi - lo) // most)
+        size = -(-(hi - lo) // max(n, 1))
+        return [(t, min(t + size, hi)) for t in range(lo, hi, size)]
+
+    def reducers(self, owner: int) -> Optional[List[List[int]]]:
+        """The chips a sum to ``owner`` runs over, as ``axis_index_groups``
+        — the chips up to the owner are the ones that hold factored rows
+        to contract, so: blocks of consecutive chips of the smallest size
+        that divides the mesh and takes them all in. None when that is
+        the owner alone: nothing to sum."""
+        size = next(d for d in range(owner + 1, self.shards + 1)
+                    if self.shards % d == 0)
+        if size == 1:
+            return None
+        return [list(range(lo, lo + size))
+                for lo in range(0, self.shards, size)]
+
+    def count_reduce(self, nbytes: int) -> None:
+        self.collectives += 1
+        self.reduced_bytes += nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -305,6 +379,10 @@ class PanelExecutor:
                                       "panel_segment_fuser", None)
         self._seg_steps: Optional[List[SegStep]] = None
         self._jitted = None
+        # over a mesh: who partitioned the program last, and how
+        self._mesh_fuser = getattr(plan.taskpool, "mesh_wave_fuser", None)
+        self._partition: Dict[str, Any] = self._gspmd(_NO_MESH_LOWERING) \
+            if self._mesh_fuser is None else {"branch": None}
 
     @property
     def supports_segments(self) -> bool:
@@ -361,6 +439,102 @@ class PanelExecutor:
         # fuser carries (factored inverses etc.) are wave-transient —
         # only the collection arrays survive
         return {name: state[name] for name in self.geoms}
+
+    # -- over a mesh: the runtime's partition -----------------------------
+    @staticmethod
+    def _gspmd(reason: str) -> Dict[str, Any]:
+        return {"branch": "gspmd", "reason": reason}
+
+    def partition_report(self) -> Dict[str, Any]:
+        """Who partitioned this program the last time it was compiled
+        over a mesh: ``branch`` is ``runtime`` (the taskpool's mesh
+        lowering under ``shard_map``; with the collectives and the bytes
+        each chip hands to them a step, and the busiest chip's share of
+        the operations, as the lowering counted them) or ``gspmd`` (the
+        one-chip program and a ``PartitionSpec``; with the ``reason``).
+        ``branch`` is None until a mesh compile has asked."""
+        return dict(self._partition)
+
+    def _split_axis(self, mesh, in_shardings, out_shardings
+                    ) -> Tuple[Optional[str], str]:
+        """``(axis, "")`` when the call asks for the one split a mesh
+        lowering is written for, else ``(None, why not)``."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        if len(mesh.axis_names) != 1:
+            return None, f"mesh has {len(mesh.axis_names)} axes"
+        axis = mesh.axis_names[0]
+
+        def rows_split(sh) -> bool:
+            if isinstance(sh, NamedSharding):
+                if sh.mesh != mesh:
+                    return False
+                sh = sh.spec
+            return isinstance(sh, PartitionSpec) and \
+                tuple(sh)[:1] == (axis,) and not any(tuple(sh)[1:])
+
+        if not (isinstance(in_shardings, (tuple, list))
+                and len(in_shardings) == 1):
+            return None, "in_shardings is not one state"
+        for sh in (in_shardings[0], out_shardings):
+            if not (isinstance(sh, dict) and set(sh) == set(self.geoms)
+                    and all(rows_split(v) for v in sh.values())):
+                return None, (f"in/out shardings are not P({axis!r}) on "
+                              "axis 0 of every collection")
+        n = mesh.devices.size
+        odd = {name: g.nt for name, g in self.geoms.items() if g.nt % n}
+        if odd:
+            return None, f"{n} shards do not divide nt={odd}"
+        return axis, ""
+
+    def partitioned(self, mesh, in_shardings, out_shardings):
+        """``(fn, key)``: the taskpool's mesh lowering of every wave as
+        one ``shard_map``ped ``state -> state`` over ``mesh`` and what
+        a store key must carry of it (None: no stable fingerprint, do
+        not share). Returns None, and :meth:`partition_report` says
+        why, when the taskpool registers no mesh lowering, when the
+        call is not a one-axis mesh with the same ``P(axis)`` on every
+        collection's axis 0 going in and coming out and a size that
+        divides ``nt``, or when the lowering declines a wave — the
+        caller then hands :meth:`run_state` to GSPMD."""
+        from jax.sharding import PartitionSpec as P
+        axis, why = (None, _NO_MESH_LOWERING) if self._mesh_fuser is None \
+            else self._split_axis(mesh, in_shardings, out_shardings)
+        if not why:
+            part = PanelPartition(axis, int(mesh.devices.size))
+            lowered = [self._mesh_fuser(wave, self.geoms, part)
+                       for wave in self.plan.waves]
+            if None in lowered:
+                why = (f"wave {lowered.index(None)} declined by the "
+                       "mesh_wave_fuser")
+        if why:
+            self._partition = self._gspmd(why)
+            debug_verbose(2, "panels", "%s over a mesh is GSPMD's to "
+                          "partition: %s", self.plan.taskpool.name, why)
+            return None
+
+        def run_shard(state):
+            state = dict(state)
+            for fn in lowered:
+                state = fn(state)
+            return {name: state[name] for name in self.geoms}
+
+        specs = {name: P(axis) for name in self.geoms}
+        # the lowering tells chips apart and sums across them itself;
+        # nothing of it is replicated for shard_map to check
+        mapped = self.jax.shard_map(run_shard, mesh=mesh, in_specs=(specs,),
+                                    out_specs=specs, check_vma=False)
+        self._partition = {
+            "branch": "runtime", "axis": axis, "shards": part.shards,
+            "collectives_per_step": part.collectives,
+            "reduced_bytes_per_step_and_chip": part.reduced_bytes,
+            "busiest_chip_ops_share": max(part.ops) / max(sum(part.ops), 1),
+            "chunk_bytes": part.chunk_bytes}
+        debug_verbose(2, "panels", "%s over %d chips, the runtime's "
+                      "partition: %s", self.plan.taskpool.name,
+                      part.shards, self._partition)
+        ok, fp = compile_cache.function_fingerprint(self._mesh_fuser)
+        return mapped, (("mesh_wave_fuser", fp, part.chunk_bytes)
+                        if ok else None)
 
     # -- host-driven convenience -----------------------------------------
     def make_state(self) -> Dict[str, Any]:

@@ -37,6 +37,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import numpy as np
 
 from ..utils import compile_cache
+from .panels import PanelExecutor
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "tiles"):
@@ -175,7 +176,13 @@ def compile_with_plan(fn: Callable, *, mesh=None, in_shardings=None,
       with shardings): XLA partitions the program, inserting the
       collectives true dataflow needs. Giving only one of the two is an
       error — a half-specified contract silently replicates the other
-      side.
+      side. One program is not left to XLA: when ``fn`` is a
+      :class:`~.panels.PanelExecutor`'s ``run_state`` whose taskpool
+      registers a mesh lowering and the call is the split it is written
+      for (:meth:`~.panels.PanelExecutor.partitioned`), the jitted
+      function is the runtime's own partition under ``shard_map``, with
+      the same shardings and donation; the executor's
+      ``partition_report()`` says which of the two was built.
     - no shardings but a ``mesh`` → ``shard_map`` fallback for pure
       data-parallel map-style execution over ``in_specs``/``out_specs``
       (default: shard the leading axis of every argument over the
@@ -213,13 +220,27 @@ def compile_with_plan(fn: Callable, *, mesh=None, in_shardings=None,
         wrapper = lambda f: jax.jit(               # noqa: E731
             f, in_shardings=in_shardings, out_shardings=out_shardings,
             donate_argnums=donate_argnums)
+        branch = ("pjit",)
+        # the program's owner before GSPMD: a PanelExecutor whose
+        # taskpool registers a mesh lowering writes the per-chip program
+        # and its collectives itself, and the key says so (the caller's
+        # fn_key names the one-chip program alone)
+        owner = getattr(fn, "__self__", None)
+        if mesh is not None and isinstance(owner, PanelExecutor) \
+                and fn.__func__ is PanelExecutor.run_state:
+            own = owner.partitioned(mesh, in_shardings, out_shardings)
+            if own is not None:
+                fn, lowering_key = own
+                shareable = shareable and lowering_key is not None
+                branch = ("runtime_partition", lowering_key)
         if not shareable:
             return wrapper(fn)
-        full_key = ("pjit", fn_key, key, _mesh_repr(mesh),
-                    _sharding_repr(in_shardings),
-                    _sharding_repr(out_shardings), tuple(donate_argnums)
-                    if not isinstance(donate_argnums, int)
-                    else donate_argnums)
+        full_key = branch + (fn_key, key, _mesh_repr(mesh),
+                             _sharding_repr(in_shardings),
+                             _sharding_repr(out_shardings),
+                             tuple(donate_argnums)
+                             if not isinstance(donate_argnums, int)
+                             else donate_argnums)
         return compile_cache.cached_jit(
             fn, key=full_key, example_args=example_args,
             jit_wrapper=wrapper)

@@ -119,50 +119,59 @@ def tri_inv_tile(L, base: int = 0):
     return rec(Lf).astype(L.dtype)
 
 
-def chol_inv_tile(A, base: int = 128):
-    """(L, L⁻¹) of an SPD tile in ONE recursion. Sharing the traversal
-    beats chol-then-invert two ways: the panel solve uses the already-
-    computed I11 as a matmul (L21 = A21·I11ᵀ) instead of a wide
-    triangular solve, and the inverse assembles from blocks the chol
-    recursion already has (I21 = −I22·L21·I11). Measured 5.9 vs 7.3
-    ms/step at nb=1024 on a v5e against separate potrf_tile_blocked +
-    tri_inv_tile — but that delta is inter-dispatch overhead: INSIDE
-    one fused XLA program the two forms run identically (105-107 TF/s
-    flagship both ways) and the fused program deserializes slower from
-    the persistent cache, so the panel fusers keep chol-then-invert.
-    Kept (tested) as the standalone-dispatch form of the pair."""
-    Af = jnp.asarray(A, jnp.float32)
+def chol_inv_tile(A, base: int = 0):
+    """(L, L⁻¹) of an SPD tile with its block columns walked by ONE
+    compiled loop body: :func:`potrf_tile_blocked`'s right-looking
+    arithmetic (XLA's cholesky on a ``base``-sized diagonal block, its
+    inverse, panel solve and trailing update as matmuls) and the inverse
+    by block forward substitution with the diagonal inverses the walk
+    already has, ``X[j,:] = L_jj⁻¹·(E_j − L[j,:j]·X[:j,:])``.
 
-    def rec(T):
-        n = T.shape[0]
-        if n <= base or n % 2:
-            L = jnp.linalg.cholesky(T)
-            return L, jax.lax.linalg.triangular_solve(
-                L, jnp.eye(n, dtype=T.dtype), left_side=True, lower=True)
-        h = n // 2
-        L11, I11 = rec(T[:h, :h])
-        L21 = jnp.matmul(T[h:, :h], I11.T,
-                         preferred_element_type=jnp.float32,
-                         precision=_prec())
-        S = T[h:, h:] - jnp.matmul(L21, L21.T,
-                                   preferred_element_type=jnp.float32,
-                                   precision=_prec())
-        L22, I22 = rec(0.5 * (S + S.T))
-        I21 = -jnp.matmul(
-            I22, jnp.matmul(L21, I11, preferred_element_type=jnp.float32,
-                            precision=_prec()),
-            preferred_element_type=jnp.float32, precision=_prec())
-        Z = jnp.zeros((h, n - h), jnp.float32)
-        L = jnp.concatenate(
-            [jnp.concatenate([L11, Z], axis=1),
-             jnp.concatenate([L21, L22], axis=1)], axis=0)
-        Inv = jnp.concatenate(
-            [jnp.concatenate([I11, Z], axis=1),
-             jnp.concatenate([I21, I22], axis=1)], axis=0)
-        return L, Inv
+    Why a loop: a program's text lives in HBM beside its data, and
+    XLA:TPU's cholesky and triangular_solve cost 1.2 and 0.4 MB of it a
+    call at base 256 whatever else is compiled. Unrolled,
+    ``potrf_tile_blocked`` + ``tri_inv_tile`` are 8.3 MB a 1024-tile
+    (measured with the TPU compiler, PERF.md §6 PR 30); a program that
+    factors 64 diagonal tiles carries 0.53 GB of it, this form 64 ×
+    2.6 MB. The mesh lowering of left-looking POTRF factors its diagonal
+    tiles with it; the one-chip fusers keep chol-then-invert, whose text
+    keys their stored programs."""
+    n = A.shape[0]
+    b = base or int(mca_param.get("ops.tri_base", 256))
+    if n % b:
+        b = n                     # one block: plain cholesky + inverse
+    f32 = jnp.float32
+    eye = jnp.eye(b, dtype=f32)
+    row = jnp.arange(n)[:, None]
 
-    L, Inv = rec(Af)
-    return L.astype(A.dtype), Inv.astype(A.dtype)
+    def mm(x, y):
+        return jnp.matmul(x, y, preferred_element_type=f32,
+                          precision=_prec())
+
+    def column(j, carry):
+        S, L, X = carry           # trailing matrix, factor, inverse
+        o = j * b
+        l11 = jnp.linalg.cholesky(jax.lax.dynamic_slice(S, (o, o), (b, b)))
+        i11 = jax.lax.linalg.triangular_solve(l11, eye, left_side=True,
+                                              lower=True)
+        # block column j below the diagonal block: L21 = A21·L11⁻ᵀ
+        panel = jnp.where(row >= o + b, mm(
+            jax.lax.dynamic_slice(S, (0, o), (n, b)), i11.T), 0.0)
+        S = S - mm(panel, panel.T)
+        # block row j of L⁻¹ from the rows above it; L[j, :j] is final
+        # and X's rows from j on are still zero. The diagonal block is
+        # L11⁻¹ itself, not its product with a one
+        x = -mm(i11, mm(jax.lax.dynamic_slice(L, (o, 0), (b, n)), X))
+        X = jax.lax.dynamic_update_slice(
+            X, jax.lax.dynamic_update_slice(x, i11, (0, o)), (o, 0))
+        L = jax.lax.dynamic_update_slice(
+            L, jax.lax.dynamic_update_slice(panel, l11, (o, 0)), (0, o))
+        return S, L, X
+
+    zero = jnp.zeros((n, n), f32)
+    _, L, X = jax.lax.fori_loop(
+        0, n // b, column, (jnp.asarray(A, f32), zero, zero))
+    return L.astype(A.dtype), X.astype(A.dtype)
 
 
 def potrf_tile_blocked(A, base: int = 0):

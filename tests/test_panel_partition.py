@@ -1,0 +1,325 @@
+"""The panel program partitioned by the runtime (compiled/panels.py
+``PanelExecutor.partitioned``, ``compile_with_plan``): left-looking POTRF
+over a one-axis mesh as per-chip matmuls and a reduction a column chunk
+under ``shard_map``, on the conftest's 8 forced CPU devices. The factor
+against ``numpy.linalg.cholesky`` and the one-chip program, what the
+compiled program holds of collectives, which branch a call takes and
+says it took, and under which store key each lives."""
+
+import hashlib
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import parsec_tpu.compiled.panels as panels
+from parsec_tpu.algorithms.geqrf import build_geqrf_hh
+from parsec_tpu.algorithms.potrf import (_potrf_left_wave_fuser,
+                                         build_potrf_left)
+from parsec_tpu.compiled.panels import PanelExecutor
+from parsec_tpu.compiled.spmd import compile_with_plan
+from parsec_tpu.compiled.wavefront import plan_taskpool
+from parsec_tpu.data.matrix import TiledMatrix
+from parsec_tpu.utils import compile_cache as cc
+from parsec_tpu.utils import mca_param
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import generate  # noqa: E402
+from benchmark.manifest import Manifest  # noqa: E402
+
+REF = Manifest(ROOT).reference("dpotrf_panel_reference")
+NB = 32
+KEY = generate.step_key(7, 1)
+
+
+@pytest.fixture(autouse=True)
+def three_tile_chunks(monkeypatch):
+    """A reduction chunk of three tiles' partial sums, so that these
+    sizes reduce in several chunks a step as the real ones do."""
+    monkeypatch.setattr(panels, "REDUCE_CHUNK_BYTES", 3 * NB * NB * 4)
+
+
+@pytest.fixture
+def hook(request):
+    mca_param.set("potrf.trsm_hook", request.param)
+    yield request.param
+    mca_param.unset("potrf.trsm_hook")
+
+
+def _mesh(chips, axis="rows"):
+    return Mesh(np.asarray(jax.devices()[:chips]), (axis,))
+
+
+def _left(n, nb=NB):
+    return PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+
+
+def _compile(ex, mesh, spec_in=None, spec_out=None, fn_key="partition"):
+    axis = mesh.axis_names[0]
+    sh_in = NamedSharding(mesh, spec_in if spec_in is not None else P(axis))
+    sh_out = NamedSharding(mesh,
+                           spec_out if spec_out is not None else P(axis))
+    fn = compile_with_plan(
+        ex.run_state, mesh=mesh,
+        in_shardings=({name: sh_in for name in ex.geoms},),
+        out_shardings={name: sh_out for name in ex.geoms},
+        donate_argnums=0, example_args=(ex.state_shapes(),),
+        fn_key=(fn_key, ex.monolith_cache_key()))
+    return fn, sh_in
+
+
+def _factor(ex, mesh, n, **spec):
+    fn, sh = _compile(ex, mesh, **spec)
+    out = fn({"A": jax.device_put(generate.spd_matrix(KEY, n, NB), sh)})
+    return fn, np.triu(np.asarray(out["A"], np.float64)).T
+
+
+def _collectives(fn):
+    """Collective instructions of a compiled program by kind (a
+    ``-start``/``-done`` pair is one)."""
+    ops = re.findall(r"= \S+ (all-reduce|all-gather|all-to-all|"
+                     r"collective-permute|reduce-scatter|"
+                     r"collective-broadcast)(?:-start)?\(", fn.as_text())
+    return {kind: ops.count(kind) for kind in set(ops)}
+
+
+@pytest.mark.parametrize("hook", ["gemm", "solve"], indirect=True)
+@pytest.mark.parametrize("panels_a_chip", [1, 2, 4])
+@pytest.mark.parametrize("chips", [2, 4, 8])
+def test_partitioned_factor_is_numpy_cholesky(chips, panels_a_chip, hook):
+    n = NB * chips * panels_a_chip
+    ex = _left(n)
+    _fn, got = _factor(ex, _mesh(chips), n)
+    rep = ex.partition_report()
+    assert rep["branch"] == "runtime" and rep["shards"] == chips
+    want = np.linalg.cholesky(REF.dense_a0(KEY, n, NB))
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("hook", ["gemm", "solve"], indirect=True)
+@pytest.mark.parametrize("chips,panels_a_chip", [(2, 1), (4, 2), (8, 2)])
+def test_partitioned_factor_is_the_one_chip_factor(chips, panels_a_chip,
+                                                   hook):
+    n = NB * chips * panels_a_chip
+    ex = _left(n)
+    _fn, got = _factor(ex, _mesh(chips), n)
+    one = ex.jitted({"A": generate.spd_matrix(KEY, n, NB)})
+    one = np.triu(np.asarray(one["A"], np.float64)).T
+    assert np.abs(got - one).max() <= 1e-5 * np.abs(one).max()
+
+
+@pytest.mark.parametrize("hook", ["gemm"], indirect=True)
+def test_four_chip_program_sums_and_moves_nothing_else(hook):
+    """No collective-permute, no all-to-all, no all-gather: all-reduces
+    alone, as many as the lowering says it wrote, themselves within
+    chunks-a-step × nt. The first chip's own row panels are summed with
+    nobody's, the second's over the first two chips."""
+    n, chips = NB * 16, 4
+    ex = _left(n)
+    fn, _got = _factor(ex, _mesh(chips), n)
+    rep = ex.partition_report()
+    nt = n // NB
+    summed = range(nt // chips, nt)     # steps whose owner is not chip 0
+    found = _collectives(fn)
+    assert set(found) == {"all-reduce"}, found
+    assert found["all-reduce"] == rep["collectives_per_step"] == sum(
+        -(-(nt - k) // 3) for k in summed) <= -(-nt // 3) * nt
+    # each such step hands its nb × (n − k·nb) f32 partial in
+    assert rep["reduced_bytes_per_step_and_chip"] == sum(
+        NB * (n - k * NB) * 4 for k in summed)
+    text = fn.as_text()
+    pairs = sum(-(-(nt - k) // 3) for k in range(nt // chips, nt // 2))
+    assert text.count("replica_groups={{0,1},{2,3}}") == pairs
+    assert text.count("replica_groups={{0,1,2,3}}") == \
+        found["all-reduce"] - pairs
+    assert "parsec:panel_reduce" in text
+
+
+@pytest.mark.parametrize("shards,owner,want", [
+    (4, 0, None), (4, 1, [[0, 1], [2, 3]]), (4, 2, [[0, 1, 2, 3]]),
+    (4, 3, [[0, 1, 2, 3]]), (2, 1, [[0, 1]]), (8, 2, [[0, 1, 2, 3],
+                                                      [4, 5, 6, 7]]),
+    (6, 2, [[0, 1, 2], [3, 4, 5]]), (6, 3, [[0, 1, 2, 3, 4, 5]])])
+def test_a_sum_runs_over_the_chips_that_can_hold_factored_rows(shards,
+                                                               owner, want):
+    assert panels.PanelPartition("rows", shards).reducers(owner) == want
+
+
+def test_busiest_chip_share_is_what_contiguous_rows_give():
+    """Chip 0 holds row panels 0..nt/4−1, which every later step
+    contracts: (64³ − 48³) / 64³ = 58% of the update's operations at
+    nt = 64 (the four-chip cell), with the owners' chains on top."""
+    ex = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(64 * 8, 64 * 8, 8, 8, name="A"))))
+    mesh = _mesh(4)
+    sh = {"A": NamedSharding(mesh, P("rows"))}
+    assert ex.partition_report()["branch"] is None      # nobody asked yet
+    assert ex.partitioned(mesh, (sh,), sh) is not None
+    share = ex.partition_report()["busiest_chip_ops_share"]
+    assert (64 ** 3 - 48 ** 3) / 64 ** 3 - 0.03 < share < 0.60
+
+
+@pytest.mark.parametrize("hook", ["gemm"], indirect=True)
+def test_a_taskpool_without_a_mesh_lowering_goes_through_gspmd(hook):
+    """GEQRF registers a wave_fuser and no mesh_wave_fuser: the old
+    branch, said and not silent, and still a QR factorization."""
+    n, nb = 128, 32
+    rng = np.random.default_rng(5)
+    a = rng.standard_normal((n, n)).astype(np.float32)
+    A = TiledMatrix.from_array(a.copy(), nb, nb, name="A")
+    ex = PanelExecutor(plan_taskpool(build_geqrf_hh(A)))
+    assert ex.partition_report() == {
+        "branch": "gspmd", "reason": "taskpool registers no mesh_wave_fuser"}
+    mesh = _mesh(4)
+    fn, sh = _compile(ex, mesh, fn_key="partition-geqrf")
+    assert ex.partition_report()["branch"] == "gspmd"
+    state = {name: jax.device_put(v, sh)
+             for name, v in ex.make_state().items()}
+    ex.write_back(fn(state))
+    r = np.triu(A.to_array().astype(np.float64))
+    assert np.allclose(r.T @ r, a.astype(np.float64).T @ a, atol=2e-3)
+
+
+@pytest.mark.parametrize("hook", ["gemm"], indirect=True)
+@pytest.mark.parametrize("case,chips,n,spec,why", [
+    ("size_does_not_divide_nt", 4, NB * 6, {}, "do not divide nt"),
+    ("specs_differ", 2, NB * 4, {"spec_out": P()}, "in/out shardings"),
+    ("columns_split", 2, NB * 4, {"spec_in": P(None, "rows"),
+                                  "spec_out": P(None, "rows")},
+     "in/out shardings")])
+def test_other_splits_take_the_old_branch_and_say_so(case, chips, n, spec,
+                                                     why, hook):
+    ex = _left(n)
+    mesh = _mesh(chips)
+    if case == "size_does_not_divide_nt":
+        # GSPMD cannot split 6 row panels' rows evenly by 4 either: the
+        # decision is all there is to see
+        sh = {"A": NamedSharding(mesh, P("rows"))}
+        assert ex.partitioned(mesh, (sh,), sh) is None
+    else:
+        _fn, got = _factor(ex, mesh, n, **spec)
+        want = np.linalg.cholesky(REF.dense_a0(KEY, n, NB))
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    rep = ex.partition_report()
+    assert rep["branch"] == "gspmd" and why in rep["reason"], rep
+
+
+@pytest.mark.parametrize("hook", ["gemm"], indirect=True)
+def test_a_two_axis_mesh_takes_the_old_branch(hook):
+    ex = _left(NB * 4)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("rows", "x"))
+    sh = {"A": NamedSharding(mesh, P("rows"))}
+    assert ex.partitioned(mesh, (sh,), sh) is None
+    assert ex.partition_report() == {"branch": "gspmd",
+                                     "reason": "mesh has 2 axes"}
+    # the report is of the last call, not of the executor
+    sh = {"A": NamedSharding(_mesh(4), P("rows"))}
+    assert ex.partitioned(_mesh(4), (sh,), sh) is not None
+    assert ex.partition_report()["branch"] == "runtime"
+
+
+# ---------------------------------------------------------------------------
+# compiled for the chip (described, not attached): what the program holds
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def test_on_the_chip_the_shard_stays_put_and_the_text_is_small(
+        v5e_2x2, monkeypatch):
+    """The TPU compiler's program at the cell's tile size (nt = 8): the
+    owner's writes happen in place — no copy of a chip's shard, which a
+    tile of Lᵀ written inside the owner's loop once cost twice — and the
+    program's text, which a chip keeps in HBM beside the shard, stays
+    under 5 MB a step (the unrolled tile kernels alone are 8.3 MB)."""
+    n, nb = 8192, 1024
+    monkeypatch.setattr(panels, "REDUCE_CHUNK_BYTES", 64 << 20)
+    mca_param.set("potrf.trsm_hook", "gemm")
+    try:
+        ex = _left(n, nb)
+        mesh = Mesh(np.asarray(v5e_2x2.devices), ("rows",))
+        sh = {"A": NamedSharding(mesh, P("rows"))}
+        fn, _key = ex.partitioned(mesh, (sh,), sh)
+        compiled = jax.jit(
+            fn, in_shardings=(sh,), out_shardings=sh, donate_argnums=0
+        ).lower({"A": jax.ShapeDtypeStruct((n, n), np.float32,
+                                           sharding=sh["A"])}).compile()
+    finally:
+        mca_param.unset("potrf.trsm_hook")
+    text = compiled.as_text()
+    assert not re.findall(rf"= f32\[{n // 4},{n}\]\S* copy\(", text)
+    assert set(re.findall(r"= \S+ (all-\w+|collective-\w+|reduce-scatter)"
+                          r"(?:-start)?\(", text)) == {"all-reduce"}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == mem.argument_size_in_bytes
+    assert mem.generated_code_size_in_bytes < 5e6 * (n // nb)
+    assert mem.temp_size_in_bytes < 2 * (n // 4) * n * 4
+
+
+# ---------------------------------------------------------------------------
+# the store key
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("hook", ["gemm"], indirect=True)
+def test_the_two_partitions_live_under_different_keys(hook):
+    """One executor, one ``fn_key``: the runtime's partition and GSPMD's
+    are two entries of the jit store, and asking again for either is a
+    hit — the same callable, nothing traced or compiled."""
+    n = NB * 4
+    ex = _left(n)
+    mesh = _mesh(2)
+    cc.reset_in_process_cache()
+    own, _ = _compile(ex, mesh, fn_key="two-keys")
+    assert ex.partition_report()["branch"] == "runtime"
+    # GSPMD's program of the same executor under the same fn_key, as a
+    # tree before the mesh lowering built it
+    sh = NamedSharding(mesh, P("rows"))
+    plain = PanelExecutor.run_state
+
+    def gspmd():
+        return compile_with_plan(
+            lambda st: plain(ex, st), mesh=mesh, in_shardings=({"A": sh},),
+            out_shardings={"A": sh}, donate_argnums=0,
+            example_args=(ex.state_shapes(),),
+            fn_key=("two-keys", ex.monolith_cache_key()))
+
+    theirs = gspmd()
+    assert theirs is not own and cc.jit_store_size() == 2
+    assert _collectives(own) != _collectives(theirs)
+    compiles = cc.backend_compile_count()
+    hits = cc.cache_stats()["jit_store_hits"]
+    assert _compile(ex, mesh, fn_key="two-keys")[0] is own
+    assert gspmd() is theirs
+    assert cc.backend_compile_count() == compiles
+    assert cc.cache_stats()["jit_store_hits"] == hits + 2
+    # a rebuilt executor of the same plan finds the same program
+    assert _compile(_left(n), mesh, fn_key="two-keys")[0] is own
+
+
+def test_one_chip_key_of_the_left_looking_program_is_the_parents():
+    """``_potrf_left_wave_fuser``'s text keys the flagship's stored
+    program and every one-chip user's: pinned to what it was before the
+    mesh lowering landed beside it."""
+    ok, fp = cc.function_fingerprint(_potrf_left_wave_fuser)
+    assert ok and fp == ("a370d3a4ffb88302ddb8867b8945361c"
+                         "fb3bd241d2fb64096b7a2f1638a14030")
+    key = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(256, 256, 64, 64, name="A")))).monolith_cache_key()
+    assert hashlib.sha256(repr(key).encode()).hexdigest() == (
+        "6ec4d4f09abc6923b3b1226cd62200d2be4f0e6d5d92db6dd0c6bd5655fe2499")
