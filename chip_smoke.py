@@ -11,7 +11,8 @@ points a user calls, at full width, cheapest phase first:
   block     build_transformer_block's body_tpu through the host runtime
             (tile_s=1024, dh=128) against reference_block
   host      parsec.init -> Context: DTD GEMM n=2048/nb=512 (insert_gemm_dtd)
-            against numpy, PTG build_potrf n=4096/nb=512 via add_taskpool/wait;
+            against numpy, DTD POTRF n=4096/nb=512 (insert_potrf_dtd) and
+            PTG build_potrf of the same size via add_taskpool/wait;
             every task on a tpuN module, none on the inline CPU module
   panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
   flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
@@ -284,7 +285,8 @@ def phase_host(sz, on_chip):
     import numpy as np
     import parsec_tpu as parsec
     from parsec_tpu import _native, dtd
-    from parsec_tpu.algorithms import build_potrf, insert_gemm_dtd
+    from parsec_tpu.algorithms import (build_potrf, insert_gemm_dtd,
+                                       insert_potrf_dtd)
     from parsec_tpu.data.matrix import SymTwoDimBlockCyclic, TiledMatrix
 
     n_dev = len(jax.devices())
@@ -327,20 +329,55 @@ def phase_host(sz, on_chip):
             first_run_s=f"{t_gemm:.1f}", rel_err=f"{err:.1e}",
             output_devices=out_devs)
 
-        # the path the benchmark's PTG cell runs (dpotrf_ptg_host): the
-        # lower triangle alone stored, as testing_dpotrf allocates it,
-        # every tile on a chip before the pool starts; the stage timers
-        # on, so that the modules count tasks by class
+        # the paths the benchmark's two POTRF cells on the host scheduler
+        # run: the lower triangle alone stored, as testing_dpotrf
+        # allocates it, every tile on a chip before the pool starts
         n, nb = sz["potrf_host"]
         nt = n // nb
         R = rng.standard_normal((n, n)).astype(np.float32)
         S_h = (0.5 * (R + R.T) + 2.0 * n * np.eye(n)).astype(np.float32)
-        P_ = TiledMatrix(n, n, nb, nb, name="P",
-                         dist=SymTwoDimBlockCyclic(1, 1, uplo="lower"))
         lower = [(i, j) for j in range(nt) for i in range(j, nt)]
-        for i, j in lower:
-            P_.write_tile((i, j), jax.device_put(
-                S_h[i * nb:(i + 1) * nb, j * nb:(j + 1) * nb]))
+
+        def spd(name):
+            mat = TiledMatrix(n, n, nb, nb, name=name,
+                              dist=SymTwoDimBlockCyclic(1, 1, uplo="lower"))
+            for i, j in lower:
+                mat.write_tile((i, j), jax.device_put(
+                    S_h[i * nb:(i + 1) * nb, j * nb:(j + 1) * nb]))
+            return mat
+
+        # by insertion (dpotrf_dtd): the tester's loop, a flush per tile
+        D_ = spd("D")
+        ran = sum(s["tasks"] for s in ctx.devices.dump_statistics()
+                  if s["name"].startswith("tpu"))
+        tp = dtd.Taskpool("smoke_potrf")
+        ctx.add_taskpool(tp)
+        t0 = time.perf_counter()
+        insert_potrf_dtd(tp, D_)
+        require(tp.wait(timeout=900), "DTD POTRF did not finish")
+        jax.block_until_ready([D_.data_of(key) for key in lower])
+        t_dtd = time.perf_counter() - t0
+        L = np.tril(D_.to_array().astype(np.float64))
+        err = rel(L @ L.T, S_h)
+        require(err <= 1e-3, f"DTD POTRF residual {err:.2e}")
+        tasks = nt + nt * (nt - 1) + nt * (nt - 1) * (nt - 2) // 6
+        ran = sum(s["tasks"] for s in ctx.devices.dump_statistics()
+                  if s["name"].startswith("tpu")) - ran
+        # the native engine (a CPU dry run's) runs bodies off the modules
+        require(ran == (tasks if tp._native is None else 0),
+                f"DTD POTRF: {ran} of {tasks} tasks on the tpu modules")
+        require(tp.tiles.all() == [] and
+                tp.tiles.retired == len(lower),
+                f"DTD POTRF flushed {tp.tiles.retired} of {len(lower)} "
+                f"tiles, {len(tp.tiles.all())} still tracked")
+        say("host", dtd_potrf=f"n={n}/nb={nb}", tasks=tasks,
+            on_tpu_modules=ran, first_run_s=f"{t_dtd:.1f}",
+            residual=f"{err:.1e}", tiles_flushed=tp.tiles.retired,
+            output_devices=tile_devices(D_.data_of(key) for key in lower))
+
+        # unfolded by the PTG front end (dpotrf_ptg_host); the stage
+        # timers on, so that the modules count tasks by class
+        P_ = spd("P")
         timers = ctx.set_stage_timers(True)
         t0 = time.perf_counter()
         ctx.add_taskpool(build_potrf(P_))

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 from ..compiled.panels import (SegRead, SegStep, SegWrite, bucket_tiles,
                                register_panel_kernel)
-from ..dsl import ptg
+from ..dsl import dtd, ptg
 from ..data.matrix import TiledMatrix
 from ..ops.tile_kernels import (gemm_tile, potrf_tile, potrf_tile_blocked,
                                 syrk_tile, trsm_tile,
@@ -56,6 +56,30 @@ mca_param.register("potrf.blocked_tile_chol", 1,
 # shared/persistent compile-cache key must cover their values
 compile_cache.register_trace_knob("potrf.trsm_hook")
 compile_cache.register_trace_knob("potrf.blocked_tile_chol")
+
+
+def _potrf_stacked(Ts):
+    """The stacked form of POTRF, of the PTG class and of the DTD body.
+    The diagonal tiles of a factorization never meet, and that is what
+    declaring it is for: a body without a stacked form gets its unrolled
+    programs of every size at first sight, and 8 + 4 tile Choleskys that
+    never run cost 0.2 GiB of HBM beside the matrix at 2048-tiles
+    (PERF.md section 6, PR 31); a stacked form is built when a group
+    first forms."""
+    import jax
+    if mca_param.get("potrf.blocked_tile_chol", 1):
+        return jax.vmap(potrf_tile_blocked)(Ts) if Ts.shape[0] > 1 \
+            else potrf_tile_blocked(Ts[0])[None]
+    return jax.vmap(potrf_tile)(Ts)
+
+
+def _trsm_stacked(Ls, Cs):
+    """The stacked form of TRSM, of the PTG class and of the DTD body:
+    every TRSM(m, k) of a group shares the factor L = POTRF(k), so the
+    group is one inversion and one wide matmul (or one wide-RHS solve)."""
+    if mca_param.get("potrf.trsm_hook", "solve") == "gemm":
+        return trsm_tiles_gemm(Ls[0], Cs)
+    return trsm_tiles_wide(Ls[0], Cs)
 
 
 def build_potrf(A: TiledMatrix) -> ptg.Taskpool:
@@ -175,27 +199,13 @@ def build_potrf(A: TiledMatrix) -> ptg.Taskpool:
                       # in place: the tile's last version is freed
                       ptg.Out(data=lambda g, m, n, k: (g.A, (m, n)))])])
 
-    def _potrf_hook(Ts):
-        import jax
-        if mca_param.get("potrf.blocked_tile_chol", 1):
-            return jax.vmap(potrf_tile_blocked)(Ts) if Ts.shape[0] > 1 \
-                else potrf_tile_blocked(Ts[0])[None]
-        return jax.vmap(potrf_tile)(Ts)
-
-    @POTRF.body(batch_hook=_potrf_hook)
+    @POTRF.body(batch_hook=_potrf_stacked)
     def potrf_body(task, T):
         return potrf_tile(T)
 
-    def _trsm_hook(Ls, Cs):
-        if mca_param.get("potrf.trsm_hook", "solve") == "gemm":
-            return trsm_tiles_gemm(Ls[0], Cs)
-        return trsm_tiles_wide(Ls[0], Cs)
-
-    # compiled-path batched form: every TRSM(m, k) of one wave shares the
-    # same factor L = POTRF(k).T, so the whole group is one inversion +
-    # wide matmul (or one wide-RHS solve; the executor verifies the
-    # shared-L grouping per wave)
-    @TRSM.body(batch_hook=_trsm_hook, batch_hook_shared=("L",))
+    # the batched form (the executor and the chip module verify the
+    # shared-L grouping per wave and per group)
+    @TRSM.body(batch_hook=_trsm_stacked, batch_hook_shared=("L",))
     def trsm_body(task, L, C):
         return trsm_tile(C, L)
 
@@ -329,6 +339,94 @@ def _potrf_wave_fuser(wave, geoms):
         return do_trailing
 
     return None
+
+
+# -- the same factorization by task insertion ------------------------------
+# Module-level bodies (stable identity: the pure-body caches are keyed by
+# fn, so every pool in the process shares one compile) over the tile
+# kernels build_potrf's classes use, argument for argument.
+
+def _potrf_dtd_potrf(t):
+    return potrf_tile(t)
+
+
+def _potrf_dtd_trsm(l, c):
+    return trsm_tile(c, l)
+
+
+def _potrf_dtd_syrk(a, c):
+    return syrk_tile(c, a, alpha=-1.0, beta=1.0)
+
+
+def _potrf_dtd_gemm(a, b, c):
+    return gemm_tile(c, a, b, alpha=-1.0, beta=1.0, tb=True)
+
+
+# The priorities ``testing_zpotrf_dtd.c`` inserts with (zpotrf_L.jdf's
+# expressions; the GEMM that updates A(n, m), n > m, puts its indices
+# where the JDF's has its own, so its middle term is negative).
+POTRF_DTD_PRIORITY = {
+    "POTRF": lambda nt, k: (nt - k) ** 3,
+    "TRSM": lambda nt, m, k:
+        (nt - m) ** 3 + 3 * (2 * nt - k - m - 1) * (m - k),
+    "SYRK": lambda nt, m, k: (nt - m) ** 3 + 3 * (m - k),
+    "GEMM": lambda nt, n, m, k:
+        (nt - m) ** 3 + 3 * (2 * nt - m - n - 3) * (m - n) + 6 * (m - k),
+}
+
+
+def insert_potrf_dtd(tp: "dtd.Taskpool", A: TiledMatrix) -> None:
+    """Insert the tiled Cholesky factorization of ``A`` (lower) into a
+    DTD taskpool the way DPLASMA's ``tests/testing_zpotrf_dtd.c`` does:
+    one sequential loop in the tester's program order, its priorities
+    (``POTRF_DTD_PRIORITY``), a ``flush_tile`` of every tile the loop
+    has finished with (A(k, k) once its TRSMs are inserted, A(m, k) once
+    row m's updates are) and one ``flush_all`` at the end; the caller
+    waits for the pool. Dependencies are discovered from the tiles'
+    access modes while the tasks inserted before already run.
+
+    The TRSMs of a column and the GEMMs of a row of the trailing update
+    go in one ``insert_tasks`` call each, POTRF and SYRK in an
+    ``insert_task``. The TRSM body declares its stacked form with L
+    shared, so TRSMs that leave as one launch are one inversion and one
+    wide matmul under ``potrf.trsm_hook=gemm``, as the PTG class's are;
+    the POTRF body declares the PTG class's too (``_potrf_stacked``).
+    Every update is written to its tile of ``A`` at its task's
+    completion: the factorization runs in the matrix's own storage."""
+    nt = A.nt
+    if A.mt != nt or A.mb != A.nb:
+        raise ValueError("POTRF needs a square grid of square tiles")
+    T, IN, INOUT = dtd.TileArg, dtd.INPUT, dtd.INOUT
+    p_potrf, p_trsm, p_syrk, p_gemm = (
+        POTRF_DTD_PRIORITY[c] for c in ("POTRF", "TRSM", "SYRK", "GEMM"))
+    for k in range(nt):
+        tp.insert_task(_potrf_dtd_potrf, T(A, (k, k), INOUT, affinity=True),
+                       priority=p_potrf(nt, k), pure=True,
+                       stacked=(_potrf_stacked, ()))
+        below = range(k + 1, nt)
+        if not below:
+            break
+        tp.insert_tasks(
+            _potrf_dtd_trsm,
+            [(T(A, (k, k), IN), T(A, (m, k), INOUT, affinity=True))
+             for m in below],
+            priorities=[p_trsm(nt, m, k) for m in below],
+            pure=True, stacked=(_trsm_stacked, (0,)))
+        tp.flush_tile(A, (k, k))
+        for m in below:
+            tp.insert_task(_potrf_dtd_syrk, T(A, (m, k), IN),
+                           T(A, (m, m), INOUT, affinity=True),
+                           priority=p_syrk(nt, m, k), pure=True)
+            right = range(m + 1, nt)
+            if right:
+                tp.insert_tasks(
+                    _potrf_dtd_gemm,
+                    [(T(A, (n, k), IN), T(A, (m, k), IN),
+                      T(A, (n, m), INOUT, affinity=True)) for n in right],
+                    priorities=[p_gemm(nt, n, m, k) for n in right],
+                    pure=True)
+            tp.flush_tile(A, (m, k))
+    tp.flush_all(A)
 
 
 def potrf_flops(n: int) -> float:

@@ -32,9 +32,9 @@ from jax.profiler import TraceAnnotation
 
 from .future import DataCopyFuture
 from .reshape import resolve_reshape
-from .spans import (SPAN_DISPATCH, SPAN_EXEC, SPAN_INSERT,  # noqa: F401
-                    SPAN_PARK, SPAN_PTG_STARTUP, SPAN_PTG_UNFOLD,
-                    SPAN_RELEASE, SPAN_SELECT, StageSpan)
+from .spans import (SPAN_DISPATCH, SPAN_DTD_FLUSH,  # noqa: F401
+                    SPAN_EXEC, SPAN_INSERT, SPAN_PARK, SPAN_PTG_STARTUP,
+                    SPAN_PTG_UNFOLD, SPAN_RELEASE, SPAN_SELECT, StageSpan)
 from .task import (GROUP_SIZES, GROUP_TAKE, Chore, DeviceType, HookReturn,
                    Task, TaskStatus)
 from .taskpool import DataRef, SuccessorRef, Taskpool
@@ -191,6 +191,9 @@ class Context:
         self._ndtd_live: List = []
         self._ndtd_lock = threading.Lock()
         self._ndtd_totals: Dict[str, int] = {}
+        # the Python DTD front end's counters, summed over the pools
+        # that ended with the stage timers on (fold_dtd_counters)
+        self.dtd_counters: Dict[str, float] = {}
         # per-tenant native completions (the tenant PINS module and the
         # metrics collector fold these in at scrape — native pools never
         # fire the per-task EXEC hooks, by design)
@@ -441,6 +444,16 @@ class Context:
         return True
 
     # -------------------------------------------------- native DTD engines
+    def fold_dtd_counters(self, counters: Dict[str, float]) -> None:
+        """What a DTD pool's front end counted while the stage timers
+        were on (``dtd.Taskpool.counters``), added to the Context's sums
+        when the pool ends; a ``*_peak`` is the largest seen."""
+        with self._lock:
+            mine = self.dtd_counters
+            for name, n in counters.items():
+                mine[name] = max(mine.get(name, 0), n) \
+                    if name.endswith("_peak") else mine.get(name, 0) + n
+
     def _ndtd_register(self, eng) -> None:
         with self._ndtd_lock:
             if eng not in self._ndtd_live:

@@ -21,6 +21,9 @@ SPAN_RELEASE = "parsec:release"
 # and task classes; a front end that names none has none)
 SPAN_PTG_STARTUP = "parsec:ptg_startup"
 SPAN_PTG_UNFOLD = "parsec:ptg_unfold"
+# the DTD front end's flushes (dsl/dtd.py): one span a call of flush,
+# flush_tile or flush_all, what the inserter's thread pays for it
+SPAN_DTD_FLUSH = "parsec:dtd_flush"
 
 
 class StageSpan:

@@ -58,7 +58,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..core.spans import SPAN_INSERT, StageSpan
+from ..core.spans import SPAN_DTD_FLUSH, SPAN_INSERT, StageSpan
 from ..core.task import Chore, DeviceType, Flow, FlowAccess, Task
 from ..core.taskpool import DEPS_COUNTER, SuccessorRef, TaskClass
 from ..core.taskpool import Taskpool as CoreTaskpool
@@ -75,12 +75,15 @@ _GOAL_UNSET = 1 << 40       # sentinel while an insert is still linking
 # process-wide jit cache for pure=True bodies: (fn, argspec sig) →
 # jitted woven callable. Keyed by the fn OBJECT (kept alive by the
 # cache — no id-reuse aliasing), so module-level bodies compile once
-# per process even across taskpools. It serves the modules that call a
-# chore's hook as it is: the CPU device, and a TPU module where its own
-# program table has no entry (inputs that share no signature); a TPU
-# module otherwise runs ``batch_body`` from its table, alone or in a
-# group, and never calls the woven ``_hook``. Not to be extended: it
-# goes when the CPU device gets its programs from that table (D12)
+# per process even across taskpools. It serves whoever calls a chore's
+# hook as it is: the CPU device, the native engine's pump, and a TPU
+# module where its own program table has no entry (inputs that share no
+# signature). A TPU module otherwise never calls the woven ``_hook``: a
+# lone task and a group run ``batch_body`` from its table, and the group
+# of a body that declares a stacked form (``insert_task(stacked=)`` →
+# ``Chore.batch_hook``) runs that hook over the stacked members. Not to
+# be extended: it goes when the CPU device gets its programs from that
+# table (D12)
 _PURE_JIT_CACHE: Dict[Any, Callable] = {}
 _PURE_JIT_LOCK = threading.Lock()
 
@@ -133,7 +136,7 @@ class _Tile:
     of the replayed insertion order (None = the collection owner)."""
 
     __slots__ = ("collection", "key", "lock", "last_writer",
-                 "last_writer_flow", "holder_rank")
+                 "last_writer_flow", "holder_rank", "flushed")
 
     def __init__(self, collection: DataCollection, key):
         self.collection = collection
@@ -143,14 +146,31 @@ class _Tile:
         self.last_writer = None
         self.last_writer_flow: Optional[str] = None
         self.holder_rank: Optional[int] = None
+        # flush_tile() was called: the last writer's retire takes the
+        # tile out of the bank, and a writer inserted later puts it back
+        # (under ``lock``, as last_writer)
+        self.flushed = False
 
 
 class _TileBank:
-    """parsec_dtd_tile_of analog: lazily materialized tracking tiles."""
+    """parsec_dtd_tile_of analog: lazily materialized tracking tiles.
+
+    A tile is tracked from its first use in an insert until it is
+    flushed (``Taskpool.flush_tile`` / ``flush_all``): a flushed tile
+    leaves the bank at once where no writer is in flight, else when its
+    last writer retires, so a pool that flushes what it has finished
+    with tracks a working set and not every tile it ever touched. A
+    tile without a writer holds nothing a fresh one would not (its
+    version is the collection's), so a later insert on it starts over.
+    The blocking ``Taskpool.flush`` waits and takes nothing out.
+    ``peak`` is the most tiles tracked at once, ``retired`` those a
+    flush took out."""
 
     def __init__(self) -> None:
         self._tiles: Dict[Tuple[int, Any], _Tile] = {}
         self._lock = threading.Lock()
+        self.peak = 0
+        self.retired = 0
 
     def tile_of(self, dc: DataCollection, key) -> _Tile:
         hkey = (dc.dc_id, tuple(key) if isinstance(key, (tuple, list)) else key)
@@ -164,6 +184,7 @@ class _TileBank:
                 if t is None:
                     t = _Tile(dc, hkey[1])
                     self._tiles[hkey] = t
+                    self.peak = max(self.peak, len(self._tiles))
         if t.collection is not dc:
             # two live collections sharing one dc_id would silently
             # alias each other's writer tracking (values vanish);
@@ -176,9 +197,42 @@ class _TileBank:
                 "a unique dc_id")
         return t
 
+    def get(self, dc: DataCollection, key) -> Optional[_Tile]:
+        """The tracked tile, or None: looking makes none."""
+        return self._tiles.get(
+            (dc.dc_id, tuple(key) if isinstance(key, (tuple, list)) else key))
+
     def all(self) -> List[_Tile]:
         with self._lock:
             return list(self._tiles.values())
+
+    # the per-tile flush; a tile's lock is taken before the bank's
+    def flush(self, tile: _Tile) -> None:
+        """Stop tracking ``tile`` once nothing inserted so far writes
+        it: now, or at its last writer's retire."""
+        with tile.lock:
+            tile.flushed = True
+            if tile.last_writer is None:
+                self.retire(tile)
+
+    def retire(self, tile: _Tile) -> None:
+        """Take a flushed ``tile`` out (the caller holds its lock and has
+        seen no writer on it). It stays ``flushed``: an insert that
+        looked it up before this and writes it after has to see that the
+        bank no longer holds it."""
+        with self._lock:
+            hkey = (tile.collection.dc_id, tile.key)
+            if self._tiles.get(hkey) is tile:
+                del self._tiles[hkey]
+                self.retired += 1
+
+    def readopt(self, tile: _Tile) -> None:
+        """A writer inserted after ``tile``'s flush (the caller holds its
+        lock): the flush was of the uses before it, and the tile, which
+        that flush may already have taken out, is tracked again."""
+        tile.flushed = False
+        with self._lock:
+            self._tiles.setdefault((tile.collection.dc_id, tile.key), tile)
 
 
 class Taskpool(CoreTaskpool):
@@ -220,6 +274,17 @@ class Taskpool(CoreTaskpool):
         # on the inserting thread(s)
         self.insert_s = 0.0
         self.insert_calls = 0
+        # on the same flag, what the front end counts (the Python
+        # engine): tile arguments linked to a writer still in flight at
+        # insertion (``dtd_args_linked``: discovery runs ahead of
+        # execution; across ranks a version held elsewhere counts here
+        # too, it arrives as an activation as well) or read from the
+        # collection because the writer had retired
+        # (``dtd_args_snapshot``), and the inserter's parks in the
+        # window (``dtd_window_waits``, ``dtd_window_wait_s``). Folded
+        # into ``Context.dtd_counters`` when the pool ends, with the
+        # bank's ``dtd_tiles_tracked_peak`` and ``dtd_tiles_flushed``
+        self.counters: Dict[str, float] = {}
         # native dynamic-task engine (dsl/dtd_native.py): resolved once
         # at first insert per the runtime.native_dtd knob and the
         # instrumented-fallback rule; None = the Python engine below
@@ -298,17 +363,22 @@ class Taskpool(CoreTaskpool):
                 # aborted pool with tasks still in flight keeps its
                 # engine pumped until they drain (retiring state)
                 ctx._ndtd_retire(eng)
+        if self.counters and self.context is not None and \
+                not self._complete_evt.is_set():
+            self.context.fold_dtd_counters(dict(
+                self.counters, dtd_tiles_tracked_peak=self.tiles.peak,
+                dtd_tiles_flushed=self.tiles.retired))
         super()._on_terminated()
 
     # ------------------------------------------------------------- classes
     def _task_class_for(self, fn: Callable, shape: Tuple,
-                        device: DeviceType,
-                        pure: bool = False) -> TaskClass:
+                        device: DeviceType, pure: bool = False,
+                        stacked: Optional[Tuple] = None) -> TaskClass:
         """Lazily create a task class per (fn, arg shape)
         (insert_function.c:1015 analog). Resolution is on the insertion
         hot path, so a cache hit is a lock-free dict read (GIL-atomic);
         the lock only serializes creation."""
-        key = (fn, shape, device, pure)
+        key = (fn, shape, device, pure, stacked)
         tc = self._classes.get(key)
         if tc is not None:
             return tc
@@ -441,10 +511,14 @@ class Taskpool(CoreTaskpool):
             if pure:
                 # batchable=False: the hook reads its task and
                 # self-jits; batch_sig/batch_body hand a device module
-                # the woven body for its own programs
-                tc.add_chore(Chore(device, _hook, batchable=False,
-                                   batch_sig=_batch_sig,
-                                   batch_body=_batch_body))
+                # the woven body for its own programs, and a declared
+                # stacked form goes where a PTG body's does
+                hook, shared = stacked or (None, None)
+                tc.add_chore(Chore(
+                    device, _hook, batchable=False,
+                    batch_sig=_batch_sig, batch_body=_batch_body,
+                    batch_hook=hook, batch_hook_shared=None if hook is None
+                    else tuple(f"f{i}" for i in shared)))
             else:
                 tc.add_chore(Chore(device, _hook, batchable=False))
             self.add_task_class(tc)
@@ -470,7 +544,8 @@ class Taskpool(CoreTaskpool):
     def insert_task(self, fn: Callable, *args, priority: int = 0,
                     device: DeviceType = DeviceType.ALL,
                     name: Optional[str] = None,
-                    pure: bool = False) -> Optional[Any]:
+                    pure: bool = False,
+                    stacked: Optional[Tuple] = None) -> Optional[Any]:
         """parsec_dtd_insert_task analog (insert_function.c:3488). In
         distributed mode every rank calls this with the identical sequence;
         returns the local Task (Python engine) or the task's insertion
@@ -488,19 +563,46 @@ class Taskpool(CoreTaskpool):
         ``ValueArg`` payloads are baked into the compiled body at trace
         time and cached by object identity, so they must be treated as
         IMMUTABLE once inserted — mutating an array payload in place
-        between inserts would silently serve the stale compile."""
+        between inserts would silently serve the stale compile.
+
+        ``stacked=(hook, shared)`` declares a pure body's stacked form,
+        what ``batch_hook`` / ``batch_hook_shared`` are to a PTG body
+        (``Chore``; an accelerator module reads both there): where ready
+        tasks of this body leave as one launch, ``hook(*stacks)`` runs
+        once in place of the body once a member. ``stacks`` are the tile
+        arguments the body reads (INPUT, INOUT), in order, each with the
+        members along a new first axis; it returns the new value(s) of
+        the written tile(s) stacked likewise. ``shared`` gives the
+        positions, among the tile arguments, of those the hook takes to
+        hold ONE tile for the whole group (a column's TRSMs share their
+        factor); a group that does not leaves as lone tasks. Value and
+        scratch arguments do not reach the hook. A task alone, and every
+        task on a module without launches of several, runs ``fn``."""
+        if stacked is not None:
+            stacked = self._stacked(stacked, pure)
         ctx = self.context
         if ctx is None or not ctx.stage_timers:
-            return self._insert_task(fn, args, priority, device, pure)
+            return self._insert_task(fn, args, priority, device, pure,
+                                     stacked)
         # the insert stage covers both engines: stage timers no longer
         # force the Python one (ISSUE 13)
         with StageSpan(SPAN_INSERT) as span:
-            out = self._insert_task(fn, args, priority, device, pure)
+            out = self._insert_task(fn, args, priority, device, pure,
+                                    stacked)
         self.insert_s += span.seconds
         self.insert_calls += 1
         return out
 
-    def _insert_task(self, fn, args, priority, device, pure):
+    @staticmethod
+    def _stacked(stacked, pure: bool) -> Tuple:
+        """``(hook, shared)`` as the class cache keys it."""
+        if not pure:
+            raise ValueError("stacked= declares the stacked form of a "
+                             "pure body: pass pure=True")
+        hook, shared = stacked
+        return hook, tuple(shared)
+
+    def _insert_task(self, fn, args, priority, device, pure, stacked=None):
         self._check_insertable()
         if self.admission is not None:
             self.admission.admit(self, 1)
@@ -510,7 +612,7 @@ class Taskpool(CoreTaskpool):
             # opaque handle — native tasks have no Python Task object)
             return eng.insert_rows(fn, [args], priority, device, pure)[0]
         tc = self._task_class_for(fn, self._shape_of(args), device,
-                                  pure=pure)
+                                  pure=pure, stacked=stacked)
         task = self._insert_one(tc, args, priority, None, None)
         self._throttle()
         return task
@@ -518,7 +620,9 @@ class Taskpool(CoreTaskpool):
     def insert_tasks(self, fn: Callable, rows, *, priority: int = 0,
                      priorities: Optional[List[int]] = None,
                      device: DeviceType = DeviceType.ALL,
-                     pure: bool = False) -> List[Optional[Any]]:
+                     pure: bool = False,
+                     stacked: Optional[Tuple] = None
+                     ) -> List[Optional[Any]]:
         """Batched :meth:`insert_task` — the insertion fast path. All
         ``rows`` (sequences of Tile/Value/Scratch args) are inserted with
         the same body, paying the per-insert lookup costs ONCE per batch
@@ -544,20 +648,24 @@ class Taskpool(CoreTaskpool):
         request's task graph is admitted all-or-nothing). Per-row
         priorities are a scheduling-lane hint consumed by the Python
         engine's schedulers; the native engine receives the scalar
-        ``priority`` (lane-aware pools — wfq — never run native)."""
+        ``priority`` (lane-aware pools — wfq — never run native).
+        ``stacked`` is :meth:`insert_task`'s, for every row."""
+        if stacked is not None:
+            stacked = self._stacked(stacked, pure)
         ctx = self.context
         if ctx is None or not ctx.stage_timers:
             return self._insert_tasks(fn, rows, priority, priorities,
-                                      device, pure)
+                                      device, pure, stacked)
         # one span per CALL, however many rows it inserts
         with StageSpan(SPAN_INSERT) as span:
             out = self._insert_tasks(fn, rows, priority, priorities,
-                                     device, pure)
+                                     device, pure, stacked)
         self.insert_s += span.seconds
         self.insert_calls += len(out)
         return out
 
-    def _insert_tasks(self, fn, rows, priority, priorities, device, pure):
+    def _insert_tasks(self, fn, rows, priority, priorities, device, pure,
+                      stacked=None):
         self._check_insertable()
         rows = list(rows)
         out: List[Optional[Task]] = []
@@ -575,7 +683,8 @@ class Taskpool(CoreTaskpool):
         if eng is not None:
             return eng.insert_rows(fn, rows, priority, device, pure)
         shape0 = self._shape_of(rows[0])
-        tc0 = self._task_class_for(fn, shape0, device, pure=pure)
+        tc0 = self._task_class_for(fn, shape0, device, pure=pure,
+                                   stacked=stacked)
         ready: List[Task] = []
         tile_cache: Dict[Any, _Tile] = {}
         for i, args in enumerate(rows):
@@ -588,7 +697,8 @@ class Taskpool(CoreTaskpool):
                 self._check_insertable()
             shape = self._shape_of(args)
             tc = tc0 if shape == shape0 else \
-                self._task_class_for(fn, shape, device, pure=pure)
+                self._task_class_for(fn, shape, device, pure=pure,
+                                     stacked=stacked)
             out.append(self._insert_one(
                 tc, args,
                 priorities[i] if priorities is not None else priority,
@@ -671,6 +781,7 @@ class Taskpool(CoreTaskpool):
         belt-and-braces bound, not the exit mechanism."""
         if self._inflight < self._window:
             return
+        t0 = time.perf_counter()
         with self._inflight_cv:
             if self._inflight < self._window:
                 return
@@ -680,9 +791,19 @@ class Taskpool(CoreTaskpool):
                     self._inflight_cv.wait(timeout=0.25)
             finally:
                 self._throttle_waiters -= 1
+        if self.context.stage_timers:
+            self._count(dtd_window_waits=1,
+                        dtd_window_wait_s=time.perf_counter() - t0)
         if self.error is not None:
             raise RuntimeError(
                 f"taskpool {self.name} aborted: {self.error}") from self.error
+
+    def _count(self, **more) -> None:
+        """Add to the front end's counters (stage timers on; the
+        inserter's thread)."""
+        c = self.counters
+        for name, n in more.items():
+            c[name] = c.get(name, 0) + n
 
     def _insert_one(self, tc: TaskClass, args, priority: int,
                     ready_out: Optional[List[Task]],
@@ -800,8 +921,13 @@ class Taskpool(CoreTaskpool):
                     tile.last_writer = task
                     tile.last_writer_flow = fname
                     tile.holder_rank = my_rank
+                    if tile.flushed:
+                        self.tiles.readopt(tile)
                 task.dsl["out_tiles"].append((tile, fname))
 
+        if self.context.stage_timers:
+            self._count(dtd_args_linked=goal,
+                        dtd_args_snapshot=len(seen_tiles) - goal)
         # Finalize the goal; racing activations may already have counted.
         # The lock must span both the goal publication AND the finalize
         # check: activate_dep reads the goal and counts under the same
@@ -912,6 +1038,8 @@ class Taskpool(CoreTaskpool):
                 if tile.last_writer is task:
                     tile.last_writer = None
                     tile.last_writer_flow = None
+                    if tile.flushed:
+                        self.tiles.retire(tile)
         # 2) only then mark done and deliver the linked successors
         with task.dsl["lock"]:
             task.dsl["done"] = True
@@ -1021,10 +1149,11 @@ class Taskpool(CoreTaskpool):
                         out.append(task)
         return out
 
-    def wait(self, context=None) -> None:
+    def wait(self, context=None, timeout: Optional[float] = None) -> bool:
         """parsec_dtd_taskpool_wait analog: drain all inserted tasks.
         Idempotent — only the first call releases the enqueue-time runtime
-        action; later calls just join."""
+        action; later calls just join. False where ``timeout`` seconds
+        passed first."""
         with self._inflight_cv:
             first = not self._closed
             self._closed = True
@@ -1037,16 +1166,69 @@ class Taskpool(CoreTaskpool):
             self._native.drain()
         if first and self._enqueue_counted:
             self.addto_runtime_actions(-1)
-        self.wait_completed()
+        return self.wait_completed(timeout)
+
+    def flush_tile(self, collection: DataCollection, key) -> None:
+        """parsec_dtd_data_flush analog, as upstream inserts it: the
+        program is done with this tile. Returns without waiting. The
+        tile's tracking ends once nothing inserted so far writes it
+        (``_TileBank``): at once, or when its last writer retires, which
+        has written the tile's last version to ``collection`` by then.
+        An insert on the tile after that reads the collection's current
+        version and tracks the tile anew; a writer inserted after the
+        flush cancels what is left of it. A tile never inserted is not
+        tracked and stays so. Across ranks the tracking is the replay's
+        state (``holder_rank``) and nothing is taken out: the collective
+        :meth:`flush` is what sends versions home there."""
+        self._flushing(self._end_tracking, collection, key)
+
+    def flush_all(self, collection: Optional[DataCollection] = None
+                  ) -> None:
+        """parsec_dtd_data_flush_all analog: :meth:`flush_tile` of every
+        tile tracked now (of ``collection``, if given). Returns without
+        waiting; ``wait()`` is what drains the pool."""
+        self._flushing(self._end_tracking, collection)
+
+    def _flushing(self, flush: Callable, *args) -> None:
+        """One flush call, under its span where the stage timers are
+        on: what the inserter's thread pays for it."""
+        ctx = self.context
+        if ctx is not None and ctx.stage_timers:
+            with StageSpan(SPAN_DTD_FLUSH):
+                flush(*args)
+        else:
+            flush(*args)
+
+    def _end_tracking(self, collection, key=None) -> None:
+        if self.nb_ranks > 1:
+            return
+        if key is not None:
+            tiles = [self.tiles.get(collection, key)]
+        else:
+            tiles = [t for t in self.tiles.all()
+                     if collection is None or t.collection is collection]
+        for tile in tiles:
+            if tile is not None:
+                self.tiles.flush(tile)
 
     def flush(self, collection: Optional[DataCollection] = None,
               timeout: float = 60.0) -> None:
-        """parsec_dtd_data_flush analog: wait until no in-flight LOCAL
-        writer remains for the collection's tiles (produced versions are
-        written back at completion, so afterwards ``data_of`` is current).
-        In distributed mode this is a COLLECTIVE: after the local quiesce,
-        each rank pushes the tiles it holds back to their owners, waits
-        for the owners' acks, and barriers."""
+        """The blocking flush: wait until no in-flight LOCAL writer
+        remains for the collection's tiles (produced versions are
+        written back at completion, so afterwards ``data_of`` is
+        current). It polls every tracked tile a millisecond apart, so it
+        is for a program that has to READ the collection in the middle
+        of a pool that stays open (the examples, the tests, a
+        distributed pool's hand-back), once, not once a tile: inside an
+        insertion loop it would make the inserter wait for the
+        execution it is there to run ahead of, and :meth:`flush_tile` /
+        :meth:`flush_all` are the forms for that. It ends no tile's
+        tracking. In distributed mode this is a COLLECTIVE: after the
+        local quiesce, each rank pushes the tiles it holds back to their
+        owners, waits for the owners' acks, and barriers."""
+        self._flushing(self._flush_wait, collection, timeout)
+
+    def _flush_wait(self, collection, timeout: float) -> None:
         from .dtd_native import _NativeWriter
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:

@@ -459,6 +459,8 @@ class NativeDTD:
                     with tile.lock:
                         tile.last_writer = _NativeWriter(seq)
                         tile.last_writer_flow = fname
+                        if tile.flushed:
+                            tp.tiles.readopt(tile)
                     out_tiles.append((tile, fname, idx))
                     if cap:
                         man.append(("write", a.collection, a.key,
@@ -753,6 +755,8 @@ class NativeDTD:
                                 lw.seq == seq:
                             tile.last_writer = None
                             tile.last_writer_flow = None
+                            if tile.flushed:    # flush_tile's retire
+                                tp.tiles.retire(tile)
                 self.outputs[seq] = retained
         except BaseException as exc:  # noqa: BLE001 — worker must survive
             self._fail(seq, exc, w)
