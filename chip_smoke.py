@@ -614,8 +614,11 @@ def phase_sharded(sz, on_chip):
         part = ex.partition_report()
         say("sharded", flagship=f"n={n}/nb={nb}", spec='P("rows")',
             branch=part["branch"],
-            collectives_per_step=part.get("collectives_per_step"),
-            busiest_chip_ops_share=part.get("busiest_chip_ops_share"),
+            **{field: part.get(field) for field in (
+                "sends_per_step", "sent_bytes_per_step_busiest_chip",
+                "send_chunk_bytes", "sends_with_a_product_behind_them_share",
+                "collectives_per_step", "reduced_bytes_per_step_and_chip",
+                "busiest_chip_ops_share")},
             first_pass_s=f"{t_first:.1f}", shard_devices=shard_devs,
             peak_hbm=_peaks())
         require(part["branch"] == "runtime",

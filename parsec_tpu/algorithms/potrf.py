@@ -811,20 +811,32 @@ def _potrf_left_mesh_wave_fuser(wave, geoms, part):
     holds — all of its panels (chips before row panel k's owner), the
     first ``k mod panels-a-chip`` of them (the owner), none (chips after
     it): static shapes under a ``lax.switch`` on the chip's index,
-    because k and so the owner are static — and the partial products
-    are summed over the chips that can hold any (``part.reducers``; the
-    first chip's own panels are summed with nobody's). The owner alone
-    subtracts, factors the diagonal tile, inverts or solves and writes
-    row panel k, in f32 with the one-chip fuser's precisions; every
-    other chip skips that as a loop of no trips, which unlike a
-    conditional leaves its shard where it is. Row panel k is never read
-    by another chip, so nothing else travels.
+    because k and so the owner are static — and every chip before the
+    owner sends it its partial product, point to point
+    (``part.senders``; the first chip's own panels need nobody's). The
+    owner alone adds what arrives to its own product, sender by sender
+    in the order of the chips, subtracts, factors the diagonal tile,
+    inverts or solves and writes row panel k, in f32 with the one-chip
+    fuser's precisions; every other chip skips that as a loop of no
+    trips, which unlike a conditional leaves its shard where it is. Row
+    panel k is never read by another chip, so nothing else travels and
+    nothing travels back.
 
-    A step runs in column chunks (``part.chunks``), each multiplied,
-    summed, solved and written before the next is multiplied: the UPDATE
-    wave only hands the POTRF and TRSM waves the reduction of a chunk,
-    to be formed where it is consumed, so that one chunk of partial sums
-    is live and never a whole row panel of them.
+    A step runs in column chunks (``part.chunks``). No product of step k
+    reads row panel k — a sender's rows are final, the owner contracts
+    the panels before it — so the UPDATE wave multiplies and sends every
+    chunk from the state as the step found it, and the POTRF and TRSM
+    waves consume them: a chunk's transfer runs under its sender's next
+    product, and a step exposes its last chunk's alone. Two
+    ``optimization_barrier``s say so to XLA. The state the step writes is
+    one every product has read: reads before in-place writes is the
+    order XLA keeps without a copy of the shard, and it looks for that
+    order no further than a data dependence. And the owner's chain waits
+    for every chunk but the last: left to consume them one by one, the
+    scheduler counts the owner's solves as work to hide a send under and
+    starts the send after the product that should have hidden it (on a
+    sender those solves are loops of no trips). A step never reads ahead
+    of the one before it.
 
     The diagonal tile is factored by ``chol_inv_tile``, the loop form of
     the one-chip fuser's ``potrf_tile_blocked`` + ``tri_inv_tile``: a
@@ -866,45 +878,67 @@ def _potrf_left_mesh_wave_fuser(wave, geoms, part):
         return lax.fori_loop(0, mine().astype(jnp.int32),
                              lambda _, D: write(D), D)
 
+    def less(cur, parts, c0=0, c1=None):
+        """``cur`` less columns ``[c0, c1)`` of a chunk's products, the
+        owner's own and then each sender's in the order of the chips:
+        one order, so one result to the bit. Taken off ``cur`` one by
+        one and not summed first: a sum of what arrived would not hang
+        on the owner's loop, and XLA would lift it out for every chip
+        to compute."""
+        cur = cur.astype(f32)
+        for arrived in parts:
+            cur = cur - arrived[:, c0:c1]
+        return cur
+
     if kind == "UPDATE":
-        chunks = part.chunks(k, hi, tile_bytes)
-        groups = part.reducers(owner)
-        for t0, t1 in chunks:
+        senders = part.senders(owner)
+        chunks = part.chunks(k, hi, tile_bytes, owner)
+        for i, (t0, t1) in enumerate(chunks):
             w = (t1 - t0) * mb
-            if groups is not None:
-                part.count_reduce(nb * w * 4)
-            for shard in range(owner):
+            for shard in senders:
+                part.count_send(shard, nb * w * 4, last=i == len(chunks) - 1)
                 part.ops[shard] += 2 * held * nb * w
             part.ops[owner] += 2 * kl * nb * nb * w
 
-        def reduced(D, i):
-            """Σ over chips of (Lᵀ[:k, k])ᵀ · Lᵀ[:k, chunk i], each chip
-            contracting the factored rows it holds (the owner's copy is
-            the one that counts)."""
-            c0, c1 = chunks[i][0] * mb, chunks[i][1] * mb
-
-            def whole(D):
-                return mm(D[:, diag].T, D[:, c0:c1])
-
-            def head(D):
-                return mm(D[:kl * nb, diag].T, D[:kl * nb, c0:c1])
-
-            def nothing(D):
-                return jnp.zeros((nb, c1 - c0), f32)
-
-            own = head if kl else nothing
-            if groups is None:
-                return lax.cond(mine(), own, nothing, D)
+        def products(D):
+            """Per chunk, the products the owner sums: (Lᵀ[:k, k])ᵀ ·
+            Lᵀ[:k, chunk] over the factored rows it holds itself, then
+            over each sender's as they arrive (zeros on any other
+            chip)."""
             me = lax.axis_index(axis)
             # 0: a chip before the owner, 1: the owner, 2: one after it
             role = (me >= owner).astype(jnp.int32) + \
                 (me > owner).astype(jnp.int32)
-            partial = lax.switch(role, (whole, own, nothing), D)
-            with jax.named_scope("parsec:panel_reduce"):
-                return lax.psum(partial, axis, axis_index_groups=groups)
+            out = []
+            for t0, t1 in chunks:
+                c0, c1 = t0 * mb, t1 * mb
+
+                def whole(D, c0=c0, c1=c1):
+                    return mm(D[:, diag].T, D[:, c0:c1])
+
+                def head(D, c0=c0, c1=c1):
+                    return mm(D[:kl * nb, diag].T, D[:kl * nb, c0:c1])
+
+                def nothing(D, w=c1 - c0):
+                    return jnp.zeros((nb, w), f32)
+
+                own = head if kl else nothing
+                partial = lax.switch(role, (whole, own, nothing), D)
+                with jax.named_scope("parsec:panel_reduce"):
+                    out.append((partial,) + tuple(
+                        lax.ppermute(partial, axis, perm=[(shard, owner)])
+                        for shard in senders))
+            return out
 
         def do_update(st):
-            st["_reduced"] = reduced
+            found = products(st[name])
+            last = found[-1][1:]
+            # the step's writes wait for its products, and the owner's
+            # chain for all that is sent but the last chunk
+            st[name], own, sent = lax.optimization_barrier(
+                (st[name], [p[0] for p in found],
+                 [p[1:] for p in found[:-1]]))
+            st["_products"] = [(o,) + s for o, s in zip(own, sent + [last])]
             return st
 
         return do_update
@@ -916,28 +950,29 @@ def _potrf_left_mesh_wave_fuser(wave, geoms, part):
 
         def do_potrf(st):
             D = st[name]
-            # chunk 0 of the update's sums: the diagonal tile at its head
-            tot = st["_reduced"](D, 0) if "_reduced" in st else None
+            # chunk 0 of the update's products: the diagonal tile's at
+            # their head (no UPDATE wave precedes step 0)
+            parts = st["_products"][0] if "_products" in st else ()
 
-            def factor(cur, tot):
-                d = cur.astype(f32) if tot is None else cur - tot[:, :nb]
+            def factor(cur, *parts):
+                d = less(cur, parts)
                 # symmetrized as the one-chip fuser does
                 L, inv = chol_inv_tile(0.5 * (d + d.T))
                 return L.T.astype(D.dtype), L if solve_mode else inv
 
-            def keep(cur, tot):
+            def keep(cur, *parts):
                 return cur, jnp.zeros((nb, nb), f32)
 
-            Lt, inv = lax.cond(mine(), factor, keep, D[rows, diag], tot)
+            Lt, inv = lax.cond(mine(), factor, keep, D[rows, diag],
+                               *(p[:, :nb] for p in parts))
             # the one tile every chip writes, its own back where it is
             # not the owner's: a tile of Lᵀ is L in the other layout, and
             # written inside owner_alone's loop it may talk XLA into
             # keeping the whole shard that way round (two copies of it)
             st[name] = D.at[rows, diag].set(Lt)
             if k == geom.nt - 1:       # no TRSM wave follows
-                st.pop("_reduced", None)
+                st.pop("_products", None)
             else:
-                st["_rowsum"] = tot
                 st["_potrf_inv"] = inv   # L itself under trsm_hook=solve
             return st
 
@@ -947,23 +982,18 @@ def _potrf_left_mesh_wave_fuser(wave, geoms, part):
 
     def do_trsm(st):
         D = st[name]
-        reduced = st.pop("_reduced", None)       # None at k = 0
-        tot = st.pop("_rowsum")
+        products = st.pop("_products", None)       # None at k = 0
         inv = st.pop("_potrf_inv")
         # the update's chunks, less the diagonal tile at the first's head
-        for i, (t0, t1) in enumerate(part.chunks(k, hi, tile_bytes)):
-            c0, c1 = t0 * mb, t1 * mb
-            if i and reduced is not None:
-                tot = reduced(D, i)
-            if not i:
-                c0 += mb
-                tot = None if tot is None else tot[:, mb:]
+        for i, (t0, t1) in enumerate(part.chunks(k, hi, tile_bytes, owner)):
+            skip = 0 if i else mb
+            c0, c1 = t0 * mb + skip, t1 * mb
             if c0 == c1:
                 continue
 
-            def write(D, c0=c0, c1=c1, tot=tot):
-                cur = D[rows, c0:c1]
-                rest = cur.astype(f32) if tot is None else cur - tot
+            def write(D, c0=c0, c1=c1, skip=skip,
+                      parts=products[i] if products else ()):
+                rest = less(D[rows, c0:c1], parts, skip)
                 if solve_mode:
                     # exact wide triangular solve: no inversion
                     rest = jax.scipy.linalg.solve_triangular(

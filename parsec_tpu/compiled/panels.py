@@ -128,11 +128,20 @@ class PanelGeometry:
         return slice(j * self.nb, (j + 1) * self.nb)
 
 
-# A mesh lowering multiplies, sums across chips, solves and writes a row
-# panel in column chunks of at most this many bytes of partial sums, so
-# that none of its temporaries is a whole row panel (XLA:TPU's all-reduce
-# is synchronous: a chunk buys memory, not overlap).
-REDUCE_CHUNK_BYTES = 64 << 20
+# A mesh lowering multiplies a row panel's update in column runs, and a
+# chip that holds factored rows sends each run's partial sums to the
+# panel's owner as it leaves the MXU: XLA:TPU starts a collective-permute
+# and finishes it later (its all-reduce it does not), so a run travels
+# under its sender's next product and a step exposes its last run alone.
+# On a v5e a run's transfer takes about half its product's time (45 GB/s
+# a link against 170 TF/s over a chip's 16 row panels), so each run is
+# half the one before it, down to a last of at most SEND_TAIL_BYTES. What
+# keeps the runs few is the program's text, which a chip holds in HBM
+# beside its shard: about 1.1 MiB a run and 0.26 MiB a send. A panel
+# nobody sends goes in runs of at most PANEL_CHUNK_BYTES, which only
+# keeps it from being one temporary.
+PANEL_CHUNK_BYTES = 96 << 20
+SEND_TAIL_BYTES = 28 << 20
 _NO_MESH_LOWERING = "taskpool registers no mesh_wave_fuser"
 
 
@@ -145,40 +154,54 @@ class PanelPartition:
     def __init__(self, axis: str, shards: int):
         self.axis = axis
         self.shards = shards
-        self.chunk_bytes = REDUCE_CHUNK_BYTES
-        self.collectives = 0
-        self.reduced_bytes = 0          # payload each chip hands in
+        self.chunk_bytes = PANEL_CHUNK_BYTES
+        self.tail_bytes = SEND_TAIL_BYTES
+        self.sends = 0
+        self.hideable_sends = 0         # with a product of their sender after
+        self.sent_bytes = [0] * shards  # payload each chip hands in
+        self.widest_send = 0            # the most bytes one send carries
         self.ops = [0] * shards         # operations lowered per chip
 
     def owner(self, geom: PanelGeometry, j: int) -> Tuple[int, int]:
         """``(shard, local row panel)`` holding row panel ``j``."""
         return divmod(j, geom.nt // self.shards)
 
-    def chunks(self, lo: int, hi: int, tile_bytes: int
+    def chunks(self, lo: int, hi: int, tile_bytes: int, owner: int
                ) -> List[Tuple[int, int]]:
-        """Tile range ``[lo, hi)`` in the fewest equal runs of whole
-        tiles whose ``tile_bytes`` stay within ``chunk_bytes``."""
-        most = max(1, self.chunk_bytes // tile_bytes)
-        n = -(-(hi - lo) // most)
-        size = -(-(hi - lo) // max(n, 1))
-        return [(t, min(t + size, hi)) for t in range(lo, hi, size)]
+        """Tile range ``[lo, hi)`` of a row panel of ``owner``'s in runs
+        of whole tiles. A panel somebody sends goes in runs each about
+        half the one before it, as many as bring the last within
+        ``tail_bytes``; a panel nobody sends, in the fewest equal runs
+        within ``chunk_bytes``."""
+        tiles = hi - lo
+        if self.senders(owner):
+            # the least n with tiles ≤ tail · (2ⁿ − 1), a tile a run at most
+            tail = max(1, self.tail_bytes // tile_bytes)
+            n = min(tiles, (-(-tiles // tail)).bit_length())
+            shares = [2 ** i for i in range(n)]     # the last run's first
+        else:
+            n = -(-tiles // max(1, self.chunk_bytes // tile_bytes))
+            shares = [1] * n
+        cuts = [hi]
+        for i in range(n - 1):
+            size = round((cuts[-1] - lo) * shares[i] / sum(shares[i:]))
+            cuts.append(cuts[-1] - max(1, size))
+        cuts.append(lo)
+        return list(zip(cuts[::-1], cuts[-2::-1]))
 
-    def reducers(self, owner: int) -> Optional[List[List[int]]]:
-        """The chips a sum to ``owner`` runs over, as ``axis_index_groups``
-        — the chips up to the owner are the ones that hold factored rows
-        to contract, so: blocks of consecutive chips of the smallest size
-        that divides the mesh and takes them all in. None when that is
-        the owner alone: nothing to sum."""
-        size = next(d for d in range(owner + 1, self.shards + 1)
-                    if self.shards % d == 0)
-        if size == 1:
-            return None
-        return [list(range(lo, lo + size))
-                for lo in range(0, self.shards, size)]
+    def senders(self, owner: int) -> range:
+        """The chips that send ``owner`` a partial of its row panel: the
+        rows are contiguous and factored in order, so the chips before
+        it hold factored rows to contract and the ones after it none."""
+        return range(owner)
 
-    def count_reduce(self, nbytes: int) -> None:
-        self.collectives += 1
-        self.reduced_bytes += nbytes
+    def count_send(self, sender: int, nbytes: int, last: bool) -> None:
+        """One chunk from ``sender`` to the step's owner; ``last``: no
+        product of the sender follows it in its step."""
+        self.sends += 1
+        self.hideable_sends += not last
+        self.sent_bytes[sender] += nbytes
+        self.widest_send = max(self.widest_send, nbytes)
 
 
 # ---------------------------------------------------------------------------
@@ -448,11 +471,14 @@ class PanelExecutor:
     def partition_report(self) -> Dict[str, Any]:
         """Who partitioned this program the last time it was compiled
         over a mesh: ``branch`` is ``runtime`` (the taskpool's mesh
-        lowering under ``shard_map``; with the collectives and the bytes
-        each chip hands to them a step, and the busiest chip's share of
-        the operations, as the lowering counted them) or ``gspmd`` (the
-        one-chip program and a ``PartitionSpec``; with the ``reason``).
-        ``branch`` is None until a mesh compile has asked."""
+        lowering under ``shard_map``; with the sends to a row panel's
+        owner a step, the bytes the busiest chip hands to them, the
+        share of them that program order lets a product hide — those
+        that are not their sender's last in their row panel — and the
+        busiest chip's share of the operations, as the lowering counted
+        them) or ``gspmd`` (the one-chip program and a
+        ``PartitionSpec``; with the ``reason``). ``branch`` is None
+        until a mesh compile has asked."""
         return dict(self._partition)
 
     def _split_axis(self, mesh, in_shardings, out_shardings
@@ -519,22 +545,27 @@ class PanelExecutor:
             return {name: state[name] for name in self.geoms}
 
         specs = {name: P(axis) for name in self.geoms}
-        # the lowering tells chips apart and sums across them itself;
+        # the lowering tells chips apart and sends across them itself;
         # nothing of it is replicated for shard_map to check
         mapped = self.jax.shard_map(run_shard, mesh=mesh, in_specs=(specs,),
                                     out_specs=specs, check_vma=False)
         self._partition = {
             "branch": "runtime", "axis": axis, "shards": part.shards,
-            "collectives_per_step": part.collectives,
-            "reduced_bytes_per_step_and_chip": part.reduced_bytes,
             "busiest_chip_ops_share": max(part.ops) / max(sum(part.ops), 1),
-            "chunk_bytes": part.chunk_bytes}
+            "sends_per_step": part.sends,
+            "sent_bytes_per_step_busiest_chip": max(part.sent_bytes),
+            "send_chunk_bytes": part.widest_send,
+            "sends_with_a_product_behind_them_share":
+                part.hideable_sends / max(part.sends, 1),
+            # the names a reader of the all-reduce form knew
+            "collectives_per_step": part.sends,
+            "reduced_bytes_per_step_and_chip": max(part.sent_bytes)}
         debug_verbose(2, "panels", "%s over %d chips, the runtime's "
                       "partition: %s", self.plan.taskpool.name,
                       part.shards, self._partition)
         ok, fp = compile_cache.function_fingerprint(self._mesh_fuser)
-        return mapped, (("mesh_wave_fuser", fp, part.chunk_bytes)
-                        if ok else None)
+        return mapped, (("mesh_wave_fuser", fp, part.chunk_bytes,
+                         part.tail_bytes) if ok else None)
 
     # -- host-driven convenience -----------------------------------------
     def make_state(self) -> Dict[str, Any]:

@@ -1,7 +1,8 @@
 """The panel program partitioned by the runtime (compiled/panels.py
 ``PanelExecutor.partitioned``, ``compile_with_plan``): left-looking POTRF
-over a one-axis mesh as per-chip matmuls and a reduction a column chunk
-under ``shard_map``, on the conftest's 8 forced CPU devices. The factor
+over a one-axis mesh as per-chip matmuls and, a column chunk, one send
+from every chip that holds factored rows to the row panel's owner, under
+``shard_map``, on the conftest's 8 forced CPU devices. The factor
 against ``numpy.linalg.cholesky`` and the one-chip program, what the
 compiled program holds of collectives, which branch a call takes and
 says it took, and under which store key each lives."""
@@ -40,10 +41,12 @@ KEY = generate.step_key(7, 1)
 
 
 @pytest.fixture(autouse=True)
-def three_tile_chunks(monkeypatch):
-    """A reduction chunk of three tiles' partial sums, so that these
-    sizes reduce in several chunks a step as the real ones do."""
-    monkeypatch.setattr(panels, "REDUCE_CHUNK_BYTES", 3 * NB * NB * 4)
+def small_chunks(monkeypatch):
+    """A last send of one tile's partial sums, and three tiles to a run
+    nobody sends, so that these sizes go in several chunks a step as the
+    real ones do."""
+    monkeypatch.setattr(panels, "SEND_TAIL_BYTES", NB * NB * 4)
+    monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES", 3 * NB * NB * 4)
 
 
 @pytest.fixture
@@ -117,40 +120,92 @@ def test_partitioned_factor_is_the_one_chip_factor(chips, panels_a_chip,
 
 
 @pytest.mark.parametrize("hook", ["gemm"], indirect=True)
-def test_four_chip_program_sums_and_moves_nothing_else(hook):
-    """No collective-permute, no all-to-all, no all-gather: all-reduces
-    alone, as many as the lowering says it wrote, themselves within
-    chunks-a-step × nt. The first chip's own row panels are summed with
-    nobody's, the second's over the first two chips."""
+def test_four_chip_program_sends_to_the_owner_and_moves_nothing_else(hook):
+    """No all-reduce, no all-gather, no all-to-all: collective-permutes
+    alone, each of one pair (a chip before the owner, the owner), as many
+    as the lowering says it wrote: a sender's row panel in halving runs
+    down to one tile. The first chip's own row panels take nobody's
+    products; the last chip's take everybody else's."""
     n, chips = NB * 16, 4
     ex = _left(n)
     fn, _got = _factor(ex, _mesh(chips), n)
     rep = ex.partition_report()
     nt = n // NB
-    summed = range(nt // chips, nt)     # steps whose owner is not chip 0
+    held = nt // chips
+    # with a sender: runs of 2^i tiles from the last back take in the panel
+    chunks = {k: (nt - k).bit_length() for k in range(held, nt)}
     found = _collectives(fn)
-    assert set(found) == {"all-reduce"}, found
-    assert found["all-reduce"] == rep["collectives_per_step"] == sum(
-        -(-(nt - k) // 3) for k in summed) <= -(-nt // 3) * nt
-    # each such step hands its nb × (n − k·nb) f32 partial in
-    assert rep["reduced_bytes_per_step_and_chip"] == sum(
-        NB * (n - k * NB) * 4 for k in summed)
+    assert set(found) == {"collective-permute"}, found
+    assert found["collective-permute"] == rep["sends_per_step"] == \
+        rep["collectives_per_step"] == \
+        sum(k // held * c for k, c in chunks.items())
     text = fn.as_text()
-    pairs = sum(-(-(nt - k) // 3) for k in range(nt // chips, nt // 2))
-    assert text.count("replica_groups={{0,1},{2,3}}") == pairs
-    assert text.count("replica_groups={{0,1,2,3}}") == \
-        found["all-reduce"] - pairs
+    pairs = re.findall(r"source_target_pairs=\{(.*?)\}\}?[,\s]", text)
+    for owner in range(1, chips):
+        of_owner = sum(c for k, c in chunks.items() if k // held == owner)
+        for sender in range(owner):
+            assert pairs.count(f"{{{sender},{owner}") == of_owner, (
+                sender, owner, pairs)
+    assert len(pairs) == found["collective-permute"]     # and no other pair
+    # chip 0 hands every such step its nb × (n − k·nb) f32 partial
+    assert rep["sent_bytes_per_step_busiest_chip"] == \
+        rep["reduced_bytes_per_step_and_chip"] == \
+        sum(NB * (n - k * NB) * 4 for k in chunks)
+    # the widest send: the first run of the widest sent panel, [6, 3, 2, 1]
+    assert rep["send_chunk_bytes"] == 6 * NB * NB * 4
+    # all of a sender's sends in a row panel but its last
+    assert rep["sends_with_a_product_behind_them_share"] == pytest.approx(
+        sum(k // held * (c - 1) for k, c in chunks.items())
+        / rep["sends_per_step"])
     assert "parsec:panel_reduce" in text
 
 
+@pytest.mark.parametrize("tiles,owner,want", [
+    (48, 1, [27, 14, 7]), (33, 1, [19, 9, 5]), (21, 2, [14, 7]),
+    (8, 3, [5, 3]), (7, 3, [7]), (1, 3, [1]),
+    (64, 0, [21, 22, 21]), (49, 0, [17, 16, 16]),
+    (24, 0, [24]), (1, 0, [1])])
+def test_a_sent_panel_goes_in_halving_runs_down_to_the_tail(tiles, owner,
+                                                            want):
+    """At the cell's tile (4 MiB of partial sums) and the cell's
+    constants: the last run of a sent panel, which its sender cannot
+    hide, within 28 MiB; a panel nobody sends in equal runs within 96."""
+    part = panels.PanelPartition("rows", 4)
+    part.tail_bytes, part.chunk_bytes = 28 << 20, 96 << 20
+    runs = part.chunks(5, 5 + tiles, 1024 * 1024 * 4, owner)
+    assert [hi - lo for lo, hi in runs] == want
+    assert runs[0][0] == 5 and runs[-1][1] == 5 + tiles
+    assert all(a[1] == b[0] for a, b in zip(runs, runs[1:]))
+
+
 @pytest.mark.parametrize("shards,owner,want", [
-    (4, 0, None), (4, 1, [[0, 1], [2, 3]]), (4, 2, [[0, 1, 2, 3]]),
-    (4, 3, [[0, 1, 2, 3]]), (2, 1, [[0, 1]]), (8, 2, [[0, 1, 2, 3],
-                                                      [4, 5, 6, 7]]),
-    (6, 2, [[0, 1, 2], [3, 4, 5]]), (6, 3, [[0, 1, 2, 3, 4, 5]])])
-def test_a_sum_runs_over_the_chips_that_can_hold_factored_rows(shards,
-                                                               owner, want):
-    assert panels.PanelPartition("rows", shards).reducers(owner) == want
+    (4, 0, []), (4, 1, [0]), (4, 2, [0, 1]), (4, 3, [0, 1, 2]),
+    (2, 1, [0]), (8, 2, [0, 1]), (6, 2, [0, 1]), (6, 3, [0, 1, 2])])
+def test_an_owner_is_sent_to_by_the_chips_that_hold_factored_rows(
+        shards, owner, want):
+    part = panels.PanelPartition("rows", shards)
+    assert list(part.senders(owner)) == want
+    for i, sender in enumerate(part.senders(owner)):
+        part.count_send(sender, 8, last=bool(i))
+    assert part.sends == len(want) and part.sent_bytes == \
+        [8] * len(want) + [0] * (shards - len(want))
+    assert part.hideable_sends == min(len(want), 1)
+
+
+@pytest.mark.parametrize("hook", ["gemm", "solve"], indirect=True)
+def test_the_owner_sums_in_one_order(hook):
+    """Two runs of the 4-chip program on one input give the same bits:
+    the owner takes its own product and then each sender's off the row
+    panel in the order of the chips, whichever arrives first (an
+    all-reduce promises no order)."""
+    n, chips = NB * 8, 4
+    ex = _left(n)
+    fn, sh = _compile(ex, _mesh(chips))
+    a0 = np.asarray(generate.spd_matrix(KEY, n, NB))
+    runs = [np.asarray(fn({"A": jax.device_put(a0, sh)})["A"])
+            for _ in range(2)]
+    assert runs[0].tobytes() == runs[1].tobytes()
+    assert np.isfinite(runs[0]).all()
 
 
 def test_busiest_chip_share_is_what_contiguous_rows_give():
@@ -241,19 +296,16 @@ def v5e_2x2():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-def test_on_the_chip_the_shard_stays_put_and_the_text_is_small(
-        v5e_2x2, monkeypatch):
-    """The TPU compiler's program at the cell's tile size (nt = 8): the
-    owner's writes happen in place — no copy of a chip's shard, which a
-    tile of Lᵀ written inside the owner's loop once cost twice — and the
-    program's text, which a chip keeps in HBM beside the shard, stays
-    under 5 MB a step (the unrolled tile kernels alone are 8.3 MB)."""
-    n, nb = 8192, 1024
-    monkeypatch.setattr(panels, "REDUCE_CHUNK_BYTES", 64 << 20)
+def _chip_program(topo, n, nb, tail_bytes, monkeypatch):
+    """The TPU compiler's four-chip program of the cell's configuration
+    at ``n`` (nothing runs: the chips are described), and the lowering's
+    report of it."""
+    monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES", 96 << 20)
+    monkeypatch.setattr(panels, "SEND_TAIL_BYTES", tail_bytes)
     mca_param.set("potrf.trsm_hook", "gemm")
     try:
         ex = _left(n, nb)
-        mesh = Mesh(np.asarray(v5e_2x2.devices), ("rows",))
+        mesh = Mesh(np.asarray(topo.devices), ("rows",))
         sh = {"A": NamedSharding(mesh, P("rows"))}
         fn, _key = ex.partitioned(mesh, (sh,), sh)
         compiled = jax.jit(
@@ -262,14 +314,81 @@ def test_on_the_chip_the_shard_stays_put_and_the_text_is_small(
                                            sharding=sh["A"])}).compile()
     finally:
         mca_param.unset("potrf.trsm_hook")
+    return compiled, ex.partition_report()
+
+
+def _schedule(text):
+    """The entry computation of a scheduled program, in its order, as
+    ``(what, name)``: a send's ``start`` and ``done`` (named by their
+    start) and a ``product`` (the switch on a chip's role around a
+    step's matmuls: the one conditional of three branches)."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M)
+    out = []
+    for line in entry.group(1).splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (?:\(.*?\)|\S+) ([\w-]+)\(",
+                     line)
+        if not m:
+            continue
+        name, op = m.groups()
+        if op == "collective-permute-start":
+            out.append(("start", name))
+        elif op == "collective-permute-done":
+            out.append(("done", re.search(
+                r"collective-permute-done\(%?([^)\s]+)\)", line).group(1)))
+        elif op == "conditional" and len(re.search(
+                r"branch_computations=\{([^}]*)\}", line).group(1)
+                .split(",")) == 3:
+            out.append(("product", name))
+    return out
+
+
+# the parent's text at N = 65536 and what the benchmark's 1% on
+# peak_hbm_gib (4.3424 GiB there) lets a program add to it
+CELL_TEXT_BYTES = 349.08 * 2 ** 20 + 0.01 * 4.3424 * 2 ** 30
+
+
+@pytest.mark.parametrize("n,tail_tiles,hideable,text_bytes", [
+    (8192, 7, 0, 5e6 * 8), (8192, 1, 13, None),
+    pytest.param(65536, 7, 113, CELL_TEXT_BYTES, marks=pytest.mark.slow)])
+def test_on_the_chip_the_shard_stays_put_and_a_send_runs_under_a_product(
+        v5e_2x2, monkeypatch, n, tail_tiles, hideable, text_bytes):
+    """The TPU compiler's program at the cell's tile size: nt = 8 with
+    the cell's constants (a last send within 7 tiles: a chunk a step
+    there) and with a last send of one tile (several chunks a step, as
+    the cell has); and, marked slow (90 s), the cell's own. The owner's
+    writes happen in place — no copy of a chip's shard, which a tile of
+    Lᵀ written inside the owner's loop once cost twice, and a product
+    that reads the state after one of its step's writes would cost
+    again. Nothing but sends crosses chips. The schedule puts a product
+    between the start and the done of every send the report calls
+    hideable. The temporaries are a step's partial sums, once on a
+    sender and once a sender on the owner, and the diagonal tile's
+    workspace; the text, which a chip keeps in HBM beside the shard,
+    stays under 5 MB a step at nt = 8 (the unrolled tile kernels alone
+    are 8.3 MB) and within the benchmark's bound at the cell's size."""
+    nb, chips = 1024, 4
+    compiled, rep = _chip_program(v5e_2x2, n, nb, tail_tiles * nb * nb * 4,
+                                  monkeypatch)
     text = compiled.as_text()
-    assert not re.findall(rf"= f32\[{n // 4},{n}\]\S* copy\(", text)
-    assert set(re.findall(r"= \S+ (all-\w+|collective-\w+|reduce-scatter)"
-                          r"(?:-start)?\(", text)) == {"all-reduce"}
+    assert not re.findall(rf"= f32\[{n // chips},{n}\]\S* copy\(", text)
+    assert set(re.findall(r"= \S+ (all-\w+|collective-\w+?|reduce-scatter)"
+                          r"(?:-start|-done)?\(", text)) == \
+        {"collective-permute"}
+    order = _schedule(text)
+    at = {item: i for i, item in enumerate(order)}
+    sends = [name for what, name in order if what == "start"]
+    assert len(sends) == rep["sends_per_step"]
+    assert hideable == round(rep["sends_with_a_product_behind_them_share"]
+                             * rep["sends_per_step"])
+    assert hideable <= sum(
+        any(what == "product"
+            for what, _ in order[at["start", s] + 1:at["done", s]])
+        for s in sends)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == mem.argument_size_in_bytes
-    assert mem.generated_code_size_in_bytes < 5e6 * (n // nb)
-    assert mem.temp_size_in_bytes < 2 * (n // 4) * n * 4
+    assert mem.temp_size_in_bytes < chips * nb * n * 4 + 8 * nb * nb * 4
+    if text_bytes:
+        assert mem.generated_code_size_in_bytes < text_bytes
 
 
 # ---------------------------------------------------------------------------
