@@ -14,6 +14,10 @@ points a user calls, at full width, cheapest phase first:
             against numpy, DTD POTRF n=4096/nb=512 (insert_potrf_dtd) and
             PTG build_potrf of the same size via add_taskpool/wait;
             every task on a tpuN module, none on the inline CPU module
+  qr_host   the benchmark's dgeqrf_ptg_host driver once at N=8192 in
+            2048-tiles (30 tasks of the four compact-WY kernels through
+            add_taskpool/wait): the three residuals of V, T and R against
+            the plain reference, every task on the tpu0 module
   panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
   flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
             NB=1024, potrf.trsm_hook=gemm, input generated on device, three
@@ -60,11 +64,13 @@ import traceback
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
+            qr_host=dict(n=8192, nb=2048),
             panels=(8192, 1024), flagship=(40960, 1024),
             flash=dict(S=16384, H=4, dh=128, F=2048),
             block=dict(H=2, T=2, TS=1024, DH=128, F=512),
             wavefront=(2048, 256), mesh_shape=(1024, 1024))
 DRY = dict(dtd=(256, 64), potrf_host=(256, 64),
+           qr_host=dict(n=128, nb=32, ib=16),
            panels=(256, 64), flagship=(256, 64),
            flash=dict(S=256, H=2, dh=16, F=64),
            block=dict(H=2, T=2, TS=64, DH=16, F=64),
@@ -420,6 +426,38 @@ def phase_host(sz, on_chip):
                 f"every output tile lives on chip {out_devs or p_devs}")
 
 
+def phase_qr_host(sz, on_chip):
+    """``dgeqrf`` as the benchmark's cell runs it, small: the driver's own
+    set-up, generator, step and check (``benchmark/drivers/
+    ptg_qr_factorization.py``), so a builder without the benchmark's
+    window sees the path on the chip."""
+    import jax
+    from benchmark.manifest import Manifest
+    from benchmark.run import Spans
+
+    man = Manifest()
+    config = man.config("dgeqrf_ptg_host")
+    driver = man.driver(config["driver"]).build(
+        config, {**config["sizes"], **sz["qr_host"]}, 20261002,
+        jax.devices()[:1], Spans(), man.reference(config["reference"]))
+    # the storage guarantee is the cell's: at this size the programs'
+    # text outweighs the matrix
+    driver.storage_limit_bytes = 1 << 62
+    try:
+        driver.setup()
+        t0 = time.perf_counter()
+        out = driver.step(driver.generate(0))
+        t_step = time.perf_counter() - t0
+        require(driver.finite(out), "a non-finite tile")
+        ok, detail = driver.check(out, 0)
+        say("qr_host", geqrf="n={n}/nb={nb}".format(**sz["qr_host"]),
+            ib=driver.ib, tasks=driver.tasks_per_step,
+            first_run_s=f"{t_step:.1f}", **detail)
+        require(ok, f"the factored form fails its check: {detail}")
+    finally:
+        driver.close()
+
+
 def _panel_run(ex, state):
     import jax
     t0 = time.perf_counter()
@@ -642,7 +680,8 @@ def phase_sharded(sz, on_chip):
 
 ONE_CHIP = [("store", phase_store), ("flash", phase_flash),
             ("block", phase_block), ("host", phase_host),
-            ("panels", phase_panels), ("flagship", phase_flagship)]
+            ("qr_host", phase_qr_host), ("panels", phase_panels),
+            ("flagship", phase_flagship)]
 MULTI_CHIP = [("ring", phase_ring), ("ici", phase_ici),
               ("sharded", phase_sharded)]
 

@@ -1,56 +1,93 @@
 """Tiled QR factorization (flat-tree DPLASMA dgeqrf) as a PTG taskpool.
 
 The BASELINE.md "PTG dgeqrf reduction-tree stress" config. Task classes
-mirror the classic dgeqrf JDF (panel factorization + trailing update per
-step k):
+mirror zgeqrf.jdf (panel factorization + trailing update per step k)
+over two collections, A (nb x nb tiles) and T (ib x nb tiles):
 
-    GEQRT(k):     QR of diagonal tile            → Q_k, R
-    TSQRT(m,k):   QR of [R; A(m,k)] stacked      → Q₂(m,k), updated R
+    GEQRT(k):     A(k,k) = Q R: R in the upper triangle, V (unit lower)
+                  under it, T(k,k)
+    TSQRT(m,k):   [R; A(m,k)] = Q [R'; 0]: R' over R in A(k,k) (GEQRT's V
+                  stays), V2 in A(m,k), T(m,k)
                   (flat reduction tree down column k: m = k+1 .. MT-1)
-    UNMQR(k,n):   row-panel update A(k,n) ← Q_kᵀ·A(k,n)
-    TSMQR(m,n,k): stacked-pair update [C(k,n); A(m,n)] ← Q₂(m,k)ᵀ·[..]
+    UNMQR(k,n):   row-panel update A(k,n) <- Q_kk^T A(k,n)
+    TSMQR(m,n,k): stacked-pair update [A(k,n); A(m,n)] <- Q_mk^T [..]
 
-On completion A holds R in its upper-triangular tile blocks and zeros
-below (V/T storage is a compact-BLAS artifact the functional dataflow
-does not keep — see ops/tile_kernels.py). Validation identity:
-AᵀA = RᵀR (orthogonal-invariant, sign-independent).
+On completion A holds the factored form upstream's dgeqrf leaves: R in
+the upper triangle, the Householder vectors below it (what dormqr, dorgqr
+and dgeqrs go on to read), and T holds the block reflectors' triangular
+factors, the T_j of a tile side by side (ops/tile_kernels.py). Q is the
+product over k ascending of Q_kk and then Q_mk, m ascending; A = Q R.
 
-Orthogonal factors flow task→task as values (no collection placement),
-so this taskpool exercises the host runtime's value-flow path; flows that
-live in A carry tile placements for distribution.
+The factorization runs in the storage of A and T, as upstream's does:
+every class writes each of its results to its tile, the running row
+A(k,n) and the trailing tile A(m,n) of a TSMQR too, so the version
+before it is freed (algorithms/potrf.py build_potrf, PERF.md section 6,
+PR 27), and an UNMQR's and a TSMQR's updates are made in the buffers
+the tiles lie in on a chip module (``Chore.donates``). Every flow carries
+its tile, so the taskpool runs on the host runtime and on the compiled
+wavefront/SPMD executors.
 """
 
 from __future__ import annotations
 
+from typing import Optional
+
+from ..core.task import GROUP_SIZES
 from ..dsl import ptg
 from ..data.matrix import TiledMatrix
 from ..ops.tile_kernels import geqrt_tile, tsmqr_tile, tsqrt_tile, unmqr_tile
 
 
-def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
-    """Build the GEQRF taskpool over tiled matrix ``A`` (MT ≥ NT)."""
+def geqrf_t_collection(A: TiledMatrix, ib: int) -> TiledMatrix:
+    """descT of ``A``: a tile of ``ib`` x nb beside every tile of A on
+    and under the diagonal (a tile is made when it is first written)."""
+    if A.nb % ib:
+        raise ValueError(f"ib={ib} does not divide nb={A.nb}")
+    return TiledMatrix(A.mt * ib, A.nt * A.nb, ib, A.nb, dist=A.dist,
+                       dtype=A.dtype, name=f"{A.name}_T")
+
+
+def _row(kernel, V, T, *stacks):
+    """``kernel(V, T, *tiles)`` over the members of a row that shares V
+    and T, their tiles stacked. A chip module's group (eight members at
+    most) is unrolled: XLA then reads each member where it lies and
+    writes it where it goes, and a launch of four costs the chip what
+    the four cost alone; one product over the stack paid a copy of every
+    tile in and out, 0.44 ms a 2048-tile TSMQR against 0.31 (PERF.md
+    section 6, PR 33). An executor's wave of any width is one vmap."""
+    import jax
+    import jax.numpy as jnp
+    n = stacks[0].shape[0]
+    if n > GROUP_SIZES[0]:
+        return jax.vmap(kernel, in_axes=(None, None) + (0,) * len(stacks))(
+            V, T, *stacks)
+    outs = [kernel(V, T, *(s[i] for s in stacks)) for i in range(n)]
+    if isinstance(outs[0], tuple):
+        return tuple(jnp.stack(o) for o in zip(*outs))
+    return jnp.stack(outs)
+
+
+def build_geqrf(A: TiledMatrix, T: Optional[TiledMatrix] = None,
+                ib: Optional[int] = None) -> ptg.Taskpool:
+    """Build the GEQRF taskpool over tiled matrix ``A`` (MT >= NT) and its
+    ``T`` (made here from ``A`` and ``ib``, one block a tile by default,
+    where none is given; the pool's ``g.T``)."""
     MT, NT = A.mt, A.nt
     if MT < NT:
         raise ValueError("GEQRF needs MT >= NT (tall or square tile grid)")
-    nb = A.nb
-    # Scratch collections give the orthogonal-factor flows tile
-    # placements so the compiled wavefront/tile-dict executors can run
-    # the DAG (values would otherwise flow only task→task); the host
-    # runtime ignores them. Qs holds the (nb,nb) diagonal factors keyed
-    # (k, 0); Q2s the (2nb,2nb) TSQRT factors keyed (m, k) — only the
-    # strictly-below-diagonal keys actually used, so the stacked store
-    # doesn't materialize (or copy per wave) the unused upper half.
-    Qs = TiledMatrix(NT * nb, nb, nb, nb, name=f"{A.name}_Qs")
+    if A.mb != A.nb:
+        raise ValueError("GEQRF needs square tiles (mb == nb)")
+    if T is None:
+        T = geqrf_t_collection(A, ib or A.nb)
+    ib = T.mb
+    if (T.mt, T.nt, T.nb) != (MT, NT, A.nb) or A.nb % ib:
+        raise ValueError("T needs a tile of ib x nb per tile of A, "
+                         "ib a divisor of nb")
+    tp = ptg.Taskpool("geqrf", A=A, T=T, MT=MT, NT=NT)
 
-    class _TSQRTFactors(TiledMatrix):
-        def keys(self):
-            return [(m, k) for k in range(NT)
-                    for m in range(k + 1, MT)]
-
-    Q2s = _TSQRTFactors(MT * 2 * nb, NT * 2 * nb, 2 * nb, 2 * nb,
-                        name=f"{A.name}_Q2s")
-    Qs.scratch = Q2s.scratch = True   # intra-DAG temporaries only
-    tp = ptg.Taskpool("geqrf", A=A, MT=MT, NT=NT, Qs=Qs, Q2s=Q2s)
+    def kept(dc, key_fn):
+        """The write-back every written flow ends with (in place)."""
+        return ptg.Out(data=lambda g, *p: (getattr(g, dc), key_fn(*p)))
 
     GEQRT = tp.task_class(
         "GEQRT", params=("k",),
@@ -59,26 +96,27 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
         priority=lambda g, k: 4 * (g.NT - k) ** 2,
         flows=[
             ptg.FlowSpec(
-                "A", ptg.READ,
+                "A", ptg.RW,
                 tile=lambda g, k: (g.A, (k, k)),
                 ins=[ptg.In(data=lambda g, k: (g.A, (k, k)),
                             guard=lambda g, k: k == 0),
                      ptg.In(src=("TSMQR", lambda g, k: (k, k, k - 1), "A2"),
-                            guard=lambda g, k: k > 0)]),
-            ptg.FlowSpec(
-                "Q", ptg.WRITE,
-                tile=lambda g, k: (g.Qs, (k, 0)),
+                            guard=lambda g, k: k > 0)],
                 outs=[ptg.Out(dst=("UNMQR",
-                               lambda g, k: [(k, n)
-                                             for n in range(k + 1, g.NT)],
-                               "Q"))]),
-            ptg.FlowSpec(
-                "R", ptg.WRITE,
-                tile=lambda g, k: (g.A, (k, k)),
-                outs=[ptg.Out(dst=("TSQRT", lambda g, k: (k + 1, k), "R"),
+                                   lambda g, k: [(k, n)
+                                                 for n in range(k + 1, g.NT)],
+                                   "V")),
+                      ptg.Out(dst=("TSQRT", lambda g, k: (k + 1, k), "R"),
                               guard=lambda g, k: k + 1 < g.MT),
-                      ptg.Out(data=lambda g, k: (g.A, (k, k)),
-                              guard=lambda g, k: k + 1 >= g.MT)]),
+                      kept("A", lambda k: (k, k))]),
+            ptg.FlowSpec(
+                "T", ptg.WRITE,
+                tile=lambda g, k: (g.T, (k, k)),
+                outs=[ptg.Out(dst=("UNMQR",
+                                   lambda g, k: [(k, n)
+                                                 for n in range(k + 1, g.NT)],
+                                   "T")),
+                      kept("T", lambda k: (k, k))]),
         ])
 
     TSQRT = tp.task_class(
@@ -88,37 +126,41 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
         affinity=lambda g, m, k: (g.A, (m, k)),
         priority=lambda g, m, k: 3 * (g.NT - k) ** 2 - m,
         flows=[
+            # the diagonal tile: R' over R, GEQRT's V under it kept
             ptg.FlowSpec(
                 "R", ptg.RW,
                 tile=lambda g, m, k: (g.A, (k, k)),
-                ins=[ptg.In(src=("GEQRT", lambda g, m, k: (k,), "R"),
+                ins=[ptg.In(src=("GEQRT", lambda g, m, k: (k,), "A"),
                             guard=lambda g, m, k: m == k + 1),
                      ptg.In(src=("TSQRT", lambda g, m, k: (m - 1, k), "R"),
                             guard=lambda g, m, k: m > k + 1)],
                 outs=[ptg.Out(dst=("TSQRT", lambda g, m, k: (m + 1, k), "R"),
                               guard=lambda g, m, k: m + 1 < g.MT),
-                      ptg.Out(data=lambda g, m, k: (g.A, (k, k)),
-                              guard=lambda g, m, k: m + 1 >= g.MT)]),
+                      kept("A", lambda m, k: (k, k))]),
+            # A(m,k) goes in, V2 comes out in its place
             ptg.FlowSpec(
-                "A", ptg.READ,
+                "A", ptg.RW,
                 tile=lambda g, m, k: (g.A, (m, k)),
                 ins=[ptg.In(data=lambda g, m, k: (g.A, (m, k)),
                             guard=lambda g, m, k: k == 0),
                      ptg.In(src=("TSMQR", lambda g, m, k: (m, k, k - 1),
                                  "A2"),
-                            guard=lambda g, m, k: k > 0)]),
-            ptg.FlowSpec(
-                "Q2", ptg.WRITE,
-                tile=lambda g, m, k: (g.Q2s, (m, k)),
+                            guard=lambda g, m, k: k > 0)],
                 outs=[ptg.Out(dst=("TSMQR",
-                               lambda g, m, k: [(m, n, k)
-                                                for n in range(k + 1, g.NT)],
-                               "Q2"))]),
-            # the V block of A(m,k) is consumed; R lives strictly above
+                                   lambda g, m, k: [(m, n, k)
+                                                    for n in range(k + 1,
+                                                                   g.NT)],
+                                   "V")),
+                      kept("A", lambda m, k: (m, k))]),
             ptg.FlowSpec(
-                "Z", ptg.WRITE,
-                tile=lambda g, m, k: (g.A, (m, k)),
-                outs=[ptg.Out(data=lambda g, m, k: (g.A, (m, k)))]),
+                "T", ptg.WRITE,
+                tile=lambda g, m, k: (g.T, (m, k)),
+                outs=[ptg.Out(dst=("TSMQR",
+                                   lambda g, m, k: [(m, n, k)
+                                                    for n in range(k + 1,
+                                                                   g.NT)],
+                                   "T")),
+                      kept("T", lambda m, k: (m, k))]),
         ])
 
     UNMQR = tp.task_class(
@@ -129,9 +171,13 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
         priority=lambda g, k, n: 3 * (g.NT - k) ** 2 - n,
         flows=[
             ptg.FlowSpec(
-                "Q", ptg.READ,
-                tile=lambda g, k, n: (g.Qs, (k, 0)),
-                ins=[ptg.In(src=("GEQRT", lambda g, k, n: (k,), "Q"))]),
+                "V", ptg.READ,
+                tile=lambda g, k, n: (g.A, (k, k)),
+                ins=[ptg.In(src=("GEQRT", lambda g, k, n: (k,), "A"))]),
+            ptg.FlowSpec(
+                "T", ptg.READ,
+                tile=lambda g, k, n: (g.T, (k, k)),
+                ins=[ptg.In(src=("GEQRT", lambda g, k, n: (k,), "T"))]),
             ptg.FlowSpec(
                 "C", ptg.RW,
                 tile=lambda g, k, n: (g.A, (k, n)),
@@ -143,8 +189,7 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
                 outs=[ptg.Out(dst=("TSMQR",
                                    lambda g, k, n: (k + 1, n, k), "C1"),
                               guard=lambda g, k, n: k + 1 < g.MT),
-                      ptg.Out(data=lambda g, k, n: (g.A, (k, n)),
-                              guard=lambda g, k, n: k + 1 >= g.MT)]),
+                      kept("A", lambda k, n: (k, n))]),
         ])
 
     TSMQR = tp.task_class(
@@ -156,11 +201,16 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
         priority=lambda g, m, n, k: (g.NT - k) ** 2 - m - n,
         flows=[
             ptg.FlowSpec(
-                "Q2", ptg.READ,
-                tile=lambda g, m, n, k: (g.Q2s, (m, k)),
+                "V", ptg.READ,
+                tile=lambda g, m, n, k: (g.A, (m, k)),
                 ins=[ptg.In(src=("TSQRT", lambda g, m, n, k: (m, k),
-                                 "Q2"))]),
-            # running row-k tile C(k,n), reduced down the column
+                                 "A"))]),
+            ptg.FlowSpec(
+                "T", ptg.READ,
+                tile=lambda g, m, n, k: (g.T, (m, k)),
+                ins=[ptg.In(src=("TSQRT", lambda g, m, n, k: (m, k),
+                                 "T"))]),
+            # running row-k tile A(k,n), reduced down the column
             ptg.FlowSpec(
                 "C1", ptg.RW,
                 tile=lambda g, m, n, k: (g.A, (k, n)),
@@ -172,8 +222,7 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
                 outs=[ptg.Out(dst=("TSMQR",
                                    lambda g, m, n, k: (m + 1, n, k), "C1"),
                               guard=lambda g, m, n, k: m + 1 < g.MT),
-                      ptg.Out(data=lambda g, m, n, k: (g.A, (k, n)),
-                              guard=lambda g, m, n, k: m + 1 >= g.MT)]),
+                      kept("A", lambda m, n, k: (k, n))]),
             # trailing tile A(m,n)
             ptg.FlowSpec(
                 "A2", ptg.RW,
@@ -197,28 +246,57 @@ def build_geqrf(A: TiledMatrix) -> ptg.Taskpool:
                                  lambda g, m, n, k: (m, n, k + 1), "A2"),
                             guard=lambda g, m, n, k: m > k + 1 and
                             n > k + 1),
+                    kept("A", lambda m, n, k: (m, n)),
                 ]),
         ])
 
-    @GEQRT.body
-    def geqrt_body(task, A_, Qv, Rv):
-        Q, R = geqrt_tile(A_)
-        return {"Q": Q, "R": R}
+    # Every class declares its stacked form (the members' READ flows
+    # stacked, the wavefront executor's convention): a body without one
+    # gets unrolled group programs at first sight. The members of a row
+    # of TSMQRs share V2 and T, those of a row of UNMQRs V and T: one
+    # operand, not one a member. GEQRT and TSQRT are serial chains, one
+    # task a column at a time: their stacked form is for an executor's
+    # wave (one of each column in flight) and declares the chain's own
+    # tile shared, which no two tasks the host runtime holds ever do, so
+    # a chip module never builds (nor compiles, steps later, when four
+    # columns' TSQRTs happen to be ready together) a group program it
+    # has no use for.
+    import jax
 
-    @TSQRT.body
-    def tsqrt_body(task, R, A_, Q2v, Zv):
-        import jax.numpy as jnp
-        Q2, Rn = tsqrt_tile(R, A_)
-        return {"R": Rn, "Q2": Q2, "Z": jnp.zeros_like(A_)}
+    @GEQRT.body(batch_hook=lambda As: dict(zip(
+        ("A", "T"), jax.vmap(lambda a: geqrt_tile(a, ib))(As))),
+        batch_hook_shared=("A",))
+    def geqrt_body(task, A_, Tv):
+        return dict(zip(("A", "T"), geqrt_tile(A_, ib)))
 
-    @UNMQR.body
-    def unmqr_body(task, Q, C):
-        return {"C": unmqr_tile(Q, C)}
+    @TSQRT.body(batch_hook=lambda Rs, As: dict(zip(
+        ("R", "A", "T"),
+        jax.vmap(lambda r, a: tsqrt_tile(r, a, ib))(Rs, As))),
+        batch_hook_shared=("R",))
+    def tsqrt_body(task, R, A_, Tv):
+        return dict(zip(("R", "A", "T"), tsqrt_tile(R, A_, ib)))
 
-    @TSMQR.body
-    def tsmqr_body(task, Q2, C1, A2):
-        nC1, nA2 = tsmqr_tile(Q2, C1, A2)
-        return {"C1": nC1, "A2": nA2}
+    # The updates run where their tiles lie (``donates``): the version of
+    # A(k,n) or A(m,n) an UNMQR or a TSMQR takes in has no other reader
+    # (a chain in m and in k, one successor a version, and the tile of A
+    # it came from is the one the task writes), so a chip module hands
+    # its buffer to the program for the flow's output, as upstream's
+    # kernels update C in place. A TSMQR still queued behind a busy chip
+    # then holds no second copy of its two tiles (PERF.md section 6, PR
+    # 33); its results come in the order of its flows, C1 then A2, each
+    # into its own buffer. GEQRT's and TSQRT's diagonal tile has the
+    # row's UNMQRs reading V beside the chain, and stays as it is.
+    @UNMQR.body(batch_hook=lambda Vs, Ts, Cs: _row(
+        unmqr_tile, Vs[0], Ts[0], Cs), batch_hook_shared=("V", "T"),
+        donates=("C",))
+    def unmqr_body(task, V, T_, C):
+        return unmqr_tile(V, T_, C)
+
+    @TSMQR.body(batch_hook=lambda Vs, Ts, C1s, A2s: _row(
+        tsmqr_tile, Vs[0], Ts[0], C1s, A2s), batch_hook_shared=("V", "T"),
+        donates=("C1", "A2"))
+    def tsmqr_body(task, V, T_, C1, A2):
+        return tsmqr_tile(V, T_, C1, A2)
 
     return tp
 

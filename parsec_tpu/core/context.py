@@ -972,7 +972,7 @@ class Context:
             if its is not None:
                 entry = bins.get(its[1])
                 if entry is None:
-                    room = dev.group_limit(nxt)
+                    room = dev.group_limit(nxt, its[0])
                     if room:
                         entry = bins[its[1]] = (its[0], [], room)
             if entry is None:
@@ -1005,7 +1005,7 @@ class Context:
         raises leaves the worker's handler to abort the pool, every load
         released."""
         dev = self.devices.device_for(found[0].device_type, task)
-        limit = dev.group_limit(task) if dev is not None else 0
+        limit = dev.group_limit(task, found[0]) if dev is not None else 0
         alone, held = [task], 1
         try:
             if limit:
@@ -1029,6 +1029,15 @@ class Context:
                       chore: Chore, dev) -> None:
         for task in tasks:      # a dispatch span per task, as alone
             self._timed_dispatch(es, self._prepare_input, es, task)
+        shared = chore.batch_hook_shared
+        if shared:
+            # a stacked form takes ONE object for an operand its group
+            # shares: the members that hold the same ones side by side,
+            # in the order their first was selected (two rows of TSMQRs
+            # become ready together, each with its own V2 and T)
+            seen: Dict[Tuple[int, ...], int] = {}
+            tasks.sort(key=lambda t: seen.setdefault(
+                tuple(id(t.data.get(name)) for name in shared), len(seen)))
         done = 0
         while done < len(tasks):
             # the largest group the module can make of them, never

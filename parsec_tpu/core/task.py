@@ -99,6 +99,18 @@ class Chore:
     # executor verifies this per group and falls back to vmap otherwise.
     batch_hook: Optional[Callable[..., Any]] = None
     batch_hook_shared: Optional[Sequence[str]] = None
+    # RW flows whose incoming version this task is the LAST reader of:
+    # the dependency graph hands it to no other task, and the task writes
+    # the tile it came from. A chip module may then give the input's
+    # buffer to its program for the flow's output (jit donation), as
+    # upstream's kernels update a tile where it lies: a launch queued
+    # behind a busy chip allocates nothing for it, and the input is gone
+    # once the launch is made. The body returns its outputs in the order
+    # of its output flows, and a donated flow is the first of its shape
+    # among them (JAX pairs a donated buffer with the first output of its
+    # shape). Read by the chip module's program table alone: an executor
+    # that lowers the whole pool places its buffers itself.
+    donates: Optional[Sequence[str]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
     # e.g. DTD's woven argspec) can still hand a device module their pure
     # body by providing BOTH of: ``batch_sig(task) -> hashable`` — a key

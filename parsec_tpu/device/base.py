@@ -48,10 +48,10 @@ class Device:
     def execute(self, es, task: Task, chore: Chore) -> HookReturn:
         raise NotImplementedError
 
-    def group_limit(self, task: Task) -> int:
-        """The most tasks like ``task`` one launch of this module may
-        carry; 0 for a module that launches every task alone (then it
-        needs no ``execute_group`` or ``group_turn``)."""
+    def group_limit(self, task: Task, chore=None) -> int:
+        """The most tasks like ``task`` one launch of ``chore`` on this
+        module may carry; 0 for a module that launches every task alone
+        (then it needs no ``execute_group`` or ``group_turn``)."""
         return 0
 
     def shutdown(self) -> None:

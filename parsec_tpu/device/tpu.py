@@ -34,20 +34,29 @@ this chip (``_pinned``).
 
 A module has one group in flight (``group_turn``; the next group waits
 for the last one's output; a launch of one does neither) and a launch
-carries the largest size whose members' inputs together stay within
-``GROUP_BYTES``, because what a launch makes waits in HBM for its
-members' release: small tasks go many to a launch, large ones alone, by
-the bytes this module sees and nothing else. Whole taskpools lowered to
-one program are ``parsec_tpu.compiled``'s business, not this module's.
+carries the largest size whose inputs together stay within
+``GROUP_BYTES`` (an operand the group shares is there once, and counted
+once), because what a launch makes waits in HBM for its members'
+release: small tasks go many to a launch, large ones alone, by the bytes
+this module sees and nothing else. A flow whose incoming version
+its task is the last to read (``Chore.donates``) is updated where it
+lies: the program is given the input's buffer for the flow's output, a
+launch still queued holds nothing new for it, and such a program
+returns a mark of its own to be waited for, since any of its outputs
+may be given on before anyone has waited (``_build``). Whole taskpools
+lowered to one program are ``parsec_tpu.compiled``'s business, not this
+module's.
 """
 
 from __future__ import annotations
 
+import collections
 import numbers
+import re
 import threading
 import time
 import weakref
-from typing import Any, Callable, Dict, List
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from .base import Device
 from ..core.spans import SPAN_EXEC, StageSpan
@@ -62,9 +71,15 @@ from ..utils.debug import debug_verbose
 # 4096^3 as four programs of sixteen: half again the device time, PERF.md
 # section 6, PR 25), while a task that size keeps the chip busy longer
 # than its launch costs the host. So a launch carries the largest size of
-# ``GROUP_SIZES`` whose members' inputs, all together, stay within this
-# many bytes: f32 GEMMs of 1024-tiles (12 MiB a task) go eight at a time,
-# of 2048-tiles (48 MiB) four at a time, of 4096-tiles (192 MiB) alone.
+# ``GROUP_SIZES`` whose inputs, all together, stay within this many
+# bytes: f32 GEMMs of 1024-tiles (12 MiB a task) go eight at a time, of
+# 2048-tiles (48 MiB) four at a time, of 4096-tiles (192 MiB) alone. The
+# bytes are those the launch holds: an operand its members share (a
+# stacked form's ``batch_hook_shared``, one object, checked a launch) is
+# one buffer and counts once, so a row of 2048-tile TSMQRs (V2 and T
+# shared, two tiles of its own a member: 18 + 4 x 32 MiB) goes four at a
+# time as four GEMMs do, and a column of TRSMs (L shared) eight, which
+# hold what four TSMQRs hold (PERF.md section 6, PR 33).
 # Settled on the v5e (PERF.md section 6, PR 28): the program of four
 # 2048-tile GEMMs costs the device no more than the four alone (0.43 s a
 # factorization against 0.45) and the host a third of their launches;
@@ -116,6 +131,11 @@ class TPUDevice(Device):
         # the last group, which the next one waits for
         self.group_turn = threading.Lock()
         self._group_out: Any = None
+        # the launches of one still on the chip's queue, oldest first:
+        # (an output, weakly, or the program's mark; the bytes of its
+        # new outputs), and their sum
+        self._lone: Deque[Tuple[Any, int]] = collections.deque()
+        self._lone_bytes = 0
         self.stats["batches"] = 0
         self.stats["batched_tasks"] = 0
         debug_verbose(3, "device", "TPU device on %s (%s)",
@@ -142,6 +162,13 @@ class TPUDevice(Device):
         and that ``GROUP_BYTES`` admits. 0 where the first task has to go
         alone (the caller takes the single path). Members that differ in
         signature never share a program. Raises where the launch does."""
+        if len(tasks) < GROUP_SIZES[-1] or \
+                not self._hook_ok(chore, tasks[:GROUP_SIZES[-1]]):
+            # asked before a program is built: what is left of a bin,
+            # and a class whose members never hold one object where its
+            # stacked form shares it (a serial chain's own tile), is
+            # never compiled for a group, in a later step least of all
+            return 0
         values = [tasks[0].input_values()]
         sig = self._sig(values[0])
         programs = self._programs(tasks[0], chore, values[0], sig,
@@ -186,13 +213,30 @@ class TPUDevice(Device):
         # inputs of as many groups. The chip is far ahead wherever groups
         # form (GROUP_BYTES), so this wait is a check. A launch of one
         # neither waits nor is waited for: it overlaps the groups' waits
-        if group and self._group_out is not None:
+        # (what the lone ones still queued hold is bounded in _queued)
+        if group and self._group_out is not None and \
+                not self._group_out.is_deleted():   # given on: behind it
             self._group_out.block_until_ready()
         with self.jax.default_device(self.jax_device):
             results = program(*flat)
+        # what tells that the launch is over: an output of its last
+        # member, or the mark a program whose chore donates returns
+        # after its members (a later launch may be GIVEN any output)
+        done = self.jax.tree_util.tree_leaves(results[len(tasks) - 1])
+        own = len(results) > len(tasks)
+        mark = results[-1] if own else done[0] if done else None
         if group:
-            done = self.jax.tree_util.tree_leaves(results[-1])
-            self._group_out = done[0] if done else None
+            self._group_out = mark
+        elif mark is not None:
+            held = sum(x.nbytes for x in done)
+            if own:     # what the program was given it does not hold anew
+                held -= sum(x.nbytes for x in flat
+                            if getattr(x, "is_deleted", bool)())
+            if held > 0:
+                # a tile weakly: one its collection has dropped is done
+                # with
+                self._queued((lambda: mark) if own else weakref.ref(mark),
+                             held)
         names = [f.name for f in tasks[0].task_class.output_flows]
         for t, res in zip(tasks, results):
             t.output.update(normalize_outputs(res, names, t))
@@ -204,6 +248,31 @@ class TPUDevice(Device):
                 self.stats["batched_tasks"] += len(tasks)
             if tasks[0].taskpool.context.stage_timers:
                 self._count_launch(tasks[0], len(tasks))
+
+    def _queued(self, mark, nbytes) -> None:
+        """A launch of one holds ``nbytes`` of new outputs and is over
+        when the array ``mark()`` is. It neither waits for the
+        module's turn nor is waited for, so nothing else bounds how far
+        its thread runs ahead of a busy chip, and what a launch makes is
+        allocated when it is enqueued: a row of two-tile updates queued
+        30 deep held 1 GiB beside a 4.5 GiB matrix, 1.6% more or less
+        from run to run (PERF.md section 6, PR 33). So the lone launches
+        still on the chip's queue hold ``GROUP_BYTES`` of new outputs at
+        most, as a group does: the thread that enqueues more waits for
+        the oldest. A chip that keeps up has finished it long before,
+        and the wait is a look. (A launch whose outputs lie in buffers
+        it was given holds nothing new and is not counted.)"""
+        oldest = []
+        with self._lock:
+            self._lone.append((mark, nbytes))
+            self._lone_bytes += nbytes
+            while self._lone_bytes > GROUP_BYTES and len(self._lone) > 1:
+                ref, n = self._lone.popleft()
+                self._lone_bytes -= n
+                oldest.append(ref())
+        for leaf in oldest:
+            if leaf is not None and not leaf.is_deleted():
+                leaf.block_until_ready()
 
     # --------------------------------------------------------- staging
 
@@ -263,26 +332,31 @@ class TPUDevice(Device):
 
     # --------------------------------------------------- program table
 
-    @staticmethod
-    def _sizes(nbytes: int):
-        """The sizes of ``GROUP_SIZES`` that members of ``nbytes`` bytes
-        of inputs each may fill, largest first."""
-        return [size for size in GROUP_SIZES
-                if size * nbytes <= GROUP_BYTES]
-
-    def group_limit(self, task: Task) -> int:
-        """The most tasks like ``task`` one launch may carry, by the
-        inputs it holds now (a PTG task's collection reads are resolved
-        later: ``execute_group`` decides on the whole signature)."""
+    def _sizes(self, named, chore: Optional[Chore]):
+        """The sizes of ``GROUP_SIZES`` a launch of members like the one
+        whose ``(flow name, value)`` pairs are ``named`` may have,
+        largest first: the operands ``chore``'s stacked form shares
+        counted once, the others once a member."""
         leaves = self.jax.tree_util.tree_leaves
-        nbytes = 0
-        for v in task.data.values():
+        shared = chore.batch_hook_shared if chore is not None else None
+        nbytes = [0, 0]                         # a member's own, shared
+        for name, v in named:
             if isinstance(v, self._arrays):     # the common tile
-                nbytes += v.nbytes
-            elif v is not None:
-                nbytes += sum(getattr(leaf, "nbytes", 0)
-                              for leaf in leaves(v))
-        sizes = self._sizes(nbytes)
+                n = v.nbytes
+            elif v is None:
+                continue
+            else:
+                n = sum(getattr(leaf, "nbytes", 0) for leaf in leaves(v))
+            nbytes[bool(shared) and name in shared] += n
+        return [size for size in GROUP_SIZES
+                if nbytes[1] + size * nbytes[0] <= GROUP_BYTES]
+
+    def group_limit(self, task: Task, chore: Optional[Chore] = None) -> int:
+        """The most tasks like ``task`` one launch of ``chore`` may
+        carry, by the inputs it holds now (a PTG task's collection reads
+        are resolved later: ``execute_group`` decides on the whole
+        signature)."""
+        sizes = self._sizes(task.data.items(), chore)
         return sizes[0] if sizes else 0
 
     def _sig(self, values):
@@ -384,6 +458,27 @@ class TPUDevice(Device):
                                             range(td.num_leaves)])
                      for td in shape] for _ in range(size)]
 
+        # the leaves of a member the chore hands over (``Chore.donates``:
+        # the program writes the flow's output where its input lies, so
+        # a launch still queued holds nothing new for it)
+        given, at = [], 0
+        for f, td in zip(flows, shape):
+            if f.name in (chore.donates or ()):
+                given += range(at, at + td.num_leaves)
+            at += td.num_leaves
+
+        def donated(size):
+            return tuple(m * at + i for m in range(size) for i in given)
+
+        def marked(program):
+            # an output of such a program may be given to a later launch
+            # before anyone has waited for this one: it returns, after
+            # its members, one element of its first output to wait for
+            def fn(*flat):
+                res = program(*flat)
+                return res + (tu.tree_leaves(res)[0].ravel()[:1],)
+            return fn
+
         def unrolled(size):
             # flat and unrolled: no stack, no vmap, no slicing; each
             # output is its own buffer, whatever the size
@@ -405,26 +500,36 @@ class TPUDevice(Device):
         # wide; an unstable one stays with this chore
         stable, fp = compile_cache.function_fingerprint(
             hook if stacked else body)
-        shared = ("tpu_program", fp, stacked, reads, *slot) \
+        shared = ("tpu_program", fp, stacked, reads, tuple(given), *slot) \
             if stable else None
-        sizes = self._sizes(sum(getattr(leaf, "nbytes", 0)
-                                for leaf in tu.tree_leaves(values)))
+        sizes = self._sizes(zip((f.name for f in flows), values), chore)
         if not stacked:
             sizes = [1] if hook is not None else sizes + [1]
         programs, one = {}, None
         with jax.default_device(self.jax_device):
             for size in sizes:
                 fn = (stacked_over if stacked else unrolled)(size)
+                if given:
+                    fn = marked(fn)
+                # the program's name is what a device trace keeps of a
+                # launch (its "XLA Modules" line: jit_parsec_<class>_x<n>)
+                fn.__name__ = fn.__qualname__ = "parsec_%s_x%d" % (
+                    re.sub(r"\W", "_", getattr(task.task_class, "name",
+                                                "task")), size)
                 if shared is None:
-                    fn = jax.jit(fn)
+                    fn = jax.jit(fn, donate_argnums=donated(size))
                 else:
                     fn = compile_cache.cached_jit(
-                        fn, key=(*shared, size), persist=False)
+                        fn, key=(*shared, size), persist=False,
+                        donate_argnums=donated(size))
                 # a shared program this module has yet to run compiles
                 # for its chip now (an unshared one is new)
                 if shared is None or (shared, size) not in self._warmed:
                     self._warmed.add((shared, size))
                     one = one or self._flat([values])
-                    fn(*one * size)     # compiles; the result is dropped
+                    args = one * size
+                    for i in donated(size):     # the task's own stay
+                        args[i] = jax.numpy.copy(args[i])
+                    fn(*args)           # compiles; the result is dropped
                 programs[size] = fn
         return programs

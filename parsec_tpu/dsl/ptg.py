@@ -184,15 +184,17 @@ class PTGTaskClass(TaskClass):
     def body(self, fn: Callable = None, device: DeviceType = DeviceType.ALL,
              evaluate: Optional[Callable] = None, batchable: bool = True,
              batch_hook: Optional[Callable] = None,
-             batch_hook_shared=None):
+             batch_hook_shared=None, donates=None):
         """Attach an incarnation (JDF ``BODY [type=...] ... END``).
         ``batch_hook``/``batch_hook_shared``: optional hand-batched form
-        for the compiled executor (see core.task.Chore)."""
+        for the compiled executor; ``donates``: the RW flows a chip
+        module may update where they lie (see core.task.Chore)."""
         def deco(f):
             self.add_chore(Chore(device, f, evaluate=evaluate,
                                  batchable=batchable,
                                  batch_hook=batch_hook,
-                                 batch_hook_shared=batch_hook_shared))
+                                 batch_hook_shared=batch_hook_shared,
+                                 donates=donates))
             return f
         return deco(fn) if fn is not None else deco
 

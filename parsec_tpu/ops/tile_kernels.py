@@ -363,19 +363,6 @@ def trsm_upper_right(LU, C):
         left_side=False, lower=False).astype(C.dtype)
 
 
-# ---- tiled-QR kernels (DPLASMA dgeqrf tile operations) -----------------
-# Functional variant: the reference's Householder kernels (GEQRT/TSQRT/
-# UNMQR/TSMQR with compact V+T storage) are re-expressed with explicit
-# per-tile orthogonal factors — Q values flow between tasks as tiles,
-# which is what XLA can batch; compact-V storage is a memory optimization
-# tied to in-place BLAS that functional dataflow doesn't need.
-
-def geqrt_tile(A):
-    """Diagonal-tile QR: A = Q·R → (Q, R)."""
-    Q, R = jnp.linalg.qr(A.astype(jnp.float32), mode="complete")
-    return Q.astype(A.dtype), R.astype(A.dtype)
-
-
 # ---- panel QR (whole block-column at once, MXU-formulated) -------------
 # The compiled GEQRF path factors an entire (mk x nb) panel per step.
 # XLA's blocked-Householder QR serializes badly on TPU (measured ~20 ms
@@ -460,26 +447,247 @@ def panel_qr_apply(Vt, Xinv, Ct):
                             precision=_prec())).astype(Ct.dtype)
 
 
-def unmqr_tile(Q, C):
-    """C ← Qᵀ·C (apply a diagonal-tile factor to a row-panel tile)."""
-    out = jnp.matmul(Q.T, C, preferred_element_type=jnp.float32,
-                     precision=_prec())
-    return out.astype(C.dtype)
+# ---- tiled-QR kernels (DPLASMA dgeqrf tile operations) -----------------
+# PLASMA's four, in compact-WY form: a tile's orthogonal factor is a
+# product of nb/ib block reflectors Q = Q_1 ... Q_{nb/ib},
+# Q_j = I - V_j T_j V_j^T, T_j upper triangular ib x ib, the T_j side by
+# side in one ib x nb tile (core_zgeqrt's layout; ib = nb is one block).
+# V stays where upstream leaves it: unit lower below R in the diagonal
+# tile (GEQRT), the whole tile under it (TSQRT, V = [I; V2]).
+#
+# A column-by-column Householder loop is 2048 dependent trips a tile on
+# this chip, so a panel is factored the way panel_qr_tile is: by
+# Cholesky-QR on its Gram matrix and Householder reconstruction
+# (Ballard et al.), every flop above a _QR_BASE-wide block a matmul.
+#   TS case, [R; A] with R upper triangular: the thin factor's top block
+#   Q1 = R R'^-1 is upper triangular, so with R' = -S chol(R^T R + A^T A)^T
+#   (S the signs of diag R: diag Q1 <= 0) V2 = A (R - R')^-1, one solve.
+#   Tall case (rows >= 2 x columns): Q from shifted Cholesky-QR, V = L of
+#   the unpivoted LU of S - Q, the signs S chosen pivot by pivot (every
+#   pivot >= 1). The one block of a diagonal tile that is nearly square
+#   goes through XLA's Householder QR: a Gram matrix cannot carry it.
+# T is then MADE from V: T^-1 = striu(V^T V) + diag(V^T V)/2, the
+# identity that makes I - V T V^T orthogonal whatever V is, computed at
+# full f32. So the stored (V, T) give an orthogonal Q to f32 rounding
+# however the update matmuls are rounded (ops.matmul_precision), and what
+# the Gram route loses (kappa(panel)^2 eps of R': the TS panels [R; A]
+# and the tall panels of a matrix of full column rank have kappa under
+# ~50) shows in A - QR beside the updates' own bf16 rounding, not in
+# Q^T Q. A rank-deficient panel is not carried (NaN), as under
+# ops.panel_qr=cholqr2.
+
+_QR_BASE = 256      # widest panel factored without splitting its columns
+_F32 = jnp.float32
 
 
-def tsqrt_tile(R, A):
-    """Triangular-on-top-of-square QR: [R; A] = Q₂·R' → (Q₂, R').
-    Q₂ is the full (2nb × 2nb) factor; R' the updated nb × nb triangle."""
-    nb = R.shape[0]
-    S = jnp.concatenate([R, A], axis=0).astype(jnp.float32)
-    Q2, Rfull = jnp.linalg.qr(S, mode="complete")
-    return Q2.astype(R.dtype), Rfull[:nb].astype(R.dtype)
+def _mm(a, b):
+    """An update's product, at the configured precision."""
+    return jnp.matmul(a, b, preferred_element_type=_F32, precision=_prec())
 
 
-def tsmqr_tile(Q2, C1, C2):
-    """Apply a TSQRT factor to a stacked pair: [C1; C2] ← Q₂ᵀ·[C1; C2]."""
-    nb = C1.shape[0]
-    S = jnp.concatenate([C1, C2], axis=0)
-    out = jnp.matmul(Q2.T, S, preferred_element_type=jnp.float32,
-                     precision=_prec()).astype(C1.dtype)
-    return out[:nb], out[nb:]
+def _mmh(a, b):
+    """A product T or a panel's R is made of: always full float32."""
+    return jnp.matmul(a, b, preferred_element_type=_F32,
+                      precision="highest")
+
+
+def _larft(G):
+    """T of the block reflector I - V T V^T from G = V^T V."""
+    b = G.shape[0]
+    t_inv = jnp.triu(G) - 0.5 * jnp.diag(jnp.diagonal(G))
+    return jax.lax.linalg.triangular_solve(
+        t_inv, jnp.eye(b, dtype=_F32), left_side=True, lower=False)
+
+
+def _merge_t(T1, T2, V1tV2):
+    """T of Q1 Q2 from the halves' and V1^T V2."""
+    T12 = -_mmh(T1, _mmh(V1tV2, T2))
+    return jnp.concatenate(
+        [jnp.concatenate([T1, T12], axis=1),
+         jnp.concatenate([jnp.zeros(T12.T.shape, _F32), T2], axis=1)],
+        axis=0)
+
+
+def _lu_signed(W):
+    """Unpivoted LU of S + W with S = diag(+-1) chosen as it goes, each
+    sign its pivot's own, so that every pivot is at least 1 in size
+    (the modified LU of Ballard et al.; signs fixed beforehand from
+    diag W leave a square orthogonal block of the wrong determinant
+    with a pivot of 0) -> (packed LU, the signs)."""
+    n = W.shape[0]
+    idx = jnp.arange(n)
+
+    def step(i, carry):
+        M, s = carry
+        si = jnp.where(M[i, i] >= 0, 1.0, -1.0).astype(_F32)
+        piv = M[i, i] + si
+        col = jnp.where(idx > i, M[:, i] / piv, 0.0)
+        row = jnp.where(idx > i, M[i, :], 0.0)
+        M = M - col[:, None] * row[None, :]
+        M = M.at[:, i].set(jnp.where(idx > i, col, M[:, i]))
+        return M.at[i, i].set(piv), s.at[i].set(si)
+
+    return jax.lax.fori_loop(0, n, step, (W, jnp.ones((n,), _F32)))
+
+
+def _qr_householder(P):
+    """:func:`_qr_base` of a panel under twice as tall as wide, by XLA's
+    own Householder QR (LAPACK's ``geqrf``: V and the ``tau``): a square
+    block of a random tile is singular to float32 once in a few thousand
+    (kappa over 1e6: four in a run of the 32768-cell), and there a Gram
+    matrix's Cholesky gives NaN whatever its shift. T from V and tau,
+    ``T^-1 = striu(V^T V) + diag(1/tau)``, as ``(I + D S)^-1 D``: a
+    ``tau`` of 0 (a column with nothing under its diagonal) is no 1/0."""
+    b = P.shape[1]
+    h, tau = jnp.linalg.qr(P, mode="raw")           # h: LAPACK's, transposed
+    a = h.T
+    eye = jnp.eye(b, dtype=_F32)
+    V = jnp.tril(a, -1) + jnp.eye(*a.shape, dtype=_F32)
+    S = jnp.triu(_mmh(V.T, V), 1)
+    T = jax.lax.linalg.triangular_solve(
+        eye + tau[:, None] * S, jnp.diag(tau), left_side=True, lower=False,
+        unit_diagonal=True)
+    return V, T, jnp.triu(a[:b])
+
+
+def _qr_base(P):
+    """Thin QR of P (m x b, m >= b) -> (V unit lower trapezoid, T, R)."""
+    m, b = P.shape
+    if m < 2 * b:
+        return _qr_householder(P)
+    eye = jnp.eye(b, dtype=_F32)
+    Q, R = P, eye
+    # a panel at least twice as tall as wide: two shifted rounds bring
+    # any kappa float32 can tell from singular to where the two plain
+    # ones end at rounding level (full column rank asked, as
+    # ops.panel_qr=cholqr2 asks it)
+    for shift in (2e-5, 2e-5, 0.0, 0.0):
+        G = _mmh(Q.T, Q)
+        if shift:
+            G = G + (shift * jnp.trace(G)) * eye
+        L = jnp.linalg.cholesky(G)
+        Q = jax.lax.linalg.triangular_solve(
+            L, Q, left_side=False, lower=True, transpose_a=True)
+        R = _mmh(L.T, R)
+    LU, s = _lu_signed(-Q[:b])
+    V = jnp.tril(LU, -1) + eye
+    if m > b:
+        V = jnp.concatenate([V, jax.lax.linalg.triangular_solve(
+            jnp.triu(LU), -Q[b:], left_side=False, lower=False)], axis=0)
+    return V, _larft(_mmh(V.T, V)), jnp.triu(s[:, None] * R)
+
+
+def _qr_rec(P):
+    """:func:`_qr_base` of a panel of any width, its columns halved
+    down to ``_QR_BASE`` (Elmroth-Gustavson)."""
+    b = P.shape[1]
+    if b <= _QR_BASE or b % 2:
+        return _qr_base(P)
+    h = b // 2
+    V1, T1, R11 = _qr_rec(P[:, :h])
+    C = P[:, h:]
+    C = C - _mm(V1, _mm(T1.T, _mm(V1.T, C)))
+    V2, T2, R22 = _qr_rec(C[h:])
+    T = _merge_t(T1, T2, _mmh(V1[h:].T, V2))
+    V = jnp.concatenate(
+        [V1, jnp.concatenate([jnp.zeros((h, b - h), _F32), V2], axis=0)],
+        axis=1)
+    R = jnp.concatenate(
+        [jnp.concatenate([R11, C[:h]], axis=1),
+         jnp.concatenate([jnp.zeros((b - h, h), _F32), R22], axis=1)],
+        axis=0)
+    return V, T, R
+
+
+def _tsqr_base(R, A):
+    """[R; A] = Q [R'; 0], R upper triangular (b x b), A (m x b), with
+    Q = I - [I; V2] T [I; V2]^T -> (V2, T, R')."""
+    L = jnp.linalg.cholesky(_mmh(R.T, R) + _mmh(A.T, A))
+    s = jnp.where(jnp.diagonal(R) >= 0, -1.0, 1.0).astype(_F32)
+    Rn = jnp.triu(s[:, None] * L.T)
+    V2 = jax.lax.linalg.triangular_solve(
+        jnp.triu(R) - Rn, A, left_side=False, lower=False)
+    G = jnp.eye(R.shape[0], dtype=_F32) + _mmh(V2.T, V2)
+    return V2, _larft(G), Rn
+
+
+def _tsqr_rec(R, A):
+    b = R.shape[0]
+    if b <= _QR_BASE or b % 2:
+        return _tsqr_base(R, A)
+    h = b // 2
+    V1, T1, R11 = _tsqr_rec(R[:h, :h], A[:, :h])
+    W = _mm(T1.T, R[:h, h:] + _mm(V1.T, A[:, h:]))
+    V2, T2, R22 = _tsqr_rec(R[h:, h:], A[:, h:] - _mm(V1, W))
+    T = _merge_t(T1, T2, _mmh(V1.T, V2))
+    Rn = jnp.concatenate(
+        [jnp.concatenate([R11, R[:h, h:] - W], axis=1),
+         jnp.concatenate([jnp.zeros((b - h, h), _F32), R22], axis=1)],
+        axis=0)
+    return jnp.concatenate([V1, V2], axis=1), T, Rn
+
+
+def geqrt_tile(A, ib: int):
+    """GEQRT: A = Q R -> (the tile with R in its upper triangle and V,
+    unit lower, under it; T, ib x nb)."""
+    nb = A.shape[1]
+    Af = jnp.asarray(A, _F32)
+    cols, Ts = [], []
+    for o in range(0, nb, ib):
+        V, T, R = _qr_rec(Af[o:, o:o + ib])
+        packed = jnp.tril(V, -1).at[:ib].add(R)
+        cols.append(jnp.concatenate([Af[:o, o:o + ib], packed], axis=0))
+        Ts.append(T)
+        if o + ib < nb:
+            C = Af[o:, o + ib:]
+            Af = Af.at[o:, o + ib:].set(
+                C - _mm(V, _mm(T.T, _mm(V.T, C))))
+    return (jnp.concatenate(cols, axis=1).astype(A.dtype),
+            jnp.concatenate(Ts, axis=1).astype(A.dtype))
+
+
+def unmqr_tile(V, T, C):
+    """UNMQR: C <- Q^T C, Q as :func:`geqrt_tile` left it (``V`` its
+    tile: what lies on and above the diagonal is R and is not read)."""
+    ib, nb = T.shape
+    Cf = jnp.asarray(C, _F32)
+    for o in range(0, nb, ib):
+        Vj = jnp.tril(jnp.asarray(V[o:, o:o + ib], _F32), -1) + \
+            jnp.eye(V.shape[0] - o, ib, dtype=_F32)
+        Tj = jnp.asarray(T[:, o:o + ib], _F32)
+        Cf = Cf.at[o:].add(-_mm(Vj, _mm(Tj.T, _mm(Vj.T, Cf[o:]))))
+    return Cf.astype(C.dtype)
+
+
+def tsqrt_tile(A1, A2, ib: int):
+    """TSQRT: [R; A2] = Q [R'; 0] with R = triu(A1) -> (A1 with R' in
+    its upper triangle, what lies under it kept; V2; T, ib x nb)."""
+    nb = A1.shape[0]
+    Rf, Af = jnp.triu(jnp.asarray(A1, _F32)), jnp.asarray(A2, _F32)
+    Vs, Ts = [], []
+    for o in range(0, nb, ib):
+        J = slice(o, o + ib)
+        V2, T, Rjj = _tsqr_rec(Rf[J, J], Af[:, J])
+        Rf = Rf.at[J, J].set(Rjj)
+        Vs.append(V2)
+        Ts.append(T)
+        if o + ib < nb:
+            W = _mm(T.T, Rf[J, o + ib:] + _mm(V2.T, Af[:, o + ib:]))
+            Rf = Rf.at[J, o + ib:].add(-W)
+            Af = Af.at[:, o + ib:].add(-_mm(V2, W))
+    return ((jnp.tril(jnp.asarray(A1, _F32), -1) + Rf).astype(A1.dtype),
+            jnp.concatenate(Vs, axis=1).astype(A2.dtype),
+            jnp.concatenate(Ts, axis=1).astype(A2.dtype))
+
+
+def tsmqr_tile(V2, T, C1, C2):
+    """TSMQR: [C1; C2] <- Q^T [C1; C2], Q as :func:`tsqrt_tile` left it."""
+    ib, nb = T.shape
+    C1f, C2f = jnp.asarray(C1, _F32), jnp.asarray(C2, _F32)
+    for o in range(0, nb, ib):
+        J = slice(o, o + ib)
+        Vj, Tj = jnp.asarray(V2[:, J], _F32), jnp.asarray(T[:, J], _F32)
+        W = _mm(Tj.T, C1f[J] + _mm(Vj.T, C2f))
+        C1f = C1f.at[J].add(-W)
+        C2f = C2f - _mm(Vj, W)
+    return C1f.astype(C1.dtype), C2f.astype(C2.dtype)

@@ -229,7 +229,7 @@ def test_a_mixed_ready_set_leaves_in_groups_by_class(
     assert compile_cache.backend_compile_count() == compiled
     # groups off: the module launches every task alone, the same bits
     groups = _groups(ctx)
-    monkeypatch.setattr(dev, "group_limit", lambda task: 0)
+    monkeypatch.setattr(dev, "group_limit", lambda task, chore=None: 0)
     alone = _potrf(ctx, a0)
     assert _groups(ctx) == groups
     assert all(np.array_equal(tiles[k], alone[k]) and
@@ -756,3 +756,37 @@ def test_a_warmed_pool_of_lone_tasks_constructs_no_chore(
     assert [a[0] for a in made] == [DeviceType.CPU]
     assert _groups(ctx) == (0, 0) and _module(ctx).stats["tasks"] == 6
     assert (x.to_array() == 3.0).all()
+
+
+def test_the_lone_launches_still_queued_hold_group_bytes_at_most(
+        make_ctx, monkeypatch):
+    """A launch of one is not waited for by the next, so its thread could
+    run any distance ahead of a busy chip, every launch's outputs
+    allocated as it is enqueued: the module keeps what the lone launches
+    still queued have made within ``GROUP_BYTES`` (the thread that
+    enqueues more waits for the oldest), and keeps none of it alive."""
+    import weakref
+    from parsec_tpu.core.task import Chore, Flow, FlowAccess, Task
+    from parsec_tpu.core.taskpool import Taskpool
+    tile = 8 * 8 * 4
+    monkeypatch.setattr(parsec_tpu.device.tpu, "GROUP_BYTES", 3 * tile)
+    ctx = make_ctx()
+    dev = _module(ctx)
+    tp = Taskpool("lone")
+    tc = tp.new_task_class("H", params=("i",),
+                           flows=[Flow("x", FlowAccess.RW)])
+    chore = Chore(DeviceType.TPU, _hooked, batch_hook=_hooked_stacked)
+    tc.add_chore(chore)
+    tp.context = ctx
+    seen = []
+    for i in range(12):
+        t = Task(tp, tc, (i,))
+        t.data["x"] = np.full((8, 8), float(i), np.float32)
+        dev.execute(None, t, chore)
+        assert (np.asarray(t.output["x"]) == 2.0 * i).all()
+        seen.append((dev._lone_bytes, len(dev._lone)))
+    assert max(b for b, _ in seen) == 3 * tile
+    assert [n for _, n in seen] == [1, 2] + [3] * 10
+    # weakly: a tile nobody holds any more is done with, and is not kept
+    assert all(isinstance(ref, weakref.ref) and ref() is None
+               for ref, _n in list(dev._lone)[:-1])
