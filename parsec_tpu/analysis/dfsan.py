@@ -186,9 +186,10 @@ class DataflowSanitizer(PinsModule):
                   native_ok=True)
         self._sub(PinsEvent.COMPLETE_EXEC_END, self._complete_end,
                   native_ok=True)
-        # adopt taskpools registered before install
+        # adopt the taskpools running at install (a finished one has no
+        # dependency left to count)
         with context._lock:
-            pools = list(context._taskpools_by_name.values())
+            pools = list(context._active_taskpools)
         for tp in pools:
             self._taskpool_init(tp)
         from ..core.datarepo import DataRepo
@@ -203,7 +204,7 @@ class DataflowSanitizer(PinsModule):
         if getattr(self.context, "dfsan", None) is self:
             self.context.dfsan = None
         with self.context._lock:
-            pools = list(self.context._taskpools_by_name.values())
+            pools = list(self.context._active_taskpools)
         for tp in pools:
             if getattr(tp.pending, "sanitizer", None) is self:
                 tp.pending.sanitizer = None

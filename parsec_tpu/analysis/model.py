@@ -11,7 +11,7 @@ check here.
 
 The model never runs task bodies: producer-side expansion walks the
 ``FlowSpec.outs`` declarations directly (the same closures
-``PTGTaskClass._iterate_successors`` evaluates), so building it is pure
+``PTGTaskClass.iterate_successors`` evaluates), so building it is pure
 and side-effect free.  Spaces are bounded by construction in PTG;
 ``max_tasks`` caps the enumeration so a registration-time lint on a huge
 taskpool degrades to the structural (per-class) checks instead of

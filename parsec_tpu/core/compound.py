@@ -17,9 +17,10 @@ class CompoundTaskpool(Taskpool):
         super().__init__(name="compound(" + "+".join(m.name for m in members) + ")")
         self.members = list(members)
         self._next = 0
-        self.startup_hook = self._compound_startup
+        # handed its pool: not a method bound to the pool it is stored on
+        self.startup_hook = CompoundTaskpool._compound_startup
 
-    def _compound_startup(self, tp) -> List:
+    def _compound_startup(self) -> List:
         # one synthetic task: "run all members in sequence"
         self.set_nb_tasks(1)
         self._start_next()
@@ -35,6 +36,9 @@ class CompoundTaskpool(Taskpool):
         prev_cb = member.on_complete
 
         def _chain(tp, _prev=prev_cb):
+            # the member is its owner's again: a finished compound and
+            # its members hold each other no longer
+            tp.on_complete = _prev
             if _prev is not None:
                 _prev(tp)
             if tp.error is not None:

@@ -199,9 +199,19 @@ class Context:
         # fire the per-task EXEC hooks, by design)
         self._ndtd_tenant_totals: Dict[str, int] = {}
         self._active_taskpools: List[Taskpool] = []
-        # name → taskpool, kept past termination: late control traffic
-        # (DTD flush writebacks/acks) must still find its taskpool
-        self._taskpools_by_name: Dict[str, Taskpool] = {}
+        # name → taskpool, past termination too: late control traffic
+        # (DTD flush writebacks/acks) must still find its taskpool. Held
+        # weakly: a finished pool is addressable as long as somebody
+        # can still address it (the rank inside its collective flush
+        # holds it), and the Context keeps no pool, and no matrix a pool
+        # refers to, for its own life
+        self._taskpools_by_name: "weakref.WeakValueDictionary[str, Taskpool]" \
+            = weakref.WeakValueDictionary()
+        # what add_taskpool exposed to the peers' one-sided fetches
+        self._exposed: Dict[int, object] = {}
+        # pools added and pools that ended (statusz "taskpools")
+        self._taskpools_added = 0
+        self._taskpools_terminated = 0
         self._aborted: List[Taskpool] = []
         self._started = False
         self._shutdown = False
@@ -297,7 +307,9 @@ class Context:
             tp.validate(mode=lint_mode)
         if tp.monitor is None:
             tp.monitor = termdet_mod.new_monitor(comm=self.comm)
-        tp.monitor.monitor(tp._on_terminated)
+        # weakly: the pool holds its monitor, and a monitor that held
+        # the pool's bound method would make every pool a cycle
+        tp.monitor.monitor(_weakly(tp._on_terminated))
         if self.comm is not None and hasattr(self.comm, "register_termdet"):
             self.comm.register_termdet(tp.name, tp.monitor)
         tp.context = self
@@ -311,9 +323,16 @@ class Context:
                 if hasattr(obj, "data_of") and hasattr(obj, "rank_of") \
                         and hasattr(obj, "name"):
                     self.comm.expose_collection(obj, scope=tp.name)
+                    # a peer fetches from it after this rank's part of
+                    # the pool has ended and its user has gone on, and
+                    # no rank is told when the last peer is through: the
+                    # collection (not the pool) stays to the Context's
+                    # end, across ranks alone
+                    self._exposed[id(obj)] = obj
         with self._lock:
             self._active_taskpools.append(tp)
             self._taskpools_by_name[tp.name] = tp
+            self._taskpools_added += 1
         if self.comm is not None and hasattr(self.comm, "taskpool_registered"):
             # drain parked activations; False = registration refused
             # (broken mesh) — the engine already aborted the pool, so
@@ -572,11 +591,20 @@ class Context:
         listener (``serving.metrics_port``)."""
         with self._lock:
             active = [tp.name for tp in self._active_taskpools]
+            # pools that ended against pools the Context can still
+            # address: the active ones and, weakly, whichever finished
+            # ones their users still hold
+            pools = {"added": self._taskpools_added,
+                     "terminated": self._taskpools_terminated,
+                     "referenced": len({id(tp) for tp in (
+                         *self._active_taskpools,
+                         *self._taskpools_by_name.values())})}
         out = {
             "rank": self.my_rank,
             "nb_ranks": self.nb_ranks,
             "scheduler": self.scheduler.name,
             "active_taskpools": active,
+            "taskpools": pools,
             "streams": {es.th_id: dict(es.stats) for es in self.streams},
             "metrics": self.metrics.to_dict(),
         }
@@ -704,7 +732,9 @@ class Context:
 
     def find_taskpool(self, name: str, active_only: bool = True):
         """Lookup by name; ``active_only=False`` includes terminated pools
-        (control traffic like DTD flush outlives termination)."""
+        that somebody still holds (control traffic like DTD flush
+        outlives termination; the rank it reaches is inside the same
+        collective flush, with the pool in hand)."""
         with self._lock:
             if active_only:
                 return next((t for t in self._active_taskpools
@@ -720,6 +750,7 @@ class Context:
         with self._cv:
             try:
                 self._active_taskpools.remove(tp)
+                self._taskpools_terminated += 1
             except ValueError:
                 pass
             if tp.error is not None and tp not in self._aborted and \
@@ -865,6 +896,9 @@ class Context:
                 # successors can never fire: abort the pool so waiters are
                 # released with the error instead of hanging (parsec_abort)
                 task.taskpool.abort(exc)
+            # a parked worker holds no task: its pool, and the tiles in
+            # the task's data, would live until this worker's next one
+            task = found = None
 
     def _select(self, es: ExecutionStream) -> Optional[Task]:
         if not self.stage_timers:
@@ -1406,6 +1440,20 @@ class _CkptState:
             return True
         t.join(timeout)
         return not t.is_alive()
+
+
+def _weakly(method):
+    """``method`` (a bound method of no arguments) as a callable that
+    does not keep its object: a call after the object is gone does
+    nothing."""
+    ref = weakref.WeakMethod(method)
+
+    def call():
+        live = ref()
+        if live is not None:
+            live()
+
+    return call
 
 
 def _hbm_entry_dead(_key, entry) -> bool:

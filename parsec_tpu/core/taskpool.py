@@ -317,6 +317,11 @@ class _PendingDeps:
                 return ent
             return None
 
+    def drop_values(self) -> None:
+        """The pool has ended and no entry left here will complete: let
+        go of the values that arrived for them."""
+        self._entries.clear()
+
     def __len__(self) -> int:
         if self._native is not None:
             return int(self._native_lib.pdep_size(self._native))

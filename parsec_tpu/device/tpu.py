@@ -119,11 +119,14 @@ class TPUDevice(Device):
             from ..comm import device_plane
             device_plane.set_stage_target(self.jax_device)
         # THE program table, {id(chore): {(pure body's key, input
-        # signature, stacked?): {size: program}}}, a chore's record
-        # dropped when the chore dies (it also holds the chore's pinned
-        # hook, ``_pinned``); and the process-shared programs this
-        # module has already run once
+        # signature, stacked?): {size: program}}} (a record also holds
+        # the chore's pinned hook, ``_pinned``), with the chore each
+        # record is of, weakly: a chore dies with its pool, and the
+        # records of the dead go when the next record is made
+        # (``_record``); and the process-shared programs this module has
+        # already run once
         self._table: Dict[int, Dict[Any, Any]] = {}
+        self._chores: Dict[int, "weakref.ref[Chore]"] = {}
         self._table_lock = threading.Lock()
         self._warmed: set = set()
         # one group in flight: held from taking the tasks to the last
@@ -398,16 +401,22 @@ class TPUDevice(Device):
 
     def _record(self, chore: Chore) -> Dict[Any, Any]:
         """This module's record of ``chore``."""
-        mine = self._table.get(id(chore))
-        if mine is None:
-            with self._table_lock:
-                mine = self._table.get(id(chore))
-                if mine is None:
-                    mine = self._table[id(chore)] = {}
-                    # id(chore) is reused once the pool's chore is gone
-                    weakref.finalize(chore, self._table.pop, id(chore),
-                                     None)
-        return mine
+        cid = id(chore)
+        of = self._chores.get(cid)
+        if of is not None and of() is chore:
+            return self._table[cid]
+        with self._table_lock:
+            of = self._chores.get(cid)
+            if of is None or of() is not chore:
+                # id(chore) is reused once a pool's chore is gone: the
+                # records of the chores that have died go now, this id's
+                # among them
+                for dead in [i for i, ref in self._chores.items()
+                             if ref() is None]:
+                    del self._chores[dead], self._table[dead]
+                self._table[cid] = {}
+                self._chores[cid] = weakref.ref(chore)
+            return self._table[cid]
 
     def _programs(self, task: Task, chore: Chore, values, sig,
                   stacked: bool = False) -> Dict[int, Callable]:

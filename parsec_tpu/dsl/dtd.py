@@ -163,14 +163,18 @@ class _TileBank:
     tile without a writer holds nothing a fresh one would not (its
     version is the collection's), so a later insert on it starts over.
     The blocking ``Taskpool.flush`` waits and takes nothing out.
-    ``peak`` is the most tiles tracked at once, ``retired`` those a
-    flush took out."""
+    What is still tracked when the pool ends goes then
+    (``Taskpool._let_go``): a finished pool holds no tile, and through
+    it no collection. ``peak`` is the most tiles tracked at once,
+    ``retired`` those a flush took out, ``dropped`` those the pool's
+    end did."""
 
     def __init__(self) -> None:
         self._tiles: Dict[Tuple[int, Any], _Tile] = {}
         self._lock = threading.Lock()
         self.peak = 0
         self.retired = 0
+        self.dropped = 0
 
     def tile_of(self, dc: DataCollection, key) -> _Tile:
         hkey = (dc.dc_id, tuple(key) if isinstance(key, (tuple, list)) else key)
@@ -226,6 +230,16 @@ class _TileBank:
                 del self._tiles[hkey]
                 self.retired += 1
 
+    def drop(self, collection: Optional[DataCollection] = None) -> None:
+        """The pool has ended and every version is where it belongs:
+        stop tracking what is left (of ``collection``, if given)."""
+        with self._lock:
+            keep = {} if collection is None else {
+                k: t for k, t in self._tiles.items()
+                if t.collection is not collection}
+            self.dropped += len(self._tiles) - len(keep)
+            self._tiles = keep
+
     def readopt(self, tile: _Tile) -> None:
         """A writer inserted after ``tile``'s flush (the caller holds its
         lock): the flush was of the uses before it, and the tile, which
@@ -233,6 +247,19 @@ class _TileBank:
         tile.flushed = False
         with self._lock:
             self._tiles.setdefault((tile.collection.dc_id, tile.key), tile)
+
+
+# a DTD class's callbacks (``_task_class_for``): functions of the task
+def _data_lookup(task: Task) -> None:
+    """prepare_input analog: resolve aliased flows (same tile passed
+    twice in one insert) from their primary flow's delivered value."""
+    for alias, primary in task.dsl.get("aliases", {}).items():
+        if alias not in task.data:
+            task.data[alias] = task.data.get(primary)
+
+
+def _iterate_successors(task: Task):
+    return task.taskpool._iterate_successors(task)
 
 
 class Taskpool(CoreTaskpool):
@@ -351,24 +378,55 @@ class Taskpool(CoreTaskpool):
             self._closed = self._closed or (self.error is not None)
             self._inflight_cv.notify_all()
         eng = self._native
+        ctx = self.context
         if eng is not None:
             if self.error is not None:
                 # abort/cancel: release the native queues (queued tasks
                 # drop at select time) and any natively-parked inserter
                 eng.cancel()
-            ctx = self.context
             if ctx is not None:
                 # fold the engine's counters into the context totals so
                 # parsec_tasks_completed_total survives the pool; an
                 # aborted pool with tasks still in flight keeps its
                 # engine pumped until they drain (retiring state)
                 ctx._ndtd_retire(eng)
-        if self.counters and self.context is not None and \
-                not self._complete_evt.is_set():
-            self.context.fold_dtd_counters(dict(
-                self.counters, dtd_tiles_tracked_peak=self.tiles.peak,
-                dtd_tiles_flushed=self.tiles.retired))
+        if not self._complete_evt.is_set():
+            # before the waiter is let go: what it drops next is free
+            self._let_go()
+            if ctx is not None and (self.counters or self.insert_calls):
+                ctx.fold_dtd_counters(dict(
+                    self.counters, dtd_tiles_tracked_peak=self.tiles.peak,
+                    dtd_tiles_flushed=self.tiles.retired,
+                    dtd_tiles_dropped_at_end=self.tiles.dropped,
+                    dtd_insert_s=self.insert_s,
+                    dtd_insert_calls=self.insert_calls))
         super()._on_terminated()
+
+    def _let_go(self) -> None:
+        """The pool has ended: it refers to none of its user's data
+        from here on. Every produced version is in its collection
+        (``_iterate_successors`` step 1), so the bank's tiles, each of
+        which holds its collection, hold nothing a fresh one would not.
+        Across ranks a tile's current version may lie away from its
+        owner until the collective :meth:`flush` that follows
+        ``wait()`` has sent it home, and a late write-back finds its
+        collection through the bank: there the bank stays while any
+        tile is held away (every rank replays the same holders, so
+        every rank decides alike), and what a flush has sent home goes
+        when that flush is through (``_flush_distributed``), the rest
+        with the pool. An aborted pool's tasks still drain: it keeps
+        what they read until it goes itself."""
+        if self.error is not None:
+            return
+        if self.nb_ranks > 1 and any(
+                t.holder_rank is not None and
+                t.holder_rank != t.collection.rank_of(t.key)
+                for t in self.tiles.all()):
+            return
+        self.tiles.drop()
+        self._tasks_by_seq.clear()
+        self._goals.clear()
+        self.pending.drop_values()
 
     # ------------------------------------------------------------- classes
     def _task_class_for(self, fn: Callable, shape: Tuple,
@@ -399,9 +457,15 @@ class Taskpool(CoreTaskpool):
             # task identity is the insertion sequence number — identical on
             # every rank, so activations address tasks unambiguously
             tc.make_key = lambda locals: ("dtd", locals[0])
-            tc.deps_goal = lambda locals: self._goals.get(locals[0], _GOAL_UNSET)
-            tc.iterate_successors = self._iterate_successors
-            tc.data_lookup = self._data_lookup
+            # the pool holds its classes, so a class holds the pool's
+            # table and plain functions that find the pool through the
+            # task, never the pool or a bound method of it: a finished
+            # pool that its user drops is freed there and then, by
+            # reference count
+            tc.deps_goal = lambda locals, _goals=self._goals: \
+                _goals.get(locals[0], _GOAL_UNSET)
+            tc.iterate_successors = _iterate_successors
+            tc.data_lookup = _data_lookup
 
             if pure:
                 # pure=True contract (insert_task): fn is a pure
@@ -1014,13 +1078,6 @@ class Taskpool(CoreTaskpool):
         self.context.comm.remote_dep_activate(shim, ref, target_rank)
 
     # ----------------------------------------------------- class callbacks
-    def _data_lookup(self, task: Task) -> None:
-        """prepare_input analog: resolve aliased flows (same tile passed
-        twice in one insert) from their primary flow's delivered value."""
-        for alias, primary in task.dsl.get("aliases", {}).items():
-            if alias not in task.data:
-                task.data[alias] = task.data.get(primary)
-
     def _iterate_successors(self, task: Task):
         ctx = self.context
         san = ctx.dfsan if ctx is not None else None
@@ -1289,6 +1346,11 @@ class Taskpool(CoreTaskpool):
                 self._flush_cv.wait(timeout=min(0.05, left))
             self._flush_acks -= sent
         comm.sync()
+        if self.completed and self.error is None:
+            # past the barrier every rank has its acks: what this flush
+            # sent home is at home everywhere, and a pool that has ended
+            # has no more use for its tracking (``_let_go``)
+            self.tiles.drop(collection)
 
     def _on_dtd_control(self, src: int, msg: Dict) -> None:
         """Handle DTD control AMs (flush writebacks + acks); invoked by

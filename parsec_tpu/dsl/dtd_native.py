@@ -83,6 +83,7 @@ from __future__ import annotations
 import ctypes
 import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -209,7 +210,14 @@ class NativeDTD:
     """Per-taskpool driver of the native ``pdtd_*`` engine."""
 
     def __init__(self, tp, lib):
-        self.tp = tp
+        # the pool holds its engine (``tp._native``, which says which
+        # engine ran long after the end); the engine holds the pool
+        # while the workers pump it and lets go once it is folded
+        # (``release_refs``), so a finished pool and its engine are no
+        # cycle. ``tp`` reads through the weak half: whoever reaches a
+        # folded engine came through its pool
+        self._tp_held = tp
+        self._tp_ref = weakref.ref(tp)
         self.lib = lib
         ctx = tp.context
         self.nworkers = ctx.nb_cores
@@ -835,6 +843,10 @@ class NativeDTD:
         self._cancelled = True
         self.lib.pdtd_cancel(self._e)
 
+    @property
+    def tp(self):
+        return self._tp_ref()
+
     def inflight(self) -> int:
         return int(self.lib.pdtd_inflight(self._e))
 
@@ -847,6 +859,7 @@ class NativeDTD:
         itself is collected."""
         self.rows.clear()
         self.outputs.clear()
+        self._tp_held = None
         if self._dfsan_manifest is not None:
             self._dfsan_manifest.clear()
             self._dfsan_commits.clear()

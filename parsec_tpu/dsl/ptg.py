@@ -51,6 +51,7 @@ Example (tiled Cholesky's POTRF class)::
 from __future__ import annotations
 
 import types
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -164,21 +165,35 @@ class PTGTaskClass(TaskClass):
         mode = DEPS_COUNTER if any(d.gather for s in specs
                                    for d in s.ins) else DEPS_MASK
         super().__init__(name, tc_id, params, flows, deps_mode=mode)
-        self.tp = tp
+        # the pool holds its classes; a class reaches its pool weakly
+        # (``tp``), holds the globals themselves and takes its vtable
+        # from the methods below, so that neither a class nor its pool
+        # is a cycle: a finished pool that its user drops is freed there
+        # and then, with the collections in ``g``, by reference count
+        self._tp = weakref.ref(tp)
+        self.g = tp.g
         self.specs = {s.name: s for s in specs}
         self.spec_list = specs
         self.space = space
         self.affinity = affinity
         if priority is not None:
-            self.priority_fn = lambda locals: priority(tp.g, *locals)
-        self.iterate_successors = self._iterate_successors
-        self.deps_goal = self._deps_goal
-        self.data_lookup = self._data_lookup
+            self.priority_fn = lambda locals, _g=tp.g: priority(_g, *locals)
+        del self.iterate_successors, self.deps_goal
         # deps_goal runs once per ARRIVING activation (activate_dep), so
         # gather classes would re-enumerate their N-element target list
         # N times without this (the reference computes goals once per
         # task instance); the closed form is pure, so cache per locals
         self._goal_cache: Dict[Tuple[int, ...], int] = {}
+
+    @property
+    def tp(self) -> "Taskpool":
+        """The class's pool (which holds the class, not the reverse)."""
+        tp = self._tp()
+        if tp is None:
+            raise ReferenceError(
+                f"task class {self.name}: its taskpool is gone (hold the "
+                f"taskpool, not only a class of it)")
+        return tp
 
     # -- body decorators --------------------------------------------------
     def body(self, fn: Callable = None, device: DeviceType = DeviceType.ALL,
@@ -225,11 +240,11 @@ class PTGTaskClass(TaskClass):
         return {tuple(x) if isinstance(x, (tuple, list)) else (x,)
                 for x in targets}
 
-    def _deps_goal(self, locals) -> int:
+    def deps_goal(self, locals) -> int:
         """Mask of flow bits (mask mode) or count (counter mode, used by
         CTL-gather classes) of *task*-fed deps; collection reads and NEW
         are resolved locally at prepare_input, not counted."""
-        g = self.tp.g
+        g = self.g
         if self.deps_mode == DEPS_COUNTER:
             key = tuple(locals)
             cached = self._goal_cache.get(key)
@@ -253,10 +268,10 @@ class PTGTaskClass(TaskClass):
                 mask |= 1 << f.index
         return mask
 
-    def _data_lookup(self, task: Task) -> None:
+    def data_lookup(self, task: Task) -> None:
         """Resolve collection-sourced and NEW inputs (generated
         data_lookup / jdf_generate_code_data_lookup analog)."""
-        g = self.tp.g
+        g = self.g
         for f in self.flows:
             if f.name in task.data:
                 continue
@@ -266,7 +281,7 @@ class PTGTaskClass(TaskClass):
             if dep.data is not None:
                 dc, key = dep.data(g, *task.locals)
                 value = dc.data_of(key)
-                ctx = self.tp.context
+                ctx = task.taskpool.context
                 if ctx is not None:
                     san = ctx.dfsan
                     if san is not None:
@@ -297,10 +312,10 @@ class PTGTaskClass(TaskClass):
             cache[flow_name] = hit
         return hit
 
-    def _iterate_successors(self, task: Task):
+    def iterate_successors(self, task: Task):
         """Producer-side expansion (generated iterate_successors analog,
         jdf2c.c; consumed by parsec_release_dep_fct parsec.c:1783)."""
-        g = self.tp.g
+        g = self.g
         for f in self.flows:
             spec = self.specs[f.name]
             value = None
@@ -317,7 +332,7 @@ class PTGTaskClass(TaskClass):
                     yield DataRef(collection=dc, key=key, value=v)
                     continue
                 cls_name, params_fn, dst_flow = dep.dst
-                dst_tc = self.tp.task_class_by_name(cls_name)
+                dst_tc = task.taskpool.task_class_by_name(cls_name)
                 targets = params_fn(g, *task.locals)
                 if isinstance(targets, tuple):
                     targets = [targets]
@@ -348,11 +363,11 @@ class PTGTaskClass(TaskClass):
     def affinity_rank(self, locals) -> int:
         if self.affinity is None:
             return 0
-        dc, key = self.affinity(self.tp.g, *locals)
+        dc, key = self.affinity(self.g, *locals)
         return dc.rank_of(key)
 
     def enumerate_space(self) -> Iterable[Tuple[int, ...]]:
-        for p in self.space(self.tp.g):
+        for p in self.space(self.g):
             yield tuple(p) if isinstance(p, (tuple, list)) else (p,)
 
     def nb_local_tasks(self, my_rank: int = 0, nb_ranks: int = 1) -> int:
@@ -373,7 +388,9 @@ class Taskpool(CoreTaskpool):
     def __init__(self, name: str = "ptg", **globals_kw):
         super().__init__(name=name)
         self.g = types.SimpleNamespace(**globals_kw)
-        self.startup_hook = self._startup
+        # the hook is handed its pool: the function, not a method bound
+        # to the pool it would be stored on
+        self.startup_hook = type(self)._startup
 
     def task_class_by_name(self, name: str) -> PTGTaskClass:
         return self._tc_by_name[name]
@@ -388,7 +405,7 @@ class Taskpool(CoreTaskpool):
         return tc
 
     # -- startup (jdf_generate_startup_tasks analog) ----------------------
-    def _startup(self, tp) -> List[Task]:
+    def _startup(self) -> List[Task]:
         ctx = self.context
         my_rank = ctx.my_rank if ctx is not None else 0
         nb_ranks = ctx.nb_ranks if ctx is not None else 1

@@ -341,10 +341,14 @@ class OverheadProfiler(PinsModule):
         for es in self.context.streams:
             for k in agg:
                 agg[k] += es.stats.get(k, 0)
-        agg["insert_s"] = 0.0
-        agg["insert_calls"] = 0
+        # insertion: what the pools that ended folded into the
+        # Context (a finished pool is not kept for its counters), and
+        # the pools still running
+        ended = self.context.dtd_counters
+        agg["insert_s"] = ended.get("dtd_insert_s", 0.0)
+        agg["insert_calls"] = ended.get("dtd_insert_calls", 0)
         with self.context._lock:
-            pools = list(self.context._taskpools_by_name.values())
+            pools = list(self.context._active_taskpools)
         for tp in pools:
             agg["insert_s"] += getattr(tp, "insert_s", 0.0)
             agg["insert_calls"] += getattr(tp, "insert_calls", 0)

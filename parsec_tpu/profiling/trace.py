@@ -89,7 +89,10 @@ class NativeRingAdapter:
     def __init__(self, engine) -> None:
         self._lock = threading.Lock()
         self._engine = engine          # dsl.dtd_native.NativeDTD while live
-        self.tp = engine.tp            # rid/root_span read late-bound
+        # rid/root_span read late-bound while the pool is live; the
+        # snapshot at its retirement keeps the two and lets the pool go
+        self.tp = engine.tp
+        self._ids = (None, None)
         self.pool_name = engine.tp.name
         self.class_names = engine.class_names   # shared, insert-grown
         self.offset_s = engine.obs_offset_s
@@ -127,6 +130,10 @@ class NativeRingAdapter:
                 return
             eng = self._engine
             self._engine = None
+            tp, self.tp = self.tp, None
+            if tp is not None:
+                self._ids = (getattr(tp, "trace_rid", None),
+                             getattr(tp, "root_span", None))
             if eng is None:
                 self._frozen = []
                 return
@@ -141,8 +148,8 @@ class NativeRingAdapter:
         if not arrays:
             return []
         tp = self.tp
-        rid = getattr(tp, "trace_rid", None)
-        root = getattr(tp, "root_span", None)
+        rid, root = self._ids if tp is None else (
+            getattr(tp, "trace_rid", None), getattr(tp, "root_span", None))
         names = self.class_names
         shift = self.offset_s - t0
         nonep = _native.OBS_PARENT_NONE

@@ -390,9 +390,11 @@ def test_a_pool_without_a_stacked_body_builds_the_chores_it_built(
         (_gemm_dtd_body, tp._shape_of(args), DeviceType.ALL, True, None),
         (list(tp._classes)[1][0], (("tile", dtd.INPUT),), DeviceType.ALL,
          False, None)]
-    # nothing counted, nothing flushed: the bank keeps what it tracked
+    # nothing counted, nothing flushed: the bank tracked both tiles to
+    # the pool's end and let them go there
     assert tp.counters == {} and ctx.dtd_counters == {}
-    assert len(tp.tiles.all()) == 2 and tp.tiles.retired == 0
+    assert tp.tiles.all() == [] and tp.tiles.retired == 0
+    assert tp.tiles.peak == 2 and tp.tiles.dropped == 2
 
 
 # -- the per-tile flush ------
