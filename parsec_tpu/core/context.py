@@ -34,7 +34,8 @@ from .future import DataCopyFuture
 from .reshape import resolve_reshape
 from .spans import (SPAN_DISPATCH, SPAN_DTD_FLUSH,  # noqa: F401
                     SPAN_EXEC, SPAN_INSERT, SPAN_PARK, SPAN_PTG_STARTUP,
-                    SPAN_PTG_UNFOLD, SPAN_RELEASE, SPAN_SELECT, StageSpan)
+                    SPAN_PTG_UNFOLD, SPAN_RELEASE, SPAN_SELECT, SPAN_TURN,
+                    StageSpan)
 from .task import (GROUP_SIZES, GROUP_TAKE, Chore, DeviceType, HookReturn,
                    Task, TaskStatus)
 from .taskpool import DataRef, SuccessorRef, Taskpool
@@ -104,6 +105,8 @@ class ExecutionStream:
                       # of release_s: a closed-form front end evaluating
                       # the completed task's successor list
                       "unfold_s": 0.0,
+                      # waiting for a module's turn, a ready task in hand
+                      "turn_s": 0.0,
                       # why _take_group stopped taking (_class: on a
                       # task that cannot be grouped), and the bins its
                       # takes launched (stage timers on)
@@ -615,6 +618,9 @@ class Context:
             # — scrape-time snapshot, the autoscaler's KV-pressure row
             out["kv"] = self.kv_state.snapshot()
         out["capacity"] = self._capacity_block()
+        # a module's counters, its longest wait for the chip and longest
+        # jitted call among them: what a stalled step stood in
+        out["devices"] = self.devices.dump_statistics()
         if self.trace is not None:
             out["trace_dropped"] = self.trace.dropped()
             # the native-ring share separately: a truncated NATIVE
@@ -1043,7 +1049,8 @@ class Context:
         alone, held = [task], 1
         try:
             if limit:
-                with dev.group_turn:
+                self._take_turn(es, dev)
+                try:
                     bins = self._take_group(es, task, found, dev, limit)
                     held = sum(len(tasks) for _, tasks in bins)
                     dev.add_load(held - 1)
@@ -1053,11 +1060,24 @@ class Context:
                             self._group_launch(es, tasks, c, dev)
                         else:
                             alone += tasks
+                finally:
+                    dev.group_turn.release()
         finally:
             if dev is not None:
                 dev.release_load(held)
         for task in alone:      # too few, or a module without groups
             self._task_progress(es, task)
+
+    def _take_turn(self, es: ExecutionStream, dev) -> None:
+        """Take ``dev``'s turn; under its span where the stage timers
+        are on: the acquisition alone, a worker that holds a ready task
+        and waits for the module's one group in flight."""
+        if not self.stage_timers:
+            dev.group_turn.acquire()
+            return
+        with StageSpan(SPAN_TURN) as span:
+            dev.group_turn.acquire()
+        es.stats["turn_s"] += span.seconds
 
     def _group_launch(self, es: ExecutionStream, tasks: List[Task],
                       chore: Chore, dev) -> None:

@@ -17,6 +17,16 @@ SPAN_PARK = "parsec:park"
 SPAN_DISPATCH = "parsec:dispatch"
 SPAN_EXEC = "parsec:exec"
 SPAN_RELEASE = "parsec:release"
+# a worker that holds a ready task and waits for its module's one group
+# in flight (Context._take_turn: the acquisition alone, outside
+# every other span), and a launch taken apart (device/tpu.py, both inside
+# the thread's parsec:exec): the host waiting for the chip (the last
+# group's output; the oldest lone launch once GROUP_BYTES are queued),
+# and the jitted call until it returns. What is left of exec is staging
+# the leaves and attaching the outputs
+SPAN_TURN = "parsec:turn"
+SPAN_EXEC_WAIT = "parsec:exec_wait"
+SPAN_EXEC_CALL = "parsec:exec_call"
 # the PTG front end's own stages (dsl/ptg.py names them on its taskpool
 # and task classes; a front end that names none has none)
 SPAN_PTG_STARTUP = "parsec:ptg_startup"

@@ -3,7 +3,6 @@
 from __future__ import annotations
 
 import threading
-import time
 from typing import Dict, List, Optional
 
 from ..core.task import Chore, DeviceType, HookReturn, Task
@@ -25,8 +24,7 @@ class Device:
         self.index = -1
         self.registry: Optional["Registry"] = None
         # statistics (reference device.h:132-141 per-device counters)
-        self.stats = {"tasks": 0, "exec_s": 0.0,
-                      "bytes_in": 0, "bytes_out": 0,
+        self.stats = {"tasks": 0, "bytes_in": 0, "bytes_out": 0,
                       # by task class name, counted while the context's
                       # stage timers are on: a launch is a lone task or
                       # a group, so one class's tasks per launch can be
@@ -76,7 +74,6 @@ class Device:
         """Run the functional body and normalize outputs into
         ``task.output`` keyed by output-flow name."""
         from ..core.task import normalize_outputs
-        t0 = time.perf_counter()
         inputs = task.input_values()
         result = chore.hook(task, *inputs)
         # the task object itself as the label: it is only ever
@@ -87,7 +84,6 @@ class Device:
         task.output.update(outs)
         with self._lock:
             self.stats["tasks"] += 1
-            self.stats["exec_s"] += time.perf_counter() - t0
             if task.taskpool.context.stage_timers:
                 self._count_launch(task, 1)
         return HookReturn.DONE

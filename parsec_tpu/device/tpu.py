@@ -59,7 +59,8 @@ import weakref
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from .base import Device
-from ..core.spans import SPAN_EXEC, StageSpan
+from ..core.spans import (SPAN_EXEC, SPAN_EXEC_CALL, SPAN_EXEC_WAIT,
+                          StageSpan)
 from ..core.task import (GROUP_SIZES, Chore, DeviceType, FlowAccess,
                          HookReturn, Task, normalize_outputs)
 from ..utils.debug import debug_verbose
@@ -141,6 +142,12 @@ class TPUDevice(Device):
         self._lone_bytes = 0
         self.stats["batches"] = 0
         self.stats["batched_tasks"] = 0
+        # the host's waits for the chip (``_under``) and its jitted
+        # calls: seconds and waits while the stage timers are on (the
+        # launches are in ``launches_by_class``); the longest of each
+        # always, so that a step that stalls says where it stood
+        self.stats.update(chip_wait_s=0.0, chip_waits=0, call_s=0.0,
+                          chip_wait_max_s=0.0, call_max_s=0.0)
         debug_verbose(3, "device", "TPU device on %s (%s)",
                       self.jax_device, self.platform)
 
@@ -196,9 +203,11 @@ class TPUDevice(Device):
     @staticmethod
     def _spanned(task: Task, launch: Callable, *args) -> None:
         """One launch, under its ``parsec:exec`` span where the stage
-        timers are on: the enqueue as the host pays it (staging,
-        ``default_device``, the jitted call until it returns), not the
-        device's work."""
+        timers are on: the enqueue as the host pays it (staging, the
+        wait for the module's last group under ``parsec:exec_wait``,
+        ``default_device`` and the jitted call until it returns under
+        ``parsec:exec_call``, attaching the outputs), not the device's
+        work."""
         if task.taskpool.context.stage_timers:
             with StageSpan(SPAN_EXEC):
                 launch(*args)
@@ -208,9 +217,10 @@ class TPUDevice(Device):
     def _launch_group(self, tasks, program, values) -> None:
         """``tasks`` (one, or a group) through ``program``, their outputs
         attached."""
-        t0 = time.perf_counter()
+        timed = tasks[0].taskpool.context.stage_timers
         group = len(tasks) > 1
         flat = self._flat(values)
+        waits = []      # seconds of each wait for the chip this launch made
         # the runtime bounds the groups it queues, not their bytes: a
         # chip that lags a few ms behind would hold the outputs and the
         # inputs of as many groups. The chip is far ahead wherever groups
@@ -219,9 +229,10 @@ class TPUDevice(Device):
         # (what the lone ones still queued hold is bounded in _queued)
         if group and self._group_out is not None and \
                 not self._group_out.is_deleted():   # given on: behind it
-            self._group_out.block_until_ready()
-        with self.jax.default_device(self.jax_device):
-            results = program(*flat)
+            waits.append(self._under(SPAN_EXEC_WAIT, timed,
+                                     self._group_out.block_until_ready)[1])
+        results, called = self._under(SPAN_EXEC_CALL, timed, self._call,
+                                      program, flat)
         # what tells that the launch is over: an output of its last
         # member, or the mark a program whose chore donates returns
         # after its members (a later launch may be GIVEN any output)
@@ -238,21 +249,50 @@ class TPUDevice(Device):
             if held > 0:
                 # a tile weakly: one its collection has dropped is done
                 # with
-                self._queued((lambda: mark) if own else weakref.ref(mark),
-                             held)
+                waits += self._queued(
+                    (lambda: mark) if own else weakref.ref(mark), held, timed)
         names = [f.name for f in tasks[0].task_class.output_flows]
         for t, res in zip(tasks, results):
             t.output.update(normalize_outputs(res, names, t))
         with self._lock:
             self.stats["tasks"] += len(tasks)
-            self.stats["exec_s"] += time.perf_counter() - t0
             if group:
                 self.stats["batches"] += 1
                 self.stats["batched_tasks"] += len(tasks)
-            if tasks[0].taskpool.context.stage_timers:
+            # the longest call and the longest wait always: what a
+            # stalled step stood in
+            if called > self.stats["call_max_s"]:
+                self.stats["call_max_s"] = called
+            if waits and max(waits) > self.stats["chip_wait_max_s"]:
+                self.stats["chip_wait_max_s"] = max(waits)
+            if timed:
+                self.stats["call_s"] += called
+                self.stats["chip_wait_s"] += sum(waits)
+                self.stats["chip_waits"] += len(waits)
                 self._count_launch(tasks[0], len(tasks))
 
-    def _queued(self, mark, nbytes) -> None:
+    def _call(self, program, flat):
+        """The jitted call until it returns."""
+        with self.jax.default_device(self.jax_device):
+            return program(*flat)
+
+    @staticmethod
+    def _under(name: str, timed: bool, fn: Callable,
+               *args) -> Tuple[Any, float]:
+        """``(fn(*args), the seconds it took)``, under the span ``name``
+        where the stage timers are on (``timed``): the host's wait for
+        the chip (``parsec:exec_wait``) and the jitted call until it
+        returns (``parsec:exec_call``), both inside the launch's
+        ``parsec:exec``."""
+        t0 = time.perf_counter()
+        if timed:
+            with StageSpan(name):
+                out = fn(*args)
+        else:
+            out = fn(*args)
+        return out, time.perf_counter() - t0
+
+    def _queued(self, mark, nbytes, timed: bool) -> List[float]:
         """A launch of one holds ``nbytes`` of new outputs and is over
         when the array ``mark()`` is. It neither waits for the
         module's turn nor is waited for, so nothing else bounds how far
@@ -264,7 +304,8 @@ class TPUDevice(Device):
         most, as a group does: the thread that enqueues more waits for
         the oldest. A chip that keeps up has finished it long before,
         and the wait is a look. (A launch whose outputs lie in buffers
-        it was given holds nothing new and is not counted.)"""
+        it was given holds nothing new and is not counted.) Returns the
+        seconds of each wait it made."""
         oldest = []
         with self._lock:
             self._lone.append((mark, nbytes))
@@ -273,9 +314,9 @@ class TPUDevice(Device):
                 ref, n = self._lone.popleft()
                 self._lone_bytes -= n
                 oldest.append(ref())
-        for leaf in oldest:
-            if leaf is not None and not leaf.is_deleted():
-                leaf.block_until_ready()
+        return [self._under(SPAN_EXEC_WAIT, timed, leaf.block_until_ready)[1]
+                for leaf in oldest
+                if leaf is not None and not leaf.is_deleted()]
 
     # --------------------------------------------------------- staging
 

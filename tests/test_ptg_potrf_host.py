@@ -328,7 +328,11 @@ def test_with_the_timers_on_tasks_and_launches_are_counted_by_class(
     # only bins of a group's size are handed to the module as groups; a
     # POTRF, one ready at a time, never forms one (its stacked programs
     # are never built: TPUDevice._programs)
-    assert {"SYRK", "GEMM"} <= set(handed) and "POTRF" not in handed
+    assert "GEMM" in handed and "POTRF" not in handed
+    # SYRKs become ready one by one: a bin of four forms for certain
+    # under one worker; four racing workers take them apart in about
+    # half the runs
+    assert nb_cores > 1 or "SYRK" in handed
     assert handed.get("TRSM", GROUP_SIZES[-1]) >= GROUP_SIZES[-1]
     assert takes and dev.dump_statistics()["tasks_by_class"] == {}
     assert not any(es.stats["group_end_" + why] for es in ctx.streams

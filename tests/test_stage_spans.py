@@ -1,5 +1,7 @@
 """The runtime's stage spans (``parsec:insert/select/park/dispatch/exec/
-release``): opened at the stage-timer sites behind ``context.stage_timers``,
+release``, and since PR 35 ``parsec:turn`` and a launch taken apart:
+``parsec:exec_wait``, ``parsec:exec_call``): opened at the stage-timer
+sites behind ``context.stage_timers``,
 which is on while a profiler session is live; written into the profiler's
 own trace and, as before, summed into ``es.stats`` / ``Taskpool.insert_s``.
 A small DTD GEMM with accelerator-typed bodies on the CPU platform, read
@@ -23,7 +25,10 @@ from parsec_tpu.utils import mca_param
 
 NB, MT, NT, KT = 16, 3, 2, 2
 TASKS, INSERT_CALLS = MT * NT * KT, MT
-STAGES = ("insert", "select", "park", "dispatch", "exec", "release")
+STAGES = ("insert", "select", "park", "dispatch", "exec", "release",
+          "turn", "exec_wait", "exec_call")
+# a GEMM whose rows of C hold four ready tasks each: groups form
+GROUPS = (4, 4, 2)
 
 
 def _gemm_body(a, b, c):
@@ -37,28 +42,29 @@ def _matrix(name, mt, nt):
     return m
 
 
-def _run_pool(ctx, name, timeout=60.0, pure=True):
+def _run_pool(ctx, name, timeout=60.0, pure=True, shape=(MT, NT, KT)):
     """One GEMM through a new pool, every task on an accelerator module:
     one insert_tasks call per row of C tiles, as insert_gemm_dtd makes.
     ``pure`` bodies may share a launch (a worker that selects four of
     them issues one group program); impure ones run one by one."""
-    a, b, c = _matrix("A", MT, KT), _matrix("B", KT, NT), _matrix("C", MT, NT)
+    mt, nt, kt = shape
+    a, b, c = _matrix("A", mt, kt), _matrix("B", kt, nt), _matrix("C", mt, nt)
     tp = dtd.Taskpool(name)
     ctx.add_taskpool(tp)
-    for m in range(MT):
+    for m in range(mt):
         tp.insert_tasks(
             _gemm_body,
             [(dtd.TileArg(a, (m, k), dtd.INPUT),
               dtd.TileArg(b, (k, n), dtd.INPUT),
               dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True))
-             for n in range(NT) for k in range(KT)],
+             for n in range(nt) for k in range(kt)],
             device=DeviceType.TPU, pure=pure)
     waiter = threading.Thread(target=tp.wait, daemon=True)
     waiter.start()
     waiter.join(timeout)
     assert not waiter.is_alive(), f"pool {name} did not drain"
     assert tp._native is None
-    assert float(c.data_of((0, 0))[0, 0]) == 1.0 + KT * NB
+    assert float(c.data_of((0, 0))[0, 0]) == 1.0 + kt * NB
     return tp
 
 
@@ -69,13 +75,18 @@ def _groups(ctx):
             sum(s["batched_tasks"] for s in stats))
 
 
-def _launches(ctx, before):
-    """Launches a pool's TASKS tasks took since ``before = _groups(ctx)``:
-    the tasks that shared none, and the group launches. An exec span
-    each."""
+def _launches(ctx, before, tasks=TASKS):
+    """Launches a pool's ``tasks`` tasks took since ``before =
+    _groups(ctx)``: the tasks that shared none, and the group launches.
+    An exec span each."""
     groups, grouped = (now - then
                        for now, then in zip(_groups(ctx), before))
-    return TASKS - grouped + groups
+    return tasks - grouped + groups
+
+
+def _module(ctx):
+    (dev,) = ctx.devices.by_type(DeviceType.TPU)
+    return dev
 
 
 @pytest.fixture
@@ -84,14 +95,14 @@ def make_ctx():
     declines a real accelerator), with the knobs a case sets undone."""
     made, knobs = [], []
 
-    def make(scheduler="lfq", **params):
+    def make(scheduler="lfq", nb_cores=3, **params):
         # one accelerator module (the suite runs on 8 virtual devices)
         params = {"runtime.native_dtd": 0, "device.tpu.max_devices": 1,
                   **params}
         for knob, value in params.items():
             mca_param.set(knob, value)
             knobs.append(knob)
-        ctx = parsec.init(nb_cores=3, scheduler=scheduler)
+        ctx = parsec.init(nb_cores=nb_cores, scheduler=scheduler)
         ctx.start()
         made.append(ctx)
         return ctx
@@ -256,3 +267,141 @@ def test_a_live_profiler_does_not_start_the_overhead_shedder(
         assert rt._overload_reason() is None
     ctx.set_stage_timers(True)
     assert "serving.shed_overhead_us" in rt._overload_reason()
+
+
+# -- a launch taken apart, and the module's turn (PR 35) ----------------------
+
+def _inside(span, others):
+    return any(lo <= span[0] and span[1] <= hi for lo, hi in others)
+
+
+@pytest.mark.parametrize("nb_cores", [1, 3])
+def test_a_traced_pool_with_groups_shows_the_turn_and_the_launch_apart(
+        make_ctx, tmp_path, nb_cores):
+    ctx = make_ctx(nb_cores=nb_cores)
+    dev = _module(ctx)
+    _run_pool(ctx, "warm", shape=GROUPS)    # compiles; leaves a last group
+    tasks = GROUPS[0] * GROUPS[1] * GROUPS[2]
+    before, stats0 = _groups(ctx), dict(dev.stats)
+    turn0 = sum(es.stats["turn_s"] for es in ctx.streams)
+    assert before[0] >= 1 and turn0 == 0.0
+    assert stats0["call_s"] == stats0["chip_wait_s"] == 0.0
+    with _Session(tmp_path) as prof:
+        _run_pool(ctx, "traced", shape=GROUPS)
+    launches = _launches(ctx, before, tasks)
+    groups = _groups(ctx)[0] - before[0]
+    assert groups >= 1
+    # a call a launch; a wait a group (the module's last group is there
+    # to be waited for: the warm pool's, then this pool's own); a turn
+    # taken by every worker that held a task of a body with groups
+    assert prof.count("exec") == prof.count("exec_call") == launches
+    assert prof.count("exec_wait") == groups \
+        == dev.stats["chip_waits"] - stats0["chip_waits"]
+    assert 1 <= prof.count("turn") <= tasks
+    for thread in prof.spans.values():
+        execs = thread.get("exec", ())
+        inner = thread.get("exec_wait", []) + thread.get("exec_call", [])
+        # both nested in a parsec:exec of the same thread, and what they
+        # take is a part of it
+        assert all(_inside(span, execs) for span in inner)
+        assert sum(hi - lo for lo, hi in inner) <= \
+            sum(hi - lo for lo, hi in execs)
+        # a turn is taken outside every other span of its thread
+        others = [span for stage, spans in thread.items()
+                  if stage != "turn" for span in spans]
+        assert not any(lo < hi0 and lo0 < hi
+                       for lo, hi in thread.get("turn", ())
+                       for lo0, hi0 in others)
+    # the sums are of the same passes
+    assert dev.stats["call_s"] > 0 and dev.stats["chip_wait_s"] > 0
+    assert dev.stats["call_s"] == pytest.approx(
+        prof.seconds("exec_call"), rel=0.5)
+    assert sum(es.stats["turn_s"] for es in ctx.streams) > 0
+    assert dev.stats["call_max_s"] >= stats0["call_max_s"] > 0
+
+
+def test_no_session_no_turn_wait_or_call_and_no_growth_of_their_sums(
+        make_ctx, monkeypatch):
+    made = []
+
+    class Counting(context_mod.StageSpan):
+        def __init__(self, name):
+            made.append(name)
+            super().__init__(name)
+
+    for site in (context_mod, dtd, parsec_tpu.device.tpu):
+        monkeypatch.setattr(site, "StageSpan", Counting)
+    ctx = make_ctx(nb_cores=1)
+    dev = _module(ctx)
+    _run_pool(ctx, "warm", shape=GROUPS)
+    _run_pool(ctx, "untraced", shape=GROUPS)
+    assert not ctx.stage_timers and made == []
+    assert _groups(ctx)[0] >= 2             # waits were made, and calls
+    assert all(es.stats["turn_s"] == 0.0 for es in ctx.streams)
+    assert dev.stats["chip_wait_s"] == dev.stats["call_s"] == 0.0
+    assert dev.stats["chip_waits"] == 0
+    # the stall's signature is kept all the same: the longest of each
+    assert dev.stats["call_max_s"] > 0 and dev.stats["chip_wait_max_s"] > 0
+    ctx.set_stage_timers(True)
+    _run_pool(ctx, "timed", shape=GROUPS)
+    assert {"parsec:turn", "parsec:exec_wait", "parsec:exec_call"} \
+        <= set(made)
+    assert made.count("parsec:exec_wait") == dev.stats["chip_waits"] >= 1
+    assert made.count("parsec:exec_call") == made.count("parsec:exec")
+
+
+@pytest.mark.parametrize("launches,waits", [(3, 0), (5, 2)])
+def test_a_lone_launch_over_the_queue_bound_waits_under_its_span(
+        make_ctx, monkeypatch, launches, waits):
+    """``TPUDevice._queued``: the lone launches still queued hold
+    ``GROUP_BYTES`` of new outputs at most (three tiles here); the thread
+    that enqueues more waits for the oldest, under ``parsec:exec_wait``.
+    Under the bound no wait is made and no span opened."""
+    import numpy as np
+    from parsec_tpu.core.task import Chore, Flow, FlowAccess, Task
+    from parsec_tpu.core.taskpool import Taskpool
+    made = []
+
+    class Counting(context_mod.StageSpan):
+        def __init__(self, name):
+            made.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(parsec_tpu.device.tpu, "StageSpan", Counting)
+    monkeypatch.setattr(parsec_tpu.device.tpu, "GROUP_BYTES", 3 * 8 * 8 * 4)
+    ctx = make_ctx(nb_cores=1)
+    ctx.set_stage_timers(True)
+    dev = _module(ctx)
+    tp = Taskpool("lone")
+    tc = tp.new_task_class("H", params=("i",),
+                           flows=[Flow("x", FlowAccess.RW)])
+    chore = Chore(DeviceType.TPU, lambda task, x: {"x": 2.0 * x})
+    tc.add_chore(chore)
+    tp.context = ctx
+    held = []                   # an output somebody holds is waited for
+    for i in range(launches):
+        t = Task(tp, tc, (i,))
+        t.data["x"] = np.full((8, 8), float(i), np.float32)
+        dev.execute(None, t, chore)
+        held.append(t)
+    assert made.count("parsec:exec") == launches \
+        == made.count("parsec:exec_call")
+    assert made.count("parsec:exec_wait") == waits == dev.stats["chip_waits"]
+    assert (dev.stats["chip_wait_s"] > 0) == bool(waits)
+    assert dev.stats["batches"] == 0
+
+
+def test_statusz_carries_each_modules_longest_wait_and_call(make_ctx):
+    ctx = make_ctx(nb_cores=1)
+    _run_pool(ctx, "untraced", shape=GROUPS)
+    _run_pool(ctx, "again", shape=GROUPS)
+    devices = {d["name"]: d for d in ctx.statusz()["devices"]}
+    assert devices == {d["name"]: d
+                       for d in ctx.devices.dump_statistics()}
+    chip = devices[_module(ctx).name]
+    assert chip["call_max_s"] > 0 and chip["chip_wait_max_s"] > 0
+    assert chip["call_s"] == chip["chip_wait_s"] == 0.0     # untraced
+    # a sum nobody read made way for them
+    assert not any("exec_s" in d for d in devices.values())
+    import json
+    json.dumps(ctx.statusz()["devices"])
