@@ -20,6 +20,13 @@ travelled on to the next task would leave the collection holding the
 version before it, and every trailing tile would be on the chip twice
 until its TRSM or POTRF wrote the factor back (1.7 times the matrix at
 N=49152 in 2048-tiles, and not the same twice: PERF.md section 6, PR 27).
+And every body names its RW flow as the last reading of its version
+(``Chore.donates``), so a chip module's program writes the update into
+the buffer the tile lies in: in place on the device too, not a new
+buffer that replaces the old one at the task's release (PR 36). The
+tile of the collection is a deleted array from the update's launch to
+its write-back: read the factor from ``A`` after the pool, never from
+arrays kept aside.
 """
 
 from __future__ import annotations
@@ -199,21 +206,33 @@ def build_potrf(A: TiledMatrix) -> ptg.Taskpool:
                       # in place: the tile's last version is freed
                       ptg.Out(data=lambda g, m, n, k: (g.A, (m, n)))])])
 
-    @POTRF.body(batch_hook=_potrf_stacked)
+    # Every class updates its tile where it lies (``donates``): the
+    # version of A(m,n) a GEMM, a SYRK, a TRSM or a POTRF takes in has no
+    # other reader (one chain a tile: the k-th update's only successor
+    # is the (k+1)-th, the last one's the tile's TRSM or POTRF, and the
+    # tile of A it came from is the one the task writes), so a chip
+    # module hands its buffer to the program for the flow's output, as
+    # upstream's kernels update C in place: a launch still queued holds
+    # nothing new, and the module may have the next one queued behind
+    # it. Whether a stacked form's program really writes there is the
+    # module's to read off the program. An executor that lowers the
+    # whole pool places its buffers itself and does not read this.
+    @POTRF.body(batch_hook=_potrf_stacked, donates=("T",))
     def potrf_body(task, T):
         return potrf_tile(T)
 
     # the batched form (the executor and the chip module verify the
     # shared-L grouping per wave and per group)
-    @TRSM.body(batch_hook=_trsm_stacked, batch_hook_shared=("L",))
+    @TRSM.body(batch_hook=_trsm_stacked, batch_hook_shared=("L",),
+               donates=("C",))
     def trsm_body(task, L, C):
         return trsm_tile(C, L)
 
-    @SYRK.body
+    @SYRK.body(donates=("C",))
     def syrk_body(task, A_, C):
         return syrk_tile(C, A_, alpha=-1.0, beta=1.0)
 
-    @GEMM.body
+    @GEMM.body(donates=("C",))
     def gemm_body(task, A_, B_, C):
         return gemm_tile(C, A_, B_, alpha=-1.0, beta=1.0, tb=True)
 

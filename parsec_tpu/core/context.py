@@ -1035,18 +1035,24 @@ class Context:
         ``_group_chore``) and the ready tasks of its taskpool this worker
         can select: every one is prepared, announced and completed
         exactly once, as alone, and all of them in this pass. The device
-        module says how many one launch may carry and
-        has one group in flight at a time: what a launch made waits on
-        the device for its members' release, so the workers take turns,
-        each holding one task until its turn. The bins that fill a size
-        are launched in that turn, in the order their first task was
-        selected; the tasks of the others go alone once the turn is
-        given up, as a task that was never taken would. A launch that
-        raises leaves the worker's handler to abort the pool, every load
-        released."""
+        module says how many one launch may carry and what a launch
+        holds: the workers take turns, each holding one task until its
+        turn, and the turn covers the take and every launch of its bins
+        (prepare, staging, the module's wait for the chip, the jitted
+        call). The bins that fill a size are launched in that turn, in
+        the order their first task was selected. What a launch made
+        anew waits on the device for its members' release, so a group
+        that holds new outputs releases its members inside the turn, one
+        group in flight; the members of a group that holds nothing new
+        (it wrote where its tiles lie) are released once the turn is
+        given up, while the next worker stages and calls. The tasks of
+        the bins too small for a group go alone after those, as a task
+        that was never taken would. A launch or a release that raises
+        leaves the worker's handler to abort the pool, every load
+        released and the turn free."""
         dev = self.devices.device_for(found[0].device_type, task)
         limit = dev.group_limit(task, found[0]) if dev is not None else 0
-        alone, held = [task], 1
+        alone, held, launched = [task], 1, []
         try:
             if limit:
                 self._take_turn(es, dev)
@@ -1057,7 +1063,7 @@ class Context:
                     alone = []
                     for c, tasks in bins:
                         if len(tasks) >= GROUP_SIZES[-1]:
-                            self._group_launch(es, tasks, c, dev)
+                            self._group_launch(es, tasks, c, dev, launched)
                         else:
                             alone += tasks
                 finally:
@@ -1065,13 +1071,17 @@ class Context:
         finally:
             if dev is not None:
                 dev.release_load(held)
+        launched.reverse()      # in place: released with the turn free,
+        while launched:         # and a member goes with its release
+            self.complete_task(es, launched.pop())
         for task in alone:      # too few, or a module without groups
             self._task_progress(es, task)
 
     def _take_turn(self, es: ExecutionStream, dev) -> None:
         """Take ``dev``'s turn; under its span where the stage timers
         are on: the acquisition alone, a worker that holds a ready task
-        and waits for the module's one group in flight."""
+        and waits while another takes, launches or, where its group
+        holds new outputs, releases."""
         if not self.stage_timers:
             dev.group_turn.acquire()
             return
@@ -1080,7 +1090,11 @@ class Context:
         es.stats["turn_s"] += span.seconds
 
     def _group_launch(self, es: ExecutionStream, tasks: List[Task],
-                      chore: Chore, dev) -> None:
+                      chore: Chore, dev, later: List[Task]) -> None:
+        """A bin of one chore through ``dev``, inside its turn: the
+        members of a launch that holds new outputs are released before
+        the next launch, those of one that holds none go to ``later``,
+        for the caller to release once the turn is given up."""
         for task in tasks:      # a dispatch span per task, as alone
             self._timed_dispatch(es, self._prepare_input, es, task)
         shared = chore.batch_hook_shared
@@ -1095,12 +1109,15 @@ class Context:
         done = 0
         while done < len(tasks):
             # the largest group the module can make of them, never
-            # padded; released before the next launch
-            n = dev.execute_group(es, tasks[done:], chore)
+            # padded
+            n, new_bytes = dev.execute_group(es, tasks[done:], chore)
             if n:
                 for task in tasks[done:done + n]:
                     self._mark_exe(es, task)
-                    self.complete_task(es, task)
+                    if new_bytes:
+                        self.complete_task(es, task)
+                    else:
+                        later.append(task)
             else:               # the module sends this one alone
                 n = 1
                 self._executed(es, tasks[done],

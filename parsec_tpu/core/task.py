@@ -109,7 +109,9 @@ class Chore:
     # of its output flows, and a donated flow is the first of its shape
     # among them (JAX pairs a donated buffer with the first output of its
     # shape). Read by the chip module's program table alone: an executor
-    # that lowers the whole pool places its buffers itself.
+    # that lowers the whole pool places its buffers itself. A declaration,
+    # not the module's rule: what a launch holds anew the module reads
+    # off the program it built (``TPUDevice._build``).
     donates: Optional[Sequence[str]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
     # e.g. DTD's woven argspec) can still hand a device module their pure
@@ -154,8 +156,10 @@ class Chore:
 # so every size is compiled the first time a signature is seen (a
 # ``batch_hook``'s when its first group forms) and none later.
 # Settled on the v5e (PERF.md section 6, PR 25): what a launch makes
-# waits in HBM for its members' release, and eight 1024-tiles are what
-# the benchmark's 1% on peak_hbm_gib leaves room for. Which of these
+# anew waits in HBM for its members' release, and eight 1024-tiles are
+# what the benchmark's 1% on peak_hbm_gib leaves room for (a launch that
+# writes where its tiles lie, ``Chore.donates``, holds nothing new, and
+# the module queues a second group behind it: PR 36). Which of these
 # sizes a task's bytes admit is the module's rule
 # (``device.tpu.GROUP_BYTES``).
 GROUP_SIZES = (8, 4)

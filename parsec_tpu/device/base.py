@@ -49,7 +49,9 @@ class Device:
     def group_limit(self, task: Task, chore=None) -> int:
         """The most tasks like ``task`` one launch of ``chore`` on this
         module may carry; 0 for a module that launches every task alone
-        (then it needs no ``execute_group`` or ``group_turn``)."""
+        (then it needs no ``execute_group``, which returns ``(tasks
+        launched, bytes of new outputs the launch holds)``, or
+        ``group_turn``)."""
         return 0
 
     def shutdown(self) -> None:

@@ -124,6 +124,12 @@ class HBMManager:
                 if e["offset"] is None or key in protect or \
                         e.get("device") != dev or e.get("pins", 0) > 0:
                     continue
+                if self._given(e["value"]):
+                    # given to a program that updates the tile where it
+                    # lies (``Chore.donates``): the buffer is the next
+                    # version's, in flight, and is tracked again under
+                    # this key at its write-back. Not ours to spill
+                    continue
                 nu = e.get("next_use")
                 # rank: (next_use descending, last_use ascending);
                 # next_use None = no schedule info -> pure LRU term
@@ -134,7 +140,13 @@ class HBMManager:
                 return False
             e = self._entries[best_key]
             spill_cb = e.get("spill")
-            host = np.asarray(e["value"])       # D2H (the slow path)
+            try:
+                host = np.asarray(e["value"])   # D2H (the slow path)
+            except RuntimeError:
+                if not self._given(e["value"]):
+                    raise
+                # given to a program since the look above: the next one
+                return self._evict_one((*protect, best_key), dev)
             if spill_cb is not None:
                 spill_cb(best_key, host)
             e["value"] = host
@@ -161,6 +173,12 @@ class HBMManager:
                     f"{zone.bytes_used()}, all resident tiles pinned)")
             off = self._account_alloc(nbytes, dev)
         return off
+
+    @staticmethod
+    def _given(value) -> bool:
+        """Was this tracked tile's buffer given to a program (a
+        ``jax.Array`` deleted by donation)? A host value never is."""
+        return getattr(value, "is_deleted", bool)()
 
     @staticmethod
     def _device_of(value) -> Any:

@@ -32,18 +32,28 @@ thread. A body without a pure form (it reads its task, or jits inside)
 and inputs that share no program run the chore's own hook, pinned to
 this chip (``_pinned``).
 
-A module has one group in flight (``group_turn``; the next group waits
-for the last one's output; a launch of one does neither) and a launch
-carries the largest size whose inputs together stay within
+A launch carries the largest size whose inputs together stay within
 ``GROUP_BYTES`` (an operand the group shares is there once, and counted
-once), because what a launch makes waits in HBM for its members'
-release: small tasks go many to a launch, large ones alone, by the bytes
-this module sees and nothing else. A flow whose incoming version
-its task is the last to read (``Chore.donates``) is updated where it
-lies: the program is given the input's buffer for the flow's output, a
-launch still queued holds nothing new for it, and such a program
-returns a mark of its own to be waited for, since any of its outputs
-may be given on before anyone has waited (``_build``). Whole taskpools
+once): small tasks go many to a launch, large ones alone, by the bytes
+this module sees and nothing else. What a launch makes is allocated when
+it is enqueued and waits in HBM for its members' release, so ONE thing
+the module reads off each program decides how far the host may run ahead
+of the chip: the bytes of NEW outputs a launch of it holds (``_build``:
+the outputs of its first run that lie in no buffer it was given). A flow
+whose incoming version its task is the last to read (``Chore.donates``)
+is updated where it lies: the program is given the input's buffer for
+the flow's output, and such a program returns a mark of its own to be
+waited for, since any of its outputs may be given on before anyone has
+waited. A group that holds new outputs is one group in flight: it keeps
+the module's turn (``group_turn``) through its last member's release and
+waits for the last group before its call. A group that holds nothing new
+is pipelined: its turn ends at the call (the members are released with
+the turn free, while the next worker stages and calls) and it waits for
+the group BEFORE the last, so the chip has the next group queued while
+it works on this one and the runtime still bounds the groups it queues,
+at two. A launch of one neither takes the turn nor waits for a group;
+the lone ones still queued hold ``GROUP_BYTES`` of new outputs at most
+(``_queued``), and one that holds nothing new is not counted. Whole taskpools
 lowered to one program are ``parsec_tpu.compiled``'s business, not this
 module's.
 """
@@ -66,8 +76,9 @@ from ..core.task import (GROUP_SIZES, Chore, DeviceType, FlowAccess,
 from ..utils.debug import debug_verbose
 
 
-# The outputs of a launch are allocated when it is enqueued and wait
-# there for their members' release, one by one; and XLA's program for
+# The new outputs of a launch are allocated when it is enqueued and wait
+# there for their members' release, one by one (a launch whose outputs
+# lie in the buffers it was given holds none); and XLA's program for
 # several large tiles is slower than the tiles' own programs (64 GEMMs of
 # 4096^3 as four programs of sixteen: half again the device time, PERF.md
 # section 6, PR 25), while a task that size keeps the chip busy longer
@@ -130,11 +141,14 @@ class TPUDevice(Device):
         self._chores: Dict[int, "weakref.ref[Chore]"] = {}
         self._table_lock = threading.Lock()
         self._warmed: set = set()
-        # one group in flight: held from taking the tasks to the last
-        # member's release (Context._group_progress); and an output of
-        # the last group, which the next one waits for
+        # the module's turn: held from taking the tasks to the call of
+        # the take's last group, and through the members' release where
+        # a group holds new outputs (Context._group_progress); and what
+        # tells that the groups still on the chip's queue are over, two
+        # at most, the older first (a program's own mark, or an output
+        # of a group that holds new ones: of those only the last)
         self.group_turn = threading.Lock()
-        self._group_out: Any = None
+        self._group_marks: List[Any] = []
         # the launches of one still on the chip's queue, oldest first:
         # (an output, weakly, or the program's mark; the bytes of its
         # new outputs), and their sum
@@ -142,6 +156,11 @@ class TPUDevice(Device):
         self._lone_bytes = 0
         self.stats["batches"] = 0
         self.stats["batched_tasks"] = 0
+        # how often the in-place rule engages: launches of one and
+        # groups that held nothing new, and groups called while the
+        # group before them was still on the chip's queue
+        self.stats.update(lone_in_place=0, groups_in_place=0,
+                          groups_pipelined=0)
         # the host's waits for the chip (``_under``) and its jitted
         # calls: seconds and waits while the stage timers are on (the
         # launches are in ``launches_by_class``); the longest of each
@@ -164,21 +183,27 @@ class TPUDevice(Device):
                           [values])
         return HookReturn.DONE
 
-    def execute_group(self, es, tasks: List[Task], chore: Chore) -> int:
+    def execute_group(self, es, tasks: List[Task],
+                      chore: Chore) -> Tuple[int, int]:
         """Launch the first tasks of ``tasks`` (one chore, one pure
-        body; prepared by the caller) as ONE program, attach their
-        outputs and return how many they were: the largest size of
-        ``GROUP_SIZES`` that ``tasks`` fills with inputs of one signature
-        and that ``GROUP_BYTES`` admits. 0 where the first task has to go
-        alone (the caller takes the single path). Members that differ in
-        signature never share a program. Raises where the launch does."""
+        body; prepared by the caller) as ONE program and attach their
+        outputs: ``(how many they were, the bytes of new outputs the
+        launch holds in HBM until they are released)``. The size is the
+        largest of ``GROUP_SIZES`` that ``tasks`` fills with inputs of
+        one signature and that ``GROUP_BYTES`` admits; the bytes are the
+        program's (``program.held``, ``_build``), 0 where every output
+        lies in a buffer the program was given: the caller may then
+        release the members after the module's turn. ``(0, 0)`` where
+        the first task has to go alone (the caller takes the single
+        path). Members that differ in signature never share a program.
+        Raises where the launch does."""
         if len(tasks) < GROUP_SIZES[-1] or \
                 not self._hook_ok(chore, tasks[:GROUP_SIZES[-1]]):
             # asked before a program is built: what is left of a bin,
             # and a class whose members never hold one object where its
             # stacked form shares it (a serial chain's own tile), is
             # never compiled for a group, in a later step least of all
-            return 0
+            return 0, 0
         values = [tasks[0].input_values()]
         sig = self._sig(values[0])
         programs = self._programs(tasks[0], chore, values[0], sig,
@@ -197,8 +222,8 @@ class TPUDevice(Device):
                 continue
             self._spanned(tasks[0], self._launch_group, tasks[:size],
                           program, values[:size])
-            return size
-        return 0
+            return size, program.held
+        return 0, 0
 
     @staticmethod
     def _spanned(task: Task, launch: Callable, *args) -> None:
@@ -215,22 +240,42 @@ class TPUDevice(Device):
             launch(*args)
 
     def _launch_group(self, tasks, program, values) -> None:
-        """``tasks`` (one, or a group) through ``program``, their outputs
+        """``tasks`` (one, or a group) through ``program`` (``held``:
+        the bytes of new outputs a launch of it holds), their outputs
         attached."""
+        held = program.held
         timed = tasks[0].taskpool.context.stage_timers
         group = len(tasks) > 1
         flat = self._flat(values)
         waits = []      # seconds of each wait for the chip this launch made
-        # the runtime bounds the groups it queues, not their bytes: a
-        # chip that lags a few ms behind would hold the outputs and the
-        # inputs of as many groups. The chip is far ahead wherever groups
-        # form (GROUP_BYTES), so this wait is a check. A launch of one
-        # neither waits nor is waited for: it overlaps the groups' waits
-        # (what the lone ones still queued hold is bounded in _queued)
-        if group and self._group_out is not None and \
-                not self._group_out.is_deleted():   # given on: behind it
-            waits.append(self._under(SPAN_EXEC_WAIT, timed,
-                                     self._group_out.block_until_ready)[1])
+        marks, queued = self._group_marks, []
+        if group and held:
+            # the runtime bounds the groups it queues, not their bytes:
+            # a chip that lags a few ms behind would hold the outputs
+            # and the inputs of as many groups. A group that holds new
+            # outputs waits for the last group, whatever kind that was:
+            # one group in flight. The chip is far ahead wherever groups
+            # form (GROUP_BYTES), so this wait is a check. A launch of
+            # one neither waits nor is waited for: it overlaps the
+            # groups' waits (what the lone ones still queued hold is
+            # bounded in _queued)
+            for over in marks[-1:]:
+                if not over.is_deleted():       # given on: behind it
+                    waits.append(self._under(SPAN_EXEC_WAIT, timed,
+                                             over.block_until_ready)[1])
+        elif group:
+            # a group that holds nothing new goes behind ONE group still
+            # on the chip's queue, so that the chip has it queued while
+            # it works on that: of the last two groups, those the chip
+            # has not finished (a look at a mark costs a quarter of a
+            # microsecond), all but the last waited for. A wait that
+            # would return at once is not made: it hands the interpreter
+            # to another thread and stands in line to have it back
+            queued = [m for m in marks
+                      if not (m.is_deleted() or m.is_ready())]
+            for over in queued[:-1]:
+                waits.append(self._under(SPAN_EXEC_WAIT, timed,
+                                         over.block_until_ready)[1])
         results, called = self._under(SPAN_EXEC_CALL, timed, self._call,
                                       program, flat)
         # what tells that the launch is over: an output of its last
@@ -240,17 +285,12 @@ class TPUDevice(Device):
         own = len(results) > len(tasks)
         mark = results[-1] if own else done[0] if done else None
         if group:
-            self._group_out = mark
-        elif mark is not None:
-            held = sum(x.nbytes for x in done)
-            if own:     # what the program was given it does not hold anew
-                held -= sum(x.nbytes for x in flat
-                            if getattr(x, "is_deleted", bool)())
-            if held > 0:
-                # a tile weakly: one its collection has dropped is done
-                # with
-                waits += self._queued(
-                    (lambda: mark) if own else weakref.ref(mark), held, timed)
+            if mark is not None:
+                self._group_marks = [mark] if held else marks[-1:] + [mark]
+        elif mark is not None and held:
+            # a tile weakly: one its collection has dropped is done with
+            waits += self._queued(
+                (lambda: mark) if own else weakref.ref(mark), held, timed)
         names = [f.name for f in tasks[0].task_class.output_flows]
         for t, res in zip(tasks, results):
             t.output.update(normalize_outputs(res, names, t))
@@ -259,6 +299,10 @@ class TPUDevice(Device):
             if group:
                 self.stats["batches"] += 1
                 self.stats["batched_tasks"] += len(tasks)
+                self.stats["groups_in_place"] += not held
+                self.stats["groups_pipelined"] += bool(queued)
+            else:
+                self.stats["lone_in_place"] += not held
             # the longest call and the longest wait always: what a
             # stalled step stood in
             if called > self.stats["call_max_s"]:
@@ -303,9 +347,10 @@ class TPUDevice(Device):
         still on the chip's queue hold ``GROUP_BYTES`` of new outputs at
         most, as a group does: the thread that enqueues more waits for
         the oldest. A chip that keeps up has finished it long before,
-        and the wait is a look. (A launch whose outputs lie in buffers
-        it was given holds nothing new and is not counted.) Returns the
-        seconds of each wait it made."""
+        and the wait is a look. (A launch whose program holds nothing
+        new, its outputs in the buffers it was given, never comes here:
+        a Cholesky's lone SYRKs and TRSMs, a QR's lone updates.) Returns
+        the seconds of each wait it made."""
         oldest = []
         with self._lock:
             self._lone.append((mark, nbytes))
@@ -463,7 +508,9 @@ class TPUDevice(Device):
                   stacked: bool = False) -> Dict[int, Callable]:
         """``{size: program}`` for ``chore`` on inputs of signature
         ``sig``; ``program(*leaves of every member) -> one result per
-        member``. Unrolled over the chore's pure body: every size of
+        member``, and ``program.held`` the bytes of new outputs a launch
+        of it holds (``_build``). Unrolled over the chore's pure body:
+        every size of
         ``GROUP_SIZES`` that ``GROUP_BYTES`` admits and 1, built and run
         once the first time a signature is seen, so nothing compiles in a
         later step, whatever sizes it forms. A chore with a
@@ -573,13 +620,24 @@ class TPUDevice(Device):
                         fn, key=(*shared, size), persist=False,
                         donate_argnums=donated(size))
                 # a shared program this module has yet to run compiles
-                # for its chip now (an unshared one is new)
-                if shared is None or (shared, size) not in self._warmed:
+                # for its chip now (an unshared one is new), and its
+                # first run says what a launch of it holds anew: the
+                # outputs that lie in no buffer it was given. Read off
+                # the program, not off the declaration: a stacked form's
+                # results are slices of one product, and whether they
+                # come back in the members' own buffers is XLA's doing
+                if shared is None or (shared, size) not in self._warmed \
+                        or not hasattr(fn, "held"):
                     self._warmed.add((shared, size))
                     one = one or self._flat([values])
                     args = one * size
                     for i in donated(size):     # the task's own stay
                         args[i] = jax.numpy.copy(args[i])
-                    fn(*args)           # compiles; the result is dropped
+                    given_to = {args[i].unsafe_buffer_pointer()
+                                for i in donated(size)}
+                    # compiles; the result is dropped
+                    fn.held = sum(
+                        x.nbytes for x in tu.tree_leaves(fn(*args)[:size])
+                        if x.unsafe_buffer_pointer() not in given_to)
                 programs[size] = fn
         return programs

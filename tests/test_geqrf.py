@@ -369,7 +369,7 @@ def test_the_updates_run_where_their_tiles_lie(make_ctx, alone):
         # (tiles this small never fill the queue's bound)
         assert len(dev._lone) == 6 + 15
     else:
-        mark = dev._group_out
+        mark = dev._group_marks[-1]
         assert mark.shape == (1,) and not mark.is_deleted()
 
 
@@ -532,12 +532,14 @@ def test_a_serial_class_is_never_compiled_for_a_group(make_ctx):
         t.data["A"] = jnp.asarray(_seeded((16, 16), 30 + i))
     compiled = compile_cache.backend_compile_count()
     for left in (tasks, tasks[1:], tasks[3:]):
-        assert dev.execute_group(None, list(left), chore) == 0
+        assert dev.execute_group(None, list(left), chore) == (0, 0)
     assert compile_cache.backend_compile_count() == compiled
     assert not any(slot[2] for record in dev._table.values()
                    for slot in record if isinstance(slot, tuple))
     # the same four sharing their diagonal tile would be a group
     for t in tasks[1:]:
         t.data["R"] = tasks[0].data["R"]
-    assert dev.execute_group(None, list(tasks), chore) == 4
+    # four of three new tiles each: R and A 16 x 16, T 8 x 16
+    assert dev.execute_group(None, list(tasks), chore) == (
+        4, 4 * (2 * 16 * 16 + 8 * 16) * 4)
     assert all(set(t.output) == {"R", "A", "T"} for t in tasks)

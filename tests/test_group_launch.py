@@ -6,6 +6,7 @@ and the device module issues each bin as one XLA program
 bodies and a small PTG POTRF (four classes ready together) on the CPU
 platform, Python engine (the one a chip gets), one device module."""
 
+import gc
 import threading
 
 import numpy as np
@@ -14,6 +15,7 @@ import pytest
 import parsec_tpu as parsec
 import parsec_tpu.device.tpu
 from parsec_tpu import dtd
+from parsec_tpu.dsl import ptg
 from parsec_tpu.algorithms import build_potrf
 from parsec_tpu.algorithms.gemm import _gemm_dtd_body
 from parsec_tpu.core import context as context_mod
@@ -130,29 +132,109 @@ def test_groups_and_lone_tasks_give_the_same_bits(make_ctx, rng, scheduler):
                                atol=1e-4)
 
 
+class _Turn:
+    """A module's turn that knows who holds it."""
+
+    def __init__(self):
+        self._lock, self.owner = threading.Lock(), None
+
+    def acquire(self):
+        self._lock.acquire()
+        self.owner = threading.get_ident()
+
+    def release(self):
+        self.owner = None
+        self._lock.release()
+
+    def locked(self):
+        return self._lock.locked()
+
+    def mine(self):
+        return self.owner == threading.get_ident()
+
+
+def _turn_probe(ctx, monkeypatch):
+    """Put a ``_Turn`` in the module's place and watch: ``launched`` says
+    for every group launch whether its worker held the turn, ``released``
+    for every member of a group whether its worker held the turn when the
+    task completed, ``new`` the bytes of new outputs each group held."""
+    dev = _module(ctx)
+    dev.group_turn = turn = _Turn()
+    seen = {"launched": [], "released": [], "new": []}
+    grouped, launch = set(), dev.execute_group
+
+    def in_turn(es, tasks, chore):
+        n, new_bytes = launch(es, tasks, chore)
+        if n:
+            seen["launched"].append(turn.mine())
+            seen["new"].append(new_bytes)
+            grouped.update(t.uid for t in tasks[:n])
+        return n, new_bytes
+
+    def end(_es, task):
+        if task.uid in grouped:
+            seen["released"].append(turn.mine())
+
+    monkeypatch.setattr(dev, "execute_group", in_turn)
+    ctx.pins.register(PinsEvent.EXEC_END, end)
+    return seen
+
+
 def test_1024_chains_of_four_refill_the_groups(make_ctx, rng, monkeypatch):
     """The cell's DAG (4096 tasks in 1024 chains of 4, four workers) at
     8x8 tiles: the successors a group releases go to the worker's own
-    queue, and the next group forms from them."""
+    queue, and the next group forms from them. A DTD body returns a new
+    tile, so every group holds new outputs and is one group in flight:
+    its worker holds the turn from taking the tasks to the last member's
+    release."""
     ctx = make_ctx(nb_cores=4)
-    dev, out_of_turn = _module(ctx), []
-    launch = dev.execute_group
-
-    def in_turn(es, tasks, chore):
-        # a module has one group in flight: the worker holds the turn
-        # from taking the tasks to the last member's release
-        out_of_turn.append(not dev.group_turn.locked())
-        return launch(es, tasks, chore)
-
-    monkeypatch.setattr(dev, "execute_group", in_turn)
+    seen = _turn_probe(ctx, monkeypatch)
     (a_h, b_h, c_h), (a, b, c) = _matrices(rng, 256, 256, 32, 8)
     _gemm(ctx, a, b, c)
     groups, grouped = _groups(ctx)
-    assert _module(ctx).stats["tasks"] == 4096
+    stats = _module(ctx).stats
+    assert stats["tasks"] == 4096
     assert grouped > 0 and grouped / groups >= 4
-    assert out_of_turn and not any(out_of_turn)
+    assert len(seen["launched"]) == groups and all(seen["launched"])
+    assert len(seen["released"]) == grouped and all(seen["released"])
+    assert all(n > 0 for n in seen["new"])
+    assert stats["groups_in_place"] == stats["groups_pipelined"] == 0
+    assert stats["lone_in_place"] == 0
     np.testing.assert_allclose(c.to_array(), c_h + a_h @ b_h, rtol=1e-4,
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("nb_cores", [1, 4])
+def test_an_in_place_groups_members_are_released_with_the_turn_free(
+        make_ctx, rng, monkeypatch, nb_cores):
+    """The twin on a PTG POTRF, whose bodies write where their tiles lie
+    (``Chore.donates``): every group is launched inside its worker's
+    turn and holds nothing new, so its members complete once the turn is
+    given up, and the module counts what it did."""
+    ctx = make_ctx(nb_cores=nb_cores)
+    ctx.devices.devices[0].weight = 0.01    # the chip's path
+    seen = _turn_probe(ctx, monkeypatch)
+    m = rng.standard_normal((PN, PN))
+    a0 = (m @ m.T + PN * np.eye(PN)).astype(np.float32)
+    tiles = _potrf(ctx, a0)
+    groups, grouped = _groups(ctx)
+    stats = _module(ctx).dump_statistics()
+    assert stats["tasks"] == sum(CLASSES.values()) and groups >= 1
+    assert len(seen["launched"]) == groups and all(seen["launched"])
+    assert len(seen["released"]) == grouped and not any(seen["released"])
+    assert seen["new"] == [0] * groups
+    # what the module counted: every group and every lone launch in
+    # place, and a group is pipelined only behind another group
+    assert stats["groups_in_place"] == groups
+    assert stats["lone_in_place"] == stats["tasks"] - grouped
+    assert 0 <= stats["groups_pipelined"] <= groups - 1
+    assert ctx.statusz()["devices"][_module(ctx).index][
+        "groups_in_place"] == groups
+    want = np.linalg.cholesky(a0.astype(np.float64))
+    for (i, j), t in tiles.items():
+        ref = want[i * PNB:(i + 1) * PNB, j * PNB:(j + 1) * PNB]
+        np.testing.assert_allclose(np.tril(t) if i == j else t, ref,
+                                   rtol=0, atol=1e-4 * np.abs(want).max())
 
 
 # -- a ready set of mixed classes ---------------------------------------------
@@ -165,22 +247,31 @@ CLASSES = {"POTRF": PNT, "TRSM": PNT * (PNT - 1) // 2,
            "GEMM": PNT * (PNT - 1) * (PNT - 2) // 6}
 
 
-def _potrf(ctx, a0):
+def _potrf_over(ctx, a0, in_place=True):
     """``build_potrf`` over the lower tiles of ``a0``, every body the
     plain one (POTRF's and TRSM's ``batch_hook`` solve by another route
-    than the lone task, so their bits differ by design): the factor's
-    tiles."""
+    than the lone task, so their bits differ by design), with or without
+    the bodies' ``donates``: the matrix, factored."""
     A = TiledMatrix(PN, PN, PNB, PNB, name="A",
                     dist=SymTwoDimBlockCyclic(1, 1, uplo="lower"))
     for i, j in LOWER:
         A.write_tile((i, j), a0[i * PNB:(i + 1) * PNB,
                                 j * PNB:(j + 1) * PNB].copy())
     tp = build_potrf(A)
-    for name in ("POTRF", "TRSM"):
+    for name in CLASSES:
         (chore,) = tp.task_class_by_name(name).incarnations
+        assert chore.donates == (("T",) if name == "POTRF" else ("C",))
         chore.batch_hook = chore.batch_hook_shared = None
+        if not in_place:
+            chore.donates = None
     ctx.add_taskpool(tp)
     assert tp.wait_completed(120)
+    return A
+
+
+def _potrf(ctx, a0):
+    """The factor's tiles, as ``_potrf_over`` leaves them."""
+    A = _potrf_over(ctx, a0)
     return {k: np.asarray(A.data_of(k)) for k in LOWER}
 
 
@@ -703,7 +794,9 @@ def test_a_batch_hook_body_goes_alone_from_the_table_and_stacks_at_its_first_gro
     assert doubled([lone]) and _hooked_stacked.traced == []
     assert compile_cache.backend_compile_count() == compiled + 1
     eight = tasks(BIG)
-    assert dev.execute_group(None, eight, chore) == BIG and doubled(eight)
+    # (how many, the bytes of new outputs the launch holds)
+    assert dev.execute_group(None, eight, chore) == (BIG, BIG * 8 * 8 * 4)
+    assert doubled(eight)
     assert sorted(_hooked_stacked.traced) == [SMALL, BIG]
     assert compile_cache.backend_compile_count() == compiled + 3
     # every later size finds its program
@@ -711,9 +804,10 @@ def test_a_batch_hook_body_goes_alone_from_the_table_and_stacks_at_its_first_gro
         if len(made) == 1:
             dev.execute(None, made[0], chore)
         else:
-            assert dev.execute_group(None, made, chore) == len(made)
+            assert dev.execute_group(None, made, chore) == (
+                len(made), len(made) * 8 * 8 * 4)
         assert doubled(made)
-    assert dev.execute_group(None, tasks(SMALL - 1), chore) == 0
+    assert dev.execute_group(None, tasks(SMALL - 1), chore) == (0, 0)
     assert compile_cache.backend_compile_count() == compiled + 3
     assert _groups(ctx) == (3, 2 * BIG + SMALL)
     assert dev.stats["tasks"] == 2 + 2 * BIG + SMALL
@@ -790,3 +884,294 @@ def test_the_lone_launches_still_queued_hold_group_bytes_at_most(
     # weakly: a tile nobody holds any more is done with, and is not kept
     assert all(isinstance(ref, weakref.ref) and ref() is None
                for ref, _n in list(dev._lone)[:-1])
+
+
+# -- updates in place: what a launch holds decides the turn and the depth -----
+
+def _tile_sized():
+    import jax
+    gc.collect()
+    return sum(1 for a in jax.live_arrays() if a.shape == (PNB, PNB))
+
+
+@pytest.mark.parametrize("scheduler", ["lfq", "gd", "wfq"])
+def test_in_place_the_factor_is_the_same_and_each_tile_is_held_once(
+        make_ctx, rng, scheduler):
+    """``build_potrf``'s bodies name their RW flow as the last reading of
+    its version: on four workers the factor is bit for bit what the same
+    pool gives without the declarations, every task is announced once,
+    and when the pool has ended the collection holds one live array a
+    tile and nothing else of that shape is on the device."""
+    ctx = make_ctx(nb_cores=4, scheduler=scheduler)
+    ctx.devices.devices[0].weight = 0.01    # the chip's path
+    m = rng.standard_normal((PN, PN))
+    a0 = (m @ m.T + PN * np.eye(PN)).astype(np.float32)
+    seen = {PinsEvent.EXEC_BEGIN: [], PinsEvent.EXEC_END: []}
+    hooks = {event: (lambda _es, task, uids=uids: uids.append(task.uid))
+             for event, uids in seen.items()}
+    for event, hook in hooks.items():
+        ctx.pins.register(event, hook)
+    before = _tile_sized()
+    A = _potrf_over(ctx, a0)
+    for event, hook in hooks.items():
+        ctx.pins.unregister(event, hook)
+    for uids in seen.values():
+        assert len(uids) == len(set(uids)) == sum(CLASSES.values())
+    stats = _module(ctx).stats
+    assert stats["groups_in_place"] == stats["batches"] >= 1
+    assert stats["lone_in_place"] == stats["tasks"] - stats["batched_tasks"]
+    held = {k: A.data_of(k) for k in LOWER}
+    assert not any(t.is_deleted() for t in held.values())
+    assert len({id(t) for t in held.values()}) == len(LOWER)
+    del held
+    assert _tile_sized() - before == len(LOWER)
+    tiles = {k: np.asarray(A.data_of(k)) for k in LOWER}
+    plain = _potrf_over(ctx, a0, in_place=False)
+    assert all(np.array_equal(tiles[k], np.asarray(plain.data_of(k)))
+               for k in LOWER)
+    assert stats["groups_in_place"] < stats["batches"]
+    assert all(d.load == 0.0 for d in ctx.devices.devices)
+
+
+def _doubled(task, x):
+    return x * 2.0
+
+
+def _module_tasks(ctx, name, donates):
+    """A class of one RW flow on the module's own interface: ``make(n)``
+    gives n fresh tasks, each with a tile of its own."""
+    from parsec_tpu.core.task import Chore, Flow, FlowAccess, Task
+    from parsec_tpu.core.taskpool import Taskpool
+    tp = Taskpool(name)
+    tc = tp.new_task_class(name, params=("i",),
+                           flows=[Flow("x", FlowAccess.RW)])
+    chore = Chore(DeviceType.TPU, _doubled, donates=donates)
+    tc.add_chore(chore)
+    tp.context = ctx
+
+    def make(n):
+        tasks = [Task(tp, tc, (i,)) for i in range(n)]
+        for i, t in enumerate(tasks):
+            t.data["x"] = np.full((8, 8), float(i), np.float32)
+        return tasks
+
+    return tp, chore, make
+
+
+def _waited_for(dev, monkeypatch):
+    """What the module's launches wait for, in order."""
+    waited, under = [], dev._under
+
+    def watched(name, timed, fn, *args):
+        if name == parsec_tpu.device.tpu.SPAN_EXEC_WAIT:
+            waited.append(fn.__self__)
+        return under(name, timed, fn, *args)
+
+    monkeypatch.setattr(dev, "_under", watched)
+    return waited
+
+
+def test_the_third_in_place_group_waits_for_the_first_and_nothing_else(
+        make_ctx, monkeypatch):
+    """Two groups deep: an in-place group waits for the group before the
+    last, so the chip has the next one queued while it works; a group
+    that holds new outputs waits for the last group, whatever kind that
+    was, and of such groups the module remembers only the last. (In
+    place, a mark that is over when the module looks at it is not waited
+    for, so what was waited for is at most what the rule names.)"""
+    ctx = make_ctx()
+    dev = _module(ctx)
+    _tp, given, update = _module_tasks(ctx, "U", ("x",))
+    _tp2, kept, fresh = _module_tasks(ctx, "F", None)
+    tile = 8 * 8 * 4
+    waited = _waited_for(dev, monkeypatch)
+    marks = []
+    for _ in range(3):
+        assert dev.execute_group(None, update(SMALL), given) == (SMALL, 0)
+        marks.append(dev._group_marks[-1])
+    assert [m.shape for m in marks] == [(1,)] * 3      # the programs' own
+    assert all(m is marks[0] for m in waited)
+    assert dev._group_marks == marks[1:]
+    # new outputs: the last group, and it alone is remembered
+    made, before = fresh(SMALL), len(waited)
+    assert dev.execute_group(None, made, kept) == (SMALL, SMALL * tile)
+    assert waited[before:] == [marks[2]]
+    assert dev._group_marks == [made[-1].output["x"]]
+    # behind it an in-place group is queued at once, the next one waits
+    # for it, and a group with new outputs for the one before itself
+    before = len(waited)
+    assert dev.execute_group(None, update(BIG), given) == (BIG, 0)
+    assert len(waited) == before
+    assert dev.execute_group(None, update(BIG), given) == (BIG, 0)
+    assert all(m is made[-1].output["x"] for m in waited[before:])
+    last, before = dev._group_marks[-1], len(waited)
+    assert dev.execute_group(None, fresh(BIG), kept) == (BIG, BIG * tile)
+    assert waited[before:] == [last]
+    assert all((np.asarray(t.output["x"]) == 2.0 * i).all()
+               for i, t in enumerate(made))
+
+
+class _Mark:
+    """A group's mark as the module asks it: over, or still queued."""
+
+    def __init__(self, ready):
+        self.ready, self.waits = ready, 0
+
+    def is_deleted(self):
+        return False
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.waits += 1
+        self.ready = True
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["in place", "new outputs"])
+@pytest.mark.parametrize("over,waits", [
+    # (the group before the last, the last) over?: waited for, if the
+    # launch holds (nothing new, new outputs)
+    ((False, False), ((1, 0), (0, 1))),
+    ((True, False), ((0, 0), (0, 1))),
+    ((True, True), ((0, 0), (0, 1)))],
+    ids=["both queued", "the last queued", "the chip idle"])
+def test_the_module_counts_how_often_the_rule_engages(
+        make_ctx, in_place, over, waits):
+    """Which of the last two groups a group waits for, by what it holds
+    and what the chip has finished (in place, a wait that would return
+    at once is not made; with new outputs today's rule stands, the wait
+    for the last group a check); and the counters: ``lone_in_place``, ``groups_in_place``
+    (launches that held nothing new), ``groups_pipelined`` (in-place
+    groups called while the group before them was still on the chip's
+    queue). In ``dump_statistics()`` and ``statusz()``, always on."""
+    ctx = make_ctx()
+    dev = _module(ctx)
+    _tp, chore, make = _module_tasks(ctx, "C", ("x",) if in_place else None)
+    (lone,) = make(1)
+    dev.execute(None, lone, chore)
+    older, last = (_Mark(ready) for ready in over)
+    dev._group_marks = [older, last]
+    n, new = dev.execute_group(None, make(SMALL), chore)
+    assert n == SMALL and bool(new) != in_place
+    assert (older.waits, last.waits) == waits[not in_place]
+    assert dev._group_marks[:-1] == ([last] if in_place else [])
+    stats = dev.dump_statistics()
+    assert stats["lone_in_place"] == int(in_place)
+    assert stats["groups_in_place"] == int(in_place)
+    assert stats["groups_pipelined"] == int(in_place and not over[1])
+    assert (stats["batches"], stats["tasks"]) == (1, 1 + SMALL)
+    mine = ctx.statusz()["devices"][dev.index]
+    assert all(mine[k] == stats[k] for k in (
+        "lone_in_place", "groups_in_place", "groups_pipelined"))
+    assert len(dev._lone) == (0 if in_place else 1)
+
+
+def _updates(x, n, few=0):
+    """A PTG pool over the tiles of ``x``: ``n`` independent updates
+    written where they lie (class U), and ``few`` of a body that cannot
+    be traced (class B), all ready when the pool starts."""
+    tp = ptg.Taskpool("updates", X=x, N=n, FEW=few)
+
+    def one_tile(name, space, at):
+        return tp.task_class(
+            name, params=("i",), space=space,
+            affinity=lambda g, i: (g.X, (at(g, i), 0)),
+            flows=[ptg.FlowSpec(
+                "X", ptg.RW, tile=lambda g, i: (g.X, (at(g, i), 0)),
+                ins=[ptg.In(data=lambda g, i: (g.X, (at(g, i), 0)))],
+                outs=[ptg.Out(data=lambda g, i: (g.X, (at(g, i), 0)))])])
+
+    U = one_tile("U", lambda g: ((i,) for i in range(g.N)),
+                 lambda g, i: i)
+    B = one_tile("B", lambda g: ((i,) for i in range(g.FEW)),
+                 lambda g, i: g.N + i)
+
+    @U.body(device=DeviceType.TPU, donates=("X",))
+    def update(task, X):
+        return X * 2.0
+
+    @B.body(device=DeviceType.TPU, donates=("X",))
+    def broken(task, X):
+        raise ValueError("a body that cannot be traced")
+
+    return tp
+
+
+@pytest.mark.parametrize("what", ["a release", "a lone launch"])
+def test_a_failure_after_the_turn_was_given_up_aborts_and_releases(
+        make_ctx, what):
+    """An in-place group's members are released with the turn free, and
+    the bins too small for a group are launched after them: one of
+    either that raises there leaves the pool aborted with its error,
+    every load released, the turn free and the context serving."""
+    ctx = make_ctx()
+    dev = _module(ctx)
+    few = 2 if what == "a lone launch" else 0
+    x = TiledMatrix.from_array(np.ones(((BIG + few) * 8, 8), np.float32),
+                               8, 8, name="X")
+    tp = _updates(x, BIG, few)
+    completed = []
+
+    def complete(task):
+        completed.append((task.locals, dev.group_turn.locked()))
+        if what == "a release" and len(completed) == 3:
+            raise ValueError("a release that raises")
+
+    tp.task_class_by_name("U").on_complete = complete
+    ctx.add_taskpool(tp)
+    with pytest.raises(RuntimeError, match=(
+            "release that raises" if what == "a release"
+            else "cannot be traced")):
+        tp.wait_completed(60.0)                 # no waiter hangs
+    assert isinstance(tp.error, ValueError)
+    # the eight left in ONE launch, which held nothing new; its members
+    # were being released with the turn free when it happened
+    assert _groups(ctx) == (1, BIG) and dev.stats["groups_in_place"] == 1
+    assert len(completed) == (3 if what == "a release" else BIG)
+    assert not any(locked for _locals, locked in completed)
+    assert not dev.group_turn.locked()
+    assert all(d.load == 0.0 for d in ctx.devices.devices)
+    # the context serves the next pool
+    y = TiledMatrix.from_array(np.ones((BIG * 8, 8), np.float32), 8, 8,
+                               name="Y")
+    again = _updates(y, BIG)
+    ctx.add_taskpool(again)
+    assert again.wait_completed(60.0) and (y.to_array() == 2.0).all()
+
+
+def test_more_workers_than_cores_on_a_short_switch_interval(make_ctx, rng):
+    """The releases of an in-place group run beside the next worker's
+    take and call, and the module's marks pass from turn to turn: eight
+    workers handed the interpreter every 10 µs factor the matrix three
+    times over, every task once, the factor the lone path's bits, what
+    the module counted whole."""
+    import os
+    import sys
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ctx = make_ctx(nb_cores=max(8, 2 * (os.cpu_count() or 1)))
+        ctx.devices.devices[0].weight = 0.01    # the chip's path
+        dev = _module(ctx)
+        m = rng.standard_normal((PN, PN))
+        a0 = (m @ m.T + PN * np.eye(PN)).astype(np.float32)
+        runs = [_potrf(ctx, a0) for _ in range(3)]
+    finally:
+        sys.setswitchinterval(interval)
+    stats = dev.dump_statistics()
+    assert stats["tasks"] == 3 * sum(CLASSES.values())
+    assert sum(es.stats["executed"] for es in ctx.streams) == stats["tasks"]
+    assert stats["groups_in_place"] == stats["batches"]
+    assert stats["lone_in_place"] == stats["tasks"] - stats["batched_tasks"]
+    assert 0 <= stats["groups_pipelined"] < stats["batches"]
+    assert not dev.group_turn.locked() and len(dev._group_marks) <= 2
+    assert all(d.load == 0.0 for d in ctx.devices.devices)
+    assert all(np.array_equal(runs[0][k], run[k])
+               for run in runs[1:] for k in LOWER)
+    want = np.linalg.cholesky(a0.astype(np.float64))
+    for (i, j), t in runs[0].items():
+        ref = want[i * PNB:(i + 1) * PNB, j * PNB:(j + 1) * PNB]
+        np.testing.assert_allclose(np.tril(t) if i == j else t, ref,
+                                   rtol=0, atol=1e-4 * np.abs(want).max())

@@ -227,13 +227,56 @@ def test_every_update_is_written_to_its_tile_and_frees_the_last_version(
     ctx.add_taskpool(tp)
     assert tp.wait_completed(300)
     assert len(held) == CLASSES["SYRK"] + CLASSES["GEMM"] and not stale
-    # beside the matrix: what one launch made and has not yet released
-    # (a module has one group in flight: seven of eight when the first
-    # member completes) and what the other workers have in flight (6 to
-    # 10 with four), never a second copy of each of 28 trailing tiles
-    assert max(held) - before <= GROUP_SIZES[0] + 4 * (nb_cores - 1), \
-        (before, max(held))
+    # beside the matrix: nothing. Every body writes where its tile lies
+    # (``Chore.donates``), so a launch, alone or a group, one queued or
+    # two, makes no array the matrix did not hold (until PR 36 a launch's
+    # outputs stood beside the versions they replaced until its members'
+    # release: GROUP_SIZES[0] + 4 * (nb_cores - 1) tiles at the most)
+    assert max(held) <= before, (before, max(held))
+    dev = _module(ctx)
+    assert dev.stats["groups_in_place"] == dev.stats["batches"] > 0
+    assert dev.stats["lone_in_place"] == \
+        dev.stats["tasks"] - dev.stats["batched_tasks"]
     assert _residual(key, {k: A.data_of(k) for k in LOWER}) <= LIMIT
+
+
+def test_a_callers_tile_is_gone_once_its_update_is_launched(make_ctx):
+    """Every tile of the triangle is updated by some task where it lies:
+    the arrays the caller wrote into the collection are deleted, the
+    collection holds the factor, and nothing deleted can be reached from
+    it."""
+    ctx = make_ctx()
+    A, key, _a0 = _matrix()
+    first = {k: A.data_of(k) for k in LOWER}
+    tiles = _factor(ctx, A)
+    assert all(t.is_deleted() for t in first.values())
+    assert not any(t.is_deleted() for t in tiles.values())
+    assert _residual(key, tiles) <= LIMIT
+
+
+@pytest.mark.parametrize("name", list(CLASSES))
+def test_every_program_of_the_cells_bodies_holds_nothing_new(make_ctx, name):
+    """What the module read off each program it built for the cell's
+    bodies (``TPUDevice._build``: the outputs of a program's first run
+    that lie in no buffer it was given): nothing, for the lone program
+    and for every group size, the stacked forms of TRSM (one product for
+    a column) among them. That figure, not the declaration, is what ends
+    a group's turn at its call and queues it behind the last group."""
+    ctx = make_ctx(nb_cores=1)          # one worker: whole bins, so groups
+    A, _key, _a0 = _matrix()
+    tp = build_potrf(A)
+    (chore,) = tp.task_class_by_name(name).incarnations
+    ctx.add_taskpool(tp)
+    assert tp.wait_completed(300)
+    built = {(slot[2], size): program.held
+             for slot, programs in _module(ctx)._table[id(chore)].items()
+             if isinstance(slot, tuple)
+             for size, program in programs.items()}
+    assert (False, 1) in built and set(built.values()) == {0}, built
+    # a POTRF is ready alone and never stacked; a TRSM's column is
+    if name != "POTRF":
+        assert any(size > 1 for _stacked, size in built)
+    assert any(stacked for stacked, _size in built) == (name == "TRSM")
 
 
 # -- the front end's spans, the counters by class, why takes ended ------
@@ -317,10 +360,10 @@ def test_with_the_timers_on_tasks_and_launches_are_counted_by_class(
     handed = {}                         # the smallest bin a class handed
     launch = ctx._group_launch
 
-    def watched(es, tasks, chore, module):
+    def watched(es, tasks, chore, module, later):
         name = tasks[0].task_class.name
         handed[name] = min(handed.get(name, len(tasks)), len(tasks))
-        launch(es, tasks, chore, module)
+        launch(es, tasks, chore, module, later)
 
     monkeypatch.setattr(ctx, "_group_launch", watched)
     A, _key, _a0 = _matrix()
