@@ -13,7 +13,11 @@ points a user calls, at full width, cheapest phase first:
   host      parsec.init -> Context: DTD GEMM n=2048/nb=512 (insert_gemm_dtd)
             against numpy, DTD POTRF n=4096/nb=512 (insert_potrf_dtd) and
             PTG build_potrf of the same size via add_taskpool/wait;
-            every task on a tpuN module, none on the inline CPU module
+            every task on a tpuN module, none on the inline CPU module;
+            with several chips the POTRF tiles are advised 2D over the
+            modules and each POTRF line says, per module, the tasks it
+            ran and the bytes copied to it from other chips: every task
+            on the module its written tile is advised to
   qr_host   the benchmark's dgeqrf_ptg_host driver once at N=8192 in
             2048-tiles (30 tasks of the four compact-WY kernels through
             add_taskpool/wait): the three residuals of V, T and R against
@@ -293,9 +297,13 @@ def phase_host(sz, on_chip):
     from parsec_tpu import _native, dtd
     from parsec_tpu.algorithms import (build_potrf, insert_gemm_dtd,
                                        insert_potrf_dtd)
-    from parsec_tpu.data.matrix import SymTwoDimBlockCyclic, TiledMatrix
+    from parsec_tpu.data.matrix import (SymTwoDimBlockCyclic, TiledMatrix,
+                                        advise_on_devices)
 
     n_dev = len(jax.devices())
+    # several chips: the POTRF matrices' tiles are advised 2D-cyclically
+    # over the chips' modules, as testing_dpotrf -g <n> advises them
+    grid = (2, n_dev // 2) if n_dev > 1 and n_dev % 2 == 0 else (1, n_dev)
     rng = np.random.default_rng(0)
     ctx = parsec.init(nb_cores=4)
     try:
@@ -344,16 +352,52 @@ def phase_host(sz, on_chip):
         S_h = (0.5 * (R + R.T) + 2.0 * n * np.eye(n)).astype(np.float32)
         lower = [(i, j) for j in range(nt) for i in range(j, nt)]
 
+        def home(mat, key):
+            """The chip the tile at ``key`` is advised to."""
+            return mods[mat.device_advice(key) % len(mods)].jax_device
+
         def spd(name):
-            mat = TiledMatrix(n, n, nb, nb, name=name,
-                              dist=SymTwoDimBlockCyclic(1, 1, uplo="lower"))
+            mat = advise_on_devices(
+                TiledMatrix(n, n, nb, nb, name=name,
+                            dist=SymTwoDimBlockCyclic(1, 1, uplo="lower")),
+                grid=grid)
             for i, j in lower:
                 mat.write_tile((i, j), jax.device_put(
-                    S_h[i * nb:(i + 1) * nb, j * nb:(j + 1) * nb]))
+                    S_h[i * nb:(i + 1) * nb, j * nb:(j + 1) * nb],
+                    home(mat, (i, j))))
             return mat
+
+        def placement(mat, before):
+            """What the modules and the workers counted since ``before``
+            (``counted()``): did placement follow the advice, and what
+            crossed between chips for it?"""
+            now = counted()
+            by_module = {
+                name: {k: v - before[0][name][k] for k, v in c.items()}
+                for name, c in now[0].items()}
+            advised, on_advised = (a - b for a, b in zip(now[1], before[1]))
+            off = [key for key in lower
+                   if mat.data_of(key).devices() != {home(mat, key)}]
+            if n_dev > 1:
+                require(advised == tasks and on_advised == tasks,
+                        f"{on_advised} of {advised} advised tasks (the "
+                        f"graph has {tasks}) ran on their tile's module")
+                require(not off, f"tiles off their advised chip: {off}")
+            return dict(tasks_on_advised=f"{on_advised}/{advised}",
+                        by_module=by_module)
+
+        def counted():
+            return ({s["name"]: {k: s[k] for k in (
+                "tasks", "remote_copies", "remote_bytes_in", "remote_hits")}
+                for s in ctx.devices.dump_statistics()
+                if s["name"].startswith("tpu")},
+                [sum(es.stats[k] for es in ctx.streams)
+                 for k in ("tasks_advised", "tasks_on_advised")])
 
         # by insertion (dpotrf_dtd): the tester's loop, a flush per tile
         D_ = spd("D")
+        tasks = nt + nt * (nt - 1) + nt * (nt - 1) * (nt - 2) // 6
+        before = counted()
         ran = sum(s["tasks"] for s in ctx.devices.dump_statistics()
                   if s["name"].startswith("tpu"))
         tp = dtd.Taskpool("smoke_potrf")
@@ -366,7 +410,6 @@ def phase_host(sz, on_chip):
         L = np.tril(D_.to_array().astype(np.float64))
         err = rel(L @ L.T, S_h)
         require(err <= 1e-3, f"DTD POTRF residual {err:.2e}")
-        tasks = nt + nt * (nt - 1) + nt * (nt - 1) * (nt - 2) // 6
         ran = sum(s["tasks"] for s in ctx.devices.dump_statistics()
                   if s["name"].startswith("tpu")) - ran
         # the native engine (a CPU dry run's) runs bodies off the modules
@@ -379,11 +422,13 @@ def phase_host(sz, on_chip):
         say("host", dtd_potrf=f"n={n}/nb={nb}", tasks=tasks,
             on_tpu_modules=ran, first_run_s=f"{t_dtd:.1f}",
             residual=f"{err:.1e}", tiles_flushed=tp.tiles.retired,
-            output_devices=tile_devices(D_.data_of(key) for key in lower))
+            output_devices=tile_devices(D_.data_of(key) for key in lower),
+            **(placement(D_, before) if tp._native is None else {}))
 
         # unfolded by the PTG front end (dpotrf_ptg_host); the stage
         # timers on, so that the modules count tasks by class
         P_ = spd("P")
+        before = counted()
         timers = ctx.set_stage_timers(True)
         t0 = time.perf_counter()
         ctx.add_taskpool(build_potrf(P_))
@@ -408,7 +453,7 @@ def phase_host(sz, on_chip):
                                   f"the graph has {want}")
         say("host", ptg_potrf=f"n={n}/nb={nb}", first_run_s=f"{t_potrf:.1f}",
             residual=f"{err:.1e}", output_devices=p_devs,
-            tasks_by_class=by_class)
+            tasks_by_class=by_class, **placement(P_, before))
     finally:
         parsec.fini(ctx)
 

@@ -111,7 +111,15 @@ class ExecutionStream:
                       # task that cannot be grouped), and the bins its
                       # takes launched (stage timers on)
                       "group_end_limit": 0, "group_end_empty": 0,
-                      "group_end_class": 0, "group_bins": 0}
+                      "group_end_class": 0, "group_bins": 0,
+                      # takes that ended on a task whose tile lies on
+                      # another chip (several chip modules; as above)
+                      "group_end_module": 0,
+                      # with several chip modules, always: the tasks
+                      # this worker launched whose written tile is
+                      # advised to a module, and those of them that ran
+                      # on that module (Context._count_advised)
+                      "tasks_advised": 0, "tasks_on_advised": 0}
         self._vp_peers = None        # cached steal orders (sched/base.py)
         self._steal_order = None
         # extensible per-stream info slots (parsec_internal.h:688-702)
@@ -238,7 +246,13 @@ class Context:
         # device_gpu.h:115-136) — cold device tiles spill back to host
         # numpy through their collection
         from ..device.hbm import manager_from_mca
-        self.hbm = manager_from_mca()
+        # host values it stages land on the first chip module's chip
+        # (a CPU mesh keeps JAX's uncommitted default placement, as the
+        # comm stage target does: device/tpu.py)
+        chips = self.devices.chips
+        self.hbm = manager_from_mca(
+            chips[0].jax_device if chips and chips[0].platform != "cpu"
+            else None)
 
         # always-on metrics plane (profiling/metrics.py): the process-
         # global registry plus this context's scrape-time collectors
@@ -402,12 +416,21 @@ class Context:
         """Stage-through one collection read (see :attr:`stage_reads`):
         host arrays are device_put (async) and written back so the
         collection holds the device copy; everything else passes
-        through."""
+        through. Among several chip modules a tile of an advised
+        collection goes to the chip it is advised to, committed there,
+        where its writer runs; any other stays uncommitted and follows
+        the module that takes it."""
         import numpy as np
         if not self.stage_reads or not isinstance(value, np.ndarray):
             return value
         import jax
-        staged = jax.device_put(value)
+        chips = self.devices.chips
+        advice = getattr(dc, "device_advice", None)
+        if len(chips) > 1 and advice is not None:
+            staged = jax.device_put(
+                value, chips[advice(key) % len(chips)].jax_device)
+        else:
+            staged = jax.device_put(value)
         dc.write_tile(key, staged)
         return staged
 
@@ -747,6 +770,13 @@ class Context:
                              if t.name == name), None)
             return self._taskpools_by_name.get(name)
 
+    def drop_copies(self, tp: Taskpool) -> None:
+        """A finished pool holds no tile: the copies of other chips'
+        tiles that ``tp``'s tasks read on a chip go with it (called by
+        the pool as it ends, before its waiters wake)."""
+        for dev in self.devices.chips:
+            dev.drop_copies(tp)
+
     def _taskpool_terminated(self, tp: Taskpool) -> None:
         if self.dfsan is not None:
             # termdet is a full synchronization point: everything the
@@ -993,12 +1023,15 @@ class Context:
         bin holds what ``dev`` says one launch of its first task may
         carry (``limit`` for ``task``'s), at ``GROUP_TAKE`` tasks in all,
         on an empty queue, or on a task that cannot be grouped (another
-        taskpool, no group chore, none ``dev`` may launch): that one
-        waits in the bypass slot. Nothing is pushed back, so the
+        taskpool, no group chore, none ``dev`` may launch, or one whose
+        written tile is advised to another chip module than ``dev``):
+        that one waits in the bypass slot, and this worker takes it up
+        next, on its own module. Nothing is pushed back, so the
         scheduler's order is what it was."""
         tp = task.taskpool
         bins = {found[1]: (found[0], [task], limit)}
         taken, end = 1, "limit"
+        many = len(self.devices.chips) > 1
         while taken < GROUP_TAKE:
             nxt = self._select(es)
             if nxt is None:
@@ -1007,8 +1040,13 @@ class Context:
             if nxt.taskpool.cancelled:
                 nxt.taskpool.addto_nb_tasks(-1)      # as _worker_main
                 continue
-            entry = None
+            entry, why = None, "class"
             its = self._group_chore(nxt) if nxt.taskpool is tp else None
+            if its is not None and many and \
+                    self.devices.preferred(nxt) not in (None, dev):
+                # the tile it writes lies on another chip: never
+                # launched here, it waits for that module's turn
+                its, why = None, "module"
             if its is not None:
                 entry = bins.get(its[1])
                 if entry is None:
@@ -1017,7 +1055,7 @@ class Context:
                         entry = bins[its[1]] = (its[0], [], room)
             if entry is None:
                 es.next_task = nxt
-                end = "class"
+                end = why
                 break
             es.stats["selected"] += 1
             entry[1].append(nxt)
@@ -1114,6 +1152,7 @@ class Context:
             if n:
                 for task in tasks[done:done + n]:
                     self._mark_exe(es, task)
+                    self._count_advised(es, task, dev)
                     if new_bytes:
                         self.complete_task(es, task)
                     else:
@@ -1176,8 +1215,20 @@ class Context:
             if rc == HookReturn.NEXT:
                 task.chore_mask &= ~(1 << i)
                 continue
+            self._count_advised(es, task, dev)
             return rc
         return HookReturn.ERROR
+
+    def _count_advised(self, es: ExecutionStream, task: Task, dev) -> None:
+        """With several chip modules: ``task`` was launched on
+        ``dev``; did the tile it writes have a module advised, and is
+        that ``dev``? (``es.stats["tasks_advised"]``,
+        ``["tasks_on_advised"]``; one test with one chip.)"""
+        if len(self.devices.chips) > 1:
+            home = self.devices.preferred(task)
+            if home is not None:
+                es.stats["tasks_advised"] += 1
+                es.stats["tasks_on_advised"] += home is dev
 
     def _hbm_track(self, dc, key, value):
         """Register a device-resident tile a task is writing to its

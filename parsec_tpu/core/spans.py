@@ -27,6 +27,10 @@ SPAN_RELEASE = "parsec:release"
 SPAN_TURN = "parsec:turn"
 SPAN_EXEC_WAIT = "parsec:exec_wait"
 SPAN_EXEC_CALL = "parsec:exec_call"
+# the making of this chip's copy of a tile that lies on another chip
+# (device/tpu.py ``_copy_here``, inside the thread's parsec:exec too): a
+# span a copy made, none where a copy already here serves the read
+SPAN_STAGE_IN = "parsec:stage_in"
 # the PTG front end's own stages (dsl/ptg.py names them on its taskpool
 # and task classes; a front end that names none has none)
 SPAN_PTG_STARTUP = "parsec:ptg_startup"

@@ -110,6 +110,13 @@ class TaskClass:
     def make_key(self, locals: Tuple[int, ...]) -> Tuple[int, Tuple[int, ...]]:
         return (self.tc_id, tuple(locals))
 
+    def written_tile(self, task: Task):
+        """``(collection, key)`` of the tile ``task`` writes, which a
+        context with several chip modules places it by
+        (``device.base.Registry.device_for``); None where the front end
+        names none (the DSLs fill it in)."""
+        return None
+
     def add_chore(self, chore: Chore) -> "TaskClass":
         self.incarnations.append(chore)
         return self
@@ -449,6 +456,8 @@ class Taskpool:
             # recovery replay's, with the stale abort)
             return
         debug_verbose(4, "taskpool", "%s terminated", self.name)
+        if self.context is not None:
+            self.context.drop_copies(self)      # before a waiter wakes
         self._complete_evt.set()
         if self.on_complete is not None:
             self.on_complete(self)

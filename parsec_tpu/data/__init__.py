@@ -17,7 +17,7 @@ the batched/compiled execution path.
 from .collection import DataCollection, LocalCollection
 from .matrix import (TiledMatrix, TwoDimBlockCyclic, SymTwoDimBlockCyclic,
                      TwoDimTabular, TwoDimBandCyclic, OneDimCyclic,
-                     SubtileView)
+                     SubtileView, advise_on_devices)
 from .data import Data, DataCopy, CoherencyState
 from .arena import Arena, ArenaDatatype, ArenaRegistry
 from .redistribute import build_redistribute_ptg, insert_redistribute_dtd

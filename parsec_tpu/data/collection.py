@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import itertools
 import threading
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Callable, Dict, Iterable, Optional
 
 _dc_ids = itertools.count(1)
 
@@ -22,6 +22,15 @@ class DataCollection:
     #: tiles); compiled executors neither read their host tiles nor
     #: write results back
     scratch = False
+
+    #: ``key -> index`` among the context's chip modules (in the order
+    #: they were registered) of the accelerator the tile at ``key``
+    #: should live on, or None: upstream's
+    #: ``parsec_advise_data_on_device(..., PREFERRED_DEVICE)``. A context
+    #: with several chip modules sends a task to the module the tile it
+    #: WRITES is advised to (``device.base.Registry.device_for``); a
+    #: collection nobody advised is placed by load, as before
+    device_advice: Optional[Callable[[Any], int]] = None
 
     def __init__(self, name: str = "dc", nodes: int = 1, myrank: int = 0):
         self.name = name

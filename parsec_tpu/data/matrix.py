@@ -232,6 +232,24 @@ class TiledMatrix(DataCollection):
         return SubtileView(self, key, mb, nb, name=name)
 
 
+def advise_on_devices(A: DataCollection,
+                      grid: Tuple[int, int] = (1, 1)) -> DataCollection:
+    """Advise every tile of ``A`` to a preferred accelerator,
+    2D-cyclically over a ``rows x cols`` grid of the context's chip
+    modules: tile ``(m, n)`` to module ``(m % rows) * cols + (n % cols)``
+    (DPLASMA's ``dplasma_advise_data_on_device`` with its 2D operator
+    over ``parsec_advise_data_on_device(...,
+    PARSEC_DEV_DATA_ADVICE_PREFERRED_DEVICE)``, as ``testing_dpotrf -g
+    <n>`` calls it). The caller puts the tiles where it advised them; the
+    runtime then runs a task where the tile it writes is advised
+    (``DataCollection.device_advice``). Returns ``A``."""
+    rows, cols = (int(x) for x in grid)
+    if rows < 1 or cols < 1:
+        raise ValueError(f"a device grid of {grid!r}")
+    A.device_advice = lambda key: (key[0] % rows) * cols + key[1] % cols
+    return A
+
+
 class SubtileView(TiledMatrix):
     """Recursive subdivision of a single parent tile (subtile.c analog).
 

@@ -58,6 +58,11 @@ class Device:
         """Stop any device-owned threads (called from Context.fini);
         base devices have none."""
 
+    def drop_copies(self, pool) -> None:
+        """Let go of what the module keeps for ``pool``'s tasks of
+        tiles that lie on other chips (called as a pool ends); a module
+        that keeps none has nothing to do."""
+
     def add_load(self, units: float) -> None:
         """In-flight units beyond the one ``Registry.device_for`` added:
         a group launch accounts every member."""
@@ -114,6 +119,12 @@ class Registry:
         from .recursive import RecursiveDevice
         self.context = context
         self.devices: List[Device] = []
+        # the chip modules in the order they were registered: what a
+        # collection's advice indexes (``preferred``); and how often a
+        # task's written tile was looked up for it, which never happens
+        # with one chip
+        self.chips: List[Device] = []
+        self.advice_lookups = 0
         self.add(CPUDevice())
         self.add(RecursiveDevice())
         if mca_param.get("device.tpu.enabled", True):
@@ -156,17 +167,50 @@ class Registry:
     def add(self, dev: Device) -> Device:
         dev.attach(self, len(self.devices))
         self.devices.append(dev)
+        if dev.device_type == DeviceType.TPU:
+            self.chips.append(dev)
         debug_verbose(4, "device", "registered device %d: %s",
                       dev.index, dev.name)
         return dev
 
-    def device_for(self, device_type: DeviceType, task: Task) -> Optional[Device]:
-        """parsec_get_best_device analog: among devices matching the chore's
-        type, pick the least (load / weight); ties go to the heavier device
-        (idle accelerator beats idle CPU). The recursive pseudo-device is
+    def preferred(self, task: Task) -> Optional[Device]:
+        """The chip module ``task`` belongs on: the one the tile it
+        writes (``TaskClass.written_tile``: a PTG class's first written
+        flow, a DTD task's affinity or first written argument) is advised
+        to (``DataCollection.device_advice``, an index among ``chips``),
+        or None where the task names no such tile or nobody advised its
+        collection. Asked only where several chip modules are
+        registered."""
+        self.advice_lookups += 1
+        where = task.task_class.written_tile(task)
+        if where is None:
+            return None
+        # any object with data_of/write_tile serves as a collection
+        advice = getattr(where[0], "device_advice", None)
+        if advice is None:
+            return None
+        return self.chips[advice(where[1]) % len(self.chips)]
+
+    def device_for(self, device_type: DeviceType,
+                   task: Task) -> Optional[Device]:
+        """parsec_get_best_device analog. With several chip modules
+        registered, an accelerator body runs on the module that the tile
+        its task WRITES is advised to (``preferred``; upstream asks the
+        preferred device of the written data first too): the tile then
+        never leaves its chip, and the task updates it where it lies.
+        Else, and always with one chip module (one test, no look-up):
+        among the devices matching the chore's type, the least
+        (load + 1) / weight; ties go to the heavier device (idle
+        accelerator beats idle CPU). The recursive pseudo-device is
         never auto-selected — only chores that name it explicitly use it
         (reference: PARSEC_DEV_RECURSIVE is special-cased in the core, not
         part of load balancing)."""
+        if len(self.chips) > 1 and device_type & DeviceType.TPU:
+            best = self.preferred(task)
+            if best is not None:
+                with best._lock:
+                    best.load += 1.0
+                return best
         best, best_score = None, None
         for dev in self.devices:
             if not (dev.device_type & device_type):

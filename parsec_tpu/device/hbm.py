@@ -66,11 +66,17 @@ class HBMManager:
     device values (evicting others to make the budget hold).
     """
 
-    def __init__(self, budget_bytes: int, unit: int = 4096):
+    def __init__(self, budget_bytes: int, unit: int = 4096,
+                 device: Any = None):
         import jax
         self.jax = jax
         self.budget = budget_bytes
         self.unit = unit
+        # the chip a host value is staged on where the caller names none
+        # (``ensure(device=)``): a Context's first chip module's, which
+        # need not be JAX's first device (a comm rank's own chip, a cap
+        # on the modules); None = wherever JAX puts an uncommitted value
+        self.home = device
         # the budget is PER CHIP: one zone per jax device tiles land on
         # (per-chip device modules stage copies onto their own chips —
         # a single global zone would not bound any real HBM)
@@ -98,11 +104,17 @@ class HBMManager:
                                                  unit=self.unit)
         return z
 
+    def zone_of(self, dev) -> ZoneAllocator:
+        """The zone of the chip ``dev`` (every chip has the budget)."""
+        with self._lock:
+            return self._zone_for(dev)
+
     @property
     def zone(self) -> ZoneAllocator:
-        """The default device's zone (per-chip budget view)."""
-        with self._lock:
-            return self._zone_for(self.jax.devices()[0])
+        """The zone of the manager's own chip (``home``; without one,
+        of the chip the last host value landed on, else JAX's first)."""
+        return self.zone_of(self.home or self._stage_dev or
+                            self.jax.devices()[0])
 
     def _account_alloc(self, nbytes: int, dev) -> Optional[int]:
         zone = self._zone_for(dev)
@@ -189,11 +201,14 @@ class HBMManager:
                protect: Tuple[Hashable, ...] = (),
                next_use: Optional[int] = None,
                spill: Optional[Callable] = None,
-               best_effort: bool = False) -> Any:
+               best_effort: bool = False, device: Any = None) -> Any:
         """Return the device-resident value for ``key``, staging it in
         (and evicting under pressure) if needed. ``value`` supplies the
         data on first sight; ``protect`` keys are not eviction
         candidates during this call (the current wave's working set).
+        A host value is staged on ``device`` (the chip of the module
+        that asks), else on the manager's ``home``, and accounted in
+        that chip's zone.
         ``best_effort=True`` never evicts: if no free space remains the
         current (possibly host) value is returned unstaged — the
         prefetch contract."""
@@ -232,12 +247,13 @@ class HBMManager:
                 # only ever made against the device the value actually
                 # lands on. The one-tile window between staging and
                 # reservation is the only transient physical overshoot.
-                guess = self._stage_dev or self.jax.devices()[0]
+                target = device or self.home
+                guess = target or self._stage_dev or self.jax.devices()[0]
                 off = self._account_alloc(nb, guess)
                 if off is None and best_effort:
                     return host_val            # no room: stay spilled
                 try:
-                    staged = self.jax.device_put(host_val)
+                    staged = self.jax.device_put(host_val, target)
                 except Exception:
                     if off is not None:        # never leak the probe
                         self._zone_for(guess).free(off)
@@ -462,10 +478,11 @@ def track_collection_write(mgr: Optional[HBMManager], dc, key,
     return mkey
 
 
-def manager_from_mca() -> Optional[HBMManager]:
+def manager_from_mca(device: Any = None) -> Optional[HBMManager]:
     """Build an :class:`HBMManager` from the MCA budget param, or None
-    when unlimited."""
+    when unlimited; ``device`` is the chip it stages host values on
+    (``HBMManager.home``)."""
     mb = int(mca_param.get("device.hbm_budget_mb", 0))
     if mb <= 0:
         return None
-    return HBMManager(mb * (1 << 20))
+    return HBMManager(mb * (1 << 20), device=device)

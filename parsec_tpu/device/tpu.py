@@ -9,8 +9,12 @@ are played by XLA/PJRT itself:
 - *stage-in/out*: one rule for every leaf the module hands to XLA
   (``_here``): a ``jax.Array`` not committed to another chip passes as it
   is — tiles made by previous TPU tasks stay resident in HBM and flow to
-  successors without host bounce — and a leaf committed elsewhere or a
-  host value is ``device_put`` on this module's chip.
+  successors without host bounce — and a host value is ``device_put`` on
+  this module's chip. A tile committed to ANOTHER chip is copied here
+  once per version, chip to chip (``_copy_here``): the module keeps the
+  copy for the next reader of that version, within ``REMOTE_BYTES``,
+  until the version is superseded or the pool that read it ends, and
+  never hands it to a program to write into.
 - *streams + events*: JAX dispatch is asynchronous — a jitted call
   returns at once with future-backed arrays, so consecutive tasks
   pipeline on device; blocking only happens at final writebacks.
@@ -70,7 +74,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from .base import Device
 from ..core.spans import (SPAN_EXEC, SPAN_EXEC_CALL, SPAN_EXEC_WAIT,
-                          StageSpan)
+                          SPAN_STAGE_IN, StageSpan)
 from ..core.task import (GROUP_SIZES, Chore, DeviceType, FlowAccess,
                          HookReturn, Task, normalize_outputs)
 from ..utils.debug import debug_verbose
@@ -98,6 +102,45 @@ from ..utils.debug import debug_verbose
 # two at a time under 128 MiB left the host 40% slower, and a size 2
 # beside the four moved no step time. 2 x 192 MiB stays refused.
 GROUP_BYTES = 192 << 20
+
+# A task runs where the tile it writes lies, so what it READS may lie on
+# another chip: a Cholesky's panel tile L(m, k) is read by the updates of
+# a whole row and a whole column of the trailing matrix, on every chip of
+# a 2 x 2 grid. This chip's copy of one version of such a tile is made
+# once and serves every later reader here (``_copy_here``); the copies a
+# module keeps, and those it has let go whose last reader the chip has
+# not finished, together hold this many bytes at most, the least
+# recently read going first. A copy is allocated when it is enqueued, as
+# a launch's outputs are, and the host runs a whole factorization ahead
+# of the chips where every launch writes in place: without a bound a
+# chip would hold every remote tile of the step (13 GiB of copies beside
+# a 4.9 GiB share of a 98304-matrix in 4096-tiles). The reads of a block
+# column come round again with every row of updates, so a bound under
+# the columns in flight makes the least recently read copy the next one
+# wanted, and every copy is made twice over: two columns of 4096-tiles
+# (48 tiles of 64 MiB) in a rehearsal whose workers keep step, three and
+# more on the chip, where each worker follows the successors it released
+# (4 GiB read 1.09 to 1.10 times the least copies there). 5 GiB hold
+# eighty such tiles (PERF.md section 6, PR 37).
+REMOTE_BYTES = 5 << 30
+
+
+class _Copy:
+    """This chip's copy of one version of a tile that lies on another:
+    ``source`` the version (weakly: the array itself, a new version is a
+    new array), ``value`` the copy (None while its first reader makes
+    it: ``making``, an event the other readers wait for), ``pool`` the
+    pool whose task had it made, ``pins`` the launches that hold it
+    between staging and their call, ``mark`` what tells that the last
+    launch that read it is over (as in ``_queued``)."""
+
+    __slots__ = ("source", "value", "nbytes", "pool", "pins", "mark",
+                 "making")
+
+    def __init__(self, source, nbytes: int, pool: int) -> None:
+        self.source, self.value, self.nbytes = source, None, nbytes
+        self.pool, self.pins, self.mark = pool, 0, None
+        self.making = threading.Event()         # None once it is made
 
 
 class TPUDevice(Device):
@@ -167,6 +210,23 @@ class TPUDevice(Device):
         # always, so that a step that stalls says where it stood
         self.stats.update(chip_wait_s=0.0, chip_waits=0, call_s=0.0,
                           chip_wait_max_s=0.0, call_max_s=0.0)
+        # this chip's copies of tiles that lie on other chips, by the
+        # id of the version they are copies of, least recently read
+        # first; (mark, bytes) of those let go whose last reader may
+        # still be on the chip's queue, oldest first; the bytes of both;
+        # and the ids whose version has died (a weak reference's
+        # callback may run on any thread, inside any lock: it appends
+        # here and the next staging looks)
+        self._copies: "collections.OrderedDict[int, _Copy]" = \
+            collections.OrderedDict()
+        self._copies_lock = threading.RLock()
+        self._retired: Deque[Tuple[Any, int]] = collections.deque()
+        self._copy_bytes = 0
+        self._dead: Deque[int] = collections.deque()
+        # copies made and their bytes, reads a copy already here served,
+        # seconds the host spent making copies: always on
+        self.stats.update(remote_copies=0, remote_bytes_in=0,
+                          remote_hits=0, remote_copy_s=0.0)
         debug_verbose(3, "device", "TPU device on %s (%s)",
                       self.jax_device, self.platform)
 
@@ -246,7 +306,25 @@ class TPUDevice(Device):
         held = program.held
         timed = tasks[0].taskpool.context.stage_timers
         group = len(tasks) > 1
-        flat = self._flat(values)
+        used: List[_Copy] = []  # the copies of remote tiles it reads
+        flat = self._flat(values, tasks[0].taskpool, used,
+                          program.donated_at)
+        over = None
+        try:
+            over = self._launch(tasks, program, flat, held, timed, group)
+        finally:
+            if used:
+                # read: what tells that this launch is over tells that
+                # the copies' last reader is
+                with self._copies_lock:
+                    for copy in used:
+                        copy.pins -= 1
+                        copy.mark = over
+
+    def _launch(self, tasks, program, flat, held, timed, group):
+        """``_launch_group`` once the leaves are on this chip; returns
+        what tells that the launch is over (``mark()``: an array to wait
+        for, or None once it has gone), None where nothing does."""
         waits = []      # seconds of each wait for the chip this launch made
         marks, queued = self._group_marks, []
         if group and held:
@@ -284,13 +362,14 @@ class TPUDevice(Device):
         done = self.jax.tree_util.tree_leaves(results[len(tasks) - 1])
         own = len(results) > len(tasks)
         mark = results[-1] if own else done[0] if done else None
+        # a tile weakly: one its collection has dropped is done with
+        ends = None if mark is None else \
+            (lambda: mark) if own else weakref.ref(mark)
         if group:
             if mark is not None:
                 self._group_marks = [mark] if held else marks[-1:] + [mark]
         elif mark is not None and held:
-            # a tile weakly: one its collection has dropped is done with
-            waits += self._queued(
-                (lambda: mark) if own else weakref.ref(mark), held, timed)
+            waits += self._queued(ends, held, timed)
         names = [f.name for f in tasks[0].task_class.output_flows]
         for t, res in zip(tasks, results):
             t.output.update(normalize_outputs(res, names, t))
@@ -314,6 +393,7 @@ class TPUDevice(Device):
                 self.stats["chip_wait_s"] += sum(waits)
                 self.stats["chip_waits"] += len(waits)
                 self._count_launch(tasks[0], len(tasks))
+        return ends
 
     def _call(self, program, flat):
         """The jitted call until it returns."""
@@ -365,34 +445,186 @@ class TPUDevice(Device):
 
     # --------------------------------------------------------- staging
 
-    def _here(self, leaf):
+    def _here(self, leaf, pool=None, used=None):
         """THE staging rule, for every leaf this module hands to XLA on
         any route: a ``jax.Array`` passes as it is unless it is committed
         to another chip (jit raises on mixed committed placements; an
-        uncommitted one follows ``default_device``); such a leaf, and a
-        host value (numpy, a Python number), is put on this module's
-        chip. A leaf that is here costs a type test and two attribute
-        reads."""
+        uncommitted one follows ``default_device``), and a host value
+        (numpy, a Python number) is put on this module's chip. A tile
+        committed to another chip is read through this chip's copy of
+        that version, which is made once, chip to chip, and kept for the
+        next reader (``_copy_here``; ``pool``: the reader's taskpool,
+        ``used``: where the launch collects the copies it reads); a
+        reader without a pool (a program's first run, a flow its program
+        writes into) gets a copy of its own. A leaf that is here costs a
+        type test and two attribute reads."""
         if isinstance(leaf, self.jax.Array):
             if not leaf.committed or getattr(leaf, "device", None) in (
                     None, self.jax_device):
                 return leaf
+            if pool is not None:
+                return self._copy_here(leaf, pool, used)
         elif not isinstance(leaf, self._host):
             return leaf         # no array: not this module's to place
         return self.jax.device_put(leaf, self.jax_device)
 
-    def _flat(self, values) -> List[Any]:
+    def _flat(self, values, pool=None, used=None,
+              donated=frozenset()) -> List[Any]:
         """The members' input leaves in order, None-valued flows left
-        out, each on this module's chip."""
+        out, each on this module's chip. A flow at one of the positions
+        ``donated`` is the program's to write into: it is never served
+        from a copy the module keeps."""
         leaves, here = self.jax.tree_util.tree_leaves, self._here
         flat: List[Any] = []
         for vals in values:
-            for v in vals:
+            for at, v in enumerate(vals):
+                mine = None if at in donated else pool
                 if isinstance(v, self._arrays):     # the common tile
-                    flat.append(here(v))
+                    flat.append(here(v, mine, used))
                 elif v is not None:
-                    flat.extend(here(leaf) for leaf in leaves(v))
+                    flat.extend(here(leaf, mine, used)
+                                for leaf in leaves(v))
         return flat
+
+    # ------------------------------------- copies of other chips' tiles
+
+    def _copy_here(self, leaf, pool, used=None):
+        """This chip's copy of ``leaf``, a tile committed to another
+        chip: the one a reader before had made, if that version's is
+        still here (``remote_hits``), else a new one, chip to chip (no
+        host hop: ``leaf`` is a device array), under ``parsec:stage_in``
+        where the stage timers are on (``remote_copies``,
+        ``remote_bytes_in``, ``remote_copy_s``). The first reader of a
+        version on this chip makes the copy, outside the module's lock
+        (a ``device_put`` takes the host half a millisecond, and the
+        readers of other tiles need not stand behind it); a reader of
+        the same version meanwhile waits for that one copy and makes no
+        second. A copy goes when its version does (superseded in its
+        collection and dropped by its last task: the weak reference
+        says), when the pool that had it made ends (``drop_copies``), or
+        as the least recently read once ``REMOTE_BYTES`` are held
+        (``_room_for``); a launch that reads it pins it from staging to
+        its call (``used``)."""
+        key = id(leaf)
+        with self._copies_lock:
+            self._let_go_dead()
+            copy = self._copies.get(key)
+            mine = copy is None or copy.source() is not leaf
+            if mine:
+                timed = pool.context is not None and \
+                    pool.context.stage_timers
+                self._room_for(leaf.nbytes, timed)
+                copy = self._copies[key] = _Copy(
+                    weakref.ref(leaf, lambda _ref, key=key,
+                                dead=self._dead: dead.append(key)),
+                    leaf.nbytes, id(pool))
+                self._copy_bytes += copy.nbytes
+            else:
+                self._copies.move_to_end(key)
+                self.stats["remote_hits"] += 1
+            copy.pins += 1
+            making = copy.making
+        if mine:
+            try:
+                copy.value, took = self._under(
+                    SPAN_STAGE_IN, timed, self.jax.device_put, leaf,
+                    self.jax_device)
+            finally:
+                with self._copies_lock:
+                    copy.making = None
+                    if copy.value is None:      # nobody finds a failed one
+                        if self._copies.get(key) is copy:
+                            self._let_go(key)
+                    else:
+                        self.stats["remote_copies"] += 1
+                        self.stats["remote_bytes_in"] += copy.nbytes
+                        self.stats["remote_copy_s"] += took
+                making.set()
+        elif making is not None:
+            making.wait()
+            if copy.value is None:
+                raise RuntimeError(
+                    f"{self.name}: the copy of a tile of another chip "
+                    f"that another reader was making failed")
+        if used is not None:
+            used.append(copy)           # unpinned after the launch's call
+        else:
+            with self._copies_lock:
+                copy.pins -= 1
+        return copy.value
+
+    @staticmethod
+    def _over(mark) -> bool:
+        """Is the launch ``mark`` tells of over, as far as a look says?"""
+        leaf = mark() if mark is not None else None
+        return leaf is None or leaf.is_deleted() or leaf.is_ready()
+
+    def _let_go(self, key: int) -> None:
+        """Forget the copy under ``key`` (under the lock): its bytes
+        stay counted until its last reader is over (``_retired``)."""
+        copy = self._copies.pop(key)
+        self._retired.append((copy.mark, copy.nbytes))
+
+    def _let_go_dead(self) -> None:
+        """Under the lock: the copies whose version has died go, and
+        the bytes of those let go whose last reader is over."""
+        while self._dead:
+            key = self._dead.popleft()
+            copy = self._copies.get(key)
+            if copy is not None and copy.source() is None:
+                self._let_go(key)
+        while self._retired and self._over(self._retired[0][0]):
+            self._copy_bytes -= self._retired.popleft()[1]
+
+    def _room_for(self, nbytes: int, timed: bool) -> None:
+        """Under the lock: keep what the module's copies hold, those let
+        go and still read on the chip among them, within
+        ``REMOTE_BYTES`` with ``nbytes`` more. The oldest let go is
+        waited for (the host waits for the chip: ``parsec:exec_wait``,
+        ``chip_wait_s``); with none, the least recently read copy that no
+        launch has pinned is let go. The host runs ahead of the chip by
+        the copies it may enqueue and no further."""
+        waits = []
+        while self._copy_bytes + nbytes > REMOTE_BYTES:
+            if self._retired:
+                mark, n = self._retired.popleft()
+                self._copy_bytes -= n
+                if not self._over(mark):
+                    waits.append(self._under(
+                        SPAN_EXEC_WAIT, timed,
+                        mark().block_until_ready)[1])
+                continue
+            key = next((k for k, c in self._copies.items()
+                        if not c.pins), None)
+            if key is None:
+                break           # every copy here is being read now
+            self._let_go(key)
+        if waits:
+            with self._lock:
+                if max(waits) > self.stats["chip_wait_max_s"]:
+                    self.stats["chip_wait_max_s"] = max(waits)
+                if timed:
+                    self.stats["chip_wait_s"] += sum(waits)
+                    self.stats["chip_waits"] += len(waits)
+
+    def drop_copies(self, pool) -> None:
+        """Let go of the copies ``pool``'s tasks had made: a finished
+        pool holds no tile (the Context calls this when a pool ends)."""
+        if not self._copies and not self._dead:
+            return
+        with self._copies_lock:
+            for key in [k for k, c in self._copies.items()
+                        if c.pool == id(pool)]:
+                self._let_go(key)
+            self._let_go_dead()
+
+    def copies_held(self) -> Tuple[int, int]:
+        """``(copies, bytes)`` of other chips' tiles this module keeps
+        now, the dead ones let go first."""
+        with self._copies_lock:
+            self._let_go_dead()
+            return len(self._copies), sum(
+                c.nbytes for c in self._copies.values())
 
     def _pinned(self, chore: Chore) -> Chore:
         """A self-dispatching hook (an impure DTD body; a PTG body that
@@ -406,12 +638,14 @@ class TPUDevice(Device):
             jax, dev, here = self.jax, self.jax_device, self._here
             own = weakref.ref(chore)    # the record must not keep it
 
-            def array_here(leaf):
-                # a host value is the body's own to read: it may be host
-                # code that writes into its numpy tile (serving/decode.py)
-                return here(leaf) if isinstance(leaf, jax.Array) else leaf
-
             def hook(t, *vals):
+                def array_here(leaf):
+                    # a host value is the body's own to read: it may be
+                    # host code that writes into its numpy tile
+                    # (serving/decode.py)
+                    return here(leaf, t.taskpool) \
+                        if isinstance(leaf, jax.Array) else leaf
+
                 with jax.default_device(dev):
                     return own().hook(t, *(
                         jax.tree_util.tree_map(array_here, v) for v in vals))
@@ -567,6 +801,12 @@ class TPUDevice(Device):
         def donated(size):
             return tuple(m * at + i for m in range(size) for i in given)
 
+        # the positions, among a member's flows, of those the program
+        # writes into: never staged from a copy the module keeps
+        donated_at = frozenset(
+            i for i, f in enumerate(flows)
+            if f.name in (chore.donates or ()))
+
         def marked(program):
             # an output of such a program may be given to a later launch
             # before anyone has waited for this one: it returns, after
@@ -639,5 +879,6 @@ class TPUDevice(Device):
                     fn.held = sum(
                         x.nbytes for x in tu.tree_leaves(fn(*args)[:size])
                         if x.unsafe_buffer_pointer() not in given_to)
+                fn.donated_at = donated_at
                 programs[size] = fn
         return programs

@@ -250,6 +250,17 @@ class _TileBank:
 
 
 # a DTD class's callbacks (``_task_class_for``): functions of the task
+def _written_tile(task: Task):
+    """``(collection, key)`` of the tile ``task``'s placement among
+    several chip modules follows: the argument marked ``affinity``, else
+    the first it writes; None for a task that writes no tile."""
+    where = task.dsl.get("affinity")
+    if where is None and task.dsl.get("out_tiles"):
+        tile = task.dsl["out_tiles"][0][0]
+        where = (tile.collection, tile.key)
+    return where
+
+
 def _data_lookup(task: Task) -> None:
     """prepare_input analog: resolve aliased flows (same tile passed
     twice in one insert) from their primary flow's delivered value."""
@@ -466,6 +477,7 @@ class Taskpool(CoreTaskpool):
                 _goals.get(locals[0], _GOAL_UNSET)
             tc.iterate_successors = _iterate_successors
             tc.data_lookup = _data_lookup
+            tc.written_tile = _written_tile
 
             if pure:
                 # pure=True contract (insert_task): fn is a pure

@@ -176,6 +176,12 @@ class PTGTaskClass(TaskClass):
         self.spec_list = specs
         self.space = space
         self.affinity = affinity
+        # the tile a task of this class writes (``written_tile``): its
+        # first written flow's, else the one its affinity names
+        self._written = next(
+            (s.tile for s in specs if s.tile is not None and
+             s.access & FlowAccess.WRITE and
+             not s.access & FlowAccess.CTL), affinity)
         if priority is not None:
             self.priority_fn = lambda locals, _g=tp.g: priority(_g, *locals)
         del self.iterate_successors, self.deps_goal
@@ -360,6 +366,13 @@ class PTGTaskClass(TaskClass):
                         src_flow=f.name)
 
     # -- distribution -----------------------------------------------------
+    def written_tile(self, task: Task):
+        """``(collection, key)`` of the tile ``task`` writes (the JDF's
+        ``: descA(m, k)``): its first written flow's ``tile``, else its
+        affinity's; None where the class names neither."""
+        fn = self._written
+        return None if fn is None else fn(self.g, *task.locals)
+
     def affinity_rank(self, locals) -> int:
         if self.affinity is None:
             return 0

@@ -288,3 +288,28 @@ def test_native_exec_hbm_tracking():
     L = np.tril(A.to_array())
     np.testing.assert_allclose(L @ L.T, A_in, rtol=2e-4, atol=2e-3)
     assert mgr.stats["spills"] > 0
+
+
+def test_a_host_value_is_staged_on_the_chip_that_asks():
+    """A manager stages host values on its own chip (``home``: a
+    Context's first chip module's, not JAX's first device), or on the
+    chip of the module that asks, and accounts them in that chip's
+    zone."""
+    import jax
+    devs = jax.devices()
+    if len(devs) < 3:
+        pytest.skip("needs three (virtual) devices")
+    tile = np.ones((16, 16), np.float32)
+    m = HBMManager(1 << 20, device=devs[1])
+    a = m.ensure("a", tile)
+    assert a.devices() == {devs[1]}
+    assert m.zone is m.zone_of(devs[1])
+    assert m.zone.bytes_used() >= tile.nbytes
+    b = m.ensure("b", tile.copy(), device=devs[2])
+    assert b.devices() == {devs[2]}
+    assert m.zone_of(devs[2]).bytes_used() >= tile.nbytes
+    assert m.zone_of(devs[0]).bytes_used() == 0
+    # without a chip of its own, as before: wherever JAX puts it
+    plain = HBMManager(1 << 20)
+    c = plain.ensure("c", tile.copy())
+    assert plain.zone_of(next(iter(c.devices()))).bytes_used() >= tile.nbytes
