@@ -111,7 +111,12 @@ class Chore:
     # shape). Read by the chip module's program table alone: an executor
     # that lowers the whole pool places its buffers itself. A declaration,
     # not the module's rule: what a launch holds anew the module reads
-    # off the program it built (``TPUDevice._build``).
+    # off the program it built (``TPUDevice._build``). Two users: a PTG
+    # body whose graph says so (``build_potrf``, ``build_geqrf``), and
+    # the DTD front end, which decides it a task at insertion: an INOUT
+    # tile no reader was inserted on since its last writer
+    # (``dtd.Taskpool._insert_one``; the given flows key the task's
+    # class, so tasks that give different flows have different chores).
     donates: Optional[Sequence[str]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
     # e.g. DTD's woven argspec) can still hand a device module their pure

@@ -14,7 +14,12 @@ out), so WAR hazards vanish — a reader snapshots the version current at
 writer simply produces a new version. Only RAW (value flows from the
 in-flight last writer) and WAW (writer chain) edges are materialized, which
 strictly increases available parallelism versus the reference's read-list
-serialization (overlap_strategies.c:38-120).
+serialization (overlap_strategies.c:38-120). Where NO reader was inserted
+on a tile since its last writer, the next INOUT writer of a pure
+accelerator body is the only holder of the incoming version and gives it
+to its program, which updates the tile where it lies as upstream's kernels
+do (``_Tile.readers``, ``insert_task``): a writer chain holds every tile
+once, and a snapshot some reader holds is never given.
 
 Distributed DTD (reference: every rank replays the same insertion
 sequence; remote activations for undiscovered tasks are parked,
@@ -133,10 +138,14 @@ class _Tile:
     """Per-(collection, key) tracking state (parsec_dtd_tile_t analog).
 
     ``holder_rank`` is the rank holding the version current at this point
-    of the replayed insertion order (None = the collection owner)."""
+    of the replayed insertion order (None = the collection owner).
+    ``readers`` counts the READ-only arguments inserted on the tile
+    since its last writer was (linked to a writer in flight or
+    snapshotted alike; a remote shell's too): 0 says that the next
+    writer's task is the only one that holds the current version."""
 
     __slots__ = ("collection", "key", "lock", "last_writer",
-                 "last_writer_flow", "holder_rank", "flushed")
+                 "last_writer_flow", "holder_rank", "flushed", "readers")
 
     def __init__(self, collection: DataCollection, key):
         self.collection = collection
@@ -146,6 +155,7 @@ class _Tile:
         self.last_writer = None
         self.last_writer_flow: Optional[str] = None
         self.holder_rank: Optional[int] = None
+        self.readers = 0
         # flush_tile() was called: the last writer's retire takes the
         # tile out of the bank, and a writer inserted later puts it back
         # (under ``lock``, as last_writer)
@@ -165,12 +175,17 @@ class _TileBank:
     The blocking ``Taskpool.flush`` waits and takes nothing out.
     What is still tracked when the pool ends goes then
     (``Taskpool._let_go``): a finished pool holds no tile, and through
-    it no collection. ``peak`` is the most tiles tracked at once,
-    ``retired`` those a flush took out, ``dropped`` those the pool's
-    end did."""
+    it no collection. One thing of a tile outlives its tracking: that
+    readers were inserted on its current version (``_Tile.readers``),
+    whose snapshots a writer inserted after the flush must leave alone;
+    the bank keeps the keys of such tiles (``_read``, a key and no
+    tile) and a tile made anew for one starts as read. ``peak`` is the
+    most tiles tracked at once, ``retired`` those a flush took out,
+    ``dropped`` those the pool's end did."""
 
     def __init__(self) -> None:
         self._tiles: Dict[Tuple[int, Any], _Tile] = {}
+        self._read: set = set()
         self._lock = threading.Lock()
         self.peak = 0
         self.retired = 0
@@ -187,6 +202,9 @@ class _TileBank:
                 t = self._tiles.get(hkey)
                 if t is None:
                     t = _Tile(dc, hkey[1])
+                    if hkey in self._read:
+                        self._read.discard(hkey)
+                        t.readers = 1
                     self._tiles[hkey] = t
                     self.peak = max(self.peak, len(self._tiles))
         if t.collection is not dc:
@@ -229,6 +247,8 @@ class _TileBank:
             if self._tiles.get(hkey) is tile:
                 del self._tiles[hkey]
                 self.retired += 1
+                if tile.readers:
+                    self._read.add(hkey)
 
     def drop(self, collection: Optional[DataCollection] = None) -> None:
         """The pool has ended and every version is where it belongs:
@@ -239,6 +259,11 @@ class _TileBank:
                 if t.collection is not collection}
             self.dropped += len(self._tiles) - len(keep)
             self._tiles = keep
+            if collection is None:
+                self._read.clear()
+            else:
+                self._read = {k for k in self._read
+                              if k[0] != collection.dc_id}
 
     def readopt(self, tile: _Tile) -> None:
         """A writer inserted after ``tile``'s flush (the caller holds its
@@ -246,7 +271,16 @@ class _TileBank:
         that flush may already have taken out, is tracked again."""
         tile.flushed = False
         with self._lock:
-            self._tiles.setdefault((tile.collection.dc_id, tile.key), tile)
+            hkey = (tile.collection.dc_id, tile.key)
+            self._tiles.setdefault(hkey, tile)
+            self._read.discard(hkey)
+
+    def read_untracked(self, tile: _Tile) -> None:
+        """A reader inserted on ``tile`` after its flush took it out
+        (the caller holds its lock, through a handle it kept): the tile
+        made anew for the key has to know."""
+        with self._lock:
+            self._read.add((tile.collection.dc_id, tile.key))
 
 
 # a DTD class's callbacks (``_task_class_for``): functions of the task
@@ -442,12 +476,28 @@ class Taskpool(CoreTaskpool):
     # ------------------------------------------------------------- classes
     def _task_class_for(self, fn: Callable, shape: Tuple,
                         device: DeviceType, pure: bool = False,
-                        stacked: Optional[Tuple] = None) -> TaskClass:
+                        stacked: Optional[Tuple] = None,
+                        given: Optional[Tuple[str, ...]] = None
+                        ) -> TaskClass:
         """Lazily create a task class per (fn, arg shape)
-        (insert_function.c:1015 analog). Resolution is on the insertion
-        hot path, so a cache hit is a lock-free dict read (GIL-atomic);
-        the lock only serializes creation."""
-        key = (fn, shape, device, pure, stacked)
+        (insert_function.c:1015 analog) and per set of flows its tasks
+        give to their program (``given``: the chore's ``donates``, so
+        tasks that give different flows never share a program or a
+        launch). None asks for the class a row is inserted under, which
+        gives the most it may: every INOUT tile argument of a pure body
+        an accelerator may run, nothing of any other body;
+        ``_insert_one`` moves a task whose tiles say otherwise to the
+        variant that gives what they allow. Resolution is on the
+        insertion hot path, so a cache hit is a lock-free dict read
+        (GIL-atomic); the lock only serializes creation."""
+        # flow names follow insert_task's tile-only numbering
+        # (value/scratch args don't consume a flow slot)
+        tiles = [access for kind, access in shape if kind == "tile"]
+        inout = tuple(f"f{i}" for i, access in enumerate(tiles)
+                      if access == FlowAccess.RW)
+        if given is None:
+            given = inout if pure and device & DeviceType.TPU else ()
+        key = (fn, shape, device, pure, stacked, given)
         tc = self._classes.get(key)
         if tc is not None:
             return tc
@@ -455,13 +505,8 @@ class Taskpool(CoreTaskpool):
             tc = self._classes.get(key)
             if tc is not None:
                 return tc
-            # flow names must match insert_task's tile-only numbering
-            # (value/scratch args don't consume a flow slot)
-            flows = []
-            for kind, access in shape:
-                if kind == "tile":
-                    flows.append(Flow(f"f{len(flows)}",
-                                      access if access else FlowAccess.READ))
+            flows = [Flow(f"f{i}", access if access else FlowAccess.READ)
+                     for i, access in enumerate(tiles)]
             tc = TaskClass(getattr(fn, "__name__", "dtd_task"),
                            len(self.task_classes), params=("seq",),
                            flows=flows, deps_mode=DEPS_COUNTER)
@@ -478,6 +523,11 @@ class Taskpool(CoreTaskpool):
             tc.iterate_successors = _iterate_successors
             tc.data_lookup = _data_lookup
             tc.written_tile = _written_tile
+            # what ``_insert_one`` needs to find a variant, the INOUT
+            # flows it counts, and those this class's chore gives
+            tc.dtd_variant_of = key[:5]
+            tc.dtd_inout = inout
+            tc.dtd_given = given
 
             if pure:
                 # pure=True contract (insert_task): fn is a pure
@@ -594,7 +644,8 @@ class Taskpool(CoreTaskpool):
                     device, _hook, batchable=False,
                     batch_sig=_batch_sig, batch_body=_batch_body,
                     batch_hook=hook, batch_hook_shared=None if hook is None
-                    else tuple(f"f{i}" for i in shared)))
+                    else tuple(f"f{i}" for i in shared),
+                    donates=given or None))
             else:
                 tc.add_chore(Chore(device, _hook, batchable=False))
             self.add_task_class(tc)
@@ -640,6 +691,30 @@ class Taskpool(CoreTaskpool):
         time and cached by object identity, so they must be treated as
         IMMUTABLE once inserted — mutating an array payload in place
         between inserts would silently serve the stale compile.
+
+        **An INOUT tile is the pool's to overwrite**, as upstream, from
+        this call until the tile is flushed or the pool ends: where no
+        reader was inserted on the tile since its last writer (so this
+        task is the only one that holds the incoming version), the tile
+        appears once among the arguments, its version is on this rank
+        and the body is ``pure`` and an accelerator's, the task GIVES
+        the incoming version to its program (``Chore.donates``): a chip
+        module writes the new version into the buffer the old one lies
+        in, the launch holds nothing new, and the old ``jax.Array`` is
+        deleted once the launch is made, the collection's own array
+        (the version no task of this pool wrote) like any other. The
+        collection holds the new version from the task's completion
+        (``write_tile``); between the launch and that write its entry
+        is a deleted array. So read a result from the collection after
+        ``flush``/``wait``, never from an array kept aside, and keep a
+        copy (NumPy, or ``jnp.copy``) of a version you want to see
+        again. A reader inserted between two writers keeps its snapshot:
+        the second writer sees that the tile was read and returns a new
+        tile, exactly as before; a reader inserted after a giving writer
+        gets that writer's output. An impure or CPU body, the same tile
+        twice in one task, a version that arrives from another rank:
+        nothing is given. The body returns its outputs in the order of
+        its written arguments (the contract on ``Chore.donates``).
 
         ``stacked=(hook, shared)`` declares a pure body's stacked form,
         what ``batch_hook`` / ``batch_hook_shared`` are to a PTG body
@@ -916,6 +991,10 @@ class Taskpool(CoreTaskpool):
         goal = 0
         flow_i = 0
         seen_tiles: Dict[Any, str] = {}   # tile → primary flow of THIS task
+        # the INOUT flows the class gives that this task has to keep:
+        # the incoming version has another reader, or is not here
+        may_give = tc.dtd_given
+        kept: set = set()
         for a in args:
             if isinstance(a, ValueArg):
                 task.dsl["argspec"].append(("value", a.value))
@@ -929,6 +1008,7 @@ class Taskpool(CoreTaskpool):
             task.dsl["argspec"].append(("tile", None))
             if a.affinity:
                 task.dsl["affinity"] = (a.collection, a.key)
+            writes = a.access & FlowAccess.WRITE
             primary = seen_tiles.get(tile)
             if primary is not None:
                 # same tile passed twice in one insert: alias the flow to
@@ -939,6 +1019,12 @@ class Taskpool(CoreTaskpool):
             else:
                 seen_tiles[tile] = fname
                 with tile.lock:
+                    if not writes:
+                        tile.readers += 1
+                        if tile.flushed and tile.last_writer is None:
+                            self.tiles.read_untracked(tile)
+                    elif tile.readers and fname in may_give:
+                        kept.add(fname)
                     writer = tile.last_writer
                     # capture the writer's flow ATOMICALLY with the
                     # writer: the completer clears both under this lock
@@ -950,6 +1036,8 @@ class Taskpool(CoreTaskpool):
                     holder = tile.holder_rank
                 if holder is None:
                     holder = a.collection.rank_of(a.key)
+                if holder != my_rank and fname in may_give:
+                    kept.add(fname)
                 linked = False
                 if isinstance(writer, Task):
                     with writer.dsl["lock"]:
@@ -992,18 +1080,31 @@ class Taskpool(CoreTaskpool):
                         # version held remotely: the holder replays this
                         # insert as a shell and pushes the value eagerly
                         goal += 1
-            if a.access & FlowAccess.WRITE:
+            if writes:
                 with tile.lock:
                     tile.last_writer = task
                     tile.last_writer_flow = fname
                     tile.holder_rank = my_rank
+                    tile.readers = 0
                     if tile.flushed:
                         self.tiles.readopt(tile)
                 task.dsl["out_tiles"].append((tile, fname))
 
+        if may_give:
+            for alias, primary in task.dsl["aliases"].items():
+                kept.update((alias, primary))   # the tile twice in a task
+            if kept:
+                # not this class's to launch: the variant that gives
+                # what this task may (the goal is unset, so nothing has
+                # looked at the task's class yet)
+                tc = task.task_class = self._task_class_for(
+                    *tc.dtd_variant_of,
+                    given=tuple(f for f in may_give if f not in kept))
         if self.context.stage_timers:
             self._count(dtd_args_linked=goal,
-                        dtd_args_snapshot=len(seen_tiles) - goal)
+                        dtd_args_snapshot=len(seen_tiles) - goal,
+                        dtd_args_given=len(tc.dtd_given),
+                        dtd_args_kept=len(tc.dtd_inout) - len(tc.dtd_given))
         # Finalize the goal; racing activations may already have counted.
         # The lock must span both the goal publication AND the finalize
         # check: activate_dep reads the goal and counts under the same
@@ -1050,6 +1151,8 @@ class Taskpool(CoreTaskpool):
                 # atomic with the writer — see the local-insert path
                 writer_flow = tile.last_writer_flow
                 holder = tile.holder_rank
+                if not a.access & FlowAccess.WRITE:
+                    tile.readers += 1   # its version may be sent later
             if holder is None:
                 holder = a.collection.rank_of(a.key)
             if a.access & FlowAccess.READ and not (a.access & FlowAccess.CTL):
@@ -1076,6 +1179,7 @@ class Taskpool(CoreTaskpool):
                     tile.last_writer = _Shell(seq, target_rank)
                     tile.last_writer_flow = fname
                     tile.holder_rank = target_rank
+                    tile.readers = 0
 
     def _send_value(self, target_rank: int, seq: int, fname: str,
                     value, priority: int = 0) -> None:

@@ -336,7 +336,10 @@ class NativeDTD:
         key = (fn, shape, device, pure)
         info = self._class_info.get(key)
         if info is None:
-            tc = self.tp._task_class_for(fn, shape, device, pure=pure)
+            # given=(): this engine counts no readers, so its tasks
+            # give no tile to a program
+            tc = self.tp._task_class_for(fn, shape, device, pure=pure,
+                                         given=())
             hook = tc.incarnations[0].hook if tc.incarnations else None
             # flow-access layout captured ONCE PER CLASS (ISSUE 14):
             # the dfsan replay's dynamic access-mode check reads it to

@@ -89,13 +89,14 @@ def _matrices(rng, m=M, n=N, k=K, nb=NB):
                    for h, name in zip(hosts, "ABC")]
 
 
-def _rows(a, b, c, m):
-    """The tasks of one row of C tiles, as ``insert_gemm_dtd`` makes them."""
+def _rows(a, b, c, m, ks=None):
+    """The tasks of one row of C tiles, as ``insert_gemm_dtd`` makes them
+    (of the k blocks ``ks`` alone, if given)."""
     one = dtd.ValueArg(1.0)
     return [(dtd.TileArg(a, (m, k), dtd.INPUT),
              dtd.TileArg(b, (k, n), dtd.INPUT),
              dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True), one, one)
-            for n in range(c.nt) for k in range(a.nt)]
+            for n in range(c.nt) for k in (ks or range(a.nt))]
 
 
 def _gemm(ctx, a, b, c, device=DeviceType.TPU, pure=True, name="gemm"):
@@ -180,26 +181,60 @@ def _turn_probe(ctx, monkeypatch):
     return seen
 
 
-def test_1024_chains_of_four_refill_the_groups(make_ctx, rng, monkeypatch):
+def _look(c):
+    return None
+
+
+def _gemm_kept(ctx, a, b, c, name="kept"):
+    """``_gemm`` with a reader of every C tile (a CPU body) inserted
+    before each of its writers, a block of k at a time: no writer is the
+    only holder of the version it takes in, so none is given its tile
+    and every one returns a new tile, as every DTD body did before the
+    front end counted a tile's readers."""
+    tp = dtd.Taskpool(name)
+    ctx.add_taskpool(tp)
+    for m in range(c.mt):
+        for k in range(a.nt):
+            tp.insert_tasks(_look, [(dtd.TileArg(c, (m, n), dtd.INPUT),)
+                                    for n in range(c.nt)],
+                            device=DeviceType.CPU)
+            tp.insert_tasks(_gemm_dtd_body, _rows(a, b, c, m, ks=[k]),
+                            device=DeviceType.TPU, pure=True)
+    assert _wait(tp) is None
+    return tp
+
+
+@pytest.mark.parametrize("tiles,n", [("given", 256), ("kept", 128)])
+def test_1024_chains_of_four_refill_the_groups(make_ctx, rng, monkeypatch,
+                                               tiles, n):
     """The cell's DAG (4096 tasks in 1024 chains of 4, four workers) at
     8x8 tiles: the successors a group releases go to the worker's own
-    queue, and the next group forms from them. A DTD body returns a new
-    tile, so every group holds new outputs and is one group in flight:
-    its worker holds the turn from taking the tasks to the last member's
+    queue, and the next group forms from them. A chain's writers follow
+    one another with no reader between, so the front end gives each its
+    tile: every group holds nothing new and its members are released
+    with the turn free, as a PTG POTRF's (the twin below). With a
+    reader before every writer (256 chains) every body returns a new
+    tile, every group holds new outputs and is one group in flight: its
+    worker holds the turn from taking the tasks to the last member's
     release."""
     ctx = make_ctx(nb_cores=4)
     seen = _turn_probe(ctx, monkeypatch)
-    (a_h, b_h, c_h), (a, b, c) = _matrices(rng, 256, 256, 32, 8)
-    _gemm(ctx, a, b, c)
+    (a_h, b_h, c_h), (a, b, c) = _matrices(rng, n, n, 32, 8)
+    (_gemm if tiles == "given" else _gemm_kept)(ctx, a, b, c)
     groups, grouped = _groups(ctx)
     stats = _module(ctx).stats
-    assert stats["tasks"] == 4096
+    assert stats["tasks"] == (n // 8) ** 2 * 4
     assert grouped > 0 and grouped / groups >= 4
     assert len(seen["launched"]) == groups and all(seen["launched"])
-    assert len(seen["released"]) == grouped and all(seen["released"])
-    assert all(n > 0 for n in seen["new"])
-    assert stats["groups_in_place"] == stats["groups_pipelined"] == 0
-    assert stats["lone_in_place"] == 0
+    assert len(seen["released"]) == grouped
+    if tiles == "given":
+        assert not any(seen["released"]) and seen["new"] == [0] * groups
+        assert stats["groups_in_place"] == groups
+        assert stats["lone_in_place"] == stats["tasks"] - grouped
+    else:
+        assert all(seen["released"]) and all(n > 0 for n in seen["new"])
+        assert stats["groups_in_place"] == stats["groups_pipelined"] == 0
+        assert stats["lone_in_place"] == 0
     np.testing.assert_allclose(c.to_array(), c_h + a_h @ b_h, rtol=1e-4,
                                atol=1e-4)
 

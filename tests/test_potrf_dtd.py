@@ -386,10 +386,14 @@ def test_a_pool_without_a_stacked_body_builds_the_chores_it_built(
     assert callable(pure.batch_sig) and callable(pure.batch_body)
     assert (impure.batchable, impure.batch_hook, impure.batch_sig,
             impure.batch_body) == (False, None, None, None)
+    # the last of a key: the flows the class's tasks give to their
+    # program (the pure body's INOUT tile, read by nobody before it)
     assert list(tp._classes) == [
-        (_gemm_dtd_body, tp._shape_of(args), DeviceType.ALL, True, None),
+        (_gemm_dtd_body, tp._shape_of(args), DeviceType.ALL, True, None,
+         ("f2",)),
         (list(tp._classes)[1][0], (("tile", dtd.INPUT),), DeviceType.ALL,
-         False, None)]
+         False, None, ())]
+    assert (pure.donates, impure.donates) == (("f2",), None)
     # nothing counted, nothing flushed: the bank tracked both tiles to
     # the pool's end and let them go there
     assert tp.counters == {} and ctx.dtd_counters == {}

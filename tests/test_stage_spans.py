@@ -42,23 +42,38 @@ def _matrix(name, mt, nt):
     return m
 
 
-def _run_pool(ctx, name, timeout=60.0, pure=True, shape=(MT, NT, KT)):
+def _look(c):
+    return None
+
+
+def _run_pool(ctx, name, timeout=60.0, pure=True, shape=(MT, NT, KT),
+              kept=False):
     """One GEMM through a new pool, every task on an accelerator module:
     one insert_tasks call per row of C tiles, as insert_gemm_dtd makes.
     ``pure`` bodies may share a launch (a worker that selects four of
-    them issues one group program); impure ones run one by one."""
+    them issues one group program); impure ones run one by one.
+    ``kept``: a reader of every C tile (a CPU body) goes in before each
+    of its writers, a block of k at a time, so that no writer is given
+    its tile and every launch holds new outputs (the front end gives an
+    INOUT tile nobody else read to the program, which then holds
+    none)."""
     mt, nt, kt = shape
     a, b, c = _matrix("A", mt, kt), _matrix("B", kt, nt), _matrix("C", mt, nt)
     tp = dtd.Taskpool(name)
     ctx.add_taskpool(tp)
     for m in range(mt):
-        tp.insert_tasks(
-            _gemm_body,
-            [(dtd.TileArg(a, (m, k), dtd.INPUT),
-              dtd.TileArg(b, (k, n), dtd.INPUT),
-              dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True))
-             for n in range(nt) for k in range(kt)],
-            device=DeviceType.TPU, pure=pure)
+        for ks in ([[k] for k in range(kt)] if kept else [range(kt)]):
+            if kept:
+                tp.insert_tasks(_look, [(dtd.TileArg(c, (m, n), dtd.INPUT),)
+                                        for n in range(nt)],
+                                device=DeviceType.CPU)
+            tp.insert_tasks(
+                _gemm_body,
+                [(dtd.TileArg(a, (m, k), dtd.INPUT),
+                  dtd.TileArg(b, (k, n), dtd.INPUT),
+                  dtd.TileArg(c, (m, n), dtd.INOUT, affinity=True))
+                 for n in range(nt) for k in ks],
+                device=DeviceType.TPU, pure=pure)
     waiter = threading.Thread(target=tp.wait, daemon=True)
     waiter.start()
     waiter.join(timeout)
@@ -280,14 +295,16 @@ def test_a_traced_pool_with_groups_shows_the_turn_and_the_launch_apart(
         make_ctx, tmp_path, nb_cores):
     ctx = make_ctx(nb_cores=nb_cores)
     dev = _module(ctx)
-    _run_pool(ctx, "warm", shape=GROUPS)    # compiles; leaves a last group
+    # compiles; leaves a last group (kept: a group that holds new
+    # outputs waits for the one before it)
+    _run_pool(ctx, "warm", shape=GROUPS, kept=True)
     tasks = GROUPS[0] * GROUPS[1] * GROUPS[2]
     before, stats0 = _groups(ctx), dict(dev.stats)
     turn0 = sum(es.stats["turn_s"] for es in ctx.streams)
     assert before[0] >= 1 and turn0 == 0.0
     assert stats0["call_s"] == stats0["chip_wait_s"] == 0.0
     with _Session(tmp_path) as prof:
-        _run_pool(ctx, "traced", shape=GROUPS)
+        _run_pool(ctx, "traced", shape=GROUPS, kept=True)
     launches = _launches(ctx, before, tasks)
     groups = _groups(ctx)[0] - before[0]
     assert groups >= 1
@@ -333,8 +350,8 @@ def test_no_session_no_turn_wait_or_call_and_no_growth_of_their_sums(
         monkeypatch.setattr(site, "StageSpan", Counting)
     ctx = make_ctx(nb_cores=1)
     dev = _module(ctx)
-    _run_pool(ctx, "warm", shape=GROUPS)
-    _run_pool(ctx, "untraced", shape=GROUPS)
+    _run_pool(ctx, "warm", shape=GROUPS, kept=True)
+    _run_pool(ctx, "untraced", shape=GROUPS, kept=True)
     assert not ctx.stage_timers and made == []
     assert _groups(ctx)[0] >= 2             # waits were made, and calls
     assert all(es.stats["turn_s"] == 0.0 for es in ctx.streams)
@@ -343,7 +360,7 @@ def test_no_session_no_turn_wait_or_call_and_no_growth_of_their_sums(
     # the stall's signature is kept all the same: the longest of each
     assert dev.stats["call_max_s"] > 0 and dev.stats["chip_wait_max_s"] > 0
     ctx.set_stage_timers(True)
-    _run_pool(ctx, "timed", shape=GROUPS)
+    _run_pool(ctx, "timed", shape=GROUPS, kept=True)
     assert {"parsec:turn", "parsec:exec_wait", "parsec:exec_call"} \
         <= set(made)
     assert made.count("parsec:exec_wait") == dev.stats["chip_waits"] >= 1
@@ -393,8 +410,8 @@ def test_a_lone_launch_over_the_queue_bound_waits_under_its_span(
 
 def test_statusz_carries_each_modules_longest_wait_and_call(make_ctx):
     ctx = make_ctx(nb_cores=1)
-    _run_pool(ctx, "untraced", shape=GROUPS)
-    _run_pool(ctx, "again", shape=GROUPS)
+    _run_pool(ctx, "untraced", shape=GROUPS, kept=True)     # waits made
+    _run_pool(ctx, "again", shape=GROUPS, kept=True)
     devices = {d["name"]: d for d in ctx.statusz()["devices"]}
     assert devices == {d["name"]: d
                        for d in ctx.devices.dump_statistics()}
