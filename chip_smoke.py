@@ -22,6 +22,12 @@ points a user calls, at full width, cheapest phase first:
             2048-tiles (30 tasks of the four compact-WY kernels through
             add_taskpool/wait): the three residuals of V, T and R against
             the plain reference, every task on the tpu0 module
+  lu_host   the benchmark's dgetrf_incpiv_ptg_host driver at N=8192 in
+            2048-tiles, once for each inner block IB of 128, 256, 512 (the
+            sweep the configuration's IB was fixed by; LU_HOST_N=32768
+            runs it at the cell's size): per IB the time of a second step
+            and the readings of the factored form against the plain
+            reference, pivots on the chip, every task on the tpu0 module
   panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
   flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
             NB=1024, potrf.trsm_hook=gemm, input generated on device, three
@@ -69,12 +75,14 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
             qr_host=dict(n=8192, nb=2048),
+            lu_host=dict(n=8192, nb=2048, ibs=(128, 256, 512)),
             panels=(8192, 1024), flagship=(40960, 1024),
             flash=dict(S=16384, H=4, dh=128, F=2048),
             block=dict(H=2, T=2, TS=1024, DH=128, F=512),
             wavefront=(2048, 256), mesh_shape=(1024, 1024))
 DRY = dict(dtd=(256, 64), potrf_host=(256, 64),
            qr_host=dict(n=128, nb=32, ib=16),
+           lu_host=dict(n=128, nb=32, ibs=(16,)),
            panels=(256, 64), flagship=(256, 64),
            flash=dict(S=256, H=2, dh=16, F=64),
            block=dict(H=2, T=2, TS=64, DH=16, F=64),
@@ -503,6 +511,53 @@ def phase_qr_host(sz, on_chip):
         driver.close()
 
 
+def phase_lu_host(sz, on_chip):
+    """``dgetrf_incpiv`` as the benchmark's cell runs it, once for each
+    inner block of the sweep: the driver's own set-up, generator, step
+    and check (``benchmark/drivers/ptg_lu_factorization.py``), a Context
+    an IB. The second step's time is the figure (the first compiles)."""
+    import jax
+    from benchmark.manifest import Manifest
+    from benchmark.run import Spans
+
+    man = Manifest()
+    config = man.config("dgetrf_incpiv_ptg_host")
+    sizes = dict(sz["lu_host"])
+    sizes["n"] = int(os.environ.get("LU_HOST_N", sizes["n"]))
+    ibs = sizes.pop("ibs")
+    if os.environ.get("LU_HOST_IBS"):
+        ibs = [int(ib) for ib in os.environ["LU_HOST_IBS"].split(",")]
+    for ib in ibs:
+        driver = man.driver(config["driver"]).build(
+            config, {**config["sizes"], **sizes, "ib": ib}, 20261003,
+            jax.devices()[:1], Spans(), man.reference(config["reference"]))
+        # the storage guarantee is the cell's: at a small size the
+        # programs' text outweighs the matrix
+        driver.storage_limit_bytes = 1 << 62
+        try:
+            driver.setup()
+            t0 = time.perf_counter()
+            out = driver.step(driver.generate(0))
+            t_first = time.perf_counter() - t0
+            steps = []
+            for step in (1, 2):
+                inp = driver.generate(step)
+                t0 = time.perf_counter()
+                out = driver.step(inp)
+                steps.append(time.perf_counter() - t0)
+            require(driver.finite(out), "a non-finite tile")
+            ok, detail = driver.check(out, 2)
+            say("lu_host", getrf_incpiv="n={n}/nb={nb}".format(**sizes),
+                ib=ib, tasks=driver.tasks_per_step,
+                first_run_s=f"{t_first:.1f}",
+                step_s=f"{min(steps):.4f}", **detail)
+            require(ok, f"the factored form fails its check: {detail}")
+        finally:
+            driver.close()
+            del driver
+            gc.collect()
+
+
 def _panel_run(ex, state):
     import jax
     t0 = time.perf_counter()
@@ -725,7 +780,8 @@ def phase_sharded(sz, on_chip):
 
 ONE_CHIP = [("store", phase_store), ("flash", phase_flash),
             ("block", phase_block), ("host", phase_host),
-            ("qr_host", phase_qr_host), ("panels", phase_panels),
+            ("qr_host", phase_qr_host), ("lu_host", phase_lu_host),
+            ("panels", phase_panels),
             ("flagship", phase_flagship)]
 MULTI_CHIP = [("ring", phase_ring), ("ici", phase_ici),
               ("sharded", phase_sharded)]

@@ -47,9 +47,9 @@ def geqrf_t_collection(A: TiledMatrix, ib: int) -> TiledMatrix:
                        dtype=A.dtype, name=f"{A.name}_T")
 
 
-def _row(kernel, V, T, *stacks):
-    """``kernel(V, T, *tiles)`` over the members of a row that shares V
-    and T, their tiles stacked. A chip module's group (eight members at
+def _row(kernel, shared, *stacks):
+    """``kernel(*shared, *tiles)`` over the members of a row that shares
+    the operands ``shared`` (V and T here), their tiles stacked. A chip module's group (eight members at
     most) is unrolled: XLA then reads each member where it lies and
     writes it where it goes, and a launch of four costs the chip what
     the four cost alone; one product over the stack paid a copy of every
@@ -59,9 +59,9 @@ def _row(kernel, V, T, *stacks):
     import jax.numpy as jnp
     n = stacks[0].shape[0]
     if n > GROUP_SIZES[0]:
-        return jax.vmap(kernel, in_axes=(None, None) + (0,) * len(stacks))(
-            V, T, *stacks)
-    outs = [kernel(V, T, *(s[i] for s in stacks)) for i in range(n)]
+        return jax.vmap(kernel, in_axes=(None,) * len(shared) +
+                        (0,) * len(stacks))(*shared, *stacks)
+    outs = [kernel(*shared, *(s[i] for s in stacks)) for i in range(n)]
     if isinstance(outs[0], tuple):
         return tuple(jnp.stack(o) for o in zip(*outs))
     return jnp.stack(outs)
@@ -287,13 +287,13 @@ def build_geqrf(A: TiledMatrix, T: Optional[TiledMatrix] = None,
     # into its own buffer. GEQRT's and TSQRT's diagonal tile has the
     # row's UNMQRs reading V beside the chain, and stays as it is.
     @UNMQR.body(batch_hook=lambda Vs, Ts, Cs: _row(
-        unmqr_tile, Vs[0], Ts[0], Cs), batch_hook_shared=("V", "T"),
+        unmqr_tile, (Vs[0], Ts[0]), Cs), batch_hook_shared=("V", "T"),
         donates=("C",))
     def unmqr_body(task, V, T_, C):
         return unmqr_tile(V, T_, C)
 
     @TSMQR.body(batch_hook=lambda Vs, Ts, C1s, A2s: _row(
-        tsmqr_tile, Vs[0], Ts[0], C1s, A2s), batch_hook_shared=("V", "T"),
+        tsmqr_tile, (Vs[0], Ts[0]), C1s, A2s), batch_hook_shared=("V", "T"),
         donates=("C1", "A2"))
     def tsmqr_body(task, V, T_, C1, A2):
         return tsmqr_tile(V, T_, C1, A2)

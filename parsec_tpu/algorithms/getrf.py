@@ -1,8 +1,20 @@
-"""Tiled LU factorization without pivoting (dgetrf_nopiv) as PTG taskpools.
+"""Tiled LU factorizations as PTG taskpools: which builder is which LU.
+
+* :func:`build_getrf`, :func:`build_getrf_left`: **no pivoting**
+  (DPLASMA ``dgetrf_nopiv``). Valid for diagonally dominant or otherwise
+  well-conditioned matrices only; a general matrix gives garbage or NaN.
+* :func:`build_getrf_incpiv`: **incremental (pairwise) pivoting**
+  (DPLASMA ``dgetrf_incpiv``, ``src/zgetrf_incpiv.jdf``): any
+  nonsingular matrix; the factored form is A (U and the multipliers), L
+  and IPIV, what ``dgetrs_incpiv`` goes on to read. This is the general
+  LU the benchmark's ``dgetrf_incpiv_ptg_host`` runs.
+
+Neither is LAPACK's partial pivoting over a whole panel (``zgetrf_1d``:
+a task over a range of tiles), which no builder here gives yet.
 
 Completes the DPLASMA-class dense-factorization trio next to
-:mod:`~.potrf` and :mod:`~.geqrf`. The right-looking form mirrors the
-classic dgetrf JDF:
+:mod:`~.potrf` and :mod:`~.geqrf`. The right-looking no-pivoting form
+mirrors the classic dgetrf JDF:
 
     GETRF(k):     A[k,k] ← packed LU (unit-lower L, upper U)
     TRSM_U(k,n):  A[k,n] ← L[k,k]⁻¹·A[k,n]       (row panel, n > k)
@@ -24,10 +36,16 @@ wave fuser lowers each to one or two large matmuls over the Aᵀ store.
 
 from __future__ import annotations
 
+from typing import Optional
+
+from ..core.reshape import UPPER_TILE
 from ..dsl import ptg
 from ..data.matrix import TiledMatrix
-from ..ops.tile_kernels import (gemm_tile, getrf_nopiv_tile,
-                                trsm_lower_unit, trsm_upper_right)
+from .geqrf import _row
+from ..ops.tile_kernels import (gemm_tile, gessm_tile, getrf_incpiv_tile,
+                                getrf_nopiv_tile, ssssm_tile,
+                                trsm_lower_unit, trsm_upper_right,
+                                tstrf_tile)
 from ..utils import compile_cache, mca_param
 
 # Compiled-path panel-TRSM kernel for the fused LU — the POTRF
@@ -66,7 +84,9 @@ def _check(A: TiledMatrix) -> int:
 
 
 def build_getrf(A: TiledMatrix) -> ptg.Taskpool:
-    """Right-looking tiled LU (the dgetrf JDF shape)."""
+    """Right-looking tiled LU WITHOUT PIVOTING (``dgetrf_nopiv``: the
+    dgetrf JDF shape). For a general matrix use
+    :func:`build_getrf_incpiv`."""
     NT = _check(A)
     tp = ptg.Taskpool("getrf", A=A, NT=NT)
 
@@ -209,7 +229,7 @@ def build_getrf(A: TiledMatrix) -> ptg.Taskpool:
 
 
 def build_getrf_left(A: TiledMatrix) -> ptg.Taskpool:
-    """Left-looking tiled LU — the panel-fused flagship form (the
+    """Left-looking tiled LU WITHOUT PIVOTING, the panel-fused flagship form (the
     :func:`~.potrf.build_potrf_left` analog). Each column-panel tile
     (UPDC) and row-panel tile (UPDR) receives ALL its k' < k
     contributions in one task that CTL-gathers its producer TRSMs and
@@ -619,5 +639,333 @@ def _getrf_left_wave_fuser(wave, geoms):
 
 
 def getrf_flops(n: int) -> float:
-    """Useful FLOPs of an n×n LU (LAPACK count)."""
-    return 2.0 * n ** 3 / 3.0 - n ** 2 / 2.0 + 5.0 * n / 6.0
+    """Useful FLOPs of an n×n LU (LAPACK working note 41's count for
+    ``dgetrf``: n³/3 − n/3 multiplications, n³/3 − n²/2 + n/6
+    additions), whichever builder ran it."""
+    return 2.0 * n ** 3 / 3.0 - n ** 2 / 2.0 - n / 6.0
+
+
+# ---- incremental pivoting (DPLASMA dgetrf_incpiv) -----------------------
+
+def getrf_l_collection(A: TiledMatrix, ib: int) -> TiledMatrix:
+    """descL of ``A``: a tile of ``ib`` x nb beside every tile of A under
+    the diagonal, the blocks' L11 of its TSTRF side by side."""
+    if A.nb % ib:
+        raise ValueError(f"ib={ib} does not divide nb={A.nb}")
+    return TiledMatrix(A.mt * ib, A.nt * A.nb, ib, A.nb, dist=A.dist,
+                       dtype=A.dtype, name=f"{A.name}_L")
+
+
+def getrf_ipiv_collection(A: TiledMatrix) -> TiledMatrix:
+    """descIPIV of ``A``: nb int32 beside every tile of A on and under
+    the diagonal (a 1 x nb tile; ops/tile_kernels.py says what a diagonal
+    tile's and a pair's hold)."""
+    import numpy as np
+    return TiledMatrix(A.mt, A.nt * A.nb, 1, A.nb, dist=A.dist,
+                       dtype=np.int32, name=f"{A.name}_IPIV")
+
+
+def _over(old, new):
+    """``new``, in the buffer ``old`` lies in: a flow whose tile is
+    written without being read still names its input, so that a chip
+    module can give the tile's buffer to the program (``Chore.donates``)
+    and a launch holds nothing new."""
+    import jax.numpy as jnp
+    return jnp.where(jnp.zeros((), bool), old, new)
+
+
+def build_getrf_incpiv(A: TiledMatrix, L: Optional[TiledMatrix] = None,
+                       IPIV: Optional[TiledMatrix] = None,
+                       ib: Optional[int] = None) -> ptg.Taskpool:
+    """Tile LU by incremental pivoting (DPLASMA ``dgetrf_incpiv``,
+    ``zgetrf_incpiv.jdf``'s four classes) over ``A`` (square, nb x nb
+    tiles), ``L`` (ib x nb tiles) and ``IPIV`` (int32), the two made here
+    from ``A`` and ``ib`` where none is given (the pool's ``g.L``,
+    ``g.IPIV``), k = 0..NT-1, m, n = k+1..NT-1:
+
+        GETRF(k):     P_k A(k,k) = L_kk U_kk, pivots inside the tile;
+                      writes A(k,k) and IPIV(k,k)
+        GESSM(k,n):   A(k,n) <- L_kk^-1 P_k A(k,n)
+        TSTRF(k,m):   the pivoted LU of the stack [U; A(m,k)], U the
+                      upper triangle of tile (k,k) as the TSTRF before it
+                      left it, ib columns at a time; writes U, the
+                      multipliers over A(m,k), L(m,k) and IPIV(m,k), and
+                      hands its SSSSMs the blocks' L11^-1 (W, a value)
+        SSSSM(k,m,n): [A(k,n); A(m,n)] <- TSTRF(k,m)'s interchanges and
+                      eliminations applied, block by block
+
+    On completion U is the upper triangle of A, and the transformation
+    that took A to it is stored in task order in A's lower part, L and
+    IPIV (``benchmark/configs/dgetrf_incpiv_ptg_host_reference.py
+    apply_l`` reads it back).
+
+    **One tile, two regions, two lives.** Upstream types the dependencies
+    of GETRF's tile: its lower part (``[type = LOWER_TILE]``) goes to the
+    row's GESSMs, its upper part (``[type = UPPER_TILE]``) down the
+    column's chain of TSTRFs, each of which rewrites it, and both end in
+    A(k,k). Here U travels as a value of its own from GETRF through the
+    chain, each TSTRF updating it in the buffer it lies in, and the last
+    one's write-back merges it into the tile A(k,k) holds, in place
+    (``Out(region=UPPER_TILE)``, core/reshape.py): the tile is never made
+    twice. That merge deletes the array the GESSMs were handed, so the
+    last TSTRF waits for them (a CTL gather; they are long gone by then).
+
+    The factorization runs in the storage of A, L and IPIV: every class
+    writes its results to their tiles, and on a chip module every tile
+    is updated in the buffer it lies in (``Chore.donates``; L's and
+    IPIV's tiles are written, not read, and still name their input for
+    that). Two values are made beside the tiles: GETRF's U, merged into
+    its tile at the chain's end, and TSTRF's W (ib x nb: the blocks'
+    L11^-1, which 1240 SSSSMs of a 16 x 16 grid would else each work out
+    from L for themselves), gone with the pair's last SSSSM. Priorities are build_geqrf's, its
+    twin in shape.
+    """
+    NT = _check(A)
+    if L is None:
+        L = getrf_l_collection(A, ib or A.nb)
+    if IPIV is None:
+        IPIV = getrf_ipiv_collection(A)
+    ib = L.mb
+    if (L.mt, L.nt, L.nb) != (NT, NT, A.nb) or A.nb % ib or \
+            (IPIV.mt, IPIV.nt, IPIV.mb, IPIV.nb) != (NT, NT, 1, A.nb):
+        raise ValueError("L needs a tile of ib x nb and IPIV one of 1 x nb "
+                         "per tile of A, ib a divisor of nb")
+    tp = ptg.Taskpool("getrf_incpiv", A=A, L=L, IPIV=IPIV, NT=NT)
+
+    def kept(dc, key_fn):
+        """The write-back every written tile ends with (in place)."""
+        return ptg.Out(data=lambda g, *p: (getattr(g, dc), key_fn(*p)))
+
+    def a_in(cls_params):
+        """A tile of A as step k finds it: the matrix's at k = 0, else
+        what SSSSM(k-1, ·, ·) left."""
+        return [ptg.In(data=lambda g, *p: (g.A, cls_params(*p)[1:]),
+                       guard=lambda g, *p: cls_params(*p)[0] == 0),
+                ptg.In(src=("SSSSM",
+                            lambda g, *p: (cls_params(*p)[0] - 1,
+                                           *cls_params(*p)[1:]), "A2"),
+                       guard=lambda g, *p: cls_params(*p)[0] > 0)]
+
+    def row(k, g):
+        return range(k + 1, g.NT)
+
+    GETRF = tp.task_class(
+        "GETRF", params=("k",),
+        space=lambda g: ((k,) for k in range(g.NT)),
+        affinity=lambda g, k: (g.A, (k, k)),
+        priority=lambda g, k: 4 * (g.NT - k) ** 2,
+        flows=[
+            ptg.FlowSpec(
+                "A", ptg.RW,
+                tile=lambda g, k: (g.A, (k, k)),
+                ins=a_in(lambda k: (k, k, k)),
+                outs=[ptg.Out(dst=("GESSM",
+                                   lambda g, k: [(k, n) for n in row(k, g)],
+                                   "L")),
+                      kept("A", lambda k: (k, k))]),
+            ptg.FlowSpec(
+                "IPIV", ptg.RW,
+                tile=lambda g, k: (g.IPIV, (k, k)),
+                ins=[ptg.In(data=lambda g, k: (g.IPIV, (k, k)))],
+                outs=[ptg.Out(dst=("GESSM",
+                                   lambda g, k: [(k, n) for n in row(k, g)],
+                                   "P")),
+                      kept("IPIV", lambda k: (k, k))]),
+            # the upper triangle on its way down the column: a value,
+            # no tile of its own
+            ptg.FlowSpec(
+                "U", ptg.WRITE,
+                outs=[ptg.Out(dst=("TSTRF", lambda g, k: (k, k + 1), "U"),
+                              guard=lambda g, k: k + 1 < g.NT)]),
+        ])
+
+    GESSM = tp.task_class(
+        "GESSM", params=("k", "n"),
+        space=lambda g: ((k, n) for k in range(g.NT) for n in row(k, g)),
+        affinity=lambda g, k, n: (g.A, (k, n)),
+        priority=lambda g, k, n: 3 * (g.NT - k) ** 2 - n,
+        flows=[
+            ptg.FlowSpec(
+                "L", ptg.READ,
+                tile=lambda g, k, n: (g.A, (k, k)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, n: (k,), "A"))]),
+            ptg.FlowSpec(
+                "P", ptg.READ,
+                tile=lambda g, k, n: (g.IPIV, (k, k)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, n: (k,), "IPIV"))]),
+            ptg.FlowSpec(
+                "C", ptg.RW,
+                tile=lambda g, k, n: (g.A, (k, n)),
+                ins=a_in(lambda k, n: (k, k, n)),
+                outs=[ptg.Out(dst=("SSSSM",
+                                   lambda g, k, n: (k, k + 1, n), "A1")),
+                      kept("A", lambda k, n: (k, n))]),
+            # it has read the tile whose upper part the column's last
+            # TSTRF merges over
+            ptg.FlowSpec(
+                "G", ptg.CTL,
+                outs=[ptg.Out(dst=("TSTRF",
+                                   lambda g, k, n: (k, g.NT - 1), "G"))]),
+        ])
+
+    TSTRF = tp.task_class(
+        "TSTRF", params=("k", "m"),
+        space=lambda g: ((k, m) for k in range(g.NT) for m in row(k, g)),
+        affinity=lambda g, k, m: (g.A, (m, k)),
+        priority=lambda g, k, m: 3 * (g.NT - k) ** 2 - m,
+        flows=[
+            ptg.FlowSpec(
+                "U", ptg.RW,
+                ins=[ptg.In(src=("GETRF", lambda g, k, m: (k,), "U"),
+                            guard=lambda g, k, m: m == k + 1),
+                     ptg.In(src=("TSTRF", lambda g, k, m: (k, m - 1), "U"),
+                            guard=lambda g, k, m: m > k + 1)],
+                outs=[ptg.Out(dst=("TSTRF", lambda g, k, m: (k, m + 1), "U"),
+                              guard=lambda g, k, m: m + 1 < g.NT),
+                      ptg.Out(data=lambda g, k, m: (g.A, (k, k)),
+                              guard=lambda g, k, m: m + 1 == g.NT,
+                              region=UPPER_TILE)]),
+            # A(m,k) goes in, the multipliers come out in its place
+            ptg.FlowSpec(
+                "A", ptg.RW,
+                tile=lambda g, k, m: (g.A, (m, k)),
+                ins=a_in(lambda k, m: (k, m, k)),
+                outs=[ptg.Out(dst=("SSSSM",
+                                   lambda g, k, m: [(k, m, n)
+                                                    for n in row(k, g)],
+                                   "L21")),
+                      kept("A", lambda k, m: (m, k))]),
+            # what dgetrs_incpiv reads; the pair's SSSSMs get W
+            ptg.FlowSpec(
+                "L", ptg.RW,
+                tile=lambda g, k, m: (g.L, (m, k)),
+                ins=[ptg.In(data=lambda g, k, m: (g.L, (m, k)))],
+                outs=[kept("L", lambda k, m: (m, k))]),
+            ptg.FlowSpec(
+                "IPIV", ptg.RW,
+                tile=lambda g, k, m: (g.IPIV, (m, k)),
+                ins=[ptg.In(data=lambda g, k, m: (g.IPIV, (m, k)))],
+                outs=[ptg.Out(dst=("SSSSM",
+                                   lambda g, k, m: [(k, m, n)
+                                                    for n in row(k, g)],
+                                   "P")),
+                      kept("IPIV", lambda k, m: (m, k))]),
+            # the blocks' L11^-1, for the pair's SSSSMs: a value, no
+            # tile, no part of the factored form
+            ptg.FlowSpec(
+                "W", ptg.WRITE,
+                outs=[ptg.Out(dst=("SSSSM",
+                                   lambda g, k, m: [(k, m, n)
+                                                    for n in row(k, g)],
+                                   "W"))]),
+            ptg.FlowSpec(
+                "G", ptg.CTL,
+                ins=[ptg.In(src=("GESSM",
+                                 lambda g, k, m: [(k, n) for n in row(k, g)],
+                                 "G"),
+                            gather=True,
+                            guard=lambda g, k, m: m + 1 == g.NT)]),
+        ])
+
+    SSSSM = tp.task_class(
+        "SSSSM", params=("k", "m", "n"),
+        space=lambda g: ((k, m, n) for k in range(g.NT) for m in row(k, g)
+                         for n in row(k, g)),
+        affinity=lambda g, k, m, n: (g.A, (m, n)),
+        priority=lambda g, k, m, n: (g.NT - k) ** 2 - m - n,
+        flows=[
+            ptg.FlowSpec(
+                "L21", ptg.READ,
+                tile=lambda g, k, m, n: (g.A, (m, k)),
+                ins=[ptg.In(src=("TSTRF", lambda g, k, m, n: (k, m), "A"))]),
+            ptg.FlowSpec(
+                "W", ptg.READ,
+                ins=[ptg.In(src=("TSTRF", lambda g, k, m, n: (k, m), "W"))]),
+            ptg.FlowSpec(
+                "P", ptg.READ,
+                tile=lambda g, k, m, n: (g.IPIV, (m, k)),
+                ins=[ptg.In(src=("TSTRF", lambda g, k, m, n: (k, m),
+                                 "IPIV"))]),
+            # the running row-k tile A(k,n), down the column
+            ptg.FlowSpec(
+                "A1", ptg.RW,
+                tile=lambda g, k, m, n: (g.A, (k, n)),
+                ins=[ptg.In(src=("GESSM", lambda g, k, m, n: (k, n), "C"),
+                            guard=lambda g, k, m, n: m == k + 1),
+                     ptg.In(src=("SSSSM",
+                                 lambda g, k, m, n: (k, m - 1, n), "A1"),
+                            guard=lambda g, k, m, n: m > k + 1)],
+                outs=[ptg.Out(dst=("SSSSM",
+                                   lambda g, k, m, n: (k, m + 1, n), "A1"),
+                              guard=lambda g, k, m, n: m + 1 < g.NT),
+                      kept("A", lambda k, m, n: (k, n))]),
+            # the trailing tile A(m,n): step k+1's input
+            ptg.FlowSpec(
+                "A2", ptg.RW,
+                tile=lambda g, k, m, n: (g.A, (m, n)),
+                ins=a_in(lambda k, m, n: (k, m, n)),
+                outs=[
+                    ptg.Out(dst=("GETRF", lambda g, k, m, n: (k + 1,), "A"),
+                            guard=lambda g, k, m, n: m == k + 1 and
+                            n == k + 1),
+                    ptg.Out(dst=("GESSM", lambda g, k, m, n: (k + 1, n),
+                                 "C"),
+                            guard=lambda g, k, m, n: m == k + 1 and
+                            n > k + 1),
+                    ptg.Out(dst=("TSTRF", lambda g, k, m, n: (k + 1, m),
+                                 "A"),
+                            guard=lambda g, k, m, n: m > k + 1 and
+                            n == k + 1),
+                    ptg.Out(dst=("SSSSM",
+                                 lambda g, k, m, n: (k + 1, m, n), "A2"),
+                            guard=lambda g, k, m, n: m > k + 1 and
+                            n > k + 1),
+                    kept("A", lambda k, m, n: (m, n)),
+                ]),
+        ])
+
+    # Stacked forms as build_geqrf declares them: a row of GESSMs shares
+    # L and P, a row of SSSSMs L21, W and P (one operand, not one a
+    # member: an int32 tile among them); GETRF and TSTRF are serial
+    # chains whose stacked form is an executor's and declares the chain's
+    # own value shared, so that a chip module never builds a group
+    # program for them.
+    import jax
+
+    def getrf(a, p):
+        lu, perm = getrf_incpiv_tile(a)
+        return {"A": lu, "IPIV": _over(p, perm),
+                "U": jax.numpy.triu(lu)}
+
+    def tstrf(u, a, l, p):
+        u, a, l_new, piv, w = tstrf_tile(u, a, ib)
+        return {"U": u, "A": a, "L": _over(l, l_new),
+                "IPIV": _over(p, piv), "W": w}
+
+    @GETRF.body(batch_hook=lambda As, Ps: jax.vmap(getrf)(As, Ps),
+                batch_hook_shared=("A",), donates=("A", "IPIV"))
+    def getrf_body(task, A_, P, U):
+        return getrf(A_, P)
+
+    @TSTRF.body(batch_hook=lambda Us, As, Ls, Ps: jax.vmap(tstrf)(
+        Us, As, Ls, Ps), batch_hook_shared=("U",),
+        donates=("U", "A", "L", "IPIV"))
+    def tstrf_body(task, U, A_, L_, P, W):
+        return tstrf(U, A_, L_, P)
+
+    @GESSM.body(batch_hook=lambda Ls, Ps, Cs: _row(
+        gessm_tile, (Ls[0], Ps[0]), Cs), batch_hook_shared=("L", "P"),
+        donates=("C",))
+    def gessm_body(task, L_, P, C):
+        return gessm_tile(L_, P, C)
+
+    def ssssm(l21, w, p, a1, a2):
+        return ssssm_tile(a1, a2, w, l21, p)
+
+    @SSSSM.body(batch_hook=lambda L21s, Ws, Ps, A1s, A2s: _row(
+        ssssm, (L21s[0], Ws[0], Ps[0]), A1s, A2s),
+        batch_hook_shared=("L21", "W", "P"), donates=("A1", "A2"))
+    def ssssm_body(task, L21, W, P, A1, A2):
+        return ssssm(L21, W, P, A1, A2)
+
+    return tp

@@ -164,7 +164,12 @@ def plan_taskpool(tp: PTGTaskpool) -> WavefrontPlan:
     applied to the gathered stack at execution (XLA fuses the cast/
     transpose into the body); terminal DataRef specs are applied by
     write_back. Groups whose instances disagree on specs are split."""
-    from ..dsl.ptg import taskpool_uses_reshape
+    from ..dsl.ptg import taskpool_uses_reshape, taskpool_writes_regions
+    if taskpool_writes_regions(tp):
+        raise ValueError(
+            f"taskpool {tp.name}: a write-back of a region of a tile "
+            f"(Out(region=...)) is the host runtime's: an executor "
+            f"scatters whole tiles")
     has_reshapes = taskpool_uses_reshape(tp)
     # ---- enumerate tasks and assign ids
     tasks: List[Tuple[PTGTaskClass, Tuple[int, ...]]] = []

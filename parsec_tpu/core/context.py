@@ -1242,6 +1242,22 @@ class Context:
         from ..device.hbm import track_collection_write
         return track_collection_write(self.hbm, dc, key, value)
 
+    def _merge_region(self, ref: DataRef) -> None:
+        """The write-back of one region of a tile (``Out(region=...)``):
+        merged into the tile the collection holds, in that tile's
+        buffer, and counted on the chip module it lies on
+        (``region_merges``)."""
+        merged = ref.collection.merge_tile(ref.key, ref.value, ref.region)
+        if self.hbm is not None:
+            mkey = self._hbm_track(ref.collection, ref.key, merged)
+            if mkey is not None:
+                self.hbm.unpin(mkey)
+        where = getattr(merged, "device", None)
+        for dev in self.devices.chips:
+            if dev.jax_device == where:
+                with dev._lock:
+                    dev.stats["region_merges"] += 1
+
     def complete_task(self, es: Optional[ExecutionStream], task: Task) -> None:
         """__parsec_complete_execution + release_deps analog
         (scheduling.c:441-470, parsec.c:1694-1921)."""
@@ -1320,6 +1336,9 @@ class Context:
                 es.stats["unfold_s"] += span.seconds
         for ref in successors:
             if isinstance(ref, DataRef):
+                if ref.region is not None:
+                    self._merge_region(ref)
+                    continue
                 # track (pinned) first, write second, unpin last — see
                 # _hbm_track
                 mkey = None

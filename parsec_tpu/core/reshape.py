@@ -130,6 +130,62 @@ class ReshapeSpec:
         return f"<ReshapeSpec {self.name}>"
 
 
+class Region:
+    """A part of a tile that a dependency carries: the runtime's half of
+    the JDF's ``[type = LOWER_TILE]`` / ``[type = UPPER_TILE]`` on a
+    dependency whose two ends are regions of ONE tile with lives of their
+    own (DPLASMA's zgetrf_incpiv: the L of GETRF's tile is read by a row
+    of GESSMs while its U goes down a chain of TSTRFs that rewrite it,
+    and both end in A(k, k)).
+
+    A value here is an immutable array, so the region that is rewritten
+    travels as a value of its own, whole-tile sized, and its terminal
+    write-back (``ptg.Out(data=..., region=UPPER_TILE)``) merges it into
+    the tile the collection holds, IN that tile's buffer
+    (:meth:`merge`): no second copy of the tile is made, and the part
+    outside the region stays as its writer left it. As with
+    ``Chore.donates`` the graph says when that is safe: the merging task
+    comes after the readers of the tile's other region (a CTL gather in
+    ``algorithms/getrf.py build_getrf_incpiv``), since the array they
+    were handed is deleted by the merge.
+
+    ``inside(rows, cols) -> mask`` of index grids ``r``, ``c``."""
+
+    def __init__(self, name: str, inside: Callable[[Any, Any], Any]):
+        self.name = name
+        self.inside = inside
+        self._program = None        # the jitted in-place merge
+
+    def merge(self, tile: Any, part: Any) -> Any:
+        """``tile`` with this region taken from ``part``. A device array
+        is updated where it lies (its buffer is given to the program and
+        ``tile`` is deleted); a host array is written into."""
+        import numpy as np
+        if isinstance(tile, np.ndarray):
+            r, c = np.indices(tile.shape[-2:], sparse=True)
+            np.copyto(tile, np.asarray(part), where=self.inside(r, c))
+            return tile
+        import jax
+        import jax.numpy as jnp
+        if not isinstance(part, jax.Array):
+            part = jax.device_put(part, tile.device)
+        if self._program is None:
+            def parsec_region_merge(tile, part):
+                r, c = jnp.indices(tile.shape[-2:], sparse=True)
+                return jnp.where(self.inside(r, c), part.astype(tile.dtype),
+                                 tile)
+            self._program = jax.jit(parsec_region_merge, donate_argnums=0)
+        return self._program(tile, part)
+
+    def __repr__(self) -> str:
+        return f"<Region {self.name}>"
+
+
+# DPLASMA's matrix_UpperTile: the upper triangle with the diagonal (what
+# lies strictly under it is the other region of a factored diagonal tile)
+UPPER_TILE = Region("UPPER_TILE", lambda r, c: r <= c)
+
+
 def compose_specs(producer: Optional[ReshapeSpec],
                   consumer: Optional[ReshapeSpec]) -> Optional[ReshapeSpec]:
     """Combine an Out-side and an In-side spec into the single conversion

@@ -68,6 +68,9 @@ class DataRef:
     collection: Any                  # data.collection.DataCollection
     key: Tuple[int, ...]
     value: Any = None
+    # core.reshape.Region: ``value`` replaces that part of the tile the
+    # collection holds, in the tile's own buffer; None: the whole tile
+    region: Any = None
 
 
 class TaskClass:

@@ -161,6 +161,17 @@ class TiledMatrix(DataCollection):
         with self._lock:
             self._tiles[tuple(key)] = value
 
+    def merge_tile(self, key, part, region) -> Any:
+        """Replace ``region`` (core.reshape.Region) of the tile under
+        ``key`` by that part of ``part``, in the buffer the tile lies in:
+        the write-back of a dependency that carries one region of a tile
+        (``[type = UPPER_TILE]``). The array the collection held is
+        deleted where it lay on a device; the merged tile is returned."""
+        tile = self.data_of(key)
+        with self._lock:
+            merged = self._tiles[tuple(key)] = region.merge(tile, part)
+        return merged
+
     def keys(self) -> Iterable[Tuple[int, int]]:
         return [(i, j) for i in range(self.mt) for j in range(self.nt)]
 

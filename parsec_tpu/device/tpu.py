@@ -204,6 +204,13 @@ class TPUDevice(Device):
         # group before them was still on the chip's queue
         self.stats.update(lone_in_place=0, groups_in_place=0,
                           groups_pipelined=0)
+        # write-backs that merged one region of a tile into the tile, in
+        # its buffer (``Context._merge_region``); and the tiles that are
+        # not floating point (a factorization's pivots) handed to this
+        # chip's programs, put here or found here, with their bytes: an
+        # operand a group shares counted once a launch (``program.ints``)
+        self.stats.update(region_merges=0, int_tiles_staged=0,
+                          int_bytes_staged=0)
         # the host's waits for the chip (``_under``) and its jitted
         # calls: seconds and waits while the stage timers are on (the
         # launches are in ``launches_by_class``); the longest of each
@@ -382,6 +389,11 @@ class TPUDevice(Device):
                 self.stats["groups_pipelined"] += bool(queued)
             else:
                 self.stats["lone_in_place"] += not held
+            if program.ints is not None:
+                own, shared = program.ints
+                for (n, nbytes), times in ((own, len(tasks)), (shared, 1)):
+                    self.stats["int_tiles_staged"] += n * times
+                    self.stats["int_bytes_staged"] += nbytes * times
             # the longest call and the longest wait always: what a
             # stalled step stood in
             if called > self.stats["call_max_s"]:
@@ -832,6 +844,20 @@ class TPUDevice(Device):
                              for i in range(size))
             return program
 
+        # the leaves of a member that are not floating point (pivots):
+        # (tiles, bytes) of a member's own and of those the stacked form
+        # shares, which a launch holds once; None where there are none
+        ints = [[0, 0], [0, 0]]
+        for f, v in zip(flows, values):
+            for leaf in tu.tree_leaves(v):
+                kind = getattr(getattr(leaf, "dtype", None), "kind", "f")
+                if kind in "iub":
+                    one_launch = stacked and f.name in (
+                        chore.batch_hook_shared or ())
+                    ints[one_launch][0] += 1
+                    ints[one_launch][1] += leaf.nbytes
+        ints = tuple(map(tuple, ints)) if ints[0][0] or ints[1][0] else None
+
         # equal bodies across taskpools, contexts and device modules
         # trace once: a stable fingerprint shares the program process-
         # wide; an unstable one stays with this chore
@@ -880,5 +906,6 @@ class TPUDevice(Device):
                         x.nbytes for x in tu.tree_leaves(fn(*args)[:size])
                         if x.unsafe_buffer_pointer() not in given_to)
                 fn.donated_at = donated_at
+                fn.ints = ints
                 programs[size] = fn
         return programs

@@ -113,12 +113,17 @@ class Out:
     - ``data=lambda g, *p: (collection, key)``: terminal write-back
     ``reshape`` converts the produced value before it reaches this dep's
     target (producer-side ``[type = ...]``); it composes with the
-    consumer's ``In.reshape``.
+    consumer's ``In.reshape``. ``region`` (core.reshape.Region, on a
+    ``data`` write-back only: ``[type = UPPER_TILE]``): the value is that
+    part of the tile and is merged into the tile the collection holds,
+    where it lies; the pool orders the merge after the readers of the
+    tile's other part.
     """
     dst: Optional[Tuple[str, Callable, str]] = None
     data: Optional[Callable] = None
     guard: Optional[Callable] = None
     reshape: Optional[Any] = None
+    region: Optional[Any] = None
 
     def active(self, g, params) -> bool:
         return self.guard is None or bool(self.guard(g, *params))
@@ -152,6 +157,10 @@ class PTGTaskClass(TaskClass):
                  priority: Optional[Callable]):
         flows = [Flow(s.name, s.access) for s in specs]
         for s in specs:
+            if any(d.region is not None and d.data is None for d in s.outs):
+                raise ValueError(
+                    f"{name}.{s.name}: a region belongs to a write-back "
+                    f"(Out(data=...)): a value between tasks travels whole")
             for d in s.ins:
                 if d.gather and not (s.access & FlowAccess.CTL):
                     raise ValueError(
@@ -335,7 +344,8 @@ class PTGTaskClass(TaskClass):
                     dc, key = dep.data(g, *task.locals)
                     v = value if dep.reshape is None \
                         else dep.reshape.apply(value)
-                    yield DataRef(collection=dc, key=key, value=v)
+                    yield DataRef(collection=dc, key=key, value=v,
+                                  region=dep.region)
                     continue
                 cls_name, params_fn, dst_flow = dep.dst
                 dst_tc = task.taskpool.task_class_by_name(cls_name)
@@ -437,16 +447,27 @@ class Taskpool(CoreTaskpool):
 
 
 def taskpool_uses_reshape(tp: Taskpool) -> bool:
-    """True if any dep of any task class declares a reshape spec. The
+    """True if any dep of any task class declares a reshape spec (or
+    a write-back of a region of a tile, which is one). The
     compiled (wavefront/SPMD) and native executors move raw tile values
     and must refuse such taskpools instead of silently skipping the
     conversions (the host runtime resolves them in complete_task)."""
     for tc in tp.task_classes:
         for spec in tc.spec_list:
             if any(d.reshape is not None for d in spec.ins) or \
-                    any(d.reshape is not None for d in spec.outs):
+                    any(d.reshape is not None or d.region is not None
+                        for d in spec.outs):
                 return True
     return False
+
+
+def taskpool_writes_regions(tp: Taskpool) -> bool:
+    """True if any write-back of the pool is of a region of a tile
+    (``Out(region=...)``). The host runtime merges one into the tile its
+    collection holds (``Context._release_deps``); the compiled and the
+    native executors scatter whole tiles and refuse such a pool."""
+    return any(d.region is not None for tc in tp.task_classes
+               for spec in tc.spec_list for d in spec.outs)
 
 
 def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:

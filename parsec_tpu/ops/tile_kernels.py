@@ -691,3 +691,159 @@ def tsmqr_tile(V2, T, C1, C2):
         C1f = C1f.at[J].add(-W)
         C2f = C2f - _mm(Vj, W)
     return C1f.astype(C1.dtype), C2f.astype(C2.dtype)
+
+
+# ---- tile LU by incremental pivoting (DPLASMA dgetrf_incpiv) -----------
+# PLASMA's four core_blas kernels (zgetrf_incpiv, zgessm, ztstrf, zssssm)
+# over a tile pair: pivots inside the diagonal tile, then pairwise
+# between the diagonal tile's U and each tile under it, ``ib`` columns at
+# a time. The pivot search, the scaling by the pivot and a panel block's
+# factorization are XLA's partial-pivoting LU at full float32, and so is
+# what is applied to the columns to the right (L11^-1 and the product
+# with the multipliers), WHATEVER ``ops.matmul_precision`` says: a pivoted
+# LU's entries grow (about n^(2/3) under partial pivoting, more under
+# pairwise pivoting), every rounding of an update is carried through the
+# rest of the elimination, and a bf16 pass's 2^-9 times that growth leaves
+# no digit: N = 32768 in 2048-tiles read a solve residual near 1 on the
+# v5e at default precision (PERF.md section 6, PR 39), where the QR and
+# Cholesky updates, which do not grow, read 1e-2. A device
+# trace splits a kernel's time by three scopes: ``parsec:lu_pivot`` (the
+# pivoted factorization of a block: serial), ``parsec:lu_swap`` (row
+# exchanges: memory-bound) and ``parsec:lu_update`` (solve and product).
+#
+# Pivots are int32 tiles of 1 x nb. A diagonal tile's holds the
+# permutation (row i of P·A is row ``perm[i]`` of A): in-tile interchanges
+# go anywhere, and a permutation is applied in one gather. A pair's holds
+# LAPACK's interchange indices block by block: at step j of block b the
+# stack's rows j and ``ipiv[b·ib + j]`` were exchanged, the stack being
+# [the block's ib rows of U; the nb rows of the lower tile]. A zero under
+# U's diagonal never wins a search, so an index is j itself or ib + r,
+# row r of the lower tile, and the block's exchanges come to one gather
+# of ib rows and one scatter (``_pair_moves``): no loop over the steps.
+
+_I32 = jnp.int32
+
+
+def _unit_lower_solve(L, C, base: int = 256):
+    """``L⁻¹·C`` for the unit lower triangle of ``L`` (what lies on and
+    above its diagonal is not read): halves down to ``base``, the
+    off-diagonal block a product."""
+    n = L.shape[0]
+    if n <= base or n % 2:
+        return jax.lax.linalg.triangular_solve(
+            L, C, left_side=True, lower=True, unit_diagonal=True)
+    h = n // 2
+    top = _unit_lower_solve(L[:h, :h], C[:h], base)
+    return jnp.concatenate(
+        [top, _unit_lower_solve(L[h:, h:], C[h:] - _mmh(L[h:, :h], top),
+                                base)], axis=0)
+
+
+def _pair_moves(piv, ib: int, nb: int):
+    """One block's interchanges as moves: ``src[j]`` the stacked row the
+    block's row j of U ends with (j: its own; under ib: another U row,
+    swapped down earlier and now back; else ib + a lower row), ``dst[j]``
+    the lower row U's row j ends in (nb: none, dropped by the scatter)."""
+    j = jnp.arange(ib, dtype=_I32)
+    low = piv >= ib
+    same = (piv[:, None] == piv[None, :]) & low[:, None]
+    before = same & (j[None, :] < j[:, None])
+    prev = jnp.max(jnp.where(before, j[None, :], -1), axis=1)
+    src = jnp.where(low, jnp.where(prev >= 0, prev, piv), j)
+    last = ~jnp.any(same & (j[None, :] > j[:, None]), axis=1)
+    return src, jnp.where(low & last, piv - ib, nb)
+
+
+def _pair_swap(top, bot, piv):
+    """Exchange rows between ``top`` (a block's ib rows of the upper
+    operand) and ``bot`` (the lower tile) as the block's ``piv`` says."""
+    ib, nb = top.shape[0], bot.shape[0]
+    src, dst = _pair_moves(piv, ib, nb)
+    own = src < ib
+    new_top = jnp.where(own[:, None], top[jnp.where(own, src, 0)],
+                        bot[jnp.where(own, 0, src - ib)])
+    return new_top, bot.at[dst].set(top, mode="drop")
+
+
+def getrf_incpiv_tile(A):
+    """GETRF: P·A = L·U, partial pivoting inside the tile -> (the tile
+    with U in its upper triangle and L, unit lower, under it; the
+    permutation, 1 x nb int32)."""
+    with jax.named_scope("parsec:lu_pivot"):
+        lu, _, perm = jax.lax.linalg.lu(jnp.asarray(A, _F32))
+    return lu.astype(A.dtype), perm.astype(_I32)[None, :]
+
+
+def gessm_tile(L, P, C):
+    """GESSM: C <- L⁻¹·P·C, L and P as :func:`getrf_incpiv_tile` left
+    them (``L`` its tile: U is not read)."""
+    with jax.named_scope("parsec:lu_swap"):
+        Cf = jnp.asarray(C, _F32)[P[0]]
+    with jax.named_scope("parsec:lu_update"):
+        return _unit_lower_solve(jnp.asarray(L, _F32), Cf).astype(C.dtype)
+
+
+def _unit_lower_inverse(L):
+    """The inverse of a unit lower block, at full float32."""
+    return jax.lax.linalg.triangular_solve(
+        L, jnp.eye(L.shape[0], dtype=L.dtype), left_side=True, lower=True,
+        unit_diagonal=True)
+
+
+def tstrf_tile(U, A, ib: int):
+    """TSTRF: the partial-pivoting LU of the stack [U; A], U the upper
+    triangle of ``U``, ``ib`` columns at a time -> (U'; the multipliers
+    L21 in A's place; L, ib x nb: the blocks' L11, unit lower, side by
+    side; the interchanges, 1 x nb int32; W, ib x nb: the blocks' L11⁻¹
+    side by side, which is no part of the factored form: the pair's
+    SSSSMs apply it as a product where each would else invert L11's
+    diagonal blocks for itself)."""
+    nb = U.shape[0]
+    Uf, Af = jnp.triu(jnp.asarray(U, _F32)), jnp.asarray(A, _F32)
+    L21s, L11s, pivs, Ws = [], [], [], []
+    for o in range(0, nb, ib):
+        J = slice(o, o + ib)
+        with jax.named_scope("parsec:lu_pivot"):
+            lu, piv, _ = jax.lax.linalg.lu(
+                jnp.concatenate([Uf[J, J], Af[:, J]], axis=0))
+            L11 = jnp.tril(lu[:ib], -1) + jnp.eye(ib, dtype=_F32)
+            W = _unit_lower_inverse(L11)
+        piv = piv.astype(_I32)
+        Uf = Uf.at[J, J].set(jnp.triu(lu[:ib]))
+        L21s.append(lu[ib:])
+        L11s.append(L11)
+        pivs.append(piv)
+        Ws.append(W)
+        if o + ib < nb:
+            with jax.named_scope("parsec:lu_swap"):
+                top, bot = _pair_swap(Uf[J, o + ib:], Af[:, o + ib:], piv)
+            with jax.named_scope("parsec:lu_update"):
+                top = _mmh(W, top)
+                Uf = Uf.at[J, o + ib:].set(top)
+                Af = Af.at[:, o + ib:].set(bot - _mmh(lu[ib:], top))
+    return (Uf.astype(U.dtype),
+            jnp.concatenate(L21s, axis=1).astype(A.dtype),
+            jnp.concatenate(L11s, axis=1).astype(A.dtype),
+            jnp.concatenate(pivs)[None, :],
+            jnp.concatenate(Ws, axis=1).astype(A.dtype))
+
+
+def ssssm_tile(A1, A2, W, L21, P):
+    """SSSSM: [A1; A2] <- the pair's transformation applied, W (the
+    blocks' L11⁻¹), L21 and P as :func:`tstrf_tile` left them: block by
+    block, the interchanges between A1's ib rows and A2, then A1's rows
+    <- L11⁻¹·them and A2 <- A2 - L21·them."""
+    ib, nb = W.shape
+    A1f, A2f = jnp.asarray(A1, _F32), jnp.asarray(A2, _F32)
+    Wf, L21f = jnp.asarray(W, _F32), jnp.asarray(L21, _F32)
+    tops = []
+    for o in range(0, nb, ib):
+        J = slice(o, o + ib)
+        with jax.named_scope("parsec:lu_swap"):
+            top, A2f = _pair_swap(A1f[J], A2f, P[0, J])
+        with jax.named_scope("parsec:lu_update"):
+            top = _mmh(Wf[:, J], top)
+            A2f = A2f - _mmh(L21f[:, J], top)
+        tops.append(top)
+    return (jnp.concatenate(tops, axis=0).astype(A1.dtype),
+            A2f.astype(A2.dtype))
