@@ -31,8 +31,8 @@ arrays kept aside.
 
 from __future__ import annotations
 
-from ..compiled.panels import (SegRead, SegStep, SegWrite, bucket_tiles,
-                               register_panel_kernel)
+from ..compiled.panels import (PanelPartition, SegRead, SegStep, SegWrite,
+                               bucket_tiles, register_panel_kernel)
 from ..dsl import dtd, ptg
 from ..data.matrix import TiledMatrix
 from ..ops.tile_kernels import (gemm_tile, potrf_tile, potrf_tile_blocked,
@@ -537,9 +537,13 @@ def build_potrf_left(A: TiledMatrix) -> ptg.Taskpool:
     direct-memory pattern reference JDF bodies use for gathered
     operands. ASAP leveling then yields exactly three waves per step k
     ([UPDATE(·,k)], [POTRF(k)], [TRSM(·,k)]), and the panel fuser turns
-    each UPDATE wave into ONE dense matmul over all previously factored
-    panels — the MXU-optimal schedule (measured ~98-106 TF/s/chip vs
-    ~68 for the fused right-looking form at N=32768-40960).
+    each UPDATE wave into dense matmuls over all previously factored
+    panels, one a column run of the row panel, their results subtracted
+    where the POTRF and TRSM waves consume them — measured on a v5e at
+    N=40960, NB=1024 (PERF.md §6, PR 40): 138 TF/s/chip for the
+    factorization, the products alone at 173; 107 while the
+    product and its subtraction were one fusion as wide as the row panel
+    (PRs 22-39), ~68 for the fused right-looking form.
 
     Distribution: UPDATE's gathered operands are resolved with the
     direct-memory pattern of reference JDF bodies — local tiles read
@@ -694,62 +698,93 @@ def build_potrf_left(A: TiledMatrix) -> ptg.Taskpool:
 
 
 def _potrf_left_wave_fuser(wave, geoms):
-    """Lower one left-looking POTRF wave to Aᵀ-dense ops.
+    """Lower one left-looking POTRF wave to Aᵀ-dense ops on one chip, as
+    :func:`_potrf_left_mesh_wave_fuser` lowers it for a row panel nobody
+    sends.
 
-    Wave shapes per step k: [UPDATE(·,k)] → one matmul applying every
-    prior panel's contribution to block-column k; [POTRF(k)] → diagonal
-    chol (inverse stashed in the carry); [TRSM(·,k)] → one panel solve
-    via the stashed inverse."""
+    Wave shapes per step k: [UPDATE(·,k)] → the products alone,
+    (Lᵀ[:k, k])ᵀ · Lᵀ[:k, run], one matmul a column run of row panel k
+    (``PanelPartition.chunks``' rule for a panel without senders: the
+    fewest equal runs of whole tiles within ``PANEL_CHUNK_BYTES``),
+    carried in ``st["_products"]``: nothing is subtracted and nothing
+    written in this wave. [POTRF(k)] → the diagonal tile less the head
+    of the first run's product, chol (inverse stashed in the carry).
+    [TRSM(·,k)] → a run at a time, row panel k less the run's product,
+    solved via the stashed inverse, one contiguous write a run; the
+    diagonal tile's Lᵀ goes with the first run's, so row panel k is
+    written once. Every product of a step reads the state as the step
+    found it (one ``optimization_barrier`` ahead of the writes says so).
+
+    The form before PR 40 subtracted inside the matmul's fusion, over the
+    whole remaining row panel at once. On a v5e at N = 40960 (PERF.md
+    §6, PR 40) XLA:TPU's fusion of a product with the subtraction behind
+    it ran at about 128 TF/s; the product alone runs at 171, in runs of
+    ``PANEL_CHUNK_BYTES`` at 173 with shorter writes behind it (0.2135 →
+    0.1720 → 0.1657 s a factorization), and in runs without the barrier
+    the compiler copies the state: the barrier is not an ornament."""
+    import jax
+    from jax import lax
+    from ..ops.tile_kernels import tri_inv_tile
     (geom,) = geoms.values()      # single-collection DAG
     jnp, mm, tile_chol = _fuser_helpers(geom)
-    names = sorted(g.tc.name for g in wave)
-    mb, nb = geom.mb, geom.nb
-
-    if names == ["UPDATE"]:
-        (grp,) = wave
-        ks = {t[1] for t in grp.tasks}
-        if len(ks) != 1:
-            return None
-        k = ks.pop()
-        ms = sorted(t[0] for t in grp.tasks)
-        lo, hi = ms[0], ms[-1] + 1
-        if ms != list(range(lo, hi)) or lo != k:
-            return None
-
-        def do_update(st, k=k, hi=hi):
-            # carry the updated row panel to the POTRF/TRSM waves of
-            # this step instead of writing it to D — the step's panel is
-            # written exactly ONCE (by do_trsm / do_potrf), halving the
-            # DUS traffic and HBM liveness vs a write-per-wave lowering
-            D = st[geom.name]
-            r0, r1 = k * nb, (k + 1) * nb
-            # Aᵀ[k-row, k..hi) −= (Lᵀ[:k, k])ᵀ · Lᵀ[:k, k..hi)
-            U = D[0:r0, r0:r1]
-            S = D[0:r0, r0:hi * mb]
-            st["_rowk"] = D[r0:r1, r0:hi * mb] - mm(U.T, S)
-            return st
-
-        return do_update
-
+    if len(wave) != 1:
+        return None
+    (grp,) = wave
+    kind = grp.tc.name
+    mb, nb, name = geom.mb, geom.nb, geom.name
+    f32 = jnp.float32
     solve_mode = mca_param.get("potrf.trsm_hook", "solve") == "solve"
 
-    if names == ["POTRF"]:
-        (grp,) = wave
+    ks = {t[-1] for t in grp.tasks}
+    if kind not in ("UPDATE", "POTRF", "TRSM") or len(ks) != 1:
+        return None
+    k = ks.pop()
+    ms = sorted(t[0] for t in grp.tasks)
+    lo, hi = ms[0], ms[-1] + 1
+    if ms != list(range(lo, hi)) or lo != (k + 1 if kind == "TRSM" else k):
+        return None
+    rows, diag = geom.cols(k), geom.rows(k)   # row panel k, its diagonal tile
+    # the column runs of row panel k: one chip is a panel nobody sends
+    runs = PanelPartition("", 1).chunks(k, hi, nb * mb * 4, 0)
+
+    if kind == "UPDATE":
+
+        def do_update(st):
+            D = st[name]
+            with jax.named_scope("parsec:panel_update"):
+                st["_products"] = [
+                    mm(D[:k * nb, diag].T, D[:k * nb, t0 * mb:t1 * mb])
+                    for t0, t1 in runs]
+            return st
+
+        # the lowering's own account (PanelExecutor.lowering_report)
+        do_update.account = {
+            "update_runs": len(runs),
+            "update_ops": 2 * k * nb * nb * (hi - k) * mb}
+        return do_update
+
+    if kind == "POTRF":
         if len(grp.tasks) != 1:
             return None
-        (k,) = grp.tasks[0]
 
-        def do_potrf(st, k=k, last=(k == geom.nt - 1)):
-            from ..ops.tile_kernels import tri_inv_tile
-            D = st[geom.name]
-            c, r = geom.cols(k), geom.rows(k)
-            rowk = st.pop("_rowk", None)
-            diag = rowk[:, :nb] if rowk is not None else D[c, r]
+        def do_potrf(st):
+            D = st[name]
+            d = D[rows, diag].astype(f32)
+            if "_products" in st:     # no UPDATE wave precedes step 0
+                d = d - st["_products"][0][:, :nb]
             # symmetrize (identity for symmetric input; elementwise triu
             # masking here measurably breaks XLA's in-place scheduling —
             # the average form fuses cleanly)
-            diag = 0.5 * (diag + diag.T)
-            L = tile_chol(diag)
+            L = tile_chol(0.5 * (d + d.T))
+            if k == geom.nt - 1:
+                # no TRSM wave follows: this step's single write is ours
+                st.pop("_products", None)
+                st[name] = D.at[rows, diag].set(L.T.astype(D.dtype))
+                return st
+            # defer the write — the TRSM wave writes Lᵀ with its first
+            # run as ONE contiguous DUS; split writes double the panel's
+            # HBM liveness
+            st["_potrf_L"] = L
             if not solve_mode:
                 # chol-then-invert, NOT ops.chol_inv_tile: measured
                 # identical in-program runtime (105-107 TF/s both ways
@@ -758,66 +793,41 @@ def _potrf_left_wave_fuser(wave, geoms):
                 # the fused program deserializes 2-4x slower from the
                 # persistent cache
                 st["_potrf_inv"] = tri_inv_tile(L)
-            if last:
-                # no TRSM wave follows: this step's single write is ours
-                st[geom.name] = D.at[c, r].set(L.T)
-            else:
-                # defer the write — the TRSM wave writes the whole row
-                # panel (Lᵀ diag + solved rest) as ONE contiguous DUS;
-                # split writes double the panel's HBM liveness
-                st["_potrf_L"] = L
-                if rowk is not None:
-                    st["_rowk_rest"] = rowk[:, nb:]
             return st
 
         return do_potrf
 
-    if names == ["TRSM"]:
-        (grp,) = wave
-        ks = {t[1] for t in grp.tasks}
-        if len(ks) != 1:
-            return None
-        k = ks.pop()
-        ms = sorted(t[0] for t in grp.tasks)
-        if ms != list(range(ms[0], ms[0] + len(ms))):
-            return None
+    def do_trsm(st):
+        L = st.pop("_potrf_L")
+        inv = None if solve_mode else st.pop("_potrf_inv")
+        # the step's writes wait for its products: reads before in-place
+        # writes is the order XLA keeps without a copy of the state, and
+        # it looks for that order no further than a data dependence
+        D, products = lax.optimization_barrier(
+            (st[name], st.pop("_products", None)))     # None at k = 0
+        # the update's runs, less the diagonal tile at the first's head
+        for i, (t0, t1) in enumerate(runs):
+            skip = 0 if i else mb
+            c0, c1 = t0 * mb + skip, t1 * mb
+            out = [] if i else [L.T.astype(D.dtype)]
+            if c0 < c1:
+                rest = D[rows, c0:c1].astype(f32)
+                if products:
+                    rest = rest - products[i][:, skip:]
+                if solve_mode:
+                    # exact wide triangular solve (potrf.trsm_hook=solve):
+                    # no inversion, no condition-number squaring
+                    rest = jax.scipy.linalg.solve_triangular(
+                        L.astype(f32), rest, lower=True)
+                else:
+                    rest = mm(inv, rest)
+                out.append(rest.astype(D.dtype))
+            # one contiguous write a run, Lᵀ with the first
+            st[name] = D = D.at[rows, t0 * mb:c1].set(
+                jnp.concatenate(out, axis=1))
+        return st
 
-        def do_trsm(st, k=k, lo=ms[0], hi=ms[-1] + 1):
-            import jax
-            from ..ops.tile_kernels import tri_inv_tile
-            D = st[geom.name]
-            c = geom.cols(k)
-            L = st.pop("_potrf_L", None)
-            rest = st.pop("_rowk_rest", None)
-            if rest is None:     # k = 0: no UPDATE wave preceded
-                rest = D[c, lo * mb:hi * mb]
-            if solve_mode:
-                # exact wide triangular solve (potrf.trsm_hook=solve):
-                # no inversion, no condition-number squaring
-                if L is None:
-                    L = D[c, geom.rows(k)].T
-                st.pop("_potrf_inv", None)
-                solved = jax.scipy.linalg.solve_triangular(
-                    L.astype(jnp.float32), rest.astype(jnp.float32),
-                    lower=True)
-            else:
-                inv = st.pop("_potrf_inv", None)
-                if inv is None:  # robustness: recompute from the factor
-                    inv = tri_inv_tile(D[c, geom.rows(k)].T)
-                solved = mm(inv, rest)
-            if L is not None and lo == k + 1:
-                # one contiguous row-panel write: Lᵀ diag + solved rest
-                st[geom.name] = D.at[c, k * mb:hi * mb].set(
-                    jnp.concatenate([L.T, solved.astype(D.dtype)],
-                                    axis=1))
-            else:
-                st[geom.name] = D.at[c, lo * mb:hi * mb].set(
-                    solved.astype(D.dtype))
-            return st
-
-        return do_trsm
-
-    return None
+    return do_trsm
 
 
 def _potrf_left_mesh_wave_fuser(wave, geoms, part):

@@ -16,8 +16,8 @@ operations against the matrix stored as ONE ``(N, M)`` HBM array holding
 layout makes every panel write a leading-dimension contiguous
 dynamic-update-slice (in-place under jit), and panel reads are strided
 slices XLA fuses into the matmuls. Measured effect for tiled POTRF on a
-v5e chip: the left-looking fused form reaches ~98-110 TF/s where the
-per-tile executors topped out at ~45.
+v5e chip: the left-looking fused form reaches 138 TF/s (PERF.md §6,
+PR 40; ~107 before it) where the per-tile executors topped out at ~45.
 
 Slot bookkeeping comes from the SAME :class:`~.wavefront.WavefrontPlan` —
 planning, leveling, and hazard verification are unchanged; only the data
@@ -44,7 +44,9 @@ factored diagonal inverse consumed by the next wave). ``geom`` is always
 the ``{name: PanelGeometry}`` dict; single-collection fusers unpack
 their one entry. Return None to
 reject a wave (the executor then refuses, naming it — no silent
-fallback; a hybrid would reintroduce the copies this path avoids).
+fallback; a hybrid would reintroduce the copies this path avoids). A
+returned function may carry static counts of what it emitted as a dict
+``fn.account``; :meth:`PanelExecutor.lowering_report` sums them.
 
 Compile-once serving (the segmented panel path)
 -----------------------------------------------
@@ -138,8 +140,16 @@ class PanelGeometry:
 # half the one before it, down to a last of at most SEND_TAIL_BYTES. What
 # keeps the runs few is the program's text, which a chip holds in HBM
 # beside its shard: about 1.1 MiB a run and 0.26 MiB a send. A panel
-# nobody sends goes in runs of at most PANEL_CHUNK_BYTES, which only
-# keeps it from being one temporary.
+# nobody sends — every panel of the one-chip program, and on a mesh the
+# first chip's — goes in the fewest equal runs of at most
+# PANEL_CHUNK_BYTES, and not only to keep a step's product from being one
+# temporary. At N = 40960 on one v5e (PERF.md §6, PR 40) the one-chip
+# program's products run at 173 TF/s in runs of 96 MiB, at 171 as one
+# product a step and at 170 in runs of 48 MiB (84 runs against 54: more
+# text too), where the product fused with its subtraction over the whole
+# row panel ran at about 128. The runs need their barrier: without it
+# XLA:TPU puts a run's product behind the write of the run before it and
+# copies the state (6.25 GiB there: the compile fails).
 PANEL_CHUNK_BYTES = 96 << 20
 SEND_TAIL_BYTES = 28 << 20
 _NO_MESH_LOWERING = "taskpool registers no mesh_wave_fuser"
@@ -373,7 +383,10 @@ class PanelExecutor:
         geom_arg = self.geoms
         self.geom = geom_arg
         # lower every wave up front — planning errors surface at build
-        # time, not mid-trace
+        # time, not mid-trace. The run size is a module constant a
+        # lowering reads, which a function's fingerprint does not cover:
+        # the stored program's key carries what it was when we lowered
+        self._chunk_bytes = PANEL_CHUNK_BYTES
         self._wave_fns: List[Callable] = []
         for w, wave in enumerate(plan.waves):
             fn = fuser(wave, geom_arg)
@@ -452,7 +465,21 @@ class PanelExecutor:
         shapes = tuple(sorted(
             (name, tuple(s.shape), str(s.dtype))
             for name, s in self.state_shapes().items()))
-        return ("panel_monolith", f_fp, p_fp, shapes)
+        return ("panel_monolith", f_fp, p_fp, shapes, self._chunk_bytes)
+
+    def lowering_report(self) -> Dict[str, int]:
+        """The one-chip lowering's own account of what it emitted, summed
+        over the waves: static counts, no run needed, as
+        :meth:`partition_report` gives a mesh lowering's. A wave's
+        function carries its share as ``fn.account``
+        (``build_potrf_left``: ``update_runs``, the column runs its
+        UPDATE waves multiply in, and ``update_ops``, their
+        operations)."""
+        report: Dict[str, int] = {}
+        for fn in self._wave_fns:
+            for what, n in getattr(fn, "account", {}).items():
+                report[what] = report.get(what, 0) + n
+        return report
 
     # -- pure dense execution --------------------------------------------
     def run_state(self, state: Dict[str, Any]) -> Dict[str, Any]:

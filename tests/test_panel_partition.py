@@ -431,14 +431,22 @@ def test_the_two_partitions_live_under_different_keys(hook):
     assert _compile(_left(n), mesh, fn_key="two-keys")[0] is own
 
 
-def test_one_chip_key_of_the_left_looking_program_is_the_parents():
+def test_the_stored_keys_of_the_left_looking_programs():
     """``_potrf_left_wave_fuser``'s text keys the flagship's stored
-    program and every one-chip user's: pinned to what it was before the
-    mesh lowering landed beside it."""
+    program and every one-chip user's: pinned, so that a change to it is
+    a decision (PR 40 made one: the update's products alone, in column
+    runs; the key carries the run size too). The mesh lowering's text is
+    pinned to what PR 32 left: the four-chip cell's program is tuned and
+    measured as it is."""
+    from parsec_tpu.algorithms.potrf import _potrf_left_mesh_wave_fuser
     ok, fp = cc.function_fingerprint(_potrf_left_wave_fuser)
-    assert ok and fp == ("a370d3a4ffb88302ddb8867b8945361c"
-                         "fb3bd241d2fb64096b7a2f1638a14030")
+    assert ok and fp == ("9617ef079e6ecfa11042f18ef67d5efd"
+                         "9142959f00ead3ca4af6abbe8eea0eef")
+    ok, fp = cc.function_fingerprint(_potrf_left_mesh_wave_fuser)
+    assert ok and fp == ("0ea9bfa91342523f5b4b2cd73ea16137"
+                         "4d5cc01352c2f97026bd468a08c6e64d")
     key = PanelExecutor(plan_taskpool(build_potrf_left(
         TiledMatrix(256, 256, 64, 64, name="A")))).monolith_cache_key()
-    assert hashlib.sha256(repr(key).encode()).hexdigest() == (
-        "6ec4d4f09abc6923b3b1226cd62200d2be4f0e6d5d92db6dd0c6bd5655fe2499")
+    assert key[-1] == 3 * NB * NB * 4      # this file's run size
+    assert hashlib.sha256(repr(key[:-1]).encode()).hexdigest() == (
+        "69b9a35925faa508631c4cd6d518e65c24fb01380ef94880be7cb456d815173c")

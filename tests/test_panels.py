@@ -5,10 +5,12 @@ executor, write-set preservation, and rejection diagnostics."""
 import numpy as np
 import pytest
 
+import parsec_tpu.compiled.panels as panels
 from parsec_tpu.algorithms.potrf import build_potrf
 from parsec_tpu.compiled.panels import PanelExecutor, PanelGeometry
 from parsec_tpu.compiled.wavefront import WavefrontExecutor, plan_taskpool
 from parsec_tpu.data.matrix import TiledMatrix
+from parsec_tpu.utils import mca_param
 
 
 def _spd(n, seed=0):
@@ -92,17 +94,141 @@ def test_left_potrf_host_runtime_matches_lapack():
     assert err < 1e-4, err
 
 
-@pytest.mark.parametrize("n,nb", [(256, 64), (192, 64), (256, 128)])
-def test_left_potrf_panel_executor(n, nb):
+@pytest.fixture
+def run_tiles(request, monkeypatch):
+    """Tiles to a column run of a row panel (None: the constant as it
+    ships, one run a step at these sizes), set where the lowering reads
+    it: the module constant, not a knob."""
+    if request.param is not None:
+        nb = request.node.callspec.params["nb"]
+        monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES",
+                            request.param * nb * nb * 4)
+    return request.param
+
+
+@pytest.fixture
+def hook(request):
+    mca_param.set("potrf.trsm_hook", request.param)
+    yield request.param
+    mca_param.unset("potrf.trsm_hook")
+
+
+def _left_factor(A_host, nb):
     from parsec_tpu.algorithms.potrf import build_potrf_left
 
-    A_host = _spd(n)
     A = TiledMatrix.from_array(A_host.copy(), nb, nb, name="A")
     ex = PanelExecutor(plan_taskpool(build_potrf_left(A)))
     ex.run()
-    L = np.tril(A.to_array())
+    return ex, np.tril(A.to_array())
+
+
+@pytest.mark.parametrize("hook", ["solve", "gemm"], indirect=True)
+@pytest.mark.parametrize("run_tiles", [None, 2, 1], indirect=True)
+@pytest.mark.parametrize("n,nb", [(256, 64), (192, 64), (256, 128)])
+def test_left_potrf_panel_executor(n, nb, run_tiles, hook):
+    """The one-chip program's factor against LAPACK, its updates in one,
+    two and three column runs a step (n=256, nb=64: step 1 has three
+    tiles), both TRSM hooks."""
+    A_host = _spd(n)
+    ex, L = _left_factor(A_host, nb)
     err = np.linalg.norm(L @ L.T - A_host) / np.linalg.norm(A_host)
     assert err < 1e-4, err
+    ref = np.linalg.cholesky(A_host.astype(np.float64))
+    np.testing.assert_allclose(L, ref, rtol=1e-4, atol=1e-4)
+    nt = n // nb
+    per_step = [-(-(nt - k) // (run_tiles or nt)) for k in range(1, nt)]
+    assert ex.lowering_report()["update_runs"] == sum(per_step)
+    if (n, nb) == (256, 64):
+        assert max(per_step) == {None: 1, 2: 2, 1: 3}[run_tiles]
+
+
+def _left_fused_subtraction(A, nb, hook):
+    """The formulation before PR 40, in plain f32 numpy over Aᵀ: a step's
+    whole row panel less its product in one piece, then the diagonal
+    tile's factor and the solve from that piece."""
+    from scipy.linalg import solve_triangular
+    D = A.T.astype(np.float32).copy()
+    for r0 in range(0, len(D), nb):
+        r1 = r0 + nb
+        rowk = D[r0:r1, r0:] - D[:r0, r0:r1].T @ D[:r0, r0:]
+        d = rowk[:, :nb]
+        L = np.linalg.cholesky(0.5 * (d + d.T))
+        if hook == "gemm":
+            solved = solve_triangular(
+                L, np.eye(nb, dtype=np.float32), lower=True) @ rowk[:, nb:]
+        else:
+            solved = solve_triangular(L, rowk[:, nb:], lower=True)
+        D[r0:r1, r0:] = np.concatenate([L.T, solved], axis=1)
+    return np.triu(D).T
+
+
+@pytest.mark.parametrize("hook", ["gemm", "solve"], indirect=True)
+@pytest.mark.parametrize("run_tiles", [None, 1], indirect=True)
+@pytest.mark.parametrize("n,nb", [(256, 64)])
+def test_left_runs_equal_the_fused_subtraction(n, nb, run_tiles, hook):
+    """Where the subtraction happens moves one f32 rounding per element
+    and nothing else: the factor of the products-alone-in-runs lowering
+    equals the whole-row-panel formulation's to f32 rounding."""
+    A_host = _spd(n, seed=40)
+    _ex, L = _left_factor(A_host, nb)
+    want = _left_fused_subtraction(A_host, nb, hook)
+    assert np.abs(L - want).max() <= 2e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("n,nb,chunk_bytes,runs,ops", [
+    # the flagship as it ships: 39 steps, 15 of them (k < 16) in two runs
+    (40960, 1024, None, 54, 22892175687680),
+    (512, 64, 3 * 64 * 64 * 4, 3 + 2 + 2 + 2 + 1 + 1 + 1, None),
+    (512, 64, 64 * 64 * 4, sum(range(1, 8)), None)])
+def test_left_lowering_report_is_the_run_rule(monkeypatch, n, nb,
+                                              chunk_bytes, runs, ops):
+    """``update_runs`` / ``update_ops``: the lowering's static account of
+    its UPDATE waves (no run needed), equal to what the run rule gives —
+    the fewest equal runs of whole tiles within ``PANEL_CHUNK_BYTES`` —
+    and to the algorithm's count of the updates' operations."""
+    from parsec_tpu.algorithms.potrf import build_potrf_left
+
+    if chunk_bytes:
+        monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES", chunk_bytes)
+    ex = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+    nt = n // nb
+    rep = ex.lowering_report()
+    assert rep["update_runs"] == runs
+    assert rep["update_ops"] == sum(
+        2 * (k * nb) * nb * (nt - k) * nb for k in range(1, nt))
+    if ops:
+        assert rep["update_ops"] == ops
+    # the size of a run is part of the stored program's key
+    monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES", 7 * nb * nb * 4)
+    other = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+    assert other.monolith_cache_key() != ex.monolith_cache_key()
+
+
+def test_left_update_wave_writes_nothing(monkeypatch):
+    """An UPDATE wave multiplies and carries: it returns the state array
+    it was given (the step's one write a run is the TRSM wave's) and a
+    product a column run, under the scope the trace reads."""
+    import jax
+    import jax.numpy as jnp
+    from parsec_tpu.algorithms.potrf import build_potrf_left
+
+    n, nb = 256, 64
+    monkeypatch.setattr(panels, "PANEL_CHUNK_BYTES", 2 * nb * nb * 4)
+    ex = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+    kinds = [w[0].tc.name for w in ex.plan.waves]
+    update = ex._wave_fns[kinds.index("UPDATE")]      # step 1: tiles 1..3
+    D = jnp.asarray(_spd(n))
+    out = update({"A": D})
+    assert out["A"] is D
+    assert [p.shape for p in out["_products"]] == [(nb, nb), (nb, 2 * nb)]
+    assert update.account == {"update_runs": 2,
+                              "update_ops": 2 * nb * nb * 3 * nb}
+    text = jax.jit(ex.run_state).lower(ex.state_shapes()).as_text(
+        debug_info=True)
+    assert "parsec:panel_update" in text
 
 
 def test_left_matches_right_fused():
