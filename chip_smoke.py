@@ -28,6 +28,13 @@ points a user calls, at full width, cheapest phase first:
             runs it at the cell's size): per IB the time of a second step
             and the readings of the factored form against the plain
             reference, pivots on the chip, every task on the tpu0 module
+  lu_panel  TSTRF's block factorization alone, on no cell's path: the VMEM
+            panel kernel (ops/tile_kernels.py _lu_panel) and XLA's
+            lax.linalg.lu, each jitted by itself, on float32 stacks of
+            256, 640, 1152, 2176 rows x 128 columns and XLA's on 2176 x
+            32 and 64: device microseconds a call and a pivot step from
+            a trace, the same interchanges from both, and the gate the
+            kernel was sent under (under 0.75 x XLA's time at 2176 x 128)
   panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
   flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
             NB=1024, potrf.trsm_hook=gemm, input generated on device, three
@@ -76,6 +83,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
             qr_host=dict(n=8192, nb=2048),
             lu_host=dict(n=8192, nb=2048, ibs=(128, 256, 512)),
+            lu_panel=dict(rows=(256, 640, 1152, 2176), widths=(32, 64)),
             panels=(8192, 1024), flagship=(40960, 1024),
             flash=dict(S=16384, H=4, dh=128, F=2048),
             block=dict(H=2, T=2, TS=1024, DH=128, F=512),
@@ -83,6 +91,7 @@ FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
 DRY = dict(dtd=(256, 64), potrf_host=(256, 64),
            qr_host=dict(n=128, nb=32, ib=16),
            lu_host=dict(n=128, nb=32, ibs=(16,)),
+           lu_panel=dict(rows=(256,), widths=()),
            panels=(256, 64), flagship=(256, 64),
            flash=dict(S=256, H=2, dh=16, F=64),
            block=dict(H=2, T=2, TS=64, DH=16, F=64),
@@ -558,6 +567,109 @@ def phase_lu_host(sz, on_chip):
             gc.collect()
 
 
+def _device_us(calls, reps=10):
+    """Median device microseconds of one run of each jitted call in
+    ``calls`` (``{name: (jitted, argument)}``, the function's name the
+    key), from a trace: a program's run is one event, ``jit_<name>``, on
+    the chip's ``XLA Modules`` line. ``{}`` where the trace has no
+    device plane (a CPU dry run)."""
+    import glob
+    import re
+    import shutil
+    import statistics
+    import tempfile
+    import jax
+    from jax.profiler import ProfileData
+    trace_dir = tempfile.mkdtemp(prefix="chip_smoke_trace_")
+    try:
+        jax.profiler.start_trace(trace_dir)
+        for fn, arg in calls.values():
+            for _ in range(reps):
+                out = fn(arg)
+            jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                      "*.xplane.pb"))[0]
+        found = {}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/device:TPU:0"):
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Modules":
+                    continue
+                for e in line.events:
+                    m = re.match(r"jit_(\w+)", e.name)
+                    if m and m.group(1) in calls:
+                        found.setdefault(m.group(1), []).append(
+                            e.duration_ns * 1e-3)
+        return {name: statistics.median(us) for name, us in found.items()}
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+
+
+def phase_lu_panel(sz, on_chip):
+    """A TSTRF block's factorization by itself: the VMEM panel kernel
+    against XLA's LU on stacks [upper-triangular; dense], the sweep that
+    split XLA's time a pivot step into a fixed and a per-row part and
+    the gate the kernel was sent under (PERF.md section 6, PR 41). Kept
+    for the next jax upgrade; no cell runs this phase's programs."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from parsec_tpu.ops.tile_kernels import _lu_panel, _lu_panel_takes
+
+    def xla_lu(stack):
+        lu, piv, _ = jax.lax.linalg.lu(stack)
+        return lu, piv
+
+    def jitted(fn, name):
+        def call(stack):
+            return fn(stack)
+        call.__name__ = name
+        return jax.jit(call)
+
+    rng = np.random.default_rng(20261004)
+    rows, full = sz["lu_panel"]["rows"], 128
+    shapes = [(r, full) for r in rows] + \
+        [(max(rows), w) for w in sz["lu_panel"]["widths"]]
+    calls = {}
+    for r, w in shapes:
+        stack = jnp.asarray(np.concatenate(
+            [np.triu(rng.uniform(-0.5, 0.5, (w, w))),
+             rng.uniform(-0.5, 0.5, (r - w, w))]).astype(np.float32))
+        calls[f"lu_xla_{r}x{w}"] = (jitted(xla_lu, f"lu_xla_{r}x{w}"), stack)
+        if _lu_panel_takes(r, w, stack.dtype):
+            calls[f"lu_panel_{r}x{w}"] = (
+                jitted(_lu_panel, f"lu_panel_{r}x{w}"), stack)
+            lu, piv = calls[f"lu_panel_{r}x{w}"][0](stack)
+            want, want_piv = calls[f"lu_xla_{r}x{w}"][0](stack)
+            require(bool((piv == want_piv).all()),
+                    f"{r}x{w}: the panel's interchanges are not XLA's")
+            err = float(jnp.abs(lu - want).max() / jnp.abs(want).max())
+            require(err <= 1e-5, f"{r}x{w}: factors {err:.1e} from XLA's")
+            require(float(jnp.abs(lu[w:]).max()) <= 1 + 1e-6,
+                    f"{r}x{w}: a multiplier over 1")
+    for fn, stack in calls.values():
+        jax.block_until_ready(fn(stack))
+    us = _device_us(calls)
+
+    def shown(value, digits):
+        return f"{value:.{digits}f}" if value else "not_measured"
+
+    for r, w in shapes:
+        xla, panel = (us.get(f"lu_{k}_{r}x{w}") for k in ("xla", "panel"))
+        say("lu_panel", stack=f"{r}x{w}", xla_us=shown(xla, 1),
+            xla_us_per_pivot_step=shown(xla and xla / w, 3),
+            panel_us=shown(panel, 1) if f"lu_panel_{r}x{w}" in calls
+            else "none",
+            panel_over_xla=shown(xla and panel and panel / xla, 3))
+    if on_chip:
+        r = max(rows)
+        ratio = us[f"lu_panel_{r}x{full}"] / us[f"lu_xla_{r}x{full}"]
+        require(ratio < 0.75, f"the panel takes {ratio:.2f} of XLA's time "
+                f"at {r}x{full}: the gate it was sent under is 0.75")
+
+
 def _panel_run(ex, state):
     import jax
     t0 = time.perf_counter()
@@ -781,7 +893,7 @@ def phase_sharded(sz, on_chip):
 ONE_CHIP = [("store", phase_store), ("flash", phase_flash),
             ("block", phase_block), ("host", phase_host),
             ("qr_host", phase_qr_host), ("lu_host", phase_lu_host),
-            ("panels", phase_panels),
+            ("lu_panel", phase_lu_panel), ("panels", phase_panels),
             ("flagship", phase_flagship)]
 MULTI_CHIP = [("ring", phase_ring), ("ici", phase_ici),
               ("sharded", phase_sharded)]

@@ -237,6 +237,16 @@ class TPUDevice(Device):
         debug_verbose(3, "device", "TPU device on %s (%s)",
                       self.jax_device, self.platform)
 
+    def dump_statistics(self) -> Dict:
+        """The module's counters and, beside them, which factorization
+        TSTRF's blocks were traced through (``ops.tile_kernels``: the
+        VMEM panel or XLA's LU; the process's traces, not this chip's
+        launches)."""
+        from ..ops.tile_kernels import LU_BLOCKS_TRACED
+        return dict(super().dump_statistics(),
+                    lu_blocks_vmem_panel=LU_BLOCKS_TRACED["vmem_panel"],
+                    lu_blocks_xla_lu=LU_BLOCKS_TRACED["xla_lu"])
+
     def execute(self, es, task: Task, chore: Chore) -> HookReturn:
         """``task`` alone: a launch of one from the body's table, or the
         chore's own hook where the table has no program for it."""

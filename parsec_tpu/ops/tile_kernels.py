@@ -9,6 +9,8 @@ in f32 even for bf16 tiles.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -697,10 +699,18 @@ def tsmqr_tile(V2, T, C1, C2):
 # PLASMA's four core_blas kernels (zgetrf_incpiv, zgessm, ztstrf, zssssm)
 # over a tile pair: pivots inside the diagonal tile, then pairwise
 # between the diagonal tile's U and each tile under it, ``ib`` columns at
-# a time. The pivot search, the scaling by the pivot and a panel block's
-# factorization are XLA's partial-pivoting LU at full float32, and so is
-# what is applied to the columns to the right (L11^-1 and the product
-# with the multipliers), WHATEVER ``ops.matmul_precision`` says: a pivoted
+# a time. A block's factorization (the pivot search, the scaling by the
+# pivot, the rank-1 updates) is partial pivoting at full float32: in
+# TSTRF, where the stack [the block's ib rows of U; the lower tile's
+# column block] is float32 with ib a multiple of 128 (the cell's 2176 x
+# 128), a panel kernel of this module that keeps the stack in VMEM from
+# the first pivot step to the last (``_lu_panel``, below); at any other
+# shape, and for GETRF's whole tile, XLA's ``lax.linalg.lu``. Both give
+# the same factors and the same interchanges (the lowest index among
+# equal magnitudes); which one a block was traced through is counted in
+# ``LU_BLOCKS_TRACED``. What is applied to the columns to the right
+# (L11^-1 and the product with the multipliers) is full float32 too,
+# WHATEVER ``ops.matmul_precision`` says: a pivoted
 # LU's entries grow (about n^(2/3) under partial pivoting, more under
 # pairwise pivoting), every rounding of an update is carried through the
 # rest of the elimination, and a bf16 pass's 2^-9 times that growth leaves
@@ -790,6 +800,179 @@ def _unit_lower_inverse(L):
         unit_diagonal=True)
 
 
+# ---- a block's stack factored in VMEM -----------------------------------
+# XLA's ``LuDecompositionBlock`` takes 1.66 us a pivot step on a 2176 x
+# 128 stack (0.22 us and 0.66 ns a row: PERF.md section 6, PR 41). The
+# kernel below takes 0.57: it holds the stack TRANSPOSED, ``t[c, k, i]`` =
+# entry (128 c + i, k), so that a column of the stack is a row over the
+# lanes (a search is a few VPU operations and two rounds of reductions
+# across the lanes, 80 ns each on a v5e whatever they reduce), a step's
+# multipliers are broadcast over the sublanes for nothing, and the pivot
+# row's entries, one to a column, are one lane of 128-column blocks: a
+# lane gather each. No row moves while the steps run: a row keeps its
+# place and ``pos`` says which position of LAPACK's interchanged stack
+# it holds; the interchanges are applied once, to the factored stack in
+# its natural layout, where a row is a sublane and an exchange two loads
+# and two stores.
+
+_LANES = 128
+# position and row of a candidate in one number (the lowest position
+# among equal magnitudes is one reduction): 12 bits each, so that a
+# float32 holds it exactly (a v5e reduces floats across lanes in half
+# the time it takes for integers) and has its sign bit left for the
+# entry's
+_PACK_BITS = 12
+_PACK = 1 << _PACK_BITS
+# rows x columns x 4 bytes the panel takes: input, output and the
+# transposed copy are in VMEM at once (a v5e core has 128 MiB)
+_PANEL_BYTES = 8 << 20
+
+# how many blocks ``tstrf_tile`` was TRACED with through each
+# factorization (the choice is made from the stack's shape at trace
+# time, so these count traces, not launches)
+LU_BLOCKS_TRACED = {"vmem_panel": 0, "xla_lu": 0}
+
+
+def _tree(op, items):
+    """``op`` over ``items`` pairwise: a chain log2(n) deep, not n."""
+    items = list(items)
+    while len(items) > 1:
+        items = [op(*items[i:i + 2]) if i + 1 < len(items) else items[i]
+                 for i in range(0, len(items), 2)]
+    return items[0]
+
+
+def _pallas():
+    """Pallas and its TPU side, at first use: an import of a second on
+    the chip's host that no other kernel of this module pays for."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl, pltpu
+
+
+def _lu_panel_kernel(x_ref, lu_ref, piv_ref, t_ref, u_ref, pos_ref):
+    pl, _ = _pallas()
+    nblk, w, _ = t_ref.shape
+    for c in range(nblk):
+        t_ref[c] = x_ref[c * _LANES:(c + 1) * _LANES, :].T
+    blk = jax.lax.broadcasted_iota(_I32, (nblk, 1, _LANES), 0)
+    row = blk * _LANES + jax.lax.broadcasted_iota(
+        _I32, (nblk, 1, _LANES), 2)
+    col = jax.lax.broadcasted_iota(_I32, (w, _LANES), 0)
+    pos_ref[...] = jnp.broadcast_to(row, pos_ref.shape)
+
+    def over(op, reduce, v):
+        """(nblk, 1, LANES) -> (1, 1, 1): across the blocks on the VPU,
+        then ONE reduction across the lanes."""
+        return reduce(_tree(op, [v[c] for c in range(nblk)]), axis=1,
+                      keepdims=True)[None]
+
+    def step(j, carry):
+        pos = pos_ref[:, 0:1, :]
+        c = t_ref[:, pl.ds(j, 1), :]             # column j of the stack
+        active = pos >= j                        # not yet a pivot row
+        size = jnp.where(active, jnp.abs(c), -1.0)
+        top = over(jnp.maximum, jnp.max, size)
+        # the first of the largest: position and row in one float32, the
+        # greater the earlier the position, the entry's sign on it; a
+        # maximum and a minimum across the lanes (issued together) say
+        # which row it is and give the pivot its sign
+        rank = float(_PACK * _PACK) - (pos * _PACK + row).astype(_F32)
+        mark = jnp.where(size == top, jnp.where(c < 0, -rank, rank), 0.0)
+        plus = over(jnp.maximum, jnp.max, mark)
+        minus = -over(jnp.minimum, jnp.min, mark)
+        pivot = jnp.where(minus > plus, -top, top)
+        first = (float(_PACK * _PACK) - jnp.maximum(plus, minus)).astype(_I32)
+        q, p = first >> _PACK_BITS, first & (_PACK - 1)   # position, row
+        is_p = row == p
+        low = active & ~is_p
+        # a column of zeros keeps its zeros: no 0/0
+        mult = jnp.where(low, c / jnp.where(pivot == 0, 1.0, pivot), 0.0)
+        t_ref[:, pl.ds(j, 1), :] = jnp.where(low, mult, c)
+        # the row at position j goes where the pivot row was
+        pos_ref[:, 0:1, :] = jnp.where(is_p, j, jnp.where(pos == j, q, pos))
+        piv_ref[0, j] = q[0, 0, 0]
+        at = p[0, 0, 0]
+        # the pivot row, an entry a column, every lane the same: the
+        # rank-1 update's other factor
+        u_ref[...] = jnp.where(col > j, jnp.take_along_axis(
+            t_ref[at // _LANES], jnp.full((w, _LANES), at % _LANES, _I32),
+            axis=1), 0.0)
+        mults = jnp.broadcast_to(mult, (nblk, 8, _LANES))
+
+        def update(r, carry):                    # columns 8r .. 8r + 7
+            k = pl.ds(pl.multiple_of(r * 8, 8), 8)
+            t_ref[:, k, :] = t_ref[:, k, :] - u_ref[k, :][None] * mults
+            return carry
+
+        return jax.lax.fori_loop(j // 8, w // 8, update, carry)
+
+    jax.lax.fori_loop(0, w, step, 0)
+    for c in range(nblk):
+        lu_ref[c * _LANES:(c + 1) * _LANES, :] = t_ref[c].T
+
+    def interchange(j, carry):
+        q = piv_ref[0, j]
+        top, low = lu_ref[pl.ds(j, 1), :], lu_ref[pl.ds(q, 1), :]
+        lu_ref[pl.ds(j, 1), :] = low
+        lu_ref[pl.ds(q, 1), :] = top
+        return carry
+
+    jax.lax.fori_loop(0, w, interchange, 0)
+
+
+def _lu_panel_takes(rows: int, w: int, dtype) -> bool:
+    """The shapes the VMEM panel is built for, read off the stack."""
+    return (dtype == _F32 and w % _LANES == 0 and rows % _LANES == 0
+            and rows <= _PACK and rows * w * 4 <= _PANEL_BYTES)
+
+
+def _lu_panel(stack):
+    """``lax.linalg.lu``'s first two results for a float32 stack the
+    panel takes: the factored stack (U over the unit lower L) and
+    LAPACK's interchange indices, 0-based int32: at step j rows j and
+    ``piv[j]`` were exchanged, ``piv[j]`` the lowest index among the
+    entries of largest magnitude in column j from row j on. Interpreted
+    iff the run was started on the CPU platform
+    (``ops/flash_attention.py``'s rule)."""
+    from ..utils.jax_platform import cpu_requested
+    return _lu_panel_call(stack, cpu_requested())
+
+
+# jitted, so that a TSTRF's sixteen blocks are one kernel traced and
+# lowered once (0.1 s each on the chip's host otherwise)
+@functools.partial(jax.jit, static_argnums=1)
+def _lu_panel_call(stack, interpret: bool):
+    pl, pltpu = _pallas()
+    rows, w = stack.shape
+    nblk = rows // _LANES
+    lu, piv = pl.pallas_call(
+        _lu_panel_kernel,
+        out_shape=(jax.ShapeDtypeStruct((rows, w), _F32),
+                   jax.ShapeDtypeStruct((1, w), _I32)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)),
+        scratch_shapes=[pltpu.VMEM((nblk, w, _LANES), _F32),
+                        pltpu.VMEM((w, _LANES), _F32),
+                        pltpu.VMEM((nblk, 8, _LANES), _I32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=3 * rows * w * 4 + (8 << 20)),
+        interpret=interpret, name="parsec_lu_panel")(stack)
+    return lu, piv[0]
+
+
+def _block_lu(stack):
+    """A block's stack factored: the VMEM panel where it takes the
+    shape, XLA's LU elsewhere; (factors, interchanges)."""
+    if _lu_panel_takes(*stack.shape, stack.dtype):
+        LU_BLOCKS_TRACED["vmem_panel"] += 1
+        return _lu_panel(stack)
+    LU_BLOCKS_TRACED["xla_lu"] += 1
+    lu, piv, _ = jax.lax.linalg.lu(stack)
+    return lu, piv
+
+
 def tstrf_tile(U, A, ib: int):
     """TSTRF: the partial-pivoting LU of the stack [U; A], U the upper
     triangle of ``U``, ``ib`` columns at a time -> (U'; the multipliers
@@ -804,7 +987,7 @@ def tstrf_tile(U, A, ib: int):
     for o in range(0, nb, ib):
         J = slice(o, o + ib)
         with jax.named_scope("parsec:lu_pivot"):
-            lu, piv, _ = jax.lax.linalg.lu(
+            lu, piv = _block_lu(
                 jnp.concatenate([Uf[J, J], Af[:, J]], axis=0))
             L11 = jnp.tril(lu[:ib], -1) + jnp.eye(ib, dtype=_F32)
             W = _unit_lower_inverse(L11)
@@ -828,6 +1011,12 @@ def tstrf_tile(U, A, ib: int):
             jnp.concatenate(Ws, axis=1).astype(A.dtype))
 
 
+# jitted, so that the members of a launch of four, and a process's launch
+# sizes, are one function traced and lowered once: a second of a
+# process's set-up on the chip's host, which is what Pallas's import
+# costs TSTRF's program there (PERF.md section 6, PR 41); XLA inlines
+# the calls, a lone SSSSM's program is the parent's to the instruction
+@jax.jit
 def ssssm_tile(A1, A2, W, L21, P):
     """SSSSM: [A1; A2] <- the pair's transformation applied, W (the
     blocks' L11⁻¹), L21 and P as :func:`tstrf_tile` left them: block by

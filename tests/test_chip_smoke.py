@@ -39,7 +39,7 @@ def test_cpu_dry_run_passes_every_phase(tmp_path):
         "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     assert "platform=cpu" in lines[0]
     verdicts = [ln for ln in lines if "verdict=" in ln]
-    assert len(verdicts) == 8 and all("verdict=PASS" in v for v in verdicts)
+    assert len(verdicts) == 9 and all("verdict=PASS" in v for v in verdicts)
     assert "kernel=interpreted" in proc.stdout
     assert elapsed < 60, elapsed
     # the cache went where it was placed from outside, nowhere else

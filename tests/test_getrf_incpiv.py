@@ -18,7 +18,8 @@ from parsec_tpu.algorithms.getrf import (build_getrf, build_getrf_incpiv,
 from parsec_tpu.core.reshape import UPPER_TILE
 from parsec_tpu.data import TiledMatrix
 from parsec_tpu.dsl import ptg
-from parsec_tpu.ops.tile_kernels import (_pair_swap, gessm_tile,
+from parsec_tpu.ops import tile_kernels
+from parsec_tpu.ops.tile_kernels import (_lu_panel, _pair_swap, gessm_tile,
                                          getrf_incpiv_tile, ssssm_tile,
                                          tstrf_tile)
 
@@ -28,6 +29,9 @@ if ROOT not in sys.path:
 
 NB = 16
 IBS = (4, 8, 16)            # every ib that divides nb, among three
+# (nb, ib) of a pair's kernels: the small ones through XLA's LU, ib = 128
+# through the VMEM panel (interpreted here)
+PAIRS = [(NB, ib) for ib in IBS] + [(128, 128), (256, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -124,37 +128,172 @@ def test_getrf_and_gessm_against_their_equations():
         got, np.linalg.solve(low.astype(np.float64), c[perm[0]]), atol=2e-5)
 
 
-@pytest.mark.parametrize("ib", IBS)
-def test_tstrf_and_ssssm_against_the_plain_reference(ref, ib):
+@pytest.mark.parametrize("nb,ib", PAIRS)
+def test_tstrf_and_ssssm_against_the_plain_reference(ref, nb, ib):
     """The pair's kernels against the reference's reading of what they
     store: the stored transformation takes the stack [U; A] to [U'; 0]
     and the pair [A1; A2] to what SSSSM returns; U' and the multipliers
     against the plain loops' own."""
     import jax.numpy as jnp
-    u = np.triu(_seeded((NB, NB), 3))
-    a, a1, a2 = (_seeded((NB, NB), s) for s in (4, 5, 6))
+    u = np.triu(_seeded((nb, nb), 3))
+    a, a1, a2 = (_seeded((nb, nb), s) for s in (4, 5, 6))
     u2, l21, low, piv, w = tstrf_tile(u, a, ib)
-    assert low.shape == (ib, NB) and piv.shape == (1, NB)
+    assert low.shape == (ib, nb) and piv.shape == (1, nb)
     assert piv.dtype == jnp.int32
     assert bool(ref.interchanges_valid(piv, ib))
     assert float(ref.multipliers_pair(l21, low)) <= 1.0
     np.testing.assert_array_equal(np.tril(np.asarray(u2), -1), 0)
+    # float32's rounding of what an elimination of nb columns carries
+    atol = 5e-6 * nb / NB
     stack = jnp.asarray(np.concatenate([u, a], axis=0))
     out = np.asarray(ref.apply_pair(0, 1, l21, low, piv, stack,
                                     inverse=False))
-    np.testing.assert_allclose(out[:NB], np.asarray(u2), atol=5e-6)
-    np.testing.assert_allclose(out[NB:], 0, atol=5e-6)
+    np.testing.assert_allclose(out[:nb], np.asarray(u2), atol=atol)
+    np.testing.assert_allclose(out[nb:], 0, atol=atol)
     back = ref.apply_pair(0, 1, l21, low, piv, jnp.asarray(out),
                           inverse=True)
-    np.testing.assert_allclose(np.asarray(back), stack, atol=5e-6)
+    np.testing.assert_allclose(np.asarray(back), stack, atol=atol)
     # the plain loops on the same stack: a 2 x 1 grid's column, by hand
     wide = np.concatenate([np.concatenate([u, a1], 1),
                            np.concatenate([a, a2], 1)], 0)
     pair = np.asarray(ref.apply_pair(
-        0, 1, l21, low, piv, jnp.asarray(wide[:, NB:]), inverse=False))
+        0, 1, l21, low, piv, jnp.asarray(wide[:, nb:]), inverse=False))
     c1, c2 = ssssm_tile(a1, a2, w, l21, piv)
-    np.testing.assert_allclose(np.asarray(c1), pair[:NB], atol=5e-6)
-    np.testing.assert_allclose(np.asarray(c2), pair[NB:], atol=5e-6)
+    np.testing.assert_allclose(np.asarray(c1), pair[:nb], atol=atol)
+    np.testing.assert_allclose(np.asarray(c2), pair[nb:], atol=atol)
+
+
+# -- a block's stack factored in VMEM (interpreted here) ------
+
+def _stack(nb, seed, ib=128):
+    """A TSTRF block's stack: [upper-triangular ib x ib; nb x ib]."""
+    return np.concatenate([np.triu(_seeded((ib, ib), seed)),
+                           _seeded((nb, ib), seed + 100)], axis=0)
+
+
+def _xla_lu(stack):
+    import jax
+    lu, piv, _ = jax.lax.linalg.lu(stack)
+    return np.asarray(lu), np.asarray(piv)
+
+
+@pytest.mark.parametrize("seed", [21, 22, 23])
+@pytest.mark.parametrize("nb", [128, 256])
+def test_the_vmem_panel_against_xlas_lu(nb, seed):
+    """The same interchanges, no multiplier over 1, and factors as close
+    to the float64 elimination with those interchanges as XLA's are (a
+    different order of the same float32 operations: on the CPU, LAPACK's
+    blocked one; 128 steps leave both some 30 ulp from it)."""
+    stack = _stack(nb, seed)
+    lu, piv = (np.asarray(x) for x in _lu_panel(stack))
+    xla, xla_piv = _xla_lu(stack)
+    assert piv.dtype == np.int32 and piv.shape == (128,)
+    np.testing.assert_array_equal(piv, xla_piv)
+    assert np.abs(np.tril(lu, -1)).max() <= 1.0
+    exact = stack.astype(np.float64)
+    for j, p in enumerate(piv):
+        exact[[j, p]] = exact[[p, j]]
+        exact[j + 1:, j] /= exact[j, j]
+        exact[j + 1:, j + 1:] -= np.outer(exact[j + 1:, j],
+                                          exact[j, j + 1:])
+    scale = np.maximum(np.abs(exact).max(axis=1, keepdims=True), 1.0)
+    ulp = np.finfo(np.float32).eps
+    off, xla_off = ((np.abs(x - exact) / scale).max() for x in (lu, xla))
+    assert off <= max(2 * xla_off, 8 * ulp) and off <= 64 * ulp
+
+
+def test_the_vmem_panel_takes_row_j_where_nothing_under_it_is_larger():
+    """A column that is all zero under U's diagonal gives index j (no
+    interchange): with a diagonal entry, and with a zero there too (a
+    column of zeros keeps its zeros and makes no NaN)."""
+    stack = _stack(128, 24)
+    # nothing over U's diagonal either, so that no update fills the column
+    stack[:5, 5] = stack[128:, 5] = 0.0       # U(5,5) alone
+    stack[:10, 9] = stack[128:, 9] = 0.0      # nothing at all
+    lu, piv = (np.asarray(x) for x in _lu_panel(stack))
+    want, want_piv = _xla_lu(stack)
+    assert piv[5] == 5
+    assert np.isfinite(lu).all()
+    # up to the column of zeros XLA's are the same factors
+    np.testing.assert_array_equal(piv[:9], want_piv[:9])
+    np.testing.assert_allclose(lu[:, :9], want[:, :9], atol=1e-5)
+    assert piv[9] == 9 and not lu[10:, 9].any()
+
+
+def test_the_vmem_panel_takes_the_lowest_index_among_equals():
+    """Equal magnitudes, of either sign, in a column: the first of them
+    in the stack as the interchanges before it left it, which is not the
+    order the rows came in."""
+    stack = _stack(128, 25)
+    stack[:, 0] = 0.0
+    stack[[130, 140, 150], 0] = [0.75, -0.75, 0.75]     # step 0: row 130
+    lu, piv = (np.asarray(x) for x in _lu_panel(stack))
+    want, want_piv = _xla_lu(stack)
+    assert piv[0] == 130
+    np.testing.assert_array_equal(piv, want_piv)
+    # a tie at step 1 between row 0, which step 0 moved down to position
+    # 130, and row 128: the lowest POSITION wins, not the lowest row
+    stack = _stack(128, 26)
+    stack[:, 0] = 0.0
+    stack[130, 0] = 0.75
+    stack[1:, 1] = 0.0
+    stack[[0, 128], 1] = 0.5
+    lu, piv = (np.asarray(x) for x in _lu_panel(stack))
+    want, want_piv = _xla_lu(stack)
+    assert list(piv[:2]) == [130, 128]
+    np.testing.assert_array_equal(piv, want_piv)
+
+
+def test_the_shape_says_which_factorization_a_block_is_traced_through(
+        make_ctx):
+    """ib = 16 (these tests', the dry runs'): XLA's LU; float32 with ib a
+    multiple of 128: the VMEM panel; a block a count, where the chip
+    module's counters are."""
+    import jax
+    import jax.numpy as jnp
+    traced = tile_kernels.LU_BLOCKS_TRACED
+
+    def trace(nb, ib, dtype=jnp.float32):
+        before = dict(traced)
+        t = jax.ShapeDtypeStruct((nb, nb), dtype)
+        jax.eval_shape(lambda u, a: tstrf_tile(u, a, ib), t, t)
+        return {k: traced[k] - before[k] for k in traced}
+
+    assert trace(64, 16) == {"vmem_panel": 0, "xla_lu": 4}
+    assert trace(256, 128) == {"vmem_panel": 2, "xla_lu": 0}
+    assert trace(256, 256) == {"vmem_panel": 1, "xla_lu": 0}
+    assert trace(128, 64) == {"vmem_panel": 0, "xla_lu": 2}
+    assert tile_kernels._lu_panel_takes(2176, 128, jnp.float32)
+    assert not tile_kernels._lu_panel_takes(2176, 128, jnp.bfloat16)
+    assert not tile_kernels._lu_panel_takes(4096 + 128, 128, jnp.float32)
+    assert not tile_kernels._lu_panel_takes(4096, 1024, jnp.float32)
+    ctx = make_ctx("tpu", nb_cores=1)
+    stats = _chip(ctx).dump_statistics()
+    assert stats["lu_blocks_vmem_panel"] == traced["vmem_panel"] >= 3
+    assert stats["lu_blocks_xla_lu"] == traced["xla_lu"] >= 6
+    assert any("lu_blocks_vmem_panel" in d
+               for d in ctx.statusz()["devices"])
+
+
+def test_a_vmapped_tstrf_traces_and_factors_each_pair():
+    """The body's ``batch_hook`` is ``jax.vmap(tstrf)``: the panel under
+    it, a stack a member."""
+    import jax
+    import jax.numpy as jnp
+    nb = ib = 128
+    us = jnp.asarray(np.stack([np.triu(_seeded((nb, nb), s))
+                               for s in (31, 32)]))
+    As = jnp.asarray(np.stack([_seeded((nb, nb), s) for s in (33, 34)]))
+
+    def tstrf(u, a):
+        return tstrf_tile(u, a, ib)
+
+    assert "pallas_call" in str(jax.make_jaxpr(jax.vmap(tstrf))(us, As))
+    got = jax.vmap(tstrf)(us, As)
+    for i in range(2):
+        for x, y in zip(got, tstrf(us[i], As[i])):
+            np.testing.assert_allclose(np.asarray(x[i]), np.asarray(y),
+                                       atol=1e-5)
 
 
 def test_a_blocks_interchanges_as_moves_against_one_at_a_time():

@@ -391,6 +391,32 @@ def test_on_the_chip_the_shard_stays_put_and_a_send_runs_under_a_product(
         assert mem.generated_code_size_in_bytes < text_bytes
 
 
+@pytest.mark.parametrize("rows,w", [(2176, 128), (2560, 512)])
+def test_on_the_chip_a_tstrf_blocks_stack_is_factored_by_one_mosaic_call(
+        v5e_2x2, monkeypatch, rows, w):
+    """``ops/tile_kernels.py _lu_panel`` at the LU cell's shape (a 2048
+    tile under a block of IB = 128) and at the widest block of
+    ``chip_smoke.py``'s IB sweep, compiled by the TPU compiler (this file
+    holds the described chip, so the kernel's compile is kept here):
+    Mosaic takes the transposes, the reductions across lanes, the lane
+    gather and the dynamic slices as written, the stack, its output and
+    the transposed copy fit the VMEM the call asks for, and the program
+    is that one call: no ``LuDecompositionBlock``, no HBM temporary."""
+    from jax.sharding import SingleDeviceSharding
+    from parsec_tpu.ops import tile_kernels
+    from parsec_tpu.utils import jax_platform
+    # the run asked for the CPU platform, where the kernel is interpreted
+    monkeypatch.setattr(jax_platform, "cpu_requested", lambda: False)
+    assert tile_kernels._lu_panel_takes(rows, w, np.float32)
+    compiled = jax.jit(tile_kernels._lu_panel).lower(jax.ShapeDtypeStruct(
+        (rows, w), np.float32,
+        sharding=SingleDeviceSharding(v5e_2x2.devices[0]))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "LuDecomposition" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 # ---------------------------------------------------------------------------
 # the store key
 # ---------------------------------------------------------------------------
