@@ -768,7 +768,8 @@ def _peaks():
 
 
 def phase_flagship(sz, on_chip):
-    """The compiled path at flagship width, as bench.py times it."""
+    """The compiled path at flagship width: the program of the
+    benchmark's ``potrf_panel_n40960`` cell."""
     import jax
     from parsec_tpu.algorithms.potrf import (panel_potrf_residual,
                                              panel_spd_state)

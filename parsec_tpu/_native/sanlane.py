@@ -29,7 +29,7 @@ import os
 import subprocess
 import sys
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from . import SAN_FLAGS, build_flags, sanitizer_runtime
 
@@ -181,8 +181,7 @@ def py_lane_script(var: str, n_tasks: int = 400,
     """The canonical Python-lane workload: a real DTD pool on the
     sanitized variant, asserting the sanitized engine actually engaged
     (variant selected, yield points compiled in, native pool live)
-    before printing ``marker``. ONE builder serves the test and bench
-    lanes so the two cannot drift apart."""
+    before printing ``marker``."""
     return f'''
 import parsec_tpu as parsec
 from parsec_tpu import _native
@@ -248,30 +247,3 @@ def run_clang_tidy(checks: str = "concurrency-*,bugprone-*") -> dict:
     return {"rc": proc.returncode,
             "warnings": out.count(" warning: "),
             "output": out[-4000:]}
-
-
-def stress_matrix(variants=None, seeds=(42, 7), iters: int = 2,
-                  scenarios: Optional[List[str]] = None) -> dict:
-    """The bench/CI sweep: every capable variant x seed over the full
-    scenario set. Returns per-variant rows with total report counts;
-    incapable variants record their skip reason."""
-    rows = {}
-    for var in (variants or tuple(SAN_FLAGS)):
-        reason = capable(var)
-        if reason is not None:
-            rows[var] = {"skipped": reason}
-            continue
-        total_reports, worst_rc, runs = 0, 0, []
-        for seed in seeds:
-            for sc in (scenarios or ["all"]):
-                r = run_stress(var, sc, seed=seed, iters=iters)
-                total_reports += r["reports"]
-                worst_rc = worst_rc or r["rc"]
-                runs.append({"scenario": sc, "seed": seed,
-                             "rc": r["rc"], "reports": r["reports"]})
-                if r["rc"] != 0 or r["reports"]:
-                    runs[-1]["output"] = r["output"][-1500:]
-        rows[var] = {"reports": total_reports, "rc": worst_rc,
-                     "clean": worst_rc == 0 and total_reports == 0,
-                     "runs": runs}
-    return rows

@@ -46,9 +46,10 @@ from ..utils import compile_cache, mca_param
 # as an MXU matmul (MAGMA-style; measured ~5-8x the wide-solve
 # throughput at nb=2048) at the cost of squaring the factor's
 # condition-number contribution — fine for the well-conditioned
-# dense-LA regime DPLASMA targets, and what bench.py opts into for the
-# headline (measured bound at N=40960 bf16: residual 4.1e-6 gemm vs the
-# solve+highest variant's 4.5e-7; see PARITY.md divergence notes).
+# dense-LA regime DPLASMA targets, and what the benchmark's POTRF
+# configurations state (benchmark/configs/dpotrf_*.json; measured bound
+# at N=40960 bf16: residual 4.1e-6 gemm vs the solve+highest variant's
+# 4.5e-7; see PARITY.md divergence notes).
 # Default "solve": a library default must not silently diverge from
 # reference numerics for ill-conditioned inputs.
 mca_param.register("potrf.trsm_hook", "solve",
@@ -454,9 +455,9 @@ def potrf_flops(n: int) -> float:
 
 
 # -- the panel executor's own check: input generated on device in its
-# Aᵀ-dense layout + a random-probe residual of the factor. Shared by
-# bench.py's flagship and chip_smoke.py, row-parametric so neither ever
-# holds a second N×N array next to the factor.
+# Aᵀ-dense layout + a random-probe residual of the factor. Used by
+# chip_smoke.py's panel phases, row-parametric so a caller never holds
+# a second N×N array next to the factor.
 
 def panel_spd_row(key, i: int, n: int, nb: int):
     """Block-row ``i`` of the Aᵀ-dense diagonally-dominant SPD input,

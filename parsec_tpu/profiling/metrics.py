@@ -43,8 +43,8 @@ from ..utils import mca_param
 
 mca_param.register("profiling.metrics", 1,
                    help="always-on metrics plane: hot-path counters + "
-                        "scrape-time collectors (0 = off; the A/B "
-                        "baseline of bench.py --section observability)")
+                        "scrape-time collectors (0 = off: the hot "
+                        "path as it was before the plane)")
 mca_param.register("serving.metrics_port", 0,
                    help="serve /metrics (Prometheus text) and /statusz "
                         "(JSON) on this localhost port via a stdlib "

@@ -15,12 +15,12 @@ it cannot retro-claim idle time and monopolize the streams).
 Starvation is measurable, not anecdotal: per-pool counters (enqueued /
 selected / pending / virtual pass, plus the last-selected wall clock) are
 exported via :meth:`WFQScheduler.pool_stats` and surfaced by the
-``tenant`` PINS module and ``bench.py --section serving``.
+``tenant`` PINS module and ``ServingRuntime.report()``.
 
 One global lock serializes the queue set. That is the right trade for the
 serving shape this scheduler exists for — many concurrent tenants whose
 task bodies dwarf the pop — and keeps selection O(live pools). The
-throughput-bench schedulers (lfq & co) remain the default elsewhere.
+per-thread schedulers (lfq & co) remain the default elsewhere.
 """
 
 from __future__ import annotations

@@ -13,16 +13,19 @@ another.
 
 The proving workload is the Orca-style continuous-batching transformer
 decode loop in :mod:`.decode` (KV cache as a tiled collection under the
-HBM budget manager, per-request decode steps as DTD insertions), benched
-by ``bench.py --section serving`` via :mod:`.serving_bench`.
+HBM budget manager, per-request decode steps as DTD insertions);
+``tests/test_serving.py`` drives admission, fairness, deadlines,
+quarantine and shedding through it, and
+``tests/test_serving_isolation.py`` the rank death beside a rank-local
+sibling (its mesh-scoped tenant is :mod:`.serving_bench`).
 
 The KV state layer (:mod:`.kv`, ROADMAP item 3 / ISSUE 15) adds the
 cross-request state plane: paged KV allocation (page-granular
 refcounts, COW, eviction), a radix prefix cache so requests sharing a
 prompt prefix share immutable pages, chunked prefill on the wfq
 prefill lane, and speculative decode as a cancellable draft-branch DTD
-pattern (:mod:`.spec`) — benched by ``bench.py --section serving_kv``
-via :mod:`.kv_bench`.
+pattern (:mod:`.spec`); ``tests/test_serving_kv.py`` checks every
+shared-prefix request bitwise against the no-sharing replay.
 """
 
 from .runtime import (AdmissionRejected, DeadlineExceeded, ServingRuntime,

@@ -44,7 +44,7 @@ from ..dsl import dtd
 
 class PoisonBody(ValueError):
     """Deliberate task-body failure injected by a misbehaving tenant
-    (the serving bench's poison traffic)."""
+    (the serving tests' poison traffic)."""
 
 
 @dataclass

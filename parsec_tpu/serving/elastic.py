@@ -36,13 +36,13 @@ from parts that already exist:
 - **Tenant migration** (:meth:`ElasticController.migrate_tenant`) is
   also exposed directly for hot-spot isolation: routing for the tenant
   pauses, the shard moves, routing resumes — the pause window is the
-  ``migration_pause`` the bench reports p99 over.
+  ``migration_pause`` the controller records.
 
 The module is workload-agnostic: the request/serving integration
 (what a "tenant" actually runs — e.g. the continuous-batching decode
 engine) plugs in through :class:`ElasticWorker` callbacks and the
-controller's routing-pause hooks. ``serving/elastic_bench.py`` is the
-proving harness (``bench.py --section elastic``).
+controller's routing-pause hooks. ``tests/test_elastic.py`` grows a
+live mesh 2 -> 4 ranks, migrates a tenant and drains back under traffic.
 """
 
 from __future__ import annotations
@@ -708,8 +708,8 @@ class ElasticController:
         ``dst`` through the checkpoint vehicle: pause routing → owner
         drains the tenant's in-flight work and saves its shard as a
         single-rank checkpoint step → ``dst`` restores the step and
-        starts serving → resume routing. Returns the pause in ms (the
-        bench's ``migration_pause`` sample). Also the hot-spot
+        starts serving → resume routing. Returns the pause in ms (one
+        ``migration_pauses_ms`` sample). Also the hot-spot
         isolation primitive — callable directly, not only from
         scale events."""
         src = self.placement.get(tenant)

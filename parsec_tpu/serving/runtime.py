@@ -398,7 +398,7 @@ class ServingRuntime:
         """Locked runtime-counter increment: submit paths run on many
         client threads, and a bare dict += is a read-modify-write that
         drops counts under preemption — these totals are the shedding/
-        quarantine evidence the bench and PARITY report."""
+        quarantine evidence ``report()`` and PARITY give."""
         with self._stats_lock:
             self.stats[key] += 1
 

@@ -1,62 +1,23 @@
-"""Mixed-tenant serving benchmark (``bench.py --section serving``).
-
-Three measurements, matching ISSUE 8's acceptance shape:
-
-1. **clean** — the serving context is rank 0 of a 2-rank socket mesh.
-   Two well-behaved decode tenants (A weight 4, B weight 1) drive an
-   open-loop load of continuous-batching decode requests for
-   ``duration_s`` while a distributed tenant D runs a cross-rank chain
-   taskpool spanning both ranks. Recorded per tenant: requests/s,
-   p50/p99 end-to-end latency, bitwise check of every completed request
-   against the float32 reference replay.
-2. **faulty** — same load plus a poison tenant P whose decode bodies
-   raise (quarantined on first failure; later submissions refused) and
-   a deterministic SIGKILL (``comm.fault_inject=kill``) of rank 1
-   mid-load, which aborts ONLY the mesh-scoped tenant D pool
-   (rank-local decode pools carry ``rank_scope={0}`` and keep
-   serving). The well-behaved tenants' p99 is compared against the
-   clean phase: the ≤2× bound is the isolation claim.
-3. **overload** — a single-rank context with a tiny shed watermark: a
-   high-weight tenant floods the ready queue, then low-weight
-   submissions are shed with ``AdmissionRejected`` — the recorded shed
-   count proves graceful degradation is rejection, not collapse.
+"""The mesh-scoped tenant of the serving isolation test
+(``tests/test_serving_isolation.py``): a round-robin 1-D collection over
+the ranks of a socket mesh, a PTG chain pool whose every link hops to
+the next rank's tile, and the peer rank's main, which can SIGKILL itself
+after a number of completed tasks (``comm.fault_inject=kill``). The
+test runs rank 0 itself beside a rank-local sibling pool and checks
+that the kill aborts the mesh-scoped pool alone.
 """
 
 from __future__ import annotations
 
-import multiprocessing as mp
-import threading
 import time
-from typing import Dict, List, Optional
 
 import numpy as np
 
-from .decode import DecodeConfig, DecodeEngine
-from ..comm.pingpong import _free_port_base
-from ..utils.stats import pctl as _pctl
-
-_DECODE_STEPS = 8           # decode steps per request
 _CHAIN_TILES = 8            # distributed tenant: tiles per rank round
 
 
-def _lat_row(lats_ms: List[float], n_submitted: int, n_rejected: int,
-             duration_s: float, bitwise_ok: bool) -> Dict:
-    return {
-        "requests": len(lats_ms),
-        "submitted": n_submitted,
-        "rejected": n_rejected,
-        "requests_per_sec": round(len(lats_ms) / duration_s, 2),
-        "p50_ms": (round(_pctl(lats_ms, 0.50) * 1e3, 3)
-                   if lats_ms else None),
-        "p99_ms": (round(_pctl(lats_ms, 0.99) * 1e3, 3)
-                   if lats_ms else None),
-        "bitwise": "OK" if bitwise_ok else "FAIL",
-    }
-
-
-# ------------------------------------------------- distributed tenant D
 class _DistVec:
-    """Round-robin 1-D collection spanning the mesh (tenant D's data)."""
+    """Round-robin 1-D collection spanning the mesh."""
 
     def __init__(self, name: str, n: int, nb_ranks: int, my_rank: int):
         self.name = name
@@ -88,10 +49,10 @@ class _DistVec:
 
 
 def _build_dist_chain(X, n_tiles: int, rounds: int, delay_s: float):
-    """Tenant D's cross-rank pool: per tile a ``rounds``-deep chain
+    """The cross-rank pool: per tile a ``rounds``-deep chain
     whose every link hops to the next rank's tile (cross-rank halo
     traffic each step) with a small per-task delay so the pool spans
-    the serving window and the injected kill lands mid-load."""
+    the sibling's rounds and the injected kill lands mid-load."""
     from ..dsl import ptg
 
     tp = ptg.Taskpool("dist_chain", X=X, N=n_tiles, T=rounds, D=delay_s)
@@ -120,10 +81,10 @@ def _build_dist_chain(X, n_tiles: int, rounds: int, delay_s: float):
 
 def _peer_main(rank: int, nb_ranks: int, base_port: int, rounds: int,
                delay_s: float, kill_after: int, q) -> None:
-    """Rank 1 of the serving mesh: runs tenant D's distributed pool.
+    """Rank 1 of the mesh: runs its share of the distributed pool.
     With ``kill_after`` > 0 this rank SIGKILLs itself
     (``comm.fault_inject=kill`` → os._exit) after that many completed
-    tasks — the mid-load rank death of the faulty phase."""
+    tasks: the mid-load rank death."""
     try:
         from ..comm.socket_engine import SocketCommEngine
         from ..core import context as ctx_mod
@@ -151,334 +112,3 @@ def _peer_main(rank: int, nb_ranks: int, base_port: int, rounds: int,
     except BaseException as exc:  # noqa: BLE001 — report to parent
         import traceback
         q.put((rank, "error", f"{exc}\n{traceback.format_exc()}"))
-
-
-class _OpenLoopTenant:
-    """Open-loop request generator for one decode tenant: a new request
-    every ``interval_s`` regardless of completions (the arrival process
-    does not slow down when the server does — the load shape that makes
-    p99 honest)."""
-
-    def __init__(self, engine: DecodeEngine, interval_s: float,
-                 n_steps: int, poison_at: Optional[int] = None):
-        self.engine = engine
-        self.interval_s = interval_s
-        self.n_steps = n_steps
-        self.poison_at = poison_at
-        self.submitted = 0
-        self.rejected = 0
-        self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._main, daemon=True)
-
-    def start(self):
-        self._thread.start()
-        return self
-
-    def _main(self):
-        rid = 0
-        next_t = time.monotonic()
-        while not self._stop.is_set():
-            try:
-                self.engine.request(rid, self.n_steps,
-                                    poison_at=self.poison_at)
-                self.submitted += 1
-            except Exception:  # noqa: BLE001 — admission/quarantine
-                self.rejected += 1
-            rid += 1
-            next_t += self.interval_s
-            delay = next_t - time.monotonic()
-            if delay > 0:
-                self._stop.wait(delay)
-            # open-loop: a late server does NOT push arrivals back
-
-    def stop(self):
-        self._stop.set()
-        self._thread.join(timeout=5.0)
-
-
-def _run_phase(faulty: bool, duration_s: float, nb_ranks: int = 2,
-               delay_s: float = 0.002) -> Dict:
-    """One serving phase as rank 0 of a fresh mesh (see module doc)."""
-    from ..comm.socket_engine import SocketCommEngine
-    from ..core import context as ctx_mod
-    from ..serving import runtime as srt
-    from ..utils import mca_param
-
-    from ..utils.benchenv import pin_wire_bench_env
-    pin_wire_bench_env()
-    mca_param.set("sched", "wfq")
-
-    rounds = max(8, int(duration_s / max(delay_s, 1e-4)) // _CHAIN_TILES)
-    # rank 1 owns every odd tile: it completes ~half of each round's
-    # tasks; kill it ~40% through the phase's rounds
-    kill_after = (max(4, int(rounds * _CHAIN_TILES * 0.4) // nb_ranks)
-                  if faulty else 0)
-
-    mpctx = mp.get_context("spawn")
-    q = mpctx.Queue()
-    base_port = _free_port_base(nb_ranks)
-    peer = mpctx.Process(target=_peer_main,
-                         args=(1, nb_ranks, base_port, rounds, delay_s,
-                               kill_after, q))
-    peer.start()
-
-    out: Dict = {"faulty": faulty}
-    engine = SocketCommEngine(0, nb_ranks, base_port=base_port)
-    ctx = ctx_mod.init(nb_cores=4, comm=engine)
-    try:
-        rt = srt.enable(ctx)
-        ten_a = rt.tenant("A", weight=4.0)
-        ten_b = rt.tenant("B", weight=1.0)
-        ctx.start()
-
-        # tenant D: the mesh-scoped distributed pool
-        XD = _DistVec("XD", _CHAIN_TILES, nb_ranks, 0)
-        dist_tp = _build_dist_chain(XD, _CHAIN_TILES, rounds, delay_s)
-        dist_sub = ctx.submit(dist_tp, tenant="D", weight=2.0,
-                              rank_scope="all")
-
-        cfg = DecodeConfig()
-        eng_a = DecodeEngine(ctx, "tA", cfg=cfg, tenant=ten_a).start()
-        eng_b = DecodeEngine(ctx, "tB", cfg=cfg, tenant=ten_b).start()
-        gen_a = _OpenLoopTenant(eng_a, 0.030, _DECODE_STEPS).start()
-        gen_b = _OpenLoopTenant(eng_b, 0.045, _DECODE_STEPS).start()
-        gen_p = None
-        if faulty:
-            ten_p = rt.tenant("P", weight=0.5)
-            eng_p = DecodeEngine(ctx, "tP", cfg=cfg, tenant=ten_p)
-            eng_p.start()
-            gen_p = _OpenLoopTenant(eng_p, 0.050, _DECODE_STEPS,
-                                    poison_at=1).start()
-
-        time.sleep(duration_s)
-        for g in (gen_a, gen_b, gen_p):
-            if g is not None:
-                g.stop()
-
-        rows = {}
-        for name, eng, gen in (("A", eng_a, gen_a), ("B", eng_b, gen_b)):
-            finished = eng.drain(timeout=60.0)
-            lats = [r.latency_s() for r in finished]
-            bitwise = all(eng.verify(r) for r in finished)
-            rows[name] = _lat_row([x for x in lats if x is not None],
-                                  gen.submitted, gen.rejected,
-                                  duration_s, bitwise and bool(finished))
-        if gen_p is not None:
-            rows["P"] = {"submitted": gen_p.submitted,
-                         "rejected": gen_p.rejected,
-                         "quarantined": rt.tenants()["P"].quarantined
-                         is not None}
-
-        # tenant D: completes clean, aborts (quarantining D only) faulty
-        d_err = None
-        try:
-            dist_sub.wait(timeout=120)
-        except Exception as exc:  # noqa: BLE001
-            d_err = f"{type(exc).__name__}: {exc}"
-        rows["D"] = {"completed": dist_sub.error is None,
-                     "error": d_err,
-                     "quarantined": rt.tenants()["D"].quarantined
-                     is not None}
-        report = rt.report()
-        out["tenants"] = rows
-        out["serving_stats"] = report["stats"]
-        out["pool_stats"] = {
-            k: {kk: v[kk] for kk in ("tenant", "weight", "selected",
-                                     "pending")}
-            for k, v in (report.get("pools") or {}).items()}
-        for eng_ in (eng_a, eng_b):
-            eng_.close()
-        if not faulty:
-            engine.sync()
-    finally:
-        ctx.fini()
-        if faulty:
-            peer.join(timeout=15.0)
-            if peer.is_alive():
-                peer.terminate()
-        else:
-            try:
-                rank, status, payload = q.get(timeout=30.0)
-                out["peer"] = {"status": status}
-            except Exception:  # noqa: BLE001
-                out["peer"] = {"status": "no-report"}
-            peer.join(timeout=15.0)
-            if peer.is_alive():
-                peer.terminate()
-    return out
-
-
-def _overload_probe(n_flood: int = 400, watermark: int = 64,
-                    n_attempts: int = 20) -> Dict:
-    """Deterministic load-shedding probe (single rank): a high-weight
-    tenant floods the ready queue past the watermark, then a low-weight
-    tenant's submissions must be shed with AdmissionRejected while the
-    flood still completes (degradation = rejection, not collapse)."""
-    from ..core import context as ctx_mod
-    from ..dsl import dtd
-    from ..serving import runtime as srt
-    from ..data.collection import LocalCollection
-    from ..utils import mca_param
-
-    mca_param.set("serving.shed_watermark", watermark)
-    mca_param.set("sched", "wfq")
-    ctx = ctx_mod.init(nb_cores=2)
-    try:
-        rt = srt.enable(ctx)
-        hi = rt.tenant("hi", weight=4.0)
-        lo = rt.tenant("lo", weight=1.0)
-        store = LocalCollection("ov", {(i,): 0.0 for i in range(n_flood)})
-        tp = dtd.Taskpool("flood")
-        ctx.submit(tp, tenant=hi)
-        gate = threading.Event()
-
-        def slow(x):
-            gate.wait(10.0)
-            return x + 1.0
-
-        # independent tiles: all n_flood tasks are READY immediately —
-        # the queue depth is real, not an in-flight chain
-        tp.insert_tasks(slow, [[dtd.TileArg(store, (i,), dtd.INOUT)]
-                               for i in range(n_flood)])
-        depth = ctx.scheduler.pending_tasks()
-        shed = 0
-        for i in range(n_attempts):
-            try:
-                ctx.submit(dtd.Taskpool(f"lo{i}"), tenant=lo)
-            except srt.AdmissionRejected:
-                shed += 1
-        gate.set()
-        tp.wait()
-        return {"flood_tasks": n_flood, "watermark": watermark,
-                "queue_depth_at_probe": depth,
-                "lo_attempts": n_attempts, "shed": shed,
-                "shed_total": rt.stats["shed"]}
-    finally:
-        mca_param.unset("serving.shed_watermark")
-        ctx.fini()
-
-
-def _native_ab_probe(n_pools: int = 40, rows_per_pool: int = 200) -> Dict:
-    """Native-vs-Python serving A/B (ISSUE 10): a single-rank serving
-    runtime on a native-capable scheduler (lfq — wfq keeps DTD pools on
-    the instrumented Python path by design, so an A/B there measures
-    nothing) pushes a stream of admission-controlled submissions through
-    both engines. Every task carries the tenant's ``on_retire`` hook, so
-    the native engine runs its Python-bodied path: insert, dependency
-    countdown, select, steal, and release native; the body + window
-    retire in Python — the serving shape of the hot loop.
-
-    Since ISSUE 13 both arms run with the FULL observability plane
-    live — the always-on metrics registry AND an installed Trace (the
-    request-span path) — because that is the production configuration:
-    the native engine keeps running under observation (its in-engine
-    event rings record the spans), which this probe asserts with
-    ``engine_native`` per arm."""
-    import time as _time
-    from .. import _native
-    from ..core import context as ctx_mod
-    from ..dsl import dtd
-    from ..profiling.trace import Trace
-    from ..serving import runtime as srt
-    from ..utils import mca_param
-
-    if not _native.available():
-        # degrade instead of raising (forcing native=1 without a
-        # toolchain raises by design): record WHY, keep the section
-        return {"python": None, "native": None, "native_vs_python": None,
-                "note": f"native core unavailable: "
-                        f"{_native.build_error()}"}
-
-    def run(native: int) -> Dict:
-        ctx = None
-        try:
-            mca_param.set("runtime.native_dtd", native)
-            mca_param.set("sched", "lfq")
-            ctx = ctx_mod.init(nb_cores=4)
-            Trace().install(ctx)      # metrics + tracing LIVE, both arms
-            rt = srt.enable(ctx)
-            ctx.start()
-            engines = set()
-            t0 = _time.perf_counter()
-            for i in range(n_pools):
-                tp = dtd.Taskpool(f"ab{native}_{i}")
-                sub = ctx.submit(tp, tenant="ab")
-                tp.insert_tasks(_null_ab_body,
-                                [() for _ in range(rows_per_pool)])
-                tp.wait()
-                sub.wait()
-                engines.add(tp._native is not None)
-            dt = _time.perf_counter() - t0
-            return {"requests_per_sec": round(n_pools / dt, 2),
-                    "rows_per_sec": round(n_pools * rows_per_pool / dt, 1),
-                    "engine_native": engines == {True},
-                    "trace_native_dropped": ctx.trace.native_dropped()}
-        finally:
-            mca_param.unset("runtime.native_dtd")
-            mca_param.unset("sched")
-            if ctx is not None:
-                ctx.fini()
-
-    run(0)                                     # warm both code paths
-    py = run(0)
-    nat = run(1)
-    ratio = (round(nat["rows_per_sec"] / py["rows_per_sec"], 3)
-             if py["rows_per_sec"] else None)
-    return {"python": py, "native": nat,
-            "native_vs_python": ratio,
-            "note": "lfq serving submissions (admission + on_retire per "
-                    "task) A/B'd across runtime.native_dtd with metrics "
-                    "+ tracing LIVE on both arms (ISSUE 13: the native "
-                    "engine keeps running under observation via its "
-                    "in-engine event rings); the wfq phase above keeps "
-                    "the instrumented Python path per the fallback rule"}
-
-
-def _null_ab_body(x=None):
-    return None
-
-
-def measure_serving(duration_s: float = 4.0) -> Dict:
-    """The full ``--section serving`` measurement (see module doc)."""
-    clean = _run_phase(False, duration_s)
-    faulty = _run_phase(True, duration_s)
-    overload = _overload_probe()
-    native_ab = _native_ab_probe()
-
-    def p99(phase, t):
-        row = phase["tenants"].get(t) or {}
-        return row.get("p99_ms")
-
-    ratios = []
-    for t in ("A", "B"):
-        c, f = p99(clean, t), p99(faulty, t)
-        if isinstance(c, (int, float)) and isinstance(f, (int, float)) \
-                and c > 0:
-            ratios.append(f / c)
-    worst_ratio = round(max(ratios), 3) if ratios else None
-
-    bitwise_ok = all(
-        (phase["tenants"][t].get("bitwise") == "OK")
-        for phase in (clean, faulty) for t in ("A", "B"))
-    isolation_ok = (
-        bitwise_ok
-        and faulty["tenants"]["P"]["quarantined"]
-        and faulty["tenants"]["D"]["quarantined"]
-        and not clean["tenants"]["D"]["quarantined"]
-        and worst_ratio is not None and worst_ratio <= 2.0)
-
-    reqs = sum(clean["tenants"][t]["requests"] for t in ("A", "B"))
-    return {
-        "duration_s": duration_s,
-        "requests_per_sec": round(reqs / duration_s, 2),
-        "p99_ms": p99(faulty, "A"),
-        "p99_ratio_worst": worst_ratio,
-        "clean": clean,
-        "faulty": faulty,
-        "overload": overload,
-        "native_ab": native_ab,
-        "native_vs_python": native_ab.get("native_vs_python"),
-        "shed_count": overload["shed"],
-        "quarantine_count": faulty["serving_stats"]["quarantined"],
-        "isolation_check": "OK" if isolation_ok else "FAIL",
-    }

@@ -28,8 +28,8 @@ economics with three layers:
    that bypass the store.
 
 Env/knob interaction (documented contract, ONE resolution —
-:func:`_resolve_dir` — shared by chip_smoke.py, bench.py and the
-examples):
+:func:`_resolve_dir` — shared by chip_smoke.py, ``benchmark/run.py``
+and the examples):
 
 - ``JAX_COMPILATION_CACHE_DIR`` env: the cache placed from OUTSIDE
   (setting it is also the opt-in). Where it is set, the XLA cache stays
@@ -41,9 +41,9 @@ examples):
   ``""`` = disabled (library default), ``auto`` = the FIXED
   ``.xla_cache`` next to the repo root (the path is part of the XLA
   cache key, so a directory that moves never hits), anything else =
-  that directory. chip_smoke.py, bench.py and the compiled-path
-  examples set it to ``auto`` — entry points opt in; the library never
-  writes caches unasked.
+  that directory. chip_smoke.py, ``benchmark/run.py`` and the
+  compiled-path examples set it to ``auto`` — entry points opt in; the
+  library never writes caches unasked.
 - ``PARSEC_COMPILE_CACHE`` env: kill switch. ``0`` disables BOTH layers
   whatever else is set; a path overrides the knob's directory.
   :func:`enable_compile_cache` remains the explicit call.

@@ -185,6 +185,75 @@ def test_store_knob_invalidation_end_to_end(tmp_path):
         assert cc.backend_compile_count() == c0     # back to full hits
 
 
+def _factor_error(A, a_host):
+    L = np.tril(A.to_array())
+    return np.linalg.norm(L @ L.T - a_host) / np.linalg.norm(a_host)
+
+
+def _serve_panel_segmented(seed):
+    """One serving process of the flagship's shape: resolve every
+    program of the segmented walk, then run. Returns the programs the
+    walk holds and the factor's residual."""
+    A, ex = _left_executor(192, 64, seed)
+    programs = ex.prepare_segments()
+    ex.write_back(ex.run_state_segmented(ex.make_state()))
+    return programs, _factor_error(A, _spd(192, seed))
+
+
+def _serve_wavefront_segmented(seed):
+    from parsec_tpu.algorithms.potrf import build_potrf
+    from parsec_tpu.compiled.wavefront import (WavefrontExecutor,
+                                               plan_taskpool)
+    from parsec_tpu.data.matrix import TiledMatrix
+    A = TiledMatrix.from_array(_spd(256, seed), 64, 64, name="A")
+    ex = WavefrontExecutor(plan_taskpool(build_potrf(A)))
+    ex.write_back_tiles(ex.run_tile_dict_segmented(ex.make_tiles()))
+    return len(ex._segments), _factor_error(A, _spd(256, seed))
+
+
+def _serve_panel_monolith(seed):
+    A, ex = _left_executor(192, 64, seed)
+    ex.write_back(ex.jitted(ex.make_state()))
+    return 1, _factor_error(A, _spd(192, seed))
+
+
+@pytest.mark.parametrize("serve", [_serve_panel_segmented,
+                                   _serve_wavefront_segmented,
+                                   _serve_panel_monolith],
+                         ids=["panel_segmented", "wavefront_segmented",
+                              "panel_monolith"])
+def test_a_second_process_compiles_nothing_and_loads_every_program(
+        tmp_path, serve):
+    """The compile-once claim across processes, for each compiled path
+    and under the flagship's kernels (``potrf.trsm_hook=gemm``): the
+    first process at a size compiles and stores every program it
+    resolves; a second process (the in-process store cleared, the
+    directory kept) resolves the same number, every one from the store,
+    with no XLA compile, and its factor is right."""
+    mca_param.set("potrf.trsm_hook", "gemm")
+    try:
+        with _tmp_store(tmp_path / "cache"):
+            cc.reset_in_process_cache()      # cold whatever ran before
+            s0 = cc.cache_stats()
+            programs, _ = serve(0)
+            s1 = cc.cache_stats()
+            assert programs > 0
+            assert s1["store_misses"] - s0["store_misses"] == programs
+            assert s1["store_hits"] == s0["store_hits"]
+
+            cc.reset_in_process_cache()      # "the second process"
+            compiled = cc.backend_compile_count()
+            again, err = serve(1)
+            s2 = cc.cache_stats()
+            assert cc.backend_compile_count() == compiled
+            assert again == programs
+            assert s2["store_hits"] - s1["store_hits"] == programs
+            assert s2["store_misses"] == s1["store_misses"]
+            assert err < 1e-4, err
+    finally:
+        mca_param.unset("potrf.trsm_hook")
+
+
 def test_jit_cache_dir_knob_auto_enables(tmp_path, monkeypatch):
     """jit.cache_dir MCA knob auto-enables the store (no manual
     enable_compile_cache call); '' disables; PARSEC_COMPILE_CACHE=0 is

@@ -137,21 +137,37 @@ def test_eviction_policy_sweep_budget_ratios():
     assert belady_total >= lru_total, (belady_total, lru_total)
 
 
-def test_budget_unbounded_matches_budgeted():
-    n, nb = 256, 64
+@pytest.mark.parametrize("hook", ["solve", "gemm"])
+@pytest.mark.parametrize("budget_tiles", [18, 5])
+def test_the_budgeted_factor_is_bitwise_the_unbudgeted_one(hook,
+                                                            budget_tiles):
+    """Out of core changes where a tile is, never what is computed: under
+    a budget of half and of an eighth of the 36-tile triangle, with the
+    exact and the inverted-triangle TRSM, every tile of the factor is
+    bit for bit the one the unbudgeted run gives, the manager never
+    holds more than the budget, and it did spill."""
+    from parsec_tpu.utils import mca_param
+    n, nb = 512, 64
     A_host = _spd(n)
-    A1 = TiledMatrix.from_array(A_host.copy(), nb, nb, name="A")
-    ex1 = WavefrontExecutor(plan_taskpool(build_potrf(A1)))
-    out1 = ex1.run_tile_dict_segmented(ex1.make_tiles())
+    mca_param.set("potrf.trsm_hook", hook)
+    try:
+        A1 = TiledMatrix.from_array(A_host.copy(), nb, nb, name="A")
+        ex1 = WavefrontExecutor(plan_taskpool(build_potrf(A1)))
+        out1 = ex1.run_tile_dict_segmented(ex1.make_tiles())
 
-    A2 = TiledMatrix.from_array(A_host.copy(), nb, nb, name="A")
-    ex2 = WavefrontExecutor(plan_taskpool(build_potrf(A2)))
-    mgr = HBMManager(10 * nb * nb * 4, unit=1024)
-    out2 = ex2.run_tile_dict_segmented(ex2.make_tiles(host=True),
-                                       manager=mgr)
+        A2 = TiledMatrix.from_array(A_host.copy(), nb, nb, name="A")
+        ex2 = WavefrontExecutor(plan_taskpool(build_potrf(A2)))
+        budget = budget_tiles * nb * nb * 4
+        mgr = HBMManager(budget, unit=1024)
+        out2 = ex2.run_tile_dict_segmented(ex2.make_tiles(host=True),
+                                           manager=mgr)
+    finally:
+        mca_param.unset("potrf.trsm_hook")
+    assert out1.keys() == out2.keys()
     for k in out1:
-        assert np.allclose(np.asarray(out1[k]), np.asarray(out2[k]),
-                           atol=1e-4), k
+        assert np.array_equal(np.asarray(out1[k]), np.asarray(out2[k])), k
+    assert mgr.stats["spills"] > 0
+    assert mgr.stats["peak_bytes"] <= budget
 
 
 def test_host_runtime_collection_spill():

@@ -33,6 +33,32 @@ def test_one_module_per_visible_device():
     parsec.fini(ctx)
 
 
+@pytest.mark.parametrize("device_modules", [True, False])
+def test_a_missing_chip_is_an_error_and_not_a_cpu_run(monkeypatch,
+                                                      device_modules):
+    """JAX with no chip falls back to the CPU platform by itself. A
+    context started then, by a run that did not ask for the CPU
+    platform, would put CPU device modules under a chip's name: it
+    raises and names the two ways out, and the second of them
+    (``device.tpu.enabled=0``) gives a context without device modules."""
+    from parsec_tpu.utils import jax_platform, mca_param
+    monkeypatch.setattr(jax_platform, "cpu_requested", lambda: False)
+    if device_modules:
+        with pytest.raises(RuntimeError, match="no accelerator found") \
+                as err:
+            parsec.init(nb_cores=1)
+        assert "JAX_PLATFORMS=cpu" in str(err.value)
+        assert "device.tpu.enabled=0" in str(err.value)
+        return
+    mca_param.set("device.tpu.enabled", False)
+    try:
+        ctx = parsec.init(nb_cores=1)
+        assert ctx.devices.by_type(DeviceType.TPU) == []
+        parsec.fini(ctx)
+    finally:
+        mca_param.unset("device.tpu.enabled")
+
+
 def test_dtd_gemm_load_splits_across_devices():
     """A DTD tiled GEMM's tasks spread over multiple device modules.
     This pins the DEVICE-MANAGER plane (per-module load balancing), so

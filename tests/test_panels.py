@@ -576,3 +576,57 @@ def test_panel_potrf_trsm_solve_mode(builder):
     L = np.tril(A.to_array().astype(np.float64))
     ref = np.linalg.cholesky(A_in.astype(np.float64))
     np.testing.assert_allclose(L, ref, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the panel executor's own check (algorithms/potrf.py panel_spd_state,
+# panel_potrf_residual): what chip_smoke.py's flagship phases decide on
+# ---------------------------------------------------------------------------
+
+def _probed_factor(n, nb):
+    """The factor of ``panel_spd_state``'s matrix through the one-chip
+    panel program, the residual the probe reads off it, and the matrix
+    itself as the docstring defines it: the lower triangle from the
+    stored upper triangle of D, diagonal blocks averaged."""
+    import jax
+    from parsec_tpu.algorithms.potrf import (build_potrf_left,
+                                             panel_potrf_residual,
+                                             panel_spd_state)
+    key = jax.random.PRNGKey(3)
+    D = np.asarray(panel_spd_state(key, n, nb)["A"], np.float64)
+    block = np.arange(n) // nb
+    above = block[:, None] < block[None, :]     # D's strictly upper blocks
+    on = block[:, None] == block[None, :]
+    A0 = np.where(above, D, 0.0)
+    A0 = A0 + A0.T + np.where(on, 0.5 * (D + D.T), 0.0)
+    ex = PanelExecutor(plan_taskpool(build_potrf_left(
+        TiledMatrix(n, n, nb, nb, name="A"))))
+    Lt = ex.jitted(panel_spd_state(key, n, nb))["A"]
+
+    def probe(factor):
+        with jax.default_matmul_precision("highest"):
+            return float(panel_potrf_residual(factor, key, n, nb))
+    return Lt, probe, A0
+
+
+@pytest.mark.parametrize("hook", ["solve", "gemm"], indirect=True)
+def test_the_panel_residual_probe_agrees_with_a_dense_residual(hook):
+    n, nb = 256, 64
+    Lt, probe, A0 = _probed_factor(n, nb)
+    L = np.triu(np.asarray(Lt)).T.astype(np.float64)
+    dense = np.linalg.norm(L @ L.T - A0) / np.linalg.norm(A0)
+    assert dense < 1e-5 and probe(Lt) < 1e-5, (dense, probe(Lt))
+
+
+def test_the_panel_residual_probe_sees_a_wrong_factor():
+    """A check that cannot fail decides nothing: one tile of the factor
+    off by a hundredth, and the factor of another matrix, both read
+    far above the 1e-4 that chip_smoke.py admits."""
+    import jax
+    from parsec_tpu.algorithms.potrf import panel_spd_state
+    n, nb = 256, 64
+    Lt, probe, _ = _probed_factor(n, nb)
+    assert probe(Lt) < 1e-5
+    assert probe(Lt.at[nb:2 * nb, 2 * nb:3 * nb].multiply(1.01)) > 1e-4
+    other = panel_spd_state(jax.random.PRNGKey(4), n, nb)["A"]
+    assert probe(Lt + 0.01 * np.triu(np.asarray(other))) > 1e-3

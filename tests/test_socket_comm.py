@@ -989,3 +989,23 @@ def test_peer_death_aborts_survivor():
             p.join(timeout=10.0)
             if p.is_alive():
                 p.terminate()
+
+
+@pytest.mark.parametrize("payload_bytes,eager_limit,path", [
+    (1024, 256 * 1024, "eager"),
+    (128 * 1024, 64 * 1024, "rendezvous"),
+])
+def test_the_two_rank_hop_harness_runs_both_wire_paths(payload_bytes,
+                                                       eager_limit, path):
+    """``comm/pingpong.py measure_latency`` is what ROADMAP B3's cell
+    will take (two ranks, every hop one remote activation carrying the
+    payload). Nothing else in the tree calls it: here it runs to its
+    end below and above the eager limit and names the path the payload
+    took."""
+    from parsec_tpu.comm.pingpong import measure_latency
+    hops = 16
+    got = measure_latency(payload_bytes=payload_bytes, hops=hops,
+                          eager_limit=eager_limit, timeout=120.0)
+    assert got["path"] == path and got["payload_bytes"] == payload_bytes
+    assert got["hops"] == hops
+    assert 0.0 < got["p50_us"] <= got["p90_us"] <= got["p99_us"]
