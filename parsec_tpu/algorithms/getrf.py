@@ -42,8 +42,10 @@ from ..core.reshape import UPPER_TILE
 from ..dsl import ptg
 from ..data.matrix import TiledMatrix
 from .geqrf import _row
-from ..ops.tile_kernels import (gemm_tile, gessm_tile, getrf_incpiv_tile,
-                                getrf_nopiv_tile, ssssm_tile,
+from ..ops.tile_kernels import (PANEL_COMPILER_OPTIONS, gemm_full_tile,
+                                gemm_tile, gessm_tile, getrf_incpiv_tile,
+                                getrf_nopiv_tile, getrf_panel_tiles,
+                                laswp_tiles, ssssm_tile, swptrsm_tiles,
                                 trsm_lower_unit, trsm_upper_right,
                                 tstrf_tile)
 from ..utils import compile_cache, mca_param
@@ -967,5 +969,286 @@ def build_getrf_incpiv(A: TiledMatrix, L: Optional[TiledMatrix] = None,
         batch_hook_shared=("L21", "W", "P"), donates=("A1", "A2"))
     def ssssm_body(task, L21, W, P, A1, A2):
         return ssssm(L21, W, P, A1, A2)
+
+    return tp
+
+
+# ---- partial pivoting over whole panels (DPLASMA dgetrf_1d) -------------
+
+def getrf_1d_ipiv_collection(A: TiledMatrix) -> TiledMatrix:
+    """descIPIV of ``A`` for :func:`build_getrf_1d`: nb int32 a panel (a
+    1 x nb tile, key ``(k, 0)``): LAPACK's interchange indices, 0-based
+    from the panel's first row (ops/tile_kernels.py
+    ``getrf_panel_tiles``)."""
+    import numpy as np
+    return TiledMatrix(A.mt, A.nb, 1, A.nb, dist=A.dist, dtype=np.int32,
+                       name=f"{A.name}_IPIV")
+
+
+def build_getrf_1d(A: TiledMatrix, IPIV: Optional[TiledMatrix] = None,
+                   ib: Optional[int] = None) -> ptg.Taskpool:
+    """Tile LU with partial pivoting over whole panels (DPLASMA
+    ``dgetrf_1d``, ``zgetrf_1d.jdf``'s four classes) over ``A`` (square,
+    nb x nb tiles) and ``IPIV`` (``getrf_1d_ipiv_collection``; made here
+    where none is given: the pool's ``g.IPIV``), inner block ``ib``
+    (default nb), k = 0..NT-1:
+
+        GETRF(k):      P_k [A(k..NT-1, k)] = L U, the pivot of every
+                       column sought down the WHOLE remaining block
+                       column; writes the column's tiles and IPIV(k)
+        SWPTRSM(k,n):  n > k: IPIV(k)'s interchanges applied to the
+                       tiles A(k..NT-1, n), then A(k,n) <- L_kk^-1 A(k,n)
+        GEMM(k,m,n):   m, n > k: A(m,n) -= A(m,k) A(k,n), full float32
+        SWPBACK(k,n):  n < k: IPIV(k)'s interchanges applied to the
+                       finished tiles A(k..NT-1, n) of L
+
+    On completion P A = L U in LAPACK's ``dgetrf`` form: U in the upper
+    triangle of A, the unit lower L under it with every interchange
+    applied to it (what ``dgetrs`` reads), the interchange indices of
+    panel k in IPIV(k). Every multiplier is at most 1 over the whole
+    column, which is what incremental pivoting
+    (:func:`build_getrf_incpiv`) gives up.
+
+    **A flow over a range of tiles.** GETRF, SWPTRSM and SWPBACK read
+    and rewrite a block column from row k down: their flow's value is
+    the ordered LIST of those tiles (``ptg.In(gather=True)`` /
+    ``ptg.Out(scatter=True)``), gathered from the step before's GEMMs
+    (the collection at k = 0) and handed on element by element: GETRF's
+    diagonal tile to the row's SWPTRSMs as L, its tile (m,k) to row m's
+    GEMMs as the left operand and on to SWPBACK(k+1,k); SWPTRSM's first
+    tile to column n's GEMMs as the right operand, its tile (m,n) to
+    GEMM(k,m,n) as C. Every tile is an operand of its task's launch, and
+    is updated in the buffer it lies in (``Chore.donates``, element by
+    element): the factorization runs in the storage of A and IPIV.
+
+    SWPBACK(k+1,k) rewrites the tiles the step's GEMMs read as their
+    left operand, so it waits for them: for GETRF(k+1) (its pivots,
+    which gathered column k+1's GEMMs) and, by a CTL gather, for the
+    SWPTRSM(k+1,n) of the other columns (each gathered its column's).
+
+    Priorities favour the next panel (look-ahead): GETRF 4(NT-k)^2,
+    SWPTRSM 3(NT-k)^2 - n (the next panel's column first), the GEMMs
+    of column k+1 2(NT-k)^2 - m, the other GEMMs (NT-k)^2 - m - n,
+    SWPBACK 0: it is on no path to a panel.
+    """
+    NT = _check(A)
+    if IPIV is None:
+        IPIV = getrf_1d_ipiv_collection(A)
+    ib = ib or A.nb
+    if A.nb % ib or (IPIV.mt, IPIV.nt, IPIV.mb, IPIV.nb) != (NT, 1, 1, A.nb):
+        raise ValueError("IPIV needs one tile of 1 x nb a panel of A, and "
+                         "ib has to divide nb")
+    tp = ptg.Taskpool("getrf_1d", A=A, IPIV=IPIV, NT=NT)
+
+    def below(k, g):
+        return range(k, g.NT)
+
+    def column(g, k, n):
+        """The tiles (k..NT-1, n) of A."""
+        return [(g.A, (m, n)) for m in below(k, g)]
+
+    def column_in(col):
+        """A block column from row k down as step k finds it: the
+        matrix's at k = 0, else what the GEMMs of step k-1 left.
+        ``col(*params) -> (k, n)``."""
+        return [ptg.In(data=lambda g, *p: column(g, *col(*p)), gather=True,
+                       guard=lambda g, *p: col(*p)[0] == 0),
+                ptg.In(src=("GEMM",
+                            lambda g, *p: [(col(*p)[0] - 1, m, col(*p)[1])
+                                           for m in below(col(*p)[0], g)],
+                            "C"),
+                       gather=True, guard=lambda g, *p: col(*p)[0] > 0)]
+
+    def rest_to(at):
+        """A list's tiles after the first, on to the ONE task ``at``
+        names (which gathers them); the first goes elsewhere."""
+        return lambda g, *p: [[]] + [[at(*p)]] * (g.NT - p[0] - 1)
+
+    GETRF = tp.task_class(
+        "GETRF", params=("k",),
+        space=lambda g: ((k,) for k in range(g.NT)),
+        affinity=lambda g, k: (g.A, (k, k)),
+        priority=lambda g, k: 4 * (g.NT - k) ** 2,
+        flows=[
+            ptg.FlowSpec(
+                "A", ptg.RW,
+                tile=lambda g, k: column(g, k, k),
+                ins=column_in(lambda k: (k, k)),
+                outs=[
+                    # (k,k): L for the row's SWPTRSMs; (m,k): the left
+                    # operand of row m's GEMMs
+                    ptg.Out(dst=("SWPTRSM",
+                                 lambda g, k: [[(k, n) for n in
+                                                range(k + 1, g.NT)]] +
+                                 [[]] * (g.NT - k - 1), "L"),
+                            scatter=True),
+                    ptg.Out(dst=("GEMM",
+                                 lambda g, k: [[]] + [
+                                     [(k, m, n) for n in range(k + 1, g.NT)]
+                                     for m in range(k + 1, g.NT)], "A"),
+                            scatter=True),
+                    ptg.Out(dst=("SWPBACK",
+                                 rest_to(lambda k: (k + 1, k)),
+                                 "C"),
+                            scatter=True, guard=lambda g, k: k + 1 < g.NT),
+                    # the diagonal tile is final; the others' write-back
+                    # is their last SWPBACK's
+                    ptg.Out(data=lambda g, k: [(g.A, (k, k))] +
+                            [None] * (g.NT - k - 1), scatter=True)]),
+            ptg.FlowSpec(
+                "IPIV", ptg.RW,
+                tile=lambda g, k: (g.IPIV, (k, 0)),
+                ins=[ptg.In(data=lambda g, k: (g.IPIV, (k, 0)))],
+                outs=[ptg.Out(dst=("SWPTRSM",
+                                   lambda g, k: [(k, n) for n in
+                                                 range(k + 1, g.NT)], "P")),
+                      ptg.Out(dst=("SWPBACK",
+                                   lambda g, k: [(k, n) for n in range(k)],
+                                   "P")),
+                      ptg.Out(data=lambda g, k: (g.IPIV, (k, 0)))]),
+        ])
+
+    SWPTRSM = tp.task_class(
+        "SWPTRSM", params=("k", "n"),
+        space=lambda g: ((k, n) for k in range(g.NT)
+                         for n in range(k + 1, g.NT)),
+        affinity=lambda g, k, n: (g.A, (k, n)),
+        priority=lambda g, k, n: 3 * (g.NT - k) ** 2 - n,
+        flows=[
+            ptg.FlowSpec(
+                "L", ptg.READ,
+                tile=lambda g, k, n: (g.A, (k, k)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, n: (k,), "A"))]),
+            ptg.FlowSpec(
+                "P", ptg.READ,
+                tile=lambda g, k, n: (g.IPIV, (k, 0)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, n: (k,), "IPIV"))]),
+            ptg.FlowSpec(
+                "C", ptg.RW,
+                tile=lambda g, k, n: column(g, k, n),
+                ins=column_in(lambda k, n: (k, n)),
+                outs=[
+                    # (k,n): a finished tile of U, the right operand of
+                    # column n's GEMMs; (m,n): GEMM(k,m,n)'s C
+                    ptg.Out(dst=("GEMM",
+                                 lambda g, k, n: [[(k, m, n) for m in
+                                                   range(k + 1, g.NT)]] +
+                                 [[]] * (g.NT - k - 1), "B"),
+                            scatter=True),
+                    ptg.Out(dst=("GEMM",
+                                 lambda g, k, n: [[]] + [
+                                     [(k, m, n)]
+                                     for m in range(k + 1, g.NT)], "C"),
+                            scatter=True),
+                    ptg.Out(data=lambda g, k, n: [(g.A, (k, n))] +
+                            [None] * (g.NT - k - 1), scatter=True)]),
+            # its column's GEMMs of step k-1 have read their left
+            # operands, which SWPBACK(k, k-1) rewrites
+            ptg.FlowSpec(
+                "G", ptg.CTL,
+                outs=[ptg.Out(dst=("SWPBACK",
+                                   lambda g, k, n: (k, k - 1), "G"),
+                              guard=lambda g, k, n: k > 0 and n > k)]),
+        ])
+
+    GEMM = tp.task_class(
+        "GEMM", params=("k", "m", "n"),
+        space=lambda g: ((k, m, n) for k in range(g.NT)
+                         for m in range(k + 1, g.NT)
+                         for n in range(k + 1, g.NT)),
+        affinity=lambda g, k, m, n: (g.A, (m, n)),
+        priority=lambda g, k, m, n: (
+            2 * (g.NT - k) ** 2 - m if n == k + 1
+            else (g.NT - k) ** 2 - m - n),
+        flows=[
+            ptg.FlowSpec(
+                "A", ptg.READ,
+                tile=lambda g, k, m, n: (g.A, (m, k)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, m, n: (k,), "A"))]),
+            ptg.FlowSpec(
+                "B", ptg.READ,
+                tile=lambda g, k, m, n: (g.A, (k, n)),
+                ins=[ptg.In(src=("SWPTRSM", lambda g, k, m, n: (k, n),
+                                 "C"))]),
+            ptg.FlowSpec(
+                "C", ptg.RW,
+                tile=lambda g, k, m, n: (g.A, (m, n)),
+                ins=[ptg.In(src=("SWPTRSM", lambda g, k, m, n: (k, n),
+                                 "C"))],
+                outs=[ptg.Out(dst=("GETRF", lambda g, k, m, n: (k + 1,),
+                                   "A"),
+                              guard=lambda g, k, m, n: n == k + 1),
+                      ptg.Out(dst=("SWPTRSM",
+                                   lambda g, k, m, n: (k + 1, n), "C"),
+                              guard=lambda g, k, m, n: n > k + 1)]),
+        ])
+
+    SWPBACK = tp.task_class(
+        "SWPBACK", params=("k", "n"),
+        space=lambda g: ((k, n) for k in range(g.NT) for n in range(k)),
+        affinity=lambda g, k, n: (g.A, (k, n)),
+        priority=lambda g, k, n: 0,
+        flows=[
+            ptg.FlowSpec(
+                "P", ptg.READ,
+                tile=lambda g, k, n: (g.IPIV, (k, 0)),
+                ins=[ptg.In(src=("GETRF", lambda g, k, n: (k,), "IPIV"))]),
+            # column n of L from row k down: GETRF(n)'s tiles, then what
+            # the SWPBACK of the panel before left (one producer, named
+            # once a tile)
+            ptg.FlowSpec(
+                "C", ptg.RW,
+                tile=lambda g, k, n: column(g, k, n),
+                ins=[ptg.In(src=("GETRF",
+                                 lambda g, k, n: [(n,)] * (g.NT - k), "A"),
+                            gather=True, guard=lambda g, k, n: k == n + 1),
+                     ptg.In(src=("SWPBACK",
+                                 lambda g, k, n: [(k - 1, n)] * (g.NT - k),
+                                 "C"),
+                            gather=True, guard=lambda g, k, n: k > n + 1)],
+                outs=[
+                    # row k takes no later interchange: (k,n) is final
+                    ptg.Out(dst=("SWPBACK",
+                                 rest_to(lambda k, n: (k + 1, n)), "C"),
+                            scatter=True,
+                            guard=lambda g, k, n: k + 1 < g.NT),
+                    ptg.Out(data=lambda g, k, n: [(g.A, (k, n))] +
+                            [None] * (g.NT - k - 1), scatter=True)]),
+            ptg.FlowSpec(
+                "G", ptg.CTL,
+                ins=[ptg.In(src=("SWPTRSM",
+                                 lambda g, k, n: [(k, c) for c in
+                                                  range(k + 1, g.NT)], "G"),
+                            gather=True,
+                            guard=lambda g, k, n: k == n + 1 and
+                            k + 1 < g.NT)]),
+        ])
+
+    import jax
+
+    def getrf(tiles, p):
+        tiles, ipiv = getrf_panel_tiles(tiles, ib)
+        return {"A": tiles, "IPIV": _over(p, ipiv)}
+
+    # a serial chain: the stacked form is declared as build_getrf_incpiv
+    # declares GETRF's, so that a chip module builds no group program
+    # for a class whose tasks never meet (one a list length as it is)
+    @GETRF.body(batch_hook=lambda As, Ps: jax.vmap(getrf)(As, Ps),
+                batch_hook_shared=("A",), donates=("A", "IPIV"),
+                compiler_options=PANEL_COMPILER_OPTIONS)
+    def getrf_body(task, A_, P):
+        return getrf(A_, P)
+
+    @SWPTRSM.body(donates=("C",))
+    def swptrsm_body(task, L_, P, C):
+        return {"C": swptrsm_tiles(L_, P, C)}
+
+    @GEMM.body(donates=("C",))
+    def gemm_body(task, A_, B_, C):
+        return gemm_full_tile(A_, B_, C)
+
+    @SWPBACK.body(donates=("C",))
+    def swpback_body(task, P, C):
+        return {"C": laswp_tiles(C, P)}
 
     return tp

@@ -331,6 +331,17 @@ def lint_taskpool(tp, max_tasks: int = 0) -> LintReport:
     the dynamic sanitizer (analysis/dfsan.py) covers instead.
     """
     report = LintReport(taskpool=tp.name)
+    if any(getattr(tc, "ranged", False) for tc in tp.task_classes):
+        # the model holds ONE tile a flow; a pool with a flow over a
+        # range of tiles (dsl/ptg.py) is check_taskpool's and dfsan's
+        report.skipped_classes = [tc.name for tc in tp.task_classes]
+        report.findings.append(Finding(
+            "ranged", NOTE, tp.name,
+            message=f"{tp.name}: a ranged data flow (gather/scatter) is "
+                    f"not modelled — no static check ran; "
+                    f"ptg.check_taskpool cross-validates its edges and "
+                    f"the dfsan sanitizer its reads"))
+        return report
     _check_structural(tp, report)
     m = build_model(tp, max_tasks=max_tasks)
     report.model = m

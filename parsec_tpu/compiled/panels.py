@@ -362,6 +362,13 @@ class PanelExecutor:
         import jax
         self.jax = jax
         self.plan = plan
+        from ..dsl.ptg import taskpool_has_ranged_flows
+        if taskpool_has_ranged_flows(plan.taskpool):
+            # a plan made by hand: plan_taskpool refuses such a pool
+            raise ValueError(
+                f"taskpool {plan.taskpool.name!r} has a ranged data flow "
+                f"(gather/scatter): a wave fuser lowers one panel slice a "
+                f"flow; a list of tiles a flow is the host runtime's")
         fuser = getattr(plan.taskpool, "wave_fuser", None)
         if fuser is None:
             raise ValueError(

@@ -164,12 +164,18 @@ def plan_taskpool(tp: PTGTaskpool) -> WavefrontPlan:
     applied to the gathered stack at execution (XLA fuses the cast/
     transpose into the body); terminal DataRef specs are applied by
     write_back. Groups whose instances disagree on specs are split."""
-    from ..dsl.ptg import taskpool_uses_reshape, taskpool_writes_regions
+    from ..dsl.ptg import (taskpool_has_ranged_flows, taskpool_uses_reshape,
+                           taskpool_writes_regions)
     if taskpool_writes_regions(tp):
         raise ValueError(
             f"taskpool {tp.name}: a write-back of a region of a tile "
             f"(Out(region=...)) is the host runtime's: an executor "
             f"scatters whole tiles")
+    if taskpool_has_ranged_flows(tp):
+        raise ValueError(
+            f"taskpool {tp.name}: a ranged data flow (In(gather=True) on "
+            f"a data flow, Out(scatter=True)) is the host runtime's: an "
+            f"executor gathers ONE tile a flow from its stacked stores")
     has_reshapes = taskpool_uses_reshape(tp)
     # ---- enumerate tasks and assign ids
     tasks: List[Tuple[PTGTaskClass, Tuple[int, ...]]] = []

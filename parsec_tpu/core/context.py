@@ -119,7 +119,10 @@ class ExecutionStream:
                       # this worker launched whose written tile is
                       # advised to a module, and those of them that ran
                       # on that module (Context._count_advised)
-                      "tasks_advised": 0, "tasks_on_advised": 0}
+                      "tasks_advised": 0, "tasks_on_advised": 0,
+                      # activations that carried ONE element of a ranged
+                      # flow's list (Out(scatter=True)), always
+                      "ranged_scatters": 0}
         self._vp_peers = None        # cached steal orders (sched/base.py)
         self._steal_order = None
         # extensible per-stream info slots (parsec_internal.h:688-702)
@@ -1334,6 +1337,14 @@ class Context:
                 successors = list(successors)
             if es is not None:
                 es.stats["unfold_s"] += span.seconds
+        if tc.ranged and es is not None:
+            # a ranged flow's list leaves element by element, one
+            # activation each; its consumer is scheduled once, by the
+            # activation that completes its count (activate_deps)
+            successors = list(successors)
+            es.stats["ranged_scatters"] += sum(
+                getattr(ref, "element", None) is not None
+                for ref in successors)
         for ref in successors:
             if isinstance(ref, DataRef):
                 if ref.region is not None:

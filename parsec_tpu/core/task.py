@@ -118,6 +118,14 @@ class Chore:
     # (``dtd.Taskpool._insert_one``; the given flows key the task's
     # class, so tasks that give different flows have different chores).
     donates: Optional[Sequence[str]] = None
+    # What the body's kernels need of the chip's compiler beyond its
+    # defaults, as XLA compiler options: a chip module compiles this
+    # chore's programs with them (on a TPU alone; a rehearsal on the CPU
+    # platform knows none of them). One user: ``build_getrf_1d``'s panel
+    # task, whose pivoted factorization of a stack of up to 32768 x 128
+    # is XLA's ``LuDecompositionBlock``, which works in scoped VMEM and
+    # is refused over 16 MiB of it (a v5e core has 128).
+    compiler_options: Optional[Dict[str, Any]] = None
     # Hooks that are NOT batchable as-is (they read per-task metadata,
     # e.g. DTD's woven argspec) can still hand a device module their pure
     # body by providing BOTH of: ``batch_sig(task) -> hashable`` — a key

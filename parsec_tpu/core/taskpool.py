@@ -53,6 +53,8 @@ class SuccessorRef:
     src_flow: Optional[str] = None   # producer's flow (planners/native exec)
     reshape_spec: Any = None         # composed reshape (core/reshape.py);
                                      # resolved before the value fans out
+    element: Optional[int] = None    # which element of the producer's
+                                     # ranged flow it carries (a scatter)
 
 
 class CancelledError(RuntimeError):
@@ -88,6 +90,9 @@ class TaskClass:
     # list, inside parsec:release (Context._release_deps, stage timers
     # on); None where the list costs nothing worth a span of its own
     unfold_span: Optional[str] = None
+    # does a flow of the class hold a list of tiles, gathered or
+    # scattered element by element (dsl/ptg.py: ranged data flows)?
+    ranged: bool = False
 
     def __init__(self, name: str, tc_id: int, params: Sequence[str],
                  flows: Sequence[Flow], deps_mode: str = DEPS_COUNTER):

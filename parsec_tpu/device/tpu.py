@@ -211,6 +211,10 @@ class TPUDevice(Device):
         # operand a group shares counted once a launch (``program.ints``)
         self.stats.update(region_merges=0, int_tiles_staged=0,
                           int_bytes_staged=0)
+        # the tiles handed to this chip's programs as elements of a
+        # ranged flow's list (dsl/ptg.py), and the launches that took
+        # such a list (``program.ranged``)
+        self.stats.update(ranged_tiles_staged=0, ranged_launches=0)
         # the host's waits for the chip (``_under``) and its jitted
         # calls: seconds and waits while the stage timers are on (the
         # launches are in ``launches_by_class``); the longest of each
@@ -399,6 +403,10 @@ class TPUDevice(Device):
                 self.stats["groups_pipelined"] += bool(queued)
             else:
                 self.stats["lone_in_place"] += not held
+            if program.ranged:
+                self.stats["ranged_tiles_staged"] += \
+                    program.ranged * len(tasks)
+                self.stats["ranged_launches"] += 1
             if program.ints is not None:
                 own, shared = program.ints
                 for (n, nbytes), times in ((own, len(tasks)), (shared, 1)):
@@ -867,13 +875,22 @@ class TPUDevice(Device):
                     ints[one_launch][0] += 1
                     ints[one_launch][1] += leaf.nbytes
         ints = tuple(map(tuple, ints)) if ints[0][0] or ints[1][0] else None
+        # the tiles of a member that are elements of a ranged flow's
+        # list: operands of the launch like any other (a program a list
+        # length: the length is in the signature), donated and read off
+        # the first run one by one
+        ranged = sum(len(v) for v in values if isinstance(v, list))
 
         # equal bodies across taskpools, contexts and device modules
         # trace once: a stable fingerprint shares the program process-
         # wide; an unstable one stays with this chore
         stable, fp = compile_cache.function_fingerprint(
             hook if stacked else body)
-        shared = ("tpu_program", fp, stacked, reads, tuple(given), *slot) \
+        # what the body's kernels ask of the chip's compiler
+        # (``Chore.compiler_options``; the CPU platform knows none)
+        options = chore.compiler_options if self.platform == "tpu" else None
+        shared = ("tpu_program", fp, stacked, reads, tuple(given),
+                  tuple(sorted((options or {}).items())), *slot) \
             if stable else None
         sizes = self._sizes(zip((f.name for f in flows), values), chore)
         if not stacked:
@@ -889,12 +906,11 @@ class TPUDevice(Device):
                 fn.__name__ = fn.__qualname__ = "parsec_%s_x%d" % (
                     re.sub(r"\W", "_", getattr(task.task_class, "name",
                                                 "task")), size)
-                if shared is None:
-                    fn = jax.jit(fn, donate_argnums=donated(size))
-                else:
-                    fn = compile_cache.cached_jit(
-                        fn, key=(*shared, size), persist=False,
-                        donate_argnums=donated(size))
+                def jit(f, gives=donated(size)):
+                    return jax.jit(f, donate_argnums=gives,
+                                   compiler_options=options)
+                fn = jit(fn) if shared is None else compile_cache.cached_jit(
+                    fn, key=(*shared, size), persist=False, jit_wrapper=jit)
                 # a shared program this module has yet to run compiles
                 # for its chip now (an unshared one is new), and its
                 # first run says what a launch of it holds anew: the
@@ -917,5 +933,6 @@ class TPUDevice(Device):
                         if x.unsafe_buffer_pointer() not in given_to)
                 fn.donated_at = donated_at
                 fn.ints = ints
+                fn.ranged = ranged
                 programs[size] = fn
         return programs

@@ -20,10 +20,27 @@ way the reference's iterators_checker PINS module does at runtime.
 Dependency counting uses the mask strategy with one bit per consumer flow
 (a JDF flow has exactly one active input dependency per task instance, so
 flow-granular bits are sufficient and duplicate activations are caught —
-reference mask mode, parsec.c:1601). Exception: classes with a CTL-gather
+reference mask mode, parsec.c:1601). Exception: classes with a gathered
 flow (``In(gather=True)``) use counter mode — N producers feed one flow,
 so the per-flow bit cannot count them and duplicate detection is traded
 away exactly as in the reference's counter mode (parsec.c:1554).
+
+**Ranged data flows.** A flow's value may be an ordered LIST of tiles: a
+panel task of an LU with partial pivoting reads and rewrites a whole
+block column. One rule, four forms: ``In(src=..., gather=True)`` on a
+data flow (the list of its producers' values, in the order ``params_fn``
+names them, whatever order they complete in), ``In(data=...,
+gather=True)`` (a list of collection tiles), ``Out(dst=...,
+scatter=True)`` (element i to the consumers ``params_fn``'s entry i
+names) and ``Out(data=..., scatter=True)`` (element i written back to
+tile i). An element is one activation; the consumer is scheduled once,
+when its count is met. The tiles of a ranged flow are operands of the
+task's launch like any other flow's (``device/tpu.py`` flattens the
+list), which is why they travel as values and are not reached through
+the collection behind the scheduler's back, as upstream's
+``zgetrf_1d.jdf`` bodies do under CTL dependencies: donation
+(``Chore.donates``), what a launch holds, read staging and the race
+sanitizer see every one of them.
 
 Example (tiled Cholesky's POTRF class)::
 
@@ -50,6 +67,7 @@ Example (tiled Cholesky's POTRF class)::
 
 from __future__ import annotations
 
+import itertools
 import types
 import weakref
 from dataclasses import dataclass, field
@@ -85,11 +103,19 @@ class In:
     this consumer's datatype/layout — the JDF ``[type = ...]`` annotation
     (reshape promises, parsec_reshape.c).
 
-    ``gather=True`` (CTL flows only): ``src``'s params_fn returns a LIST
-    of producer coordinates and the flow waits for ALL of them — the
-    reference's CTL-gather fan-in (tests/dsl/ptg/controlgather/
-    ctlgat.jdf, PARSEC_HAS_CTL_GATHER). A class with a gather flow uses
-    counter-mode dependency tracking.
+    ``gather=True``: ``src``'s params_fn returns a LIST of producer
+    coordinates and the flow waits for ALL of them. On a CTL flow that is
+    the reference's CTL-gather fan-in (tests/dsl/ptg/controlgather/
+    ctlgat.jdf, PARSEC_HAS_CTL_GATHER; a coordinate named twice counts
+    once). On a data flow the task's value for the flow is the ordered
+    list of the producers' values: element i is what the i-th coordinate
+    of the list sent, whatever order they completed in; a producer that
+    sends several elements (a scatter) is named once for each, and they
+    fill its places in the order it scattered them. With ``data=`` the
+    function returns the list of ``(collection, key)`` and the value is
+    the list of those tiles, each read as a single tile is. A flow that
+    gathers does so in every one of its ins. A class with a gathered
+    flow uses counter-mode dependency tracking.
     """
     src: Optional[Tuple[str, Callable, str]] = None
     data: Optional[Callable] = None
@@ -118,12 +144,22 @@ class Out:
     part of the tile and is merged into the tile the collection holds,
     where it lies; the pool orders the merge after the readers of the
     tile's other part.
+
+    ``scatter=True``: the flow's value is a list (a ranged flow), and
+    this dep hands it out element by element. ``dst``'s params_fn returns
+    a list as long as the value: entry i names who gets element i — one
+    coordinate tuple, a list of them (element i goes to each), or an
+    empty list (nobody). ``data`` returns a list as long as the value of
+    ``(collection, key)``, element i written back to tile i (None: not
+    written). A length that differs from the value's raises at the task.
+    Without it a ranged ``dst`` still broadcasts ONE value.
     """
     dst: Optional[Tuple[str, Callable, str]] = None
     data: Optional[Callable] = None
     guard: Optional[Callable] = None
     reshape: Optional[Any] = None
     region: Optional[Any] = None
+    scatter: bool = False
 
     def active(self, g, params) -> bool:
         return self.guard is None or bool(self.guard(g, *params))
@@ -145,6 +181,34 @@ class FlowSpec:
     outs: List[Out] = field(default_factory=list)
     tile: Optional[Callable] = None
 
+    @property
+    def ranged(self) -> bool:
+        """Is the flow's value a list of tiles (a gathered data flow, or
+        one that is scattered)? Its ``tile`` then names the list."""
+        return not self.access & FlowAccess.CTL and (
+            any(d.gather for d in self.ins) or
+            any(d.scatter for d in self.outs))
+
+
+class _AnyList:
+    """:func:`check_taskpool`'s stand-in for a ranged flow's value: as
+    long as whatever it is scattered over."""
+
+    def __iter__(self):
+        return itertools.repeat(0)
+
+
+_ANY = _AnyList()
+
+
+def _coords(targets) -> List[Tuple[int, ...]]:
+    """One coordinate tuple or a list of them (a generator too), as the
+    list of tuples: the Out-dst convention."""
+    if isinstance(targets, tuple):
+        return [targets]
+    return [tuple(x) if isinstance(x, (tuple, list)) else (x,)
+            for x in targets]
+
 
 class PTGTaskClass(TaskClass):
     """Task class built from closed-form flow specs."""
@@ -161,15 +225,27 @@ class PTGTaskClass(TaskClass):
                 raise ValueError(
                     f"{name}.{s.name}: a region belongs to a write-back "
                     f"(Out(data=...)): a value between tasks travels whole")
+            ctl = bool(s.access & FlowAccess.CTL)
             for d in s.ins:
-                if d.gather and not (s.access & FlowAccess.CTL):
-                    raise ValueError(
-                        f"{name}.{s.name}: gather ins are CTL-only (data "
-                        f"fan-in needs one flow per producer)")
-                if d.gather and d.src is None:
+                if d.gather and d.src is None and (ctl or d.data is None):
                     raise ValueError(
                         f"{name}.{s.name}: gather requires a src "
-                        f"producer list")
+                        f"producer list" + ("" if ctl else
+                                            " or a data tile list"))
+            if not ctl and len({d.gather for d in s.ins}) > 1:
+                raise ValueError(
+                    f"{name}.{s.name}: a data flow that gathers a list "
+                    f"gathers in every one of its ins (its value is a "
+                    f"list or a tile, not one or the other by guard)")
+            if s.ranged and any(d.reshape is not None
+                                for d in (*s.ins, *s.outs)):
+                raise ValueError(
+                    f"{name}.{s.name}: a ranged flow (gather/scatter) "
+                    f"takes no reshape: its value is a list of tiles")
+            if ctl and any(d.scatter for d in s.outs):
+                raise ValueError(
+                    f"{name}.{s.name}: a CTL flow carries nothing to "
+                    f"scatter (a ranged dst already reaches many tasks)")
         # gather fan-in needs counting, not one-bit-per-flow masking
         mode = DEPS_COUNTER if any(d.gather for s in specs
                                    for d in s.ins) else DEPS_MASK
@@ -185,10 +261,22 @@ class PTGTaskClass(TaskClass):
         self.spec_list = specs
         self.space = space
         self.affinity = affinity
+        # ranged data flows: the deps that hand a flow's value on whole
+        # (``iterate_successors``) and those that hand out a list's
+        # elements (``_scattered``); the values gathered so far for the
+        # tasks of this class that wait for a list (``_part``)
+        self.ranged = any(s.ranged for s in specs)
+        self._whole = {s.name: [d for d in s.outs if not d.scatter]
+                       for s in specs}
+        self._scatters = [(self.flow_by_name[s.name], d) for s in specs
+                          for d in s.outs if d.scatter]
+        self._parts: Dict[Tuple[str, Tuple[int, ...]], Tuple] = {}
         # the tile a task of this class writes (``written_tile``): its
-        # first written flow's, else the one its affinity names
+        # first written flow's (the first of them where the flow is
+        # ranged), else the one its affinity names
         self._written = next(
-            (s.tile for s in specs if s.tile is not None and
+            ((lambda g, *p, _tiles=s.tile: _tiles(g, *p)[0]) if s.ranged
+             else s.tile for s in specs if s.tile is not None and
              s.access & FlowAccess.WRITE and
              not s.access & FlowAccess.CTL), affinity)
         if priority is not None:
@@ -214,17 +302,21 @@ class PTGTaskClass(TaskClass):
     def body(self, fn: Callable = None, device: DeviceType = DeviceType.ALL,
              evaluate: Optional[Callable] = None, batchable: bool = True,
              batch_hook: Optional[Callable] = None,
-             batch_hook_shared=None, donates=None):
+             batch_hook_shared=None, donates=None,
+             compiler_options=None):
         """Attach an incarnation (JDF ``BODY [type=...] ... END``).
         ``batch_hook``/``batch_hook_shared``: optional hand-batched form
         for the compiled executor; ``donates``: the RW flows a chip
-        module may update where they lie (see core.task.Chore)."""
+        module may update where they lie (every tile of a ranged flow's
+        list); ``compiler_options``: what its kernels need of the chip's
+        compiler (see core.task.Chore)."""
         def deco(f):
             self.add_chore(Chore(device, f, evaluate=evaluate,
                                  batchable=batchable,
                                  batch_hook=batch_hook,
                                  batch_hook_shared=batch_hook_shared,
-                                 donates=donates))
+                                 donates=donates,
+                                 compiler_options=compiler_options))
             return f
         return deco(fn) if fn is not None else deco
 
@@ -270,10 +362,12 @@ class PTGTaskClass(TaskClass):
                 dep = self._active_in(g, self.specs[f.name], locals)
                 if dep is None or dep.src is None:
                     continue
-                if dep.gather:
-                    count += len(self._coord_set(dep.src[1](g, *locals)))
-                else:
+                if not dep.gather:
                     count += 1
+                elif f.is_ctl:
+                    count += len(self._coord_set(dep.src[1](g, *locals)))
+                else:       # one activation an element, named or not
+                    count += len(_coords(dep.src[1](g, *locals)))
             self._goal_cache[key] = count
             return count
         mask = 0
@@ -294,38 +388,137 @@ class PTGTaskClass(TaskClass):
             if dep is None:
                 continue
             if dep.data is not None:
-                dc, key = dep.data(g, *task.locals)
-                value = dc.data_of(key)
-                ctx = task.taskpool.context
-                if ctx is not None:
-                    san = ctx.dfsan
-                    if san is not None:
-                        # race-checked: a collection read unordered with
-                        # a terminal writer of the same tile observes a
-                        # schedule-dependent version (analysis/dfsan.py)
-                        san.observe_read(task, dc, key)
-                    # stage-through: the collection keeps the device
-                    # copy so one H2D serves every reader (Context.
-                    # stage_read; no-op without an accelerator)
-                    value = ctx.stage_read(dc, key, value)
+                where = dep.data(g, *task.locals)
+                if dep.gather:      # a list of tiles, each read as one
+                    value = [self._read_tile(task, dc, key)
+                             for dc, key in where]
+                else:
+                    value = self._read_tile(task, *where)
             elif dep.new is not None:
                 value = dep.new(g, *task.locals)
+            elif dep.gather and not f.is_ctl:
+                # the list its producers filled, every element there
+                # before its activation was counted
+                value = self._part(f.name, task.locals)[0]
+                self._parts.pop((f.name, task.locals), None)
             else:
                 continue
             if dep.reshape is not None:
                 value = dep.reshape.apply(value)
             task.data[f.name] = value
 
-    def _reshape_in(self, flow_name: str) -> bool:
-        """Does any In of this class's ``flow_name`` declare a reshape?
-        (cached — keeps the no-reshape hot path free of guard evals)"""
-        cache = self.__dict__.setdefault("_reshape_in_cache", {})
+    @staticmethod
+    def _read_tile(task: Task, dc, key):
+        """One collection tile as ``task`` reads it."""
+        value = dc.data_of(key)
+        ctx = task.taskpool.context
+        if ctx is not None:
+            san = ctx.dfsan
+            if san is not None:
+                # race-checked: a collection read unordered with
+                # a terminal writer of the same tile observes a
+                # schedule-dependent version (analysis/dfsan.py)
+                san.observe_read(task, dc, key)
+            # stage-through: the collection keeps the device
+            # copy so one H2D serves every reader (Context.
+            # stage_read; no-op without an accelerator)
+            value = ctx.stage_read(dc, key, value)
+        return value
+
+    _RESHAPES, _GATHERS = 1, 2
+
+    def _in_kind(self, flow_name: str) -> int:
+        """What a producer has to do for this class's ``flow_name``
+        beyond handing its value over: 0 nothing, ``_RESHAPES`` an In of
+        it declares a reshape, ``_GATHERS`` it is a data flow that
+        gathers a list (cached — keeps the plain hot path free of guard
+        evals and at the one test it had)."""
+        cache = self.__dict__.setdefault("_in_kind_cache", {})
         hit = cache.get(flow_name)
         if hit is None:
-            hit = any(d.reshape is not None
-                      for d in self.specs[flow_name].ins)
+            spec = self.specs[flow_name]
+            hit = self._GATHERS if spec.ranged and \
+                any(d.gather for d in spec.ins) else \
+                self._RESHAPES if any(d.reshape is not None
+                                      for d in spec.ins) else 0
             cache[flow_name] = hit
         return hit
+
+    # -- ranged flows: a list gathered, a list scattered -----------------
+    def _part(self, flow_name: str, locals: Tuple[int, ...]):
+        """``(values, places)`` of the list task ``locals`` of this class
+        gathers in ``flow_name``: the elements that have arrived, each at
+        its place, and the places of each producer in the consumer's own
+        list (``In.src``'s params_fn, in its order)."""
+        key = (flow_name, locals)
+        part = self._parts.get(key)
+        if part is None:
+            dep = self._active_in(self.g, self.specs[flow_name], locals)
+            if dep is None or not dep.gather or dep.src is None:
+                raise RuntimeError(
+                    f"{self.name}{locals}: flow {flow_name} gathers no "
+                    f"list of producers there")
+            places: Dict[Tuple[int, ...], List[int]] = {}
+            members = _coords(dep.src[1](self.g, *locals))
+            for i, coord in enumerate(members):
+                places.setdefault(coord, []).append(i)
+            # two producers may both come first: one list stays
+            part = self._parts.setdefault(
+                key, ([None] * len(members), places))
+        return part
+
+    def _put(self, flow_name: str, locals: Tuple[int, ...],
+             producer: Task, nth: int, value: Any) -> None:
+        """``producer``'s ``nth`` element for task ``locals``'s gathered
+        ``flow_name``, put at its place BEFORE its activation is counted
+        (whoever completes the count finds every element)."""
+        values, places = self._part(flow_name, locals)
+        try:
+            values[places[producer.locals][nth]] = value
+        except (KeyError, IndexError):
+            raise RuntimeError(
+                f"{self.name}{locals}.{flow_name}: the gathered list does "
+                f"not name {producer!r} {nth + 1} time(s)") from None
+
+    def _scattered(self, task: Task):
+        """The activations and write-backs of the deps that hand out a
+        ranged flow's list element by element (``Out(scatter=True)``)."""
+        g, p = self.g, task.locals
+        for f, dep in self._scatters:
+            if not dep.active(g, p):
+                continue
+            values = task.output.get(f.name, task.data.get(f.name))
+            over = (dep.data or dep.dst[1])(g, *p)
+            listed = isinstance(values, (list, tuple))
+            if values is not _ANY and not (listed and
+                                           len(values) == len(over)):
+                raise ValueError(
+                    f"{task!r}: flow {f.name} holds "
+                    f"{len(values) if listed else 'no list of'} values "
+                    f"and is scattered over {len(over)} "
+                    f"{'tiles' if dep.data else dep.dst[0] + ' targets'}")
+            if dep.data is not None:
+                for v, where in zip(values, over):
+                    if where is not None:
+                        yield DataRef(collection=where[0], key=where[1],
+                                      value=v)
+                continue
+            dst_tc = task.taskpool.task_class_by_name(dep.dst[0])
+            dst_flow = dst_tc.flow_by_name[dep.dst[2]]
+            gathers = dst_tc._in_kind(dst_flow.name) == self._GATHERS
+            sent: Dict[Tuple[int, ...], int] = {}
+            for i, (v, targets) in enumerate(zip(values, over)):
+                for tgt in _coords(targets):
+                    if gathers:
+                        nth = sent[tgt] = sent.get(tgt, -1) + 1
+                        dst_tc._put(dst_flow.name, tgt, task, nth, v)
+                    yield SuccessorRef(
+                        task_class=dst_tc, locals=tgt,
+                        flow_name=dst_flow.name,
+                        value=None if gathers else v,
+                        dep_index=dst_flow.index,
+                        priority=dst_tc.priority_fn(tgt),
+                        src_flow=f.name, element=i)
 
     def iterate_successors(self, task: Task):
         """Producer-side expansion (generated iterate_successors analog,
@@ -337,7 +530,7 @@ class PTGTaskClass(TaskClass):
             if not f.is_ctl:
                 value = task.output.get(f.name, task.data.get(f.name))
             promise = None   # one shared DataCopyFuture per produced flow
-            for dep in spec.outs:
+            for dep in self._whole[f.name]:
                 if not dep.active(g, task.locals):
                     continue
                 if dep.data is not None:
@@ -353,17 +546,22 @@ class PTGTaskClass(TaskClass):
                 if isinstance(targets, tuple):
                     targets = [targets]
                 dst_bit_flow = dst_tc.flow_by_name[dst_flow]
-                consumer_reshapes = dst_tc._reshape_in(dst_flow)
+                consumer_kind = dst_tc._in_kind(dst_flow)
                 for tgt in targets:
                     tgt = tuple(tgt) if isinstance(tgt, (tuple, list)) else (tgt,)
                     composed = None
-                    if dep.reshape is not None or consumer_reshapes:
-                        dst_in = dst_tc._active_in(
-                            g, dst_tc.specs[dst_flow], tgt)
-                        composed = compose_specs(
-                            dep.reshape,
-                            dst_in.reshape if dst_in is not None else None)
                     v = None if dst_bit_flow.is_ctl else value
+                    if dep.reshape is not None or consumer_kind:
+                        if consumer_kind == self._GATHERS:
+                            # one element of the list the consumer gathers
+                            dst_tc._put(dst_flow, tgt, task, 0, v)
+                            v = None
+                        else:
+                            dst_in = dst_tc._active_in(
+                                g, dst_tc.specs[dst_flow], tgt)
+                            composed = compose_specs(
+                                dep.reshape, dst_in.reshape
+                                if dst_in is not None else None)
                     if composed is not None and v is not None:
                         if promise is None:
                             promise = DataCopyFuture(value)
@@ -374,12 +572,15 @@ class PTGTaskClass(TaskClass):
                         dep_index=dst_bit_flow.index,
                         priority=dst_tc.priority_fn(tgt),
                         src_flow=f.name)
+        if self._scatters:
+            yield from self._scattered(task)
 
     # -- distribution -----------------------------------------------------
     def written_tile(self, task: Task):
         """``(collection, key)`` of the tile ``task`` writes (the JDF's
-        ``: descA(m, k)``): its first written flow's ``tile``, else its
-        affinity's; None where the class names neither."""
+        ``: descA(m, k)``): its first written flow's ``tile`` (the first
+        of the list where that flow is ranged), else its affinity's;
+        None where the class names neither."""
         fn = self._written
         return None if fn is None else fn(self.g, *task.locals)
 
@@ -432,6 +633,12 @@ class Taskpool(CoreTaskpool):
         ctx = self.context
         my_rank = ctx.my_rank if ctx is not None else 0
         nb_ranks = ctx.nb_ranks if ctx is not None else 1
+        if nb_ranks > 1 and taskpool_has_ranged_flows(self):
+            raise NotImplementedError(
+                f"taskpool {self.name}: a ranged data flow (gather/"
+                f"scatter) keeps the elements that have arrived with the "
+                f"consumer's class on one rank; {nb_ranks} ranks are "
+                f"not supported")
         total = 0
         ready: List[Task] = []
         for tc in self.task_classes:
@@ -470,6 +677,17 @@ def taskpool_writes_regions(tp: Taskpool) -> bool:
                for spec in tc.spec_list for d in spec.outs)
 
 
+def taskpool_has_ranged_flows(tp: Taskpool) -> bool:
+    """True if any data flow of the pool is a list of tiles
+    (``In(gather=True)`` on a data flow, ``Out(scatter=True)``). The
+    host runtime counts an element an activation and launches the list
+    as operands of one program; the compiled executors gather and
+    scatter ONE tile a flow from their stacked stores and refuse such a
+    pool, and so does a context of several ranks (an element's value is
+    kept with the consumer's class, on this rank)."""
+    return any(tc.ranged for tc in tp.task_classes)
+
+
 def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:
     """Cross-validate producer (outs) and consumer (ins) dep declarations
     by enumerating the whole space — the iterators_checker PINS module
@@ -492,7 +710,7 @@ def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:
             task = Task(tp, tc, p)
             for f in tc.flows:
                 task.data[f.name] = 0
-                task.output[f.name] = 0
+                task.output[f.name] = _ANY if tc.specs[f.name].ranged else 0
             for ref in tc.iterate_successors(task):
                 if isinstance(ref, DataRef):
                     continue
@@ -509,7 +727,7 @@ def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:
                 src_cls, src_params_fn, src_flow = dep.src
                 sp = src_params_fn(g, *ref.locals)
                 if dep.gather:
-                    members = PTGTaskClass._coord_set(sp)
+                    members = _coords(sp)
                     if src_cls != tc.name or tuple(p) not in members:
                         raise AssertionError(
                             f"{ref.task_class.name}{ref.locals}."
@@ -548,10 +766,14 @@ def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:
                 if dep is None or dep.src is None:
                     continue
                 src_cls, src_params_fn, _sf = dep.src
-                if dep.gather:
+                if dep.gather and f.is_ctl:
                     for coord in PTGTaskClass._coord_set(
                             src_params_fn(g, *p)):
                         expected[(src_cls, coord, f.name)] = 1
+                elif dep.gather:    # a producer is named once an element
+                    for coord in _coords(src_params_fn(g, *p)):
+                        key = (src_cls, coord, f.name)
+                        expected[key] = expected.get(key, 0) + 1
                 else:
                     sp = src_params_fn(g, *p)
                     sp = tuple(sp) if isinstance(sp, (tuple, list)) else (sp,)
@@ -562,3 +784,5 @@ def check_taskpool(tp: Taskpool, nb_ranks: int = 1) -> None:
                 raise AssertionError(
                     f"{tc.name}{p}: producer multiplicity mismatch — "
                     f"expected {expected}, got {got_pairs}")
+    for tc in tp.task_classes:      # the stand-in values it scattered
+        tc._parts.clear()

@@ -1036,3 +1036,150 @@ def ssssm_tile(A1, A2, W, L21, P):
         tops.append(top)
     return (jnp.concatenate(tops, axis=0).astype(A1.dtype),
             A2f.astype(A2.dtype))
+
+
+# ---- LU with partial pivoting over a whole panel (dgetrf_1d) ------------
+# The panel task factors the STACK of a block column's tiles: the pivot
+# of every column is the largest entry of the whole remaining column,
+# whichever tile holds it, so every multiplier is at most 1 over the
+# whole column and the factored form is LAPACK's dgetrf's. The pivots
+# are LAPACK's interchange indices, 0-based from the panel's first row:
+# at step j rows j and ipiv[j] >= j of the panel were exchanged. The
+# same three scopes split a kernel's device time as above.
+
+# XLA's ``LuDecompositionBlock`` factors a stack in scoped VMEM and takes
+# two to three times the stack's bytes of it: 48 MiB for the 32768 x 128
+# of a 16-tile panel, where the compiler's default allows 16 (a v5e core
+# has 128 MiB). A program that factors such a stack is compiled with this
+# (``Chore.compiler_options``; tests/test_panel_partition.py compiles the
+# tallest for a described v5e).
+PANEL_COMPILER_OPTIONS = {"xla_tpu_scoped_vmem_limit_kib": 65536}
+
+
+def _swap_moves(piv, n: int):
+    """LAPACK's interchanges j <-> ``piv[j]`` (j = 0..n-1 in turn,
+    ``piv[j]`` >= j, rows of the stack they act on) as row moves, without
+    a loop over the steps: ``(top, low, frm)`` with row j of the result
+    (j < n) the stack's row ``top[j]``, and its row ``low[j]`` the
+    stack's row ``frm[j]`` where ``low[j]`` >= 0 (-1: no move; a row
+    under the first n that is exchanged several times is written by the
+    last). ``frm`` < n always: what goes down comes from the first n
+    rows. The row that lies at position j when step j begins came there
+    by a chain of earlier exchanges with positions under n; the chains
+    are followed by pointer doubling."""
+    j = jnp.arange(n, dtype=_I32)
+    earlier = j[None, :] < j[:, None]
+    # the last step before j that exchanged position j with its own
+    came = jnp.max(jnp.where((piv[None, :] == j[:, None]) & earlier,
+                             j[None, :], -1), axis=1)
+    lies = jnp.where(came >= 0, came, j)
+    for _ in range(max(n - 1, 1).bit_length()):
+        lies = lies[lies]
+    same = piv[None, :] == piv[:, None]
+    prev = jnp.max(jnp.where(same & earlier, j[None, :], -1), axis=1)
+    top = jnp.where(prev >= 0, lies[jnp.maximum(prev, 0)], piv)
+    last = ~jnp.any(same & (j[None, :] > j[:, None]), axis=1)
+    return top, jnp.where((piv >= n) & last, piv, -1), lies
+
+
+def _stack_swap(S, piv, o, n: int):
+    """The interchanges ``piv`` (relative to row ``o``, which may be
+    traced) applied to the rows of the stack ``S`` from ``o`` on: 2n rows
+    move, the stack stays where it lies."""
+    top, low, frm = _swap_moves(piv, n)
+    head = jax.lax.dynamic_slice_in_dim(S, o, n, axis=0)
+    new_head = S[o + top]
+    S = S.at[jnp.where(low >= 0, o + low, S.shape[0])].set(
+        head[frm], mode="drop")
+    return jax.lax.dynamic_update_slice_in_dim(S, new_head, o, axis=0)
+
+
+def getrf_panel_tiles(tiles, ib: int):
+    """GETRF of dgetrf_1d: P·[tiles stacked] = L·U by partial pivoting
+    over the whole stack, ``ib`` columns at a time -> (the tiles with U
+    in the first one's upper triangle and the multipliers, each at most
+    1 in size, under it and in the others; LAPACK's interchange indices,
+    1 x nb int32, 0-based from the stack's first row). A block's
+    pivoted factorization is :func:`_block_lu`'s: the VMEM panel where
+    the stack fits it, XLA's ``lax.linalg.lu`` on the tall ones.
+
+    The inner blocks are ONE rolled loop whose body masks what the
+    blocks before have finished (the block column is handed to the
+    factorization with its finished rows zeroed and last: a zero never
+    wins a search and stays zero; the trailing product runs over the
+    whole stack with the finished rows and columns zeroed): a sixteenth
+    of the program text of sixteen blocks unrolled with their own shapes
+    (8 MiB against 73 for a 16-tile panel, one such program a list
+    length) and a quarter of the compile time, for twice the panel's
+    own products, which are a twentieth of the factorization's (PERF.md
+    section 6, PR 43)."""
+    nb, n = tiles[0].shape[0], len(tiles)
+    S = jnp.concatenate([jnp.asarray(t, _F32) for t in tiles], axis=0) \
+        if n > 1 else jnp.asarray(tiles[0], _F32)
+    r = jnp.arange(S.shape[0], dtype=_I32)[:, None]
+    c = jnp.arange(nb, dtype=_I32)[None, :]
+
+    def block(b, carry):
+        S, pivs = carry
+        o = b * ib
+        with jax.named_scope("parsec:lu_pivot"):
+            col = jax.lax.dynamic_slice_in_dim(S, o, ib, axis=1)
+            lu, piv = _block_lu(jnp.roll(jnp.where(r >= o, col, 0.0), -o,
+                                         axis=0))
+            piv = piv.astype(_I32)
+        pivs = jax.lax.dynamic_update_slice(pivs, piv + o, (o,))
+        with jax.named_scope("parsec:lu_swap"):
+            S = _stack_swap(S, piv, o, ib)
+        back = jnp.roll(lu, o, axis=0)
+        S = jax.lax.dynamic_update_slice_in_dim(
+            S, jnp.where(r >= o, back, jax.lax.dynamic_slice_in_dim(
+                S, o, ib, axis=1)), o, axis=1)
+        with jax.named_scope("parsec:lu_update"):
+            right = c >= o + ib
+            top = jax.lax.dynamic_slice_in_dim(S, o, ib, axis=0)
+            u12 = jnp.where(right, _unit_lower_solve(lu[:ib], top), 0.0)
+            S = jax.lax.dynamic_update_slice_in_dim(
+                S, jnp.where(right, u12, top), o, axis=0)
+            S = S - _mmh(jnp.where(r >= o + ib, back, 0.0), u12)
+        return S, pivs
+
+    S, pivs = jax.lax.fori_loop(0, nb // ib, block,
+                                (S, jnp.zeros((nb,), _I32)))
+    return ([S[t * nb:(t + 1) * nb].astype(tiles[t].dtype)
+             for t in range(n)], pivs[None, :])
+
+
+def laswp_tiles(tiles, ipiv):
+    """LAPACK's ``dlaswp`` over the stack of ``tiles``: the interchanges
+    ``ipiv`` (1 x nb int32, as :func:`getrf_panel_tiles` left them)
+    applied to the stack's rows (:func:`_stack_swap`: the nb rows that
+    come to the first tile are gathered from wherever they lie, the rows
+    that leave it, all from the first tile, are scattered to where they
+    go), and the stack cut into its tiles again."""
+    nb, r = tiles[0].shape[0], len(tiles)
+    with jax.named_scope("parsec:lu_swap"):
+        S = _stack_swap(jnp.concatenate(tiles, axis=0) if r > 1
+                        else tiles[0], ipiv[0].astype(_I32), 0, nb)
+        return [S[t * nb:(t + 1) * nb] for t in range(r)]
+
+
+@jax.jit
+def swptrsm_tiles(L, ipiv, tiles):
+    """SWPTRSM of dgetrf_1d: the panel's interchanges applied to a block
+    column's ``tiles``, then its first tile <- L⁻¹ · it for the unit
+    lower triangle of ``L`` (the panel's diagonal tile)."""
+    out = laswp_tiles(tiles, ipiv)
+    with jax.named_scope("parsec:lu_update"):
+        out[0] = _unit_lower_solve(
+            jnp.asarray(L, _F32), jnp.asarray(out[0], _F32)).astype(
+                tiles[0].dtype)
+    return out
+
+
+@jax.jit
+def gemm_full_tile(A, B, C):
+    """C − A·B at full float32 whatever ``ops.matmul_precision`` says:
+    a pivoted LU's trailing update (see the note above on growth)."""
+    with jax.named_scope("parsec:lu_update"):
+        return (jnp.asarray(C, _F32) - _mmh(
+            jnp.asarray(A, _F32), jnp.asarray(B, _F32))).astype(C.dtype)

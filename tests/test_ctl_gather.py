@@ -146,13 +146,28 @@ def test_gather_bare_tuple_is_one_coordinate():
     assert PTGTaskClass._coord_set([1, 2]) == {(1,), (2,)}
 
 
-def test_gather_on_data_flow_rejected():
+def test_gather_on_a_data_flow_is_a_list_since_pr_43():
+    """Until PR 43 a gather was CTL-only ("data fan-in needs one flow per
+    producer"); a data flow that gathers now holds the ordered list of
+    its producers' values (tests/test_ptg_ranged_flows.py), and a CTL
+    gather still counts a coordinate named twice once."""
     store = LocalCollection("S", {(0,): 0})
-    tp = ptg.Taskpool("bad", S=store)
-    with pytest.raises(ValueError, match="CTL-only"):
+    tp = ptg.Taskpool("lists", S=store)
+    tc = tp.task_class(
+        "B", params=("i",), space=lambda g: ((0,),),
+        flows=[ptg.FlowSpec(
+            "X", ptg.RW,
+            ins=[ptg.In(src=("B", lambda g, i: [(0,), (0,)], "X"),
+                        gather=True)]),
+            ptg.FlowSpec(
+            "C", ptg.CTL,
+            ins=[ptg.In(src=("B", lambda g, i: [(0,), (0,)], "C"),
+                        gather=True)])])
+    assert tc.ranged and tc.deps_mode == "counter"
+    assert tc.deps_goal((0,)) == 2 + 1      # two elements, one CTL
+    with pytest.raises(ValueError, match="gather requires a src"):
         tp.task_class(
-            "B", params=("i",), space=lambda g: ((0,),),
+            "D", params=("i",), space=lambda g: ((0,),),
             flows=[ptg.FlowSpec(
-                "X", ptg.RW,
-                ins=[ptg.In(src=("B", lambda g, i: [(0,)], "X"),
-                            gather=True)])])
+                "X", ptg.RW, ins=[ptg.In(new=lambda g, i: 0,
+                                         gather=True)])])

@@ -417,6 +417,38 @@ def test_on_the_chip_a_tstrf_blocks_stack_is_factored_by_one_mosaic_call(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_on_the_chip_a_sixteen_tile_panel_is_factored_where_it_lies(
+        v5e_2x2, monkeypatch):
+    """``ops/tile_kernels.py getrf_panel_tiles`` at the panel-pivoted LU
+    cell's tallest shape (sixteen 2048-tiles, IB = 128), compiled by the
+    TPU compiler: XLA's ``LuDecompositionBlock`` factors the 32768 x 128
+    block in scoped VMEM and is REFUSED at the compiler's default of 16
+    MiB ("Ran out of memory in memory space vmem ... size 32.13M and
+    limit 16.00M"; two minutes of a test to see it refused, so not
+    compiled here), which is why the panel's chore states what it needs
+    (``Chore.compiler_options`` = ``PANEL_COMPILER_OPTIONS``); with it
+    the program is one rolled loop (under 16 MiB of text, one such
+    program a list length), holds one copy of the stack beside the tiles
+    and writes every tile where it was given (all 256 MiB aliased)."""
+    from jax.sharding import SingleDeviceSharding
+    from parsec_tpu.ops import tile_kernels
+    from parsec_tpu.utils import jax_platform
+    monkeypatch.setattr(jax_platform, "cpu_requested", lambda: False)
+    one = SingleDeviceSharding(v5e_2x2.devices[0])
+    tiles = [jax.ShapeDtypeStruct((2048, 2048), np.float32, sharding=one)
+             for _ in range(16)]
+    compiled = jax.jit(
+        lambda ts: tile_kernels.getrf_panel_tiles(ts, 128),
+        donate_argnums=0).lower(tiles).compile(
+            compiler_options=tile_kernels.PANEL_COMPILER_OPTIONS)
+    assert "LuDecomposition" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    stack = 16 * 2048 * 2048 * 4
+    assert mem.alias_size_in_bytes == stack
+    assert mem.temp_size_in_bytes < 1.1 * stack
+    assert mem.generated_code_size_in_bytes < 16 << 20
+
+
 # ---------------------------------------------------------------------------
 # the store key
 # ---------------------------------------------------------------------------
