@@ -28,13 +28,16 @@ points a user calls, at full width, cheapest phase first:
             runs it at the cell's size): per IB the time of a second step
             and the readings of the factored form against the plain
             reference, pivots on the chip, every task on the tpu0 module
-  lu_panel  TSTRF's block factorization alone, on no cell's path: the VMEM
+  lu_panel  a block's factorization alone, on no cell's path: the VMEM
             panel kernel (ops/tile_kernels.py _lu_panel) and XLA's
             lax.linalg.lu, each jitted by itself, on float32 stacks of
-            256, 640, 1152, 2176 rows x 128 columns and XLA's on 2176 x
-            32 and 64: device microseconds a call and a pivot step from
-            a trace, the same interchanges from both, and the gate the
-            kernel was sent under (under 0.75 x XLA's time at 2176 x 128)
+            256, 640, 1152, 2176 rows x 128 columns (TSTRF's) and 4096,
+            8192, 17408, 32768 (dgetrf_1d's panels: the tall kernel over
+            4096, at 32768 rows with zero rows under the stack)
+            and XLA's on 2176 x 32 and 64: device microseconds a
+            call and a pivot step from a trace, the same interchanges
+            from both, and the gate the kernel was sent under (under
+            0.75 x XLA's time at 2176 x 128 and at every tall height)
   panels    GEMM, GEQRF, GETRF panel programs at NB=1024, N=8192, residuals
   flagship  build_potrf_left -> plan_taskpool -> PanelExecutor, N=40960,
             NB=1024, potrf.trsm_hook=gemm, input generated on device, three
@@ -83,7 +86,8 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
             qr_host=dict(n=8192, nb=2048),
             lu_host=dict(n=8192, nb=2048, ibs=(128, 256, 512)),
-            lu_panel=dict(rows=(256, 640, 1152, 2176), widths=(32, 64)),
+            lu_panel=dict(rows=(256, 640, 1152, 2176, 4096, 8192, 17408,
+                                32768), widths=(32, 64), gate=2176),
             panels=(8192, 1024), flagship=(40960, 1024),
             flash=dict(S=16384, H=4, dh=128, F=2048),
             block=dict(H=2, T=2, TS=1024, DH=128, F=512),
@@ -91,7 +95,7 @@ FULL = dict(dtd=(2048, 512), potrf_host=(4096, 512),
 DRY = dict(dtd=(256, 64), potrf_host=(256, 64),
            qr_host=dict(n=128, nb=32, ib=16),
            lu_host=dict(n=128, nb=32, ibs=(16,)),
-           lu_panel=dict(rows=(256,), widths=()),
+           lu_panel=dict(rows=(256, 4224), widths=(), gate=256),
            panels=(256, 64), flagship=(256, 64),
            flash=dict(S=256, H=2, dh=16, F=64),
            block=dict(H=2, T=2, TS=64, DH=16, F=64),
@@ -608,36 +612,42 @@ def _device_us(calls, reps=10):
 
 
 def phase_lu_panel(sz, on_chip):
-    """A TSTRF block's factorization by itself: the VMEM panel kernel
-    against XLA's LU on stacks [upper-triangular; dense], the sweep that
-    split XLA's time a pivot step into a fixed and a per-row part and
-    the gate the kernel was sent under (PERF.md section 6, PR 41). Kept
+    """A block's factorization by itself: the VMEM panel kernel against
+    XLA's LU on stacks [upper-triangular; dense], the sweep that split
+    XLA's time a pivot step into a fixed and a per-row part and the gate
+    the kernel was sent under (PERF.md section 6, PRs 41 and 45). Kept
     for the next jax upgrade; no cell runs this phase's programs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from parsec_tpu.ops.tile_kernels import _lu_panel, _lu_panel_takes
+    from parsec_tpu.ops.tile_kernels import (PANEL_COMPILER_OPTIONS,
+                                             _lu_panel, _lu_panel_takes,
+                                             _lu_panel_tall)
 
     def xla_lu(stack):
         lu, piv, _ = jax.lax.linalg.lu(stack)
         return lu, piv
 
-    def jitted(fn, name):
+    def jitted(fn, name, **options):
         def call(stack):
             return fn(stack)
         call.__name__ = name
-        return jax.jit(call)
+        return jax.jit(call, **options)
 
+    # XLA's kernel on a tall stack needs the panel's scoped VMEM
+    xla_options = dict(compiler_options=PANEL_COMPILER_OPTIONS) \
+        if on_chip else {}
     rng = np.random.default_rng(20261004)
-    rows, full = sz["lu_panel"]["rows"], 128
+    rows, gate, full = sz["lu_panel"]["rows"], sz["lu_panel"]["gate"], 128
     shapes = [(r, full) for r in rows] + \
-        [(max(rows), w) for w in sz["lu_panel"]["widths"]]
+        [(gate, w) for w in sz["lu_panel"]["widths"]]
     calls = {}
     for r, w in shapes:
         stack = jnp.asarray(np.concatenate(
             [np.triu(rng.uniform(-0.5, 0.5, (w, w))),
              rng.uniform(-0.5, 0.5, (r - w, w))]).astype(np.float32))
-        calls[f"lu_xla_{r}x{w}"] = (jitted(xla_lu, f"lu_xla_{r}x{w}"), stack)
+        calls[f"lu_xla_{r}x{w}"] = (
+            jitted(xla_lu, f"lu_xla_{r}x{w}", **xla_options), stack)
         if _lu_panel_takes(r, w, stack.dtype):
             calls[f"lu_panel_{r}x{w}"] = (
                 jitted(_lu_panel, f"lu_panel_{r}x{w}"), stack)
@@ -658,16 +668,21 @@ def phase_lu_panel(sz, on_chip):
 
     for r, w in shapes:
         xla, panel = (us.get(f"lu_{k}_{r}x{w}") for k in ("xla", "panel"))
+        taken = f"lu_panel_{r}x{w}" in calls
         say("lu_panel", stack=f"{r}x{w}", xla_us=shown(xla, 1),
             xla_us_per_pivot_step=shown(xla and xla / w, 3),
-            panel_us=shown(panel, 1) if f"lu_panel_{r}x{w}" in calls
+            panel=("tall" if _lu_panel_tall(r) else "packed") if taken
             else "none",
+            panel_us=shown(panel, 1) if taken else "none",
+            panel_us_per_pivot_step=shown(panel and panel / w, 3)
+            if taken else "none",
             panel_over_xla=shown(xla and panel and panel / xla, 3))
     if on_chip:
-        r = max(rows)
-        ratio = us[f"lu_panel_{r}x{full}"] / us[f"lu_xla_{r}x{full}"]
-        require(ratio < 0.75, f"the panel takes {ratio:.2f} of XLA's time "
-                f"at {r}x{full}: the gate it was sent under is 0.75")
+        for r in [gate] + [r for r in rows if _lu_panel_tall(r)]:
+            ratio = us[f"lu_panel_{r}x{full}"] / us[f"lu_xla_{r}x{full}"]
+            require(ratio < 0.75, f"the panel takes {ratio:.2f} of XLA's "
+                    f"time at {r}x{full}: the gate it was sent under is "
+                    "0.75")
 
 
 def _panel_run(ex, state):

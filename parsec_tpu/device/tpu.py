@@ -243,13 +243,13 @@ class TPUDevice(Device):
 
     def dump_statistics(self) -> Dict:
         """The module's counters and, beside them, which factorization
-        TSTRF's blocks were traced through (``ops.tile_kernels``: the
-        VMEM panel or XLA's LU; the process's traces, not this chip's
-        launches)."""
+        the pivoted LUs' blocks were traced through (``ops.tile_kernels``:
+        the VMEM panel, its tall form over 4096 rows, or XLA's LU; the
+        process's traces, not this chip's launches)."""
         from ..ops.tile_kernels import LU_BLOCKS_TRACED
         return dict(super().dump_statistics(),
-                    lu_blocks_vmem_panel=LU_BLOCKS_TRACED["vmem_panel"],
-                    lu_blocks_xla_lu=LU_BLOCKS_TRACED["xla_lu"])
+                    **{f"lu_blocks_{k}": n
+                       for k, n in LU_BLOCKS_TRACED.items()})
 
     def execute(self, es, task: Task, chore: Chore) -> HookReturn:
         """``task`` alone: a launch of one from the body's table, or the

@@ -704,8 +704,9 @@ def tsmqr_tile(V2, T, C1, C2):
 # TSTRF, where the stack [the block's ib rows of U; the lower tile's
 # column block] is float32 with ib a multiple of 128 (the cell's 2176 x
 # 128), a panel kernel of this module that keeps the stack in VMEM from
-# the first pivot step to the last (``_lu_panel``, below); at any other
-# shape, and for GETRF's whole tile, XLA's ``lax.linalg.lu``. Both give
+# the first pivot step to the last (``_lu_panel``, below; dgetrf_1d's
+# panels take it too, up to 32768 x 128); at any other shape, and for
+# GETRF's whole tile, XLA's ``lax.linalg.lu``. Both give
 # the same factors and the same interchanges (the lowest index among
 # equal magnitudes); which one a block was traced through is counted in
 # ``LU_BLOCKS_TRACED``. What is applied to the columns to the right
@@ -814,23 +815,44 @@ def _unit_lower_inverse(L):
 # it holds; the interchanges are applied once, to the factored stack in
 # its natural layout, where a row is a sublane and an exchange two loads
 # and two stores.
+#
+# Over 4096 rows (the panel-pivoted LU's stacks: 6144 to 32768 x 128,
+# where XLA's kernel takes 11.2 us a pivot step at the mean height) the
+# stack is held the other way round (``_lu_panel_tall_kernel``):
+# ``t[k nb8 + b, i]`` = entry (128 b + i, k), the blocks b padded to nb8,
+# a multiple of 8. A column of the stack is then nb8 / 8 whole vregs, not
+# nb8 vregs of one sublane each: the search and the multipliers cost an
+# eighth, a step's update is one (nb8, 128) slab a column minus the
+# multipliers times a number, and the pivot row is one strided load (a
+# sublane of every column's slab) and a lane gather. Positions and rows
+# go to 32767, 15 bits each, which one float32 cannot hold together: the
+# lowest position among the largest magnitudes is a reduction of its own
+# (exact: 15 bits), and the row there and its sign a third (a maximum and
+# a minimum, issued together). Every loop over the blocks is rolled and
+# the vregs of a reduction are halved pairwise, so what is traced and
+# lowered does not grow with the stack; every tall stack is factored at
+# the panel's cap (``_lu_panel_height``), so that a process lowers one
+# such kernel and not one a panel height.
 
 _LANES = 128
 # position and row of a candidate in one number (the lowest position
 # among equal magnitudes is one reduction): 12 bits each, so that a
 # float32 holds it exactly (a v5e reduces floats across lanes in half
 # the time it takes for integers) and has its sign bit left for the
-# entry's
+# entry's; a taller stack takes ``_lu_panel_tall_kernel``
 _PACK_BITS = 12
 _PACK = 1 << _PACK_BITS
 # rows x columns x 4 bytes the panel takes: input, output and the
-# transposed copy are in VMEM at once (a v5e core has 128 MiB)
+# transposed copy are in VMEM at once (a v5e core has 128 MiB); the
+# packed kernel keeps PR 41's 8 MiB, the tall one the 16 MiB of a 32768
+# x 128 stack, a 16-tile panel's
 _PANEL_BYTES = 8 << 20
+_TALL_BYTES = 16 << 20
 
-# how many blocks ``tstrf_tile`` was TRACED with through each
-# factorization (the choice is made from the stack's shape at trace
-# time, so these count traces, not launches)
-LU_BLOCKS_TRACED = {"vmem_panel": 0, "xla_lu": 0}
+# how many blocks ``tstrf_tile`` and ``getrf_panel_tiles`` were TRACED
+# with through each factorization (the choice is made from the stack's
+# shape at trace time, so these count traces, not launches)
+LU_BLOCKS_TRACED = {"vmem_panel": 0, "vmem_panel_tall": 0, "xla_lu": 0}
 
 
 def _tree(op, items):
@@ -910,6 +932,13 @@ def _lu_panel_kernel(x_ref, lu_ref, piv_ref, t_ref, u_ref, pos_ref):
     jax.lax.fori_loop(0, w, step, 0)
     for c in range(nblk):
         lu_ref[c * _LANES:(c + 1) * _LANES, :] = t_ref[c].T
+    _interchange(lu_ref, piv_ref)
+
+
+def _interchange(lu_ref, piv_ref):
+    """LAPACK's interchanges applied, in turn, to the factored stack in
+    its natural layout."""
+    pl, _ = _pallas()
 
     def interchange(j, carry):
         q = piv_ref[0, j]
@@ -918,13 +947,121 @@ def _lu_panel_kernel(x_ref, lu_ref, piv_ref, t_ref, u_ref, pos_ref):
         lu_ref[pl.ds(q, 1), :] = top
         return carry
 
-    jax.lax.fori_loop(0, w, interchange, 0)
+    jax.lax.fori_loop(0, lu_ref.shape[1], interchange, 0)
+
+
+def _halves(op, v):
+    """``op`` over the (8, 128) vregs of ``v`` (n x 8 rows): the halves
+    pairwise, log2(n) deep, down to one vreg."""
+    while v.shape[0] > 8:
+        if v.shape[0] // 8 % 2:
+            v = jnp.concatenate([op(v[:8], v[-8:]), v[8:-8]], axis=0)
+        h = v.shape[0] // 2
+        v = op(v[:h], v[h:])
+    return v
+
+
+def _lu_panel_tall_kernel(x_ref, lu_ref, piv_ref, t_ref, u_ref, pos_ref,
+                          rid_ref):
+    pl, _ = _pallas()
+    rows, w = x_ref.shape
+    nb8 = pos_ref.shape[0]
+
+    def column(k):                               # column k of the stack
+        return pl.ds(pl.multiple_of(k * nb8, 8), nb8)
+
+    def into(b, carry):
+        t_ref[pl.ds(b, w, stride=nb8), :] = x_ref[
+            pl.ds(pl.multiple_of(b * _LANES, _LANES), _LANES), :].T
+        return carry
+
+    jax.lax.fori_loop(0, rows // _LANES, into, 0)
+    row = (jax.lax.broadcasted_iota(_I32, (nb8, _LANES), 0) * _LANES
+           + jax.lax.broadcasted_iota(_I32, (nb8, _LANES), 1))
+    # positions and rows as float32 (exact to 2^24): float reductions;
+    # the padding's position is -1, never a candidate nor position j
+    pos_ref[...] = jnp.where(row < rows, row, -1).astype(_F32)
+    rid_ref[...] = (row + 1).astype(_F32)
+    col = jax.lax.broadcasted_iota(_I32, (w, _LANES), 0)
+
+    def over(op, reduce, v):
+        return reduce(_halves(op, v), axis=(0, 1), keepdims=True)
+
+    def step(j, carry):
+        jf = j.astype(_F32)
+        pos, c = pos_ref[...], t_ref[column(j), :]
+        active = pos >= jf                       # not yet a pivot row
+        size = jnp.where(active, jnp.abs(c), -1.0)
+        top = over(jnp.maximum, jnp.max, size)
+        # the first of the largest: its position, then its row and sign
+        # (one entry holds that position: a maximum and a minimum across
+        # the lanes, issued together, say which and of what sign)
+        q = over(jnp.minimum, jnp.min,
+                 jnp.where(size == top, pos, float(rows)))
+        is_p = pos == q
+        rid = rid_ref[...]
+        mark = jnp.where(is_p, jnp.where(c < 0, -rid, rid), 0.0)
+        plus = over(jnp.maximum, jnp.max, mark)
+        minus = over(jnp.minimum, jnp.min, mark)
+        pivot = jnp.where(minus < 0, -top, top)
+        low = active & ~is_p
+        # a column of zeros keeps its zeros: no 0/0
+        mult = jnp.where(low, c / jnp.where(pivot == 0, 1.0, pivot), 0.0)
+        t_ref[column(j), :] = jnp.where(low, mult, c)
+        # the row at position j goes where the pivot row was
+        pos_ref[...] = jnp.where(is_p, jf, jnp.where(pos == jf, q, pos))
+        piv_ref[0, j] = q.astype(_I32)[0, 0]
+        at = (plus - minus).astype(_I32)[0, 0] - 1
+        # the pivot row, an entry a column, every lane the same: the
+        # rank-1 update's other factor (0 up to column j, which the
+        # update's first eight columns may hold)
+        u_ref[...] = jnp.where(col > j, jnp.take_along_axis(
+            t_ref[pl.ds(at // _LANES, w, stride=nb8), :],
+            jnp.full((w, _LANES), at % _LANES, _I32), axis=1), 0.0)
+
+        def update(r, carry):                    # columns 8r .. 8r + 7
+            u = u_ref[pl.ds(pl.multiple_of(r * 8, 8), 8), :]
+            for i in range(8):
+                k = column(r * 8 + i)
+                t_ref[k, :] = t_ref[k, :] - u[i:i + 1] * mult
+            return carry
+
+        # eight columns an iteration: 0.2-0.3 us a step less than one
+        # (PERF.md section 6, PR 45)
+        return jax.lax.fori_loop((j + 1) // 8, w // 8, update, carry)
+
+    jax.lax.fori_loop(0, w, step, 0)
+
+    def out(b, carry):
+        lu_ref[pl.ds(pl.multiple_of(b * _LANES, _LANES), _LANES), :] = \
+            t_ref[pl.ds(b, w, stride=nb8), :].T
+        return carry
+
+    jax.lax.fori_loop(0, rows // _LANES, out, 0)
+    _interchange(lu_ref, piv_ref)
 
 
 def _lu_panel_takes(rows: int, w: int, dtype) -> bool:
     """The shapes the VMEM panel is built for, read off the stack."""
-    return (dtype == _F32 and w % _LANES == 0 and rows % _LANES == 0
-            and rows <= _PACK and rows * w * 4 <= _PANEL_BYTES)
+    if not (dtype == _F32 and w % _LANES == 0 and rows % _LANES == 0):
+        return False
+    if _lu_panel_tall(rows):
+        return rows <= _lu_panel_height(w)
+    return rows * w * 4 <= _PANEL_BYTES
+
+
+def _lu_panel_tall(rows: int) -> bool:
+    """Does a stack of ``rows`` take the tall kernel (positions and rows
+    over what the packed search holds)?"""
+    return rows > _PACK
+
+
+def _lu_panel_height(w: int) -> int:
+    """The height the tall kernel factors every tall stack at: the
+    panel's cap, 32768 rows at 128 columns (one kernel a process, not one
+    a panel height), whole (8, 128) vregs of blocks: a multiple of 1024
+    rows, so that no partial block is left out of the kernel's copies."""
+    return _TALL_BYTES // (4 * w) // (8 * _LANES) * (8 * _LANES)
 
 
 def _lu_panel(stack):
@@ -934,9 +1071,52 @@ def _lu_panel(stack):
     ``piv[j]`` were exchanged, ``piv[j]`` the lowest index among the
     entries of largest magnitude in column j from row j on. Interpreted
     iff the run was started on the CPU platform
-    (``ops/flash_attention.py``'s rule)."""
+    (``ops/flash_attention.py``'s rule).
+
+    A tall stack is factored at the panel's cap (32768 rows at 128
+    columns) with zero rows under it (a zero never wins a search, so the
+    factors and the interchanges are the stack's): one kernel, not one a
+    height, and on the chip it is lowered once a process
+    (:func:`_lu_panel_exported`). Every program that factors a tall
+    stack would else lower its own kernel, 14 of dgetrf_1d's 16 panel
+    programs, on the warm step's critical path (PERF.md section 6, PR
+    45)."""
     from ..utils.jax_platform import cpu_requested
-    return _lu_panel_call(stack, cpu_requested())
+    interpret = cpu_requested()
+    rows, w = stack.shape
+    if not _lu_panel_tall(rows):
+        return _lu_panel_call(stack, interpret)
+    height = _lu_panel_height(w)
+    padded = jnp.pad(stack, ((0, height - rows), (0, 0)))
+    lu, piv = _lu_panel_exported(height, w, interpret)(padded)
+    return lu[:rows], piv
+
+
+@functools.lru_cache(maxsize=None)
+def _lu_panel_exported(rows: int, w: int, interpret: bool):
+    """The tall kernel for a (rows, w) stack, traced and lowered once a
+    process (``jax.export``): a program that calls it takes the module
+    as it is, 8 ms of lowering on this sandbox's host where lowering the
+    kernel anew takes 40. Interpreted, it is exported for the CPU, so
+    that the tests go the chip's way. An exported module has no batching
+    rule, so a batch of stacks (``tstrf_tile``'s stacked form) goes one
+    at a time."""
+    from jax import export
+    module = export.export(
+        jax.jit(lambda s: _lu_panel_call(s, interpret)),
+        platforms=("cpu",) if interpret else ("tpu",))(
+        jax.ShapeDtypeStruct((rows, w), _F32))
+
+    @jax.custom_batching.custom_vmap
+    def call(stack):
+        return module.call(stack)
+
+    @call.def_vmap          # reached only with the stacks batched
+    def _one_at_a_time(axis_size, in_batched, stacks):
+        outs = [module.call(stacks[i]) for i in range(axis_size)]
+        return tuple(jnp.stack(x) for x in zip(*outs)), (True, True)
+
+    return call
 
 
 # jitted, so that a TSTRF's sixteen blocks are one kernel traced and
@@ -945,17 +1125,26 @@ def _lu_panel(stack):
 def _lu_panel_call(stack, interpret: bool):
     pl, pltpu = _pallas()
     rows, w = stack.shape
-    nblk = rows // _LANES
+    if _lu_panel_tall(rows):
+        nb8 = -(-rows // (8 * _LANES)) * 8
+        kernel, scratch = _lu_panel_tall_kernel, [
+            pltpu.VMEM((w * nb8, _LANES), _F32),
+            pltpu.VMEM((w, _LANES), _F32),
+            pltpu.VMEM((nb8, _LANES), _F32), pltpu.VMEM((nb8, _LANES), _F32)]
+    else:
+        nblk = rows // _LANES
+        kernel, scratch = _lu_panel_kernel, [
+            pltpu.VMEM((nblk, w, _LANES), _F32),
+            pltpu.VMEM((w, _LANES), _F32),
+            pltpu.VMEM((nblk, 8, _LANES), _I32)]
     lu, piv = pl.pallas_call(
-        _lu_panel_kernel,
+        kernel,
         out_shape=(jax.ShapeDtypeStruct((rows, w), _F32),
                    jax.ShapeDtypeStruct((1, w), _I32)),
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.SMEM)),
-        scratch_shapes=[pltpu.VMEM((nblk, w, _LANES), _F32),
-                        pltpu.VMEM((w, _LANES), _F32),
-                        pltpu.VMEM((nblk, 8, _LANES), _I32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=3 * rows * w * 4 + (8 << 20)),
         interpret=interpret, name="parsec_lu_panel")(stack)
@@ -966,7 +1155,8 @@ def _block_lu(stack):
     """A block's stack factored: the VMEM panel where it takes the
     shape, XLA's LU elsewhere; (factors, interchanges)."""
     if _lu_panel_takes(*stack.shape, stack.dtype):
-        LU_BLOCKS_TRACED["vmem_panel"] += 1
+        LU_BLOCKS_TRACED["vmem_panel_tall" if _lu_panel_tall(stack.shape[0])
+                         else "vmem_panel"] += 1
         return _lu_panel(stack)
     LU_BLOCKS_TRACED["xla_lu"] += 1
     lu, piv, _ = jax.lax.linalg.lu(stack)
@@ -1050,9 +1240,12 @@ def ssssm_tile(A1, A2, W, L21, P):
 # XLA's ``LuDecompositionBlock`` factors a stack in scoped VMEM and takes
 # two to three times the stack's bytes of it: 48 MiB for the 32768 x 128
 # of a 16-tile panel, where the compiler's default allows 16 (a v5e core
-# has 128 MiB). A program that factors such a stack is compiled with this
+# has 128 MiB). A panel's program is compiled with this
 # (``Chore.compiler_options``; tests/test_panel_partition.py compiles the
-# tallest for a described v5e).
+# tallest for a described v5e): since PR 45 the VMEM panel factors the
+# cell's stacks (its Mosaic call states its own VMEM), and XLA's kernel
+# is reached by a block that is not a multiple of 128 wide or a stack
+# over the panel's 16 MiB.
 PANEL_COMPILER_OPTIONS = {"xla_tpu_scoped_vmem_limit_kib": 65536}
 
 
@@ -1101,7 +1294,9 @@ def getrf_panel_tiles(tiles, ib: int):
     1 in size, under it and in the others; LAPACK's interchange indices,
     1 x nb int32, 0-based from the stack's first row). A block's
     pivoted factorization is :func:`_block_lu`'s: the VMEM panel where
-    the stack fits it, XLA's ``lax.linalg.lu`` on the tall ones.
+    ib is a multiple of 128 and the stack holds 16 MiB at most (its tall
+    kernel over 4096 rows: a 16-tile panel's 32768 x 128), XLA's
+    ``lax.linalg.lu`` elsewhere.
 
     The inner blocks are ONE rolled loop whose body masks what the
     blocks before have finished (the block column is handed to the

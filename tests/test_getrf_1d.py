@@ -140,15 +140,19 @@ def test_the_interchanges_as_row_moves_against_the_loop(kind):
 
 @pytest.mark.parametrize("r,nb,ib", [(1, 16, 4), (3, 16, 4), (4, 32, 8),
                                      (2, NB, IB), (1, 128, 128),
-                                     (2, 128, 128)])
+                                     (2, 128, 128), (3, 2048, 128)])
 def test_the_panel_kernel_is_lapacks_getrf_of_the_stack(r, nb, ib):
     """IPIV entry for entry, L\\U to rounding; ib = 128 goes through the
-    VMEM panel (interpreted here), the others through XLA's LU."""
+    VMEM panel (interpreted here; three 2048-tiles, a 6144-row stack,
+    through its tall kernel), the others through XLA's LU."""
     import jax
     import jax.numpy as jnp
+    from parsec_tpu.ops.tile_kernels import LU_BLOCKS_TRACED
     a = _seeded((r * nb, nb), 10 * r + nb)
     tiles = [jnp.asarray(a[t * nb:(t + 1) * nb]) for t in range(r)]
+    tall = LU_BLOCKS_TRACED["vmem_panel_tall"]
     out, ipiv = jax.jit(lambda ts: getrf_panel_tiles(ts, ib))(tiles)
+    assert (LU_BLOCKS_TRACED["vmem_panel_tall"] > tall) == (r * nb > 4096)
     lu, piv = scipy.linalg.lu_factor(a.astype(np.float64))
     assert ipiv.shape == (1, nb) and ipiv.dtype == jnp.int32
     assert (np.asarray(ipiv)[0] == piv).all()

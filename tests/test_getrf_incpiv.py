@@ -178,16 +178,20 @@ def _xla_lu(stack):
 
 
 @pytest.mark.parametrize("seed", [21, 22, 23])
-@pytest.mark.parametrize("nb", [128, 256])
-def test_the_vmem_panel_against_xlas_lu(nb, seed):
+@pytest.mark.parametrize("nb,ib", [(128, 128), (256, 128), (4096, 128),
+                                   (8064, 128), (4224, 384)])
+def test_the_vmem_panel_against_xlas_lu(nb, ib, seed):
     """The same interchanges, no multiplier over 1, and factors as close
     to the float64 elimination with those interchanges as XLA's are (a
     different order of the same float32 operations: on the CPU, LAPACK's
-    blocked one; 128 steps leave both some 30 ulp from it)."""
-    stack = _stack(nb, seed)
+    blocked one; 128 steps leave both some 30 ulp from it, 384 some
+    80); 4224 and 8192 rows take the tall kernel, and 4608 x 384 too,
+    factored at 10240 rows (its cap's 10922 cut to whole vregs of
+    blocks)."""
+    stack = _stack(nb, seed, ib)
     lu, piv = (np.asarray(x) for x in _lu_panel(stack))
     xla, xla_piv = _xla_lu(stack)
-    assert piv.dtype == np.int32 and piv.shape == (128,)
+    assert piv.dtype == np.int32 and piv.shape == (ib,)
     np.testing.assert_array_equal(piv, xla_piv)
     assert np.abs(np.tril(lu, -1)).max() <= 1.0
     exact = stack.astype(np.float64)
@@ -199,14 +203,17 @@ def test_the_vmem_panel_against_xlas_lu(nb, seed):
     scale = np.maximum(np.abs(exact).max(axis=1, keepdims=True), 1.0)
     ulp = np.finfo(np.float32).eps
     off, xla_off = ((np.abs(x - exact) / scale).max() for x in (lu, xla))
-    assert off <= max(2 * xla_off, 8 * ulp) and off <= 64 * ulp
+    # the rounding grows with the steps: 64 ulp a 128 of them
+    assert off <= max(2 * xla_off, 8 * ulp) and off <= ib // 2 * ulp
 
 
-def test_the_vmem_panel_takes_row_j_where_nothing_under_it_is_larger():
+@pytest.mark.parametrize("nb", [128, 4096])
+def test_the_vmem_panel_takes_row_j_where_nothing_under_it_is_larger(nb):
     """A column that is all zero under U's diagonal gives index j (no
     interchange): with a diagonal entry, and with a zero there too (a
-    column of zeros keeps its zeros and makes no NaN)."""
-    stack = _stack(128, 24)
+    column of zeros keeps its zeros and makes no NaN); in both kernels
+    (4224 rows: the tall one)."""
+    stack = _stack(nb, 24)
     # nothing over U's diagonal either, so that no update fills the column
     stack[:5, 5] = stack[128:, 5] = 0.0       # U(5,5) alone
     stack[:10, 9] = stack[128:, 9] = 0.0      # nothing at all
@@ -242,13 +249,46 @@ def test_the_vmem_panel_takes_the_lowest_index_among_equals():
     want, want_piv = _xla_lu(stack)
     assert list(piv[:2]) == [130, 128]
     np.testing.assert_array_equal(piv, want_piv)
+    # the tall kernel (4224 rows), the same tie beyond position 4095:
+    # row 0, moved to position 4100 by step 0, against row 4000 at
+    # position 4000; position 4000 wins, where the lowest row would be
+    # 0 and positions cut to 12 bits would compare 4 with 4000
+    stack = _stack(4096, 27)
+    stack[:, 0] = 0.0
+    stack[4100, 0] = 0.75
+    stack[:, 1] = 0.0
+    stack[[0, 4000], 1] = [0.5, -0.5]
+    assert tile_kernels._lu_panel_tall(stack.shape[0])
+    lu, piv = (np.asarray(x) for x in _lu_panel(stack))
+    want, want_piv = _xla_lu(stack)
+    assert list(piv[:2]) == [4100, 4000]
+    np.testing.assert_array_equal(piv, want_piv)
+    assert lu[1, 1] == -0.5
+
+
+def test_the_vmem_panel_vmapped_over_tall_stacks_takes_one_at_a_time():
+    """A batch of tall stacks (``tstrf_tile``'s stacked form at tiles of
+    4096) goes through the exported kernel's batching rule, as on the
+    chip: each stack's factors and interchanges are XLA's, in order."""
+    import jax
+    stacks = np.stack([_stack(4096, 28), _stack(4096, 29)])
+    before = tile_kernels._lu_panel_exported.cache_info()
+    lu, piv = (np.asarray(x) for x in jax.vmap(_lu_panel)(stacks))
+    after = tile_kernels._lu_panel_exported.cache_info()
+    assert after.hits + after.misses > before.hits + before.misses
+    assert lu.shape == stacks.shape and piv.shape == (2, 128)
+    for i, stack in enumerate(stacks):
+        want, want_piv = _xla_lu(stack)
+        np.testing.assert_array_equal(piv[i], want_piv)
+        np.testing.assert_allclose(lu[i], want, atol=1e-5)
 
 
 def test_the_shape_says_which_factorization_a_block_is_traced_through(
         make_ctx):
     """ib = 16 (these tests', the dry runs'): XLA's LU; float32 with ib a
-    multiple of 128: the VMEM panel; a block a count, where the chip
-    module's counters are."""
+    multiple of 128: the VMEM panel, its tall kernel over 4096 rows, up
+    to a 32768 x 128 stack's 16 MiB (the height it factors every tall
+    stack at); a block a count, where the chip module's counters are."""
     import jax
     import jax.numpy as jnp
     traced = tile_kernels.LU_BLOCKS_TRACED
@@ -259,19 +299,44 @@ def test_the_shape_says_which_factorization_a_block_is_traced_through(
         jax.eval_shape(lambda u, a: tstrf_tile(u, a, ib), t, t)
         return {k: traced[k] - before[k] for k in traced}
 
-    assert trace(64, 16) == {"vmem_panel": 0, "xla_lu": 4}
-    assert trace(256, 128) == {"vmem_panel": 2, "xla_lu": 0}
-    assert trace(256, 256) == {"vmem_panel": 1, "xla_lu": 0}
-    assert trace(128, 64) == {"vmem_panel": 0, "xla_lu": 2}
-    assert tile_kernels._lu_panel_takes(2176, 128, jnp.float32)
-    assert not tile_kernels._lu_panel_takes(2176, 128, jnp.bfloat16)
-    assert not tile_kernels._lu_panel_takes(4096 + 128, 128, jnp.float32)
-    assert not tile_kernels._lu_panel_takes(4096, 1024, jnp.float32)
+    def counts(packed, tall, xla):
+        return {"vmem_panel": packed, "vmem_panel_tall": tall,
+                "xla_lu": xla}
+
+    assert trace(64, 16) == counts(0, 0, 4)
+    assert trace(256, 128) == counts(2, 0, 0)
+    assert trace(256, 256) == counts(1, 0, 0)
+    assert trace(128, 64) == counts(0, 0, 2)
+    assert trace(4096, 128) == counts(0, 32, 0)      # 4224-row stacks
+    takes = tile_kernels._lu_panel_takes
+    assert takes(2176, 128, jnp.float32)
+    assert not takes(2176, 128, jnp.bfloat16)
+    assert takes(4096, 128, jnp.float32)
+    assert not tile_kernels._lu_panel_tall(4096)
+    assert takes(4096 + 128, 128, jnp.float32)
+    assert tile_kernels._lu_panel_tall(4096 + 128)
+    assert takes(32768, 128, jnp.float32)
+    assert not takes(32768 + 128, 128, jnp.float32)
+    assert not takes(4096, 2048, jnp.float32)
+    # the packed kernel keeps PR 41's 8 MiB
+    assert takes(2048, 1024, jnp.float32)
+    assert not takes(4096, 1024, jnp.float32)
+    # every tall stack is factored at the cap's height, a multiple of
+    # 1024 rows (whole vregs of blocks) under 16 MiB
+    assert tile_kernels._lu_panel_height(128) == 32768
+    assert tile_kernels._lu_panel_height(256) == 16384
+    assert tile_kernels._lu_panel_height(384) == 10240
+    assert takes(10240, 384, jnp.float32)
+    assert not takes(10240 + 128, 384, jnp.float32)
+    assert tile_kernels._lu_panel_height(896) == 4096
+    assert not takes(4096 + 128, 896, jnp.float32)
     ctx = make_ctx("tpu", nb_cores=1)
     stats = _chip(ctx).dump_statistics()
     assert stats["lu_blocks_vmem_panel"] == traced["vmem_panel"] >= 3
+    assert stats["lu_blocks_vmem_panel_tall"] == \
+        traced["vmem_panel_tall"] >= 32
     assert stats["lu_blocks_xla_lu"] == traced["xla_lu"] >= 6
-    assert any("lu_blocks_vmem_panel" in d
+    assert any("lu_blocks_vmem_panel_tall" in d
                for d in ctx.statusz()["devices"])
 
 

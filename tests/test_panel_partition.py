@@ -391,17 +391,19 @@ def test_on_the_chip_the_shard_stays_put_and_a_send_runs_under_a_product(
         assert mem.generated_code_size_in_bytes < text_bytes
 
 
-@pytest.mark.parametrize("rows,w", [(2176, 128), (2560, 512)])
+@pytest.mark.parametrize("rows,w", [(2176, 128), (2560, 512),
+                                    (32768, 128)])
 def test_on_the_chip_a_tstrf_blocks_stack_is_factored_by_one_mosaic_call(
         v5e_2x2, monkeypatch, rows, w):
     """``ops/tile_kernels.py _lu_panel`` at the LU cell's shape (a 2048
-    tile under a block of IB = 128) and at the widest block of
-    ``chip_smoke.py``'s IB sweep, compiled by the TPU compiler (this file
-    holds the described chip, so the kernel's compile is kept here):
-    Mosaic takes the transposes, the reductions across lanes, the lane
-    gather and the dynamic slices as written, the stack, its output and
-    the transposed copy fit the VMEM the call asks for, and the program
-    is that one call: no ``LuDecompositionBlock``, no HBM temporary."""
+    tile under a block of IB = 128), at the widest block of
+    ``chip_smoke.py``'s IB sweep and at a 16-tile panel's stack (the tall
+    kernel), compiled by the TPU compiler (this file holds the described
+    chip, so the kernel's compile is kept here): Mosaic takes the
+    transposes, the reductions across lanes, the lane gather and the
+    dynamic and strided slices as written, the stack, its output and the
+    transposed copy fit the VMEM the call asks for, and the program is
+    that one call: no ``LuDecompositionBlock``, no HBM temporary."""
     from jax.sharding import SingleDeviceSharding
     from parsec_tpu.ops import tile_kernels
     from parsec_tpu.utils import jax_platform
@@ -417,19 +419,78 @@ def test_on_the_chip_a_tstrf_blocks_stack_is_factored_by_one_mosaic_call(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_on_the_chip_a_tall_stack_is_factored_at_the_caps_height(
+        v5e_2x2, monkeypatch):
+    """A tall stack under the cap is factored at 32768 rows with zero
+    rows under it, by a kernel exported once a process: two stacks of
+    different heights lower the kernel once between them, and each
+    program is one Mosaic call and no ``LuDecomposition``."""
+    from jax.sharding import SingleDeviceSharding
+    from parsec_tpu.ops import tile_kernels
+    from parsec_tpu.utils import jax_platform
+    monkeypatch.setattr(jax_platform, "cpu_requested", lambda: False)
+    one = SingleDeviceSharding(v5e_2x2.devices[0])
+    exported = tile_kernels._lu_panel_exported
+    before = exported.cache_info()
+    for rows in (6144, 12288):
+        text = jax.jit(tile_kernels._lu_panel).lower(jax.ShapeDtypeStruct(
+            (rows, 128), np.float32, sharding=one)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert "LuDecomposition" not in text
+    after = exported.cache_info()
+    assert after.hits >= before.hits + 1
+    assert after.misses <= before.misses + 1
+    # TSTRF's stacked form vmaps the kernel: a stack at a time
+    text = jax.jit(jax.vmap(tile_kernels._lu_panel)).lower(
+        jax.ShapeDtypeStruct((2, 6144, 128), np.float32,
+                             sharding=one)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+# sha256 of the text ``jax.jit(_lu_panel)`` is lowered to at 2176 x 128
+# for a described v5e, source locations left out, as the parent of PR 45
+# (291a4c3, PR 41's kernel) lowers it: the incpiv cell's TSTRF blocks
+_PACKED_PANEL_2176_SHA256 = (
+    "bc94b5244b584a3eb645d180bfaa205479aee7ac66dcc272d6e0dc0035b48d56")
+
+
+def test_on_the_chip_a_tstrf_blocks_kernel_is_lowered_as_pr_41_built_it(
+        v5e_2x2, monkeypatch):
+    """Stacks of 4096 rows and under keep the packed search of PR 41's
+    kernel, byte for byte: the tall kernel is a kernel beside it, taken
+    from the stack's shape alone. Locations are left out of the text
+    (they are line numbers of this repo's files) and the lowering caches
+    are cleared, so that an earlier lowering with them is not reused."""
+    import hashlib
+
+    from jax._src import config
+    from jax.sharding import SingleDeviceSharding
+    from parsec_tpu.ops import tile_kernels
+    from parsec_tpu.utils import jax_platform
+    monkeypatch.setattr(jax_platform, "cpu_requested", lambda: False)
+    jax.clear_caches()
+    with config.traceback_in_locations_limit(0):
+        text = jax.jit(tile_kernels._lu_panel).lower(jax.ShapeDtypeStruct(
+            (2176, 128), np.float32,
+            sharding=SingleDeviceSharding(v5e_2x2.devices[0]))).as_text()
+    jax.clear_caches()
+    assert not tile_kernels._lu_panel_tall(2176)
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        _PACKED_PANEL_2176_SHA256
+
+
 def test_on_the_chip_a_sixteen_tile_panel_is_factored_where_it_lies(
         v5e_2x2, monkeypatch):
     """``ops/tile_kernels.py getrf_panel_tiles`` at the panel-pivoted LU
     cell's tallest shape (sixteen 2048-tiles, IB = 128), compiled by the
-    TPU compiler: XLA's ``LuDecompositionBlock`` factors the 32768 x 128
-    block in scoped VMEM and is REFUSED at the compiler's default of 16
-    MiB ("Ran out of memory in memory space vmem ... size 32.13M and
-    limit 16.00M"; two minutes of a test to see it refused, so not
-    compiled here), which is why the panel's chore states what it needs
-    (``Chore.compiler_options`` = ``PANEL_COMPILER_OPTIONS``); with it
-    the program is one rolled loop (under 16 MiB of text, one such
-    program a list length), holds one copy of the stack beside the tiles
-    and writes every tile where it was given (all 256 MiB aliased)."""
+    TPU compiler as the panel's chore has it compiled
+    (``Chore.compiler_options`` = ``PANEL_COMPILER_OPTIONS``: the scoped
+    VMEM XLA's ``LuDecompositionBlock`` needed for the 32768 x 128 block
+    until PR 45): the block is factored by the tall VMEM panel
+    (``parsec_lu_panel``), not by XLA's kernel; the program is one rolled
+    loop (under 16 MiB of text, one such program a list length), holds
+    one copy of the stack beside the tiles and writes every tile where it
+    was given (all 256 MiB aliased)."""
     from jax.sharding import SingleDeviceSharding
     from parsec_tpu.ops import tile_kernels
     from parsec_tpu.utils import jax_platform
@@ -441,7 +502,8 @@ def test_on_the_chip_a_sixteen_tile_panel_is_factored_where_it_lies(
         lambda ts: tile_kernels.getrf_panel_tiles(ts, 128),
         donate_argnums=0).lower(tiles).compile(
             compiler_options=tile_kernels.PANEL_COMPILER_OPTIONS)
-    assert "LuDecomposition" in compiled.as_text()
+    text = compiled.as_text()
+    assert "parsec_lu_panel" in text and "LuDecomposition" not in text
     mem = compiled.memory_analysis()
     stack = 16 * 2048 * 2048 * 4
     assert mem.alias_size_in_bytes == stack
