@@ -238,6 +238,11 @@ class TPUDevice(Device):
         # seconds the host spent making copies: always on
         self.stats.update(remote_copies=0, remote_bytes_in=0,
                           remote_hits=0, remote_copy_s=0.0)
+        # the programs this module built, by where each came from: loaded
+        # from the executor store, or traced and lowered in this process
+        # (a program another module or context of the process had is
+        # neither): always on
+        self.stats.update(programs_loaded=0, programs_traced=0)
         debug_verbose(3, "device", "TPU device on %s (%s)",
                       self.jax_device, self.platform)
 
@@ -895,6 +900,20 @@ class TPUDevice(Device):
         sizes = self._sizes(zip((f.name for f in flows), values), chore)
         if not stacked:
             sizes = [1] if hook is not None else sizes + [1]
+        # a shared program goes through the executor store where a
+        # program loaded from it runs: a compiled executable is bound to
+        # the device it was compiled for, and libtpu reloads a one-device
+        # program onto the backend's first chip. So this module is the
+        # Registry's one chip module, on that chip; with several, every
+        # module shares one ``jax.jit`` program in-process. Its key adds
+        # the program's name (which a device trace reads), the chip and
+        # the body's own file where it lies outside the package
+        chips = self.registry.chips if self.registry is not None else ()
+        stored = shared is not None and len(chips) == 1 and \
+            self.jax_device == jax.devices()[0] and \
+            compile_cache.persistent_store() is not None
+        if stored:
+            source = compile_cache.source_digest(hook if stacked else body)
         programs, one = {}, None
         with jax.default_device(self.jax_device):
             for size in sizes:
@@ -903,21 +922,39 @@ class TPUDevice(Device):
                     fn = marked(fn)
                 # the program's name is what a device trace keeps of a
                 # launch (its "XLA Modules" line: jit_parsec_<class>_x<n>)
-                fn.__name__ = fn.__qualname__ = "parsec_%s_x%d" % (
+                fn.__name__ = fn.__qualname__ = name = "parsec_%s_x%d" % (
                     re.sub(r"\W", "_", getattr(task.task_class, "name",
                                                 "task")), size)
                 def jit(f, gives=donated(size)):
                     return jax.jit(f, donate_argnums=gives,
                                    compiler_options=options)
-                fn = jit(fn) if shared is None else compile_cache.cached_jit(
-                    fn, key=(*shared, size), persist=False, jit_wrapper=jit)
+                if shared is None:
+                    fn, origin = jit(fn), "traced"
+                elif stored:
+                    one = one or self._flat([values])
+                    fn, origin = compile_cache.cached_program(
+                        fn, key=(*shared, size, name, self.jax_device.id,
+                                 source),
+                        example_args=[jax.ShapeDtypeStruct(
+                            x.shape, x.dtype, weak_type=x.weak_type)
+                            for x in one * size], jit_wrapper=jit)
+                else:
+                    fn, origin = compile_cache.cached_program(
+                        fn, key=(*shared, size), persist=False,
+                        jit_wrapper=jit)
+                with self._lock:
+                    if origin == "store":
+                        self.stats["programs_loaded"] += 1
+                    elif origin == "traced":
+                        self.stats["programs_traced"] += 1
                 # a shared program this module has yet to run compiles
-                # for its chip now (an unshared one is new), and its
-                # first run says what a launch of it holds anew: the
-                # outputs that lie in no buffer it was given. Read off
-                # the program, not off the declaration: a stacked form's
-                # results are slices of one product, and whether they
-                # come back in the members' own buffers is XLA's doing
+                # for its chip now (an unshared one is new; a stored one
+                # is compiled or loaded), and its first run says what a
+                # launch of it holds anew: the outputs that lie in no
+                # buffer it was given. Read off the program, not off the
+                # declaration: a stacked form's results are slices of
+                # one product, and whether they come back in the
+                # members' own buffers is XLA's doing
                 if shared is None or (shared, size) not in self._warmed \
                         or not hasattr(fn, "held"):
                     self._warmed.add((shared, size))

@@ -16,9 +16,10 @@ economics with three layers:
    identity, so every fresh wrapper used to pay a full re-trace).
 2. **Persistent executor store** (:class:`ExecutorStore`): AOT
    ``lower() -> compile() -> serialize_executable`` keyed by a
-   :func:`lowering_fingerprint` covering the parsec_tpu version salt,
-   jax/jaxlib versions, device kind/count, and the caller's key parts
-   (NB, dtype, bucket shape, body hooks, mesh/sharding). A cache hit
+   :func:`lowering_fingerprint` covering a digest of the package's
+   source (:func:`package_digest`), jax/jaxlib versions, device
+   kind/count, and the caller's key parts (NB, dtype, bucket shape,
+   body hooks, mesh/sharding). A cache hit
    skips tracing AND lowering AND XLA — the second *process* to serve a
    bucket pays only deserialization. (The XLA persistent cache, by
    contrast, must re-trace and re-lower the whole program just to
@@ -179,10 +180,10 @@ def function_fingerprint(fn: Callable) -> Tuple[bool, str]:
     so unstable fingerprints must stay in per-instance caches.
 
     Deliberately NOT covered: the code of *global* functions the body
-    calls by name (only the name is hashed) — repo-level changes are
-    covered by the parsec version salt in :func:`lowering_fingerprint`,
-    and runtime-variant behavior must go through registered trace
-    knobs."""
+    calls by name (only the name is hashed) — the package's are covered
+    by its source digest in :func:`lowering_fingerprint`, a body's own
+    file outside the package by :func:`source_digest`, and
+    runtime-variant behavior must go through registered trace knobs."""
     import numpy as np
 
     parts = []
@@ -304,15 +305,95 @@ def _device_signature() -> Tuple:
     return (d.platform, getattr(d, "device_kind", "?"), len(devs))
 
 
+# the package's root, and the suffixes of the sources it is built from:
+# its Python modules and the native engine g++ builds from them
+_PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SOURCES = (".py", ".cpp", ".cc", ".h")
+
+
+@functools.lru_cache(maxsize=None)
+def _tree_digest(root: str) -> str:
+    """sha256 over every source file under ``root``, by its path under
+    ``root`` and its bytes: a copy of the tree elsewhere digests the
+    same, one byte changed anywhere digests otherwise."""
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = sorted(d for d in dirnames
+                             if d not in ("__pycache__", "build"))
+        for name in sorted(filenames):
+            if name.endswith(_SOURCES):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode() + b"\x00")
+                with open(path, "rb") as fh:
+                    h.update(hashlib.sha256(fh.read()).digest())
+    return h.hexdigest()
+
+
+def package_digest() -> str:
+    """The digest of the package's source, computed once a process: a
+    program the store keeps is the program of this code, whatever the
+    body calls by name (:func:`function_fingerprint`) or a lowering reads
+    from a module constant."""
+    return _tree_digest(_PACKAGE)
+
+
+@functools.lru_cache(maxsize=None)
+def _file_digest(path: str) -> str:
+    with open(path, "rb") as fh:
+        return hashlib.sha256(fh.read()).hexdigest()
+
+
+@functools.lru_cache(maxsize=None)
+def _installed_roots() -> Tuple[str, ...]:
+    import sysconfig
+    paths = sysconfig.get_paths()
+    return tuple(os.path.abspath(paths[k]) + os.sep
+                 for k in ("stdlib", "platstdlib", "purelib", "platlib")
+                 if k in paths)
+
+
+def source_digest(fn: Callable) -> str:
+    """Digest of the files that define ``fn`` where they lie outside the
+    package and outside the installation's libraries (a test's or a
+    user's module): ``fn`` itself, through partials, and the functions
+    the package's own wrappers close over (a DTD body's woven function
+    holds the user's). ``""`` where every one is the package's, whose
+    code :func:`package_digest` covers."""
+    files, seen = set(), set()
+    installed = _installed_roots()
+    todo = [fn]
+    while todo:
+        f = todo.pop()
+        if id(f) in seen:
+            continue
+        seen.add(id(f))
+        if isinstance(f, functools.partial):
+            todo.append(f.func)
+            continue
+        code = getattr(f, "__code__", None)
+        path = os.path.abspath(getattr(code, "co_filename", "") or "")
+        if path.startswith(_PACKAGE + os.sep):
+            # a wrapper of the package's: look at what it closes over
+            for cell in getattr(f, "__closure__", None) or ():
+                try:
+                    todo.append(cell.cell_contents)
+                except ValueError:          # empty cell
+                    pass
+        elif os.path.isfile(path) and not path.startswith(installed):
+            files.add(path)
+    return "".join(_file_digest(p) for p in sorted(files))
+
+
 def lowering_fingerprint(*key_parts: Any) -> str:
     """sha256 digest over the standard fingerprint fields + the
-    caller's key parts. Standard fields: parsec_tpu version (+
-    ``jit.cache_salt``), jax/jaxlib versions, backend device
-    kind/count, and the registered trace-knob snapshot."""
+    caller's key parts. Standard fields: parsec_tpu version and the
+    digest of its source (+ ``jit.cache_salt``), jax/jaxlib versions,
+    backend device kind/count, and the registered trace-knob
+    snapshot."""
     import jax
     import jaxlib
     from ..version import __version__
-    out: list = [f"schema{_SCHEMA}", __version__,
+    out: list = [f"schema{_SCHEMA}", __version__, package_digest(),
                  str(mca_param.get("jit.cache_salt", "")),
                  jax.__version__, jaxlib.__version__,
                  repr(_device_signature())]
@@ -428,8 +509,7 @@ _STORE_LOCK = threading.Lock()
 
 
 def _default_dir() -> str:
-    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), ".xla_cache")
+    return os.path.join(os.path.dirname(_PACKAGE), ".xla_cache")
 
 
 def _resolve_dir(path: Optional[str] = None) -> Optional[str]:
@@ -529,10 +609,15 @@ def jit_store_size() -> int:
         return len(_JIT_STORE)
 
 
-def cached_jit(fn: Callable, *, key: Tuple, example_args: Tuple = None,
-               donate_argnums=(), static_argnums=(),
-               jit_wrapper: Callable = None,
-               persist: bool = True) -> Callable:
+def persistent_store() -> Optional[ExecutorStore]:
+    """The store a program with ``persist=True`` goes to: the active one
+    (:func:`executor_store`) unless ``jit.persist_executors`` is 0."""
+    if not int(mca_param.get("jit.persist_executors", 1)):
+        return None
+    return executor_store()
+
+
+def cached_jit(fn: Callable, **kwargs) -> Callable:
     """The compiled path's jit entry point: a callable shared in-process
     by semantic ``key`` and (when the store is enabled and
     ``example_args`` abstract shapes are given) AOT-compiled +
@@ -551,29 +636,41 @@ def cached_jit(fn: Callable, *, key: Tuple, example_args: Tuple = None,
     ``jit_wrapper`` overrides ``jax.jit`` construction (the pjit front
     end passes shardings through it). Keys MUST cover everything that
     changes the trace: the caller's code fingerprints, shapes/dtypes,
-    bucket sizes — the standard fields (versions, device, trace knobs,
-    salt) are added by :func:`lowering_fingerprint`.
+    bucket sizes — the standard fields (versions, source, device, trace
+    knobs, salt) are added by :func:`lowering_fingerprint`. Keywords
+    as :func:`cached_program`'s.
     """
+    return cached_program(fn, **kwargs)[0]
+
+
+def cached_program(fn: Callable, *, key: Tuple, example_args: Tuple = None,
+                   donate_argnums=(), static_argnums=(),
+                   jit_wrapper: Callable = None,
+                   persist: bool = True) -> Tuple[Callable, str]:
+    """:func:`cached_jit`'s callable and where it came from:
+    ``"process"`` (the in-process store: nothing traced now),
+    ``"store"`` (deserialized: nothing traced or lowered) or
+    ``"traced"`` (traced and lowered in this process, AOT here or, for a
+    plain ``jax.jit`` wrapper, at its first call)."""
     digest = lowering_fingerprint(*key)
     with _JIT_LOCK:
         hit = _JIT_STORE.get(digest)
     if hit is not None:
         with _CNT_LOCK:
             _counters["jit_store_hits"] += 1
-        return hit
+        return hit, "process"
     import jax
     if jit_wrapper is not None:
         jitted = jit_wrapper(fn)
     else:
         jitted = jax.jit(fn, donate_argnums=donate_argnums,
                          static_argnums=static_argnums)
-    result = jitted
-    store = executor_store() if (persist and int(
-        mca_param.get("jit.persist_executors", 1))) else None
+    result, origin = jitted, "traced"
+    store = persistent_store() if persist else None
     if example_args is not None:
         loaded = store.load(digest) if store is not None else None
         if loaded is not None:
-            result = loaded
+            result, origin = loaded, "store"
         else:
             # a failing AOT compile propagates: a plain-jit fallback
             # would only fail again at first call, minus the context
@@ -583,4 +680,4 @@ def cached_jit(fn: Callable, *, key: Tuple, example_args: Tuple = None,
                 _canon(key, out)
                 store.save(digest, result, "|".join(out))
     with _JIT_LOCK:
-        return _JIT_STORE.setdefault(digest, result)
+        return _JIT_STORE.setdefault(digest, result), origin
